@@ -11,8 +11,7 @@ evidence-citing rule engine (obs/postmortem.py) over it. Prints a
 confidence-ranked verdict with remediation and a last-minutes
 timeline; `--json` emits the contracted ``diagnosis`` record instead.
 `--out` additionally appends that record to a metrics JSONL sink (the
-supervisor and scripts/tpu_window.py use the library entry point
-directly).
+supervisor uses the library entry point directly).
 
     python -m pipegcn_tpu.cli.debug scrub <run-dir> [--json]
 

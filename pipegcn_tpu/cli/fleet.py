@@ -232,9 +232,6 @@ def _driver_main(args, argv) -> int:
             env.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count="
             f"{args.n_partitions}").strip()
-    env.setdefault("PIPEGCN_PLATFORM",
-                   os.environ.get("PIPEGCN_PLATFORM", "cpu"))
-    env.setdefault("JAX_PLATFORMS", env["PIPEGCN_PLATFORM"])
 
     manager = FleetManager(
         fleet_dir, args.replicas, child_args=list(argv), ml=ml,

@@ -186,9 +186,13 @@ def summarize_run(records: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
                 out["overlapped_comm_fraction"] = out["comm_fraction"]
         fl = summary.get("flops_per_epoch")
         base = out.get("epoch_time_s") or out.get("median_epoch_s")
-        peak = peak_flops_for(str(out.get("device") or ""))
+        platform = ((header or {}).get("device") or {}).get("platform")
         nd = out.get("n_devices") or 1
-        if isinstance(fl, (int, float)) and fl and base and peak:
+        if isinstance(fl, (int, float)) and fl and base \
+                and platform == "tpu":
+            # a utilization exists only for a chip run; a TPU kind that
+            # is not in the peaks table raises rather than guessing
+            peak = peak_flops_for(str(out.get("device")))
             out["mfu_pct"] = round(100.0 * fl / (base * peak * nd), 2)
 
     # ---- measured profiling window (obs/profiler.py) ----

@@ -149,14 +149,12 @@ def build_serving_engine(args, log=print):
     if args.model in ("gcn", "gat") and args.use_pp:
         raise ValueError("--use-pp is a GraphSAGE-only optimization")
 
-    import jax
-
-    plat = os.environ.get("PIPEGCN_PLATFORM")
-    if plat:
-        jax.config.update("jax_platforms", plat)
+    from ..backend import device_line, place_compile_cache
     from .main import _maybe_init_distributed
 
+    place_compile_cache()
     _maybe_init_distributed(args)
+    log(device_line())
 
     from ..models.sage import ModelConfig
     from ..parallel.trainer import TrainConfig, Trainer

@@ -7,16 +7,25 @@ equivalents, compiled on demand from the bundled C++ sources with the
 system toolchain (g++), no third-party deps.
 
 Loading policy: the first call to `get_lib()` compiles (if needed) and
-dlopens the shared library. Failures — no compiler, read-only install —
-degrade gracefully: callers check `available()` and fall back to the
-pure-numpy implementations. Set PIPEGCN_NATIVE=0 to force the fallback.
+dlopens the shared library. The library file is named by a hash of the
+bundled sources and the compiler flags, so only a build of exactly
+these sources is ever loaded: a stale library left in the tree (or
+copied along with it to another machine) has another name and is never
+opened. Failures (no compiler, a failed or timed-out build, a
+read-only install) are printed to stderr with their reason; callers
+check `available()` and fall back to the pure-numpy implementations,
+which produce a DIFFERENT layout (capped cluster count), so the
+measurement entry points (bench.py, chip_smoke.py) refuse to run
+without the native library. Set PIPEGCN_NATIVE=0 to force the fallback.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -24,34 +33,49 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ["partitioner.cpp", "halo_builder.cpp"]
-_LIB_NAME = "libpipegcn_native.so"
+_CXXFLAGS = ["-O3", "-std=c++17", "-fPIC", "-shared"]
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+# how the library came to be in this process: "loaded <path>",
+# "built in this run <path>", or "unavailable: <reason>"
+_status = "not requested yet"
+
+
+def lib_name() -> str:
+    """libpipegcn_native-<hash>.so, the hash over the source files and
+    the compiler flags."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    for s in _SOURCES:
+        with open(os.path.join(_DIR, s), "rb") as f:
+            h.update(s.encode())
+            h.update(f.read())
+    return f"libpipegcn_native-{h.hexdigest()[:16]}.so"
+
+
+def _fail(reason: str) -> None:
+    global _status
+    _status = f"unavailable: {reason}"
+    print(f"pipegcn_tpu.native {_status}", file=sys.stderr)
 
 
 def _build(lib_path: str) -> bool:
-    srcs = [os.path.join(_DIR, s) for s in _SOURCES
-            if os.path.exists(os.path.join(_DIR, s))]
-    if not srcs:
-        return False
+    srcs = [os.path.join(_DIR, s) for s in _SOURCES]
     # compile to a unique temp name in the destination dir, then rename:
     # rename is atomic, so concurrent processes never dlopen a half-
     # written library (the per-process lock can't serialize across
     # processes)
     tmp_path = f"{lib_path}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-o", tmp_path,
-           *srcs]
+    cmd = ["g++", *_CXXFLAGS, "-o", tmp_path, *srcs]
     try:
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
         if res.returncode != 0:
-            import sys
-            print(f"pipegcn_tpu.native build failed:\n{res.stderr}",
-                  file=sys.stderr)
+            _fail(f"build failed:\n{res.stderr}")
             return False
         os.replace(tmp_path, lib_path)
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        _fail(f"build did not run to an end: {exc!r}")
         return False
     finally:
         if os.path.exists(tmp_path):
@@ -70,50 +94,49 @@ def _lib_path() -> str:
     # cache dir if the install is read-only (never a shared temp dir —
     # a world-writable predictable path would let another local user
     # plant a library that we would dlopen)
-    cand = os.path.join(_DIR, _LIB_NAME)
+    name = lib_name()
+    cand = os.path.join(_DIR, name)
     if os.path.exists(cand) or os.access(_DIR, os.W_OK):
         return cand
     cache = os.environ.get("XDG_CACHE_HOME",
                            os.path.join(os.path.expanduser("~"), ".cache"))
     d = os.path.join(cache, "pipegcn_tpu")
     os.makedirs(d, mode=0o700, exist_ok=True)
-    return os.path.join(d, _LIB_NAME)
-
-
-def _stale(lib_path: str) -> bool:
-    if not os.path.exists(lib_path):
-        return True
-    lib_mtime = os.path.getmtime(lib_path)
-    return any(
-        os.path.getmtime(os.path.join(_DIR, s)) > lib_mtime
-        for s in _SOURCES if os.path.exists(os.path.join(_DIR, s))
-    )
+    return os.path.join(d, name)
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
     """The native library, building it on first use; None if unavailable."""
-    global _lib, _tried
+    global _lib, _tried, _status
     if _lib is not None:
         return _lib
     if os.environ.get("PIPEGCN_NATIVE", "1") == "0":
+        _status = "unavailable: PIPEGCN_NATIVE=0"
         return None
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         path = _lib_path()
-        if _stale(path) and not _build(path):
-            return None
+        how = "loaded"
+        if not os.path.exists(path):
+            if not _build(path):
+                return None
+            how = "built in this run"
         try:
             lib = ctypes.CDLL(path)
             _declare(lib)
-        except (OSError, AttributeError):
-            # AttributeError: a stale library missing newly-declared
-            # symbols (e.g. built before a source was added) must degrade
-            # to the numpy fallback like every other load failure
+        except (OSError, AttributeError) as exc:
+            _fail(f"could not load {path}: {exc!r}")
             return None
         _lib = lib
+        _status = f"{how} {path}"
     return _lib
+
+
+def status() -> str:
+    """How the library came to be in this process (after get_lib())."""
+    return _status
 
 
 def available() -> bool:

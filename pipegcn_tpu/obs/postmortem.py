@@ -38,8 +38,8 @@ fallback-exhausted. Everything else keeps the restart/backoff policy
 
 The verdict dict validates as the schema-v11 ``diagnosis`` record
 kind. `pipegcn_tpu.cli.debug` is the CLI (`pipegcn-debug explain
-<run-dir>`); the elastic supervisor and scripts/tpu_window.py call
-:func:`diagnose_run` directly.
+<run-dir>`); the elastic supervisor calls :func:`diagnose_run`
+directly.
 """
 
 from __future__ import annotations

@@ -382,8 +382,8 @@ BLACKBOX_FIELDS: Dict[str, str] = {
 }
 
 # one record per postmortem verdict (obs/postmortem.py rule engine,
-# written by pipegcn-debug / the elastic supervisor / tpu_window's
-# failed-step auto-explain): the confidence-ranked root cause of a run.
+# written by pipegcn-debug / the elastic supervisor): the
+# confidence-ranked root cause of a run.
 # verdict names the failure class (wedged-collective | oom |
 # fallback-exhausted | corrupt-artifact | config-error | desync |
 # sdc | storage-fault | recompile-storm | divergence | preemption |
@@ -391,8 +391,7 @@ BLACKBOX_FIELDS: Dict[str, str] = {
 # the rule matched on; deterministic says whether a supervisor should
 # fail fast (True: relaunching reproduces the failure) or keep its
 # restart/backoff policy. Extras: run_dir, candidates (the full ranked
-# list), timeline, generation/member (supervisor path), step
-# (tpu_window path).
+# list), timeline, generation/member (supervisor path).
 DIAGNOSIS_FIELDS: Dict[str, str] = {
     "event": "string",             # "diagnosis"
     "verdict": "string",           # failure class (see above)

@@ -1,14 +1,13 @@
 """Bench trend tracking over the repo's measurement artifacts
 (docs/OBSERVABILITY.md "Live monitoring", scripts/bench_trend.py).
 
-Every real-chip window leaves ``BENCH_r<N>.json`` (the bench headline,
-or a failure tail when the round died) and ``MULTICHIP_r<N>.json`` /
-``MULTICHIP_40part.json`` behind. This module folds that series into a
-per-lever delta history — epoch time, fused-candidate epoch time,
-pipeline speedup, MFU, vs-baseline ratio — flags any lever whose
-latest value regressed past tolerance from its best-known headline,
-and renders the table ``scripts/tpu_window.py`` auto-publishes as a
-trend verdict when the queued window finally runs.
+A driver round leaves ``BENCH_r<N>.json`` (the bench headline, or a
+failure tail when the round died) and ``MULTICHIP_r<N>.json`` /
+``MULTICHIP_40part.json`` behind. This module folds whatever series is
+present (none is a valid, empty series) into a per-lever delta
+history — epoch time, fused-candidate epoch time, pipeline speedup,
+MFU, vs-baseline ratio — flags any lever whose latest value regressed
+past tolerance from its best-known headline, and renders the table.
 
 Pure stdlib + filesystem reads; no jax.
 """
@@ -205,7 +204,8 @@ def format_trend(t: Dict[str, Any]) -> str:
              f"(ok: {t['ok_rounds']}, failed: {t['failed_rounds']}, "
              f"tol {t['tol'] * 100:.0f}%)"]
     if not t["levers"]:
-        lines.append("  no headline data (every round failed?)")
+        lines.append("  no headline data (no BENCH_*.json record, or "
+                     "every round failed)")
     w = max((len(k) for k in t["levers"]), default=0)
     for key, v in sorted(t["levers"].items()):
         hist = " -> ".join(f"r{h['round']}:{h['value']:.4g}"

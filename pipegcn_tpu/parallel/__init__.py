@@ -1,7 +1,3 @@
-from ..compat import ensure_jax_compat
-
-ensure_jax_compat()  # older jax: alias shard_map/pcast before any use
-
 from .mesh import make_mesh, PARTS_AXIS
 from .halo import halo_exchange, exchange_blocks, return_blocks, make_stale_concat
 from .trainer import Trainer, TrainConfig

@@ -239,7 +239,7 @@ class ShardedEvaluator:
             arrs["edge_src"] = dummy
             arrs["edge_dst"] = dummy
         data = {
-            k: jax.device_put(jnp.asarray(v), trainer._shard)
+            k: jax.device_put(np.asarray(v), trainer._shard)
             for k, v in arrs.items()
         }
         if trainer.cfg.use_pp:

@@ -58,11 +58,8 @@ def halo_transport_dtypes(halo_dtype: Optional[str]) -> Tuple:
 def _ensure_varying(x: jax.Array, axis_name: str) -> jax.Array:
     """Mark x device-varying over axis_name unless it already is (pcast
     rejects varying->varying)."""
-    try:
-        if axis_name in jax.typeof(x).vma:
-            return x
-    except (AttributeError, TypeError):
-        pass
+    if axis_name in jax.typeof(x).vma:
+        return x
     return jax.lax.pcast(x, axis_name, to="varying")
 
 

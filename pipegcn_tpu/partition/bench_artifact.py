@@ -1,5 +1,5 @@
 """Canonical bench partition-artifact recipe, shared by bench.py and
-the window-queue probe scripts.
+the probe scripts.
 
 partitions/ is not git-tracked, so artifacts vanish between rounds;
 every consumer goes through :func:`ensure` (or :func:`build_artifact`

@@ -165,6 +165,10 @@ class ShardedGraph:
     # repeat runs skip their O(E) host builds. Not serialized.
     cache_dir: Optional[str] = None
 
+    # set by load(): the directory this graph was read from; None for a
+    # graph built in this process (even once saved). Not serialized.
+    loaded_from: Optional[str] = None
+
     # set by load(parts=...): the global partition ids THIS process
     # will own under the current elastic membership assignment
     # (resilience/elastic.py); None = unsupervised / owns everything.
@@ -175,6 +179,12 @@ class ShardedGraph:
     @property
     def halo_size(self) -> int:
         return (self.num_parts - 1) * self.b_max
+
+    @property
+    def source(self) -> str:
+        """Where this graph came from, for set-up logs."""
+        return (f"loaded from {self.loaded_from}" if self.loaded_from
+                else "built in this run")
 
     @staticmethod
     def edge_checksum(g: Graph) -> int:
@@ -781,7 +791,8 @@ class ShardedGraph:
                 p = os.path.join(adir, f"{k}.npy")
                 if os.path.exists(p):
                     arrays[k] = np.load(p, mmap_mode="r")
-            sg = ShardedGraph(**manifest, cache_dir=path, **arrays)
+            sg = ShardedGraph(**manifest, cache_dir=path, loaded_from=path,
+                              **arrays)
             if sg.reorder != "none":
                 sg.validate_layout()
             if parts is not None:
@@ -798,7 +809,7 @@ class ShardedGraph:
         keys = ShardedGraph._ARRAYS + [k for k in
                                        ShardedGraph._REORDER_ARRAYS
                                        if k in arrays.files]
-        sg = ShardedGraph(**manifest, cache_dir=path,
+        sg = ShardedGraph(**manifest, cache_dir=path, loaded_from=path,
                           **{k: arrays[k] for k in keys})
         if sg.reorder != "none":
             sg.validate_layout()

@@ -124,8 +124,8 @@ def partition_graph(
 # default locality-cluster granularity; artifact cache keys derive
 # from it via cluster_suffix so every consumer shares ONE definition
 # of "which layout is this". 1024 beat the earlier 4096 default on
-# the chip (1.5182 vs 1.5935 s/epoch, results/tpu_bench.md): same
-# 80% dense coverage from 2.4x fewer, denser tiles.
+# the chip on earlier code (1.5182 vs 1.5935 s/epoch; record removed
+# in PR 21): same 80% dense coverage from 2.4x fewer, denser tiles.
 DEFAULT_CLUSTER_SIZE = 1024
 
 

@@ -402,8 +402,7 @@ def _cpu_device_flags(env: Dict[str, str], parts_per_node: int) -> None:
     --xla_force_host_platform_device_count; keep it in sync with the
     generation's parts_per_node (this IS the redistribution mechanism
     on the test mesh: fewer processes, more virtual devices each)."""
-    plat = env.get("PIPEGCN_PLATFORM") or env.get("JAX_PLATFORMS", "")
-    if "cpu" not in plat:
+    if "cpu" not in env.get("JAX_PLATFORMS", ""):
         return
     kept = [t for t in env.get("XLA_FLAGS", "").split()
             if not t.startswith("--xla_force_host_platform_device_count")]

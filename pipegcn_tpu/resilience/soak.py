@@ -598,7 +598,6 @@ def _run_fleet_drill(cfg: SoakConfig, episode: int, ep_dir: str,
                 "--fleet-retry-timeout", "15"]
     env = _episode_env()
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PIPEGCN_PLATFORM"] = "cpu"
     try:
         proc = subprocess.run(cmd, env=env, cwd=_REPO,
                               timeout=cfg.episode_timeout_s,
@@ -648,7 +647,6 @@ def _run_autoscale_drill(cfg: SoakConfig, episode: int, ep_dir: str,
     log(f"  autoscale drill: kill@{kill_w} partition@{part_w}")
     env = _episode_env()
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PIPEGCN_PLATFORM"] = "cpu"
     try:
         proc = subprocess.run(cmd, env=env, cwd=_REPO,
                               timeout=cfg.episode_timeout_s,

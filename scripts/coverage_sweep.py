@@ -80,9 +80,9 @@ def main():
                          "probe SpMM epoch)")
     args = ap.parse_args()
 
-    import jax
+    from pipegcn_tpu.backend import start_measurement
 
-    jax.config.update("jax_platforms", "cpu")
+    start_measurement(cpu=True)  # a host-side study by design
 
     from pipegcn_tpu.graph import load_data
     from pipegcn_tpu.ops.block_spmm import (DENSE_A_BYTE_BUDGET,

@@ -42,15 +42,13 @@ def main():
     ap.add_argument("--out", default="results/epoch_anatomy.json")
     args = ap.parse_args()
 
-    from bench import init_backend
-
-    backend = init_backend(1, 60.0, args.cpu)
     import dataclasses
 
     import jax
 
-    if backend.startswith("cpu"):
-        jax.config.update("jax_platforms", "cpu")
+    from pipegcn_tpu.backend import start_measurement
+
+    start_measurement(cpu=args.cpu)
 
     from pipegcn_tpu.models import ModelConfig
     from pipegcn_tpu.parallel import TrainConfig

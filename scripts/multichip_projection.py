@@ -48,12 +48,12 @@ def main():
               f"'{args.dataset}' is extrapolation, not calibration",
               file=sys.stderr)
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     os.environ.setdefault(
         "XLA_FLAGS",
         f"--xla_force_host_platform_device_count={args.parts}")
+    from pipegcn_tpu.backend import start_measurement
+
+    start_measurement(cpu=True)  # a host-side model by design
 
     from pipegcn_tpu.graph import load_data
     from pipegcn_tpu.ops.block_spmm import (DENSE_A_BYTE_BUDGET,

@@ -118,8 +118,9 @@ def main():
 
     import jax
 
-    if not args.tpu:
-        jax.config.update("jax_platforms", "cpu")
+    from pipegcn_tpu.backend import start_measurement
+
+    start_measurement(cpu=not args.tpu)
 
     from pipegcn_tpu.graph import synthetic_graph
     from pipegcn_tpu.models import ModelConfig

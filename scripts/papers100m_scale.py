@@ -230,9 +230,9 @@ def main() -> None:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.parts}"
         ).strip()
-        import jax
+        from pipegcn_tpu.backend import start_measurement
 
-        jax.config.update("jax_platforms", "cpu")
+        start_measurement(cpu=True)  # a CPU-mesh dry run by design
         from pipegcn_tpu.models import ModelConfig
         from pipegcn_tpu.parallel import Trainer, TrainConfig
 

@@ -270,9 +270,9 @@ def main():
           f"halo(uniform)={sg.halo_size}", flush=True)
 
     # ---- stage 4: one pipelined step --------------------------------
-    import jax
+    from pipegcn_tpu.backend import start_measurement
 
-    jax.config.update("jax_platforms", "cpu")
+    start_measurement(cpu=True)  # a host-side study by design
 
     from pipegcn_tpu.models import ModelConfig
     from pipegcn_tpu.parallel import SequentialRunner, TrainConfig

@@ -56,6 +56,12 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from pipegcn_tpu.backend import device_line, place_compile_cache
+
+    place_compile_cache()
+    # results are labelled by jax.default_backend(); say which it is
+    print(f"# {device_line()}", file=sys.stderr)
+
     from pipegcn_tpu.models import ModelConfig
     from pipegcn_tpu.ops.bucket_spmm import (bucket_aggregate,
                                              transport_cast,

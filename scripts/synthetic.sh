@@ -1,5 +1,5 @@
 # Hermetic smoke run on an 8-virtual-device CPU mesh (no dataset needed)
-PIPEGCN_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 python main.py \
   --dataset synthetic:2000:10:32:8 \
   --dropout 0.3 \

@@ -21,7 +21,6 @@ monitoring"):
 Everything here is host-side and jax-free except nothing — the marker
 is `live` (scripts/chaos.sh monitor lane)."""
 
-import glob
 import json
 import os
 import time
@@ -557,22 +556,24 @@ def test_trend_flags_regression_on_worsening_series():
     assert "latest-round-failed" in t["flags"]
 
 
-def test_bench_trend_over_repo_artifacts():
-    """Smoke over the real BENCH_r*.json series committed in the repo:
-    the loader survives the failed r01 round (no headline anywhere in
-    its tail) and the table renders every lever."""
-    if not glob.glob(os.path.join(REPO, "BENCH_r*.json")):
-        pytest.skip("no BENCH artifacts in this checkout")
-    series = load_series(REPO)
-    assert any(not b["ok"] or b["headline"] is None
-               for b in series["bench"]) or True
+def test_bench_trend_copes_with_no_records(tmp_path, capsys):
+    """A checkout with no BENCH_*.json / MULTICHIP_*.json (the records
+    of the earlier harness were removed) is an empty series, not an
+    error: the table renders, the verdict is clean, the CLI exits 0
+    even under --strict."""
+    import importlib.util
+
+    series = load_series(str(tmp_path))
+    assert series == {"bench": [], "multichip": [], "sweep": None}
     t = trend(series)
-    assert t["n_rounds"] == len(series["bench"]) > 0
-    table = format_trend(t)
-    assert "verdict:" in table
-    for b in series["bench"]:
-        if not b["ok"]:
-            assert b["round"] in t["failed_rounds"]
+    assert t["n_rounds"] == 0 and not t["levers"] and not t["regressed"]
+    assert "verdict: clean" in format_trend(t)
+    spec = importlib.util.spec_from_file_location(
+        "bench_trend", os.path.join(REPO, "scripts", "bench_trend.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert mod.main(["--root", str(tmp_path), "--strict"]) == 0
+    assert "0 round(s)" in capsys.readouterr().out
 
 
 def test_report_cli_accepts_run_directory(tmp_path, capsys):

@@ -311,9 +311,10 @@ def test_overflow_skips_step_and_backs_off_in_fit(sharded):
 # ---------------- kernel fallback ladder (trainer) --------------------
 
 
-def test_kernel_crash_downgrades_and_completes(sharded, tmp_path):
+def test_kernel_crash_downgrades_and_completes(sharded, tmp_path, capsys):
     """Acceptance: a simulated kernel-dispatch failure completes
-    training via an automatic logged fallback instead of crashing."""
+    training via an automatic logged fallback instead of crashing, and
+    the error that forced it is on stderr whoever reads the metrics."""
     t = _trainer(sharded, mkw={"spmm_impl": "block", "block_tile": 16},
                  enable_pipeline=True, n_epochs=6)
     assert t._current_impl() == "block"
@@ -340,6 +341,9 @@ def test_kernel_crash_downgrades_and_completes(sharded, tmp_path):
                for line in logs)
     assert summarize_numerics(recs)["kernel_fallbacks"] == \
         ["block->bucket"]
+    err = capsys.readouterr().err
+    assert "KERNEL DOWNGRADE block -> bucket" in err
+    assert "fault-injected kernel dispatch failure" in err
 
 
 def test_fallback_ladder_exhaustion_raises(sharded):

@@ -381,52 +381,3 @@ def test_hard_flush_tolerates_stringio():
     ml = MetricsLogger(io.StringIO())
     ml.fault(kind="divergence", epoch=1, rank=0)  # auto hard_flush
     ml.hard_flush()  # explicit call: no fileno -> still fine
-
-
-# ---------------- TPU-window preflight ------------------------------------
-
-def _load_tpu_window():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "tpu_window", os.path.join(REPO, "scripts", "tpu_window.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_window_preflight_skips_missing_artifacts(tmp_path):
-    """Dry-run against an emptied partitions/: entries that declare the
-    bench artifact are skipped; self-building entries stay runnable."""
-    tw = _load_tpu_window()
-    repo = str(tmp_path)
-    os.makedirs(os.path.join(repo, "partitions"))  # empty
-    queue = [
-        ("needs_part", ["x"], 10, ["partitions/bench-reddit-1-c2-s1024"]),
-        ("self_building", ["y"], 10, []),
-        ("glob_ok", ["z"], 10, ["partitions/*"]),
-    ]
-    skipped = tw.preflight_queue(queue, repo=repo)
-    assert set(skipped) == {"needs_part", "glob_ok"}
-    assert skipped["needs_part"] == ["partitions/bench-reddit-1-c2-s1024"]
-    # the artifact appearing flips the verdict
-    os.makedirs(os.path.join(repo, "partitions",
-                             "bench-reddit-1-c2-s1024"))
-    assert tw.preflight_queue(queue, repo=repo) == {}
-
-
-def test_window_queue_declares_requirements():
-    """The real queue's Reddit-shape probes must declare the bench
-    artifact (the two burned windows the preflight exists to prevent);
-    every entry is a 4-tuple."""
-    tw = _load_tpu_window()
-    by_name = {name: req for name, _, _, req in tw.QUEUE}
-    for step in ("epoch_anatomy", "rem_probe", "spmm_tune",
-                 "bench_auto_tuned"):
-        assert tw._BENCH_PART in by_name[step]
-    # the round-4/5 gaters keep first claim on the window, and the
-    # on-chip tuner warm runs before the auto-dispatch bench
-    order = [name for name, _, _, _ in tw.QUEUE]
-    assert order.index("epoch_anatomy") < order.index("spmm_tune")
-    assert order.index("rem_probe") < order.index("spmm_tune")
-    assert order.index("spmm_tune") < order.index("bench_auto_tuned")

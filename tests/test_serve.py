@@ -514,7 +514,6 @@ def test_serve_cli_kill_drill(tmp_path):
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "PYTHONPATH": repo,
-        "PIPEGCN_PLATFORM": "cpu",
     }
     proc = subprocess.Popen(
         [sys.executable, "-m", "pipegcn_tpu.cli.serve",
