@@ -45,6 +45,35 @@ def place_compile_cache() -> str:
     return DEFAULT_CACHE_DIR
 
 
+def compiled_text_uncached(lowered) -> str:
+    """Optimized HLO text of `lowered`, compiled past the persistent
+    cache. The cache's key leaves instruction metadata out
+    (`jax_compilation_cache_include_metadata_in_key` is off, and has to
+    stay off: source lines are metadata), so an entry written by an
+    older checkout serves its OWN `op_name` scopes; a text read from a
+    cache hit names the scopes of whoever compiled the program first.
+    The join from a trace to named scopes (obs/profiler.py,
+    obs/anatomy.py) needs this checkout's names, and the instruction
+    names are the same either way (same compiler, same program). Costs
+    one real compile; nothing is written to the cache."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        # an option at its default value changes nothing in the program
+        # but keys JAX's in-memory table of compiled lowerings apart: a
+        # lowering whose executable came out of the persistent cache
+        # would otherwise hand that same executable back
+        return lowered.compile(
+            compiler_options={"xla_dump_hlo_as_text": False}).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
 def device_summary() -> dict:
     """The device as JAX reports it: platform, device_kind, count."""
     import jax

@@ -204,6 +204,17 @@ def summarize_run(records: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
                 p["overlap_fraction"], 4)
         if isinstance(p.get("phases"), dict):
             out["profile_phases"] = p["phases"]
+        if isinstance(p.get("paths"), dict):
+            out["profile_paths"] = p["paths"]
+        for k in ("window_s", "busy_s", "unscoped_s"):
+            if isinstance(p.get(k), (int, float)):
+                out[f"profile_{k}"] = p[k]
+        if isinstance(p.get("programs"), list):
+            out["profile_scan_lengths"] = [
+                q.get("scan_length") for q in p["programs"]
+                if isinstance(q, dict) and q.get("n_events")]
+        if isinstance(p.get("idle_gaps"), list):
+            out["profile_idle_gaps"] = p["idle_gaps"]
         for k in ("comm_s", "compute_s"):
             if isinstance(p.get(k), (int, float)):
                 out[f"profile_{k}"] = p[k]
@@ -505,6 +516,25 @@ def format_summary(path: str, s: Dict[str, Any]) -> str:
         lines.append("  {:<26} {}".format(
             "profiled device time", ", ".join(
                 f"{k} {v:.4f}s" for k, v in top)))
+    if s.get("profile_paths"):
+        # self seconds by scope path, to the depth the kernels name
+        if "profile_busy_s" in s and "profile_window_s" in s:
+            lines.append("  {:<26} {:.4f}s of {:.4f}s; scans of {}"
+                         .format("device busy (profiled)",
+                                 s["profile_busy_s"],
+                                 s["profile_window_s"],
+                                 s.get("profile_scan_lengths", [])))
+        for k, v in sorted(s["profile_paths"].items(),
+                           key=lambda kv: -kv[1])[:16]:
+            lines.append("    {:<24} {:.4f}s".format(k, v))
+        if s.get("profile_unscoped_s"):
+            lines.append("    {:<24} {:.4f}s".format(
+                "(no scope)", s["profile_unscoped_s"]))
+        for g in (s.get("profile_idle_gaps") or [])[:3]:
+            lines.append("  {:<26} {:.4f}s in {} gap(s): {}{}".format(
+                "device idle (profiled)", g.get("s", 0.0), g.get("n", 0),
+                g.get("span") or "no span of the program",
+                f" > {g['inner']}" if g.get("inner") else ""))
     if s.get("staleness_probes"):
         lines.append("  {:<26} {} probes, max {:.4f}, last {:.4f}"
                      .format("staleness rel drift",

@@ -1,7 +1,7 @@
-"""Cross-rank timeline CLI: metrics JSONL -> Perfetto trace.json.
+"""Cross-rank timeline CLI: metrics JSONL -> Perfetto timeline.json.
 
     python -m pipegcn_tpu.cli.timeline rank0.jsonl rank1.jsonl \
-        [--out trace.json] [--ranks 0,1]
+        [--out timeline.json] [--ranks 0,1]
 
 Merges one metrics JSONL stream per rank (written with --metrics-out;
 schema obs/schema.py) into a single Chrome-trace file loadable in
@@ -30,11 +30,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m pipegcn_tpu.cli.timeline",
         description="Merge per-rank metrics JSONL files into one "
-                    "Perfetto/Chrome-trace trace.json")
+                    "Perfetto/Chrome-trace timeline.json")
     ap.add_argument("files", nargs="+",
                     help="metrics JSONL file(s), one per rank")
-    ap.add_argument("--out", default="trace.json",
-                    help="output Chrome-trace path (default trace.json)")
+    ap.add_argument("--out", default="timeline.json",
+                    help="output Chrome-trace path (default timeline.json)")
     ap.add_argument("--ranks", default="",
                     help="comma-separated rank ids matching the file "
                          "order (default: rank fields in the records, "

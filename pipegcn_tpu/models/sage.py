@@ -511,6 +511,20 @@ def forward(
                            preferred_element_type=out_dtype)
             return y + b.astype(out_dtype)
 
+    def dense2(x, ah, lp, out_dtype, n_rows=None):
+        # the SAGE update x[:n_rows] @ w1 + mean @ w2: the slice of the
+        # inner rows, the cast of the mean and the sum of the two
+        # products are dense work too
+        with jax.named_scope("dense"):
+            x = x if n_rows is None else x[:n_rows]
+            y = (jnp.matmul(x, lp["w1"].astype(x.dtype),
+                            preferred_element_type=out_dtype)
+                 + lp["b1"].astype(out_dtype))
+            ah = ah.astype(cdt)
+            return y + (jnp.matmul(ah, lp["w2"].astype(ah.dtype),
+                                   preferred_element_type=out_dtype)
+                        + lp["b2"].astype(out_dtype))
+
     for i in range(cfg.n_layers):
       # named scope per layer: forward ops (and the backward ops XLA
       # derives from them) show up as "layer{i}/..." in profiler
@@ -521,7 +535,8 @@ def forward(
         # loss; hidden layers stay in the compute dtype
         out_dt = jnp.float32 if i == cfg.n_layers - 1 else cdt
         if training and cfg.dropout > 0:
-            rng, sub = jax.random.split(rng)
+            with jax.named_scope("dropout"):
+                rng, sub = jax.random.split(rng)
         if is_graph:
             is_gcn = cfg.model == "gcn"
             is_gat = cfg.model == "gat"
@@ -567,9 +582,7 @@ def forward(
                         h = dense(ah.astype(cdt), lp["w"], lp["b"],
                                   out_dt)
                     else:
-                        h = (dense(h[:n_dst], lp["w1"], lp["b1"], out_dt)
-                             + dense(ah.astype(cdt), lp["w2"], lp["b2"],
-                                     out_dt))
+                        h = dense2(h, ah, lp, out_dt, n_dst)
             elif is_gat:
                 lp = params["layers"][i]
                 h = _gat_layer(h, lp, edge_src, edge_dst, n_dst,
@@ -592,8 +605,7 @@ def forward(
                     h = dense(jnp.concatenate([h, ah.astype(cdt)], axis=1),
                               lp["w"], lp["b"], out_dt)
                 else:
-                    h = (dense(h, lp["w1"], lp["b1"], out_dt)
-                         + dense(ah.astype(cdt), lp["w2"], lp["b2"], out_dt))
+                    h = dense2(h, ah, lp, out_dt)
         else:
             if training and cfg.dropout > 0:
                 h = _dropout(sub, h, cfg.dropout, cfg.dropout_bits)
@@ -624,7 +636,10 @@ def forward(
                             h, np_["scale"], np_["bias"], norm_state[i]
                         )
                 probe("norm", h)
-            h = jax.nn.relu(h)
+                # the activation is one elementwise pass with the norm
+                h = jax.nn.relu(h)
+            else:
+                h = jax.nn.relu(h)
 
     if training and cfg.norm == "batch":
         return h, new_norm_state

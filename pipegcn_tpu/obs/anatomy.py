@@ -34,7 +34,8 @@ import re
 import time
 from typing import Any, Dict, List, Tuple
 
-from .profiler import PHASES, _INSTR_RE, _OPNAME_RE, classify_op
+from .profiler import (PHASES, _INSTR_RE, _OPNAME_RE, classify_op,
+                       scope_path)
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -160,6 +161,58 @@ def hlo_anatomy(compiled_text: str) -> Dict[str, Any]:
     }
 
 
+# instructions that hold other instructions or only rename values
+_CONTAINERS = {"parameter", "constant", "tuple", "get-tuple-element",
+               "bitcast", "while", "call", "conditional"}
+_CALLED_RE = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+
+
+def scope_coverage(compiled_text: str, under: str = "spmm"
+                   ) -> Dict[str, Dict[str, float]]:
+    """How far the kernels' own scopes reach below the named scope
+    `under`: of the bytes that the instructions under it read and write
+    (every computation but fusion and reduction bodies, so a scan's body
+    counts), the share whose scope path goes on below `under`
+    (`spmm/gather`, not bare `spmm`). Forward and backward
+    (`spmm/bwd/...`) apart: {"fwd" | "bwd": {bytes, covered, fraction,
+    n_ops}}. Where no trace is taken this stands for the share of self
+    time that carries a second-level scope."""
+    bodies = set()
+    for line in compiled_text.splitlines():
+        if " fusion(" in line or "to_apply=" in line:
+            bodies.update(_CALLED_RE.findall(line))
+    out = {d: {"bytes": 0.0, "covered": 0.0, "n_ops": 0}
+           for d in ("fwd", "bwd")}
+    skip = False
+    for line in compiled_text.splitlines():
+        cm = _COMPUTATION_RE.match(line)
+        if cm:
+            skip = cm.group(1) in bodies
+            continue
+        m = _INSTR_RE.match(line)
+        if skip or not m or m.group("kind") in _CONTAINERS:
+            continue
+        om = _OPNAME_RE.search(line)
+        toks = scope_path(om.group("op")).split("/") if om else []
+        if under not in toks:
+            continue
+        below = toks[toks.index(under) + 1:]
+        slot = out["bwd" if below[:1] == ["bwd"] else "fwd"]
+        by = float(_shape_bytes(_parse_shapes(m.group("type"))))
+        mo = _OPERANDS_RE.search(line)
+        if mo:
+            by += float(_shape_bytes(_parse_shapes(mo.group(1))))
+        slot["bytes"] += by
+        slot["n_ops"] += 1
+        if [t for t in below if t != "bwd"]:
+            slot["covered"] += by
+    for slot in out.values():
+        slot["fraction"] = (slot["covered"] / slot["bytes"]
+                            if slot["bytes"] else None)
+    return out
+
+
 def step_anatomy(trainer) -> Dict[str, Any]:
     """The full ``anatomy`` record body for a Trainer's single-epoch
     compiled step: the HLO walk above + XLA's own cost analysis and
@@ -174,7 +227,9 @@ def step_anatomy(trainer) -> Dict[str, Any]:
     compiled = trainer._step.lower(
         trainer.state, trainer.data, rng,
         jnp.float32(trainer.loss_scaler.scale)).compile()
-    rec = hlo_anatomy(compiled.as_text())
+    # the text past the persistent cache: an entry written by an older
+    # checkout would name that checkout's scopes
+    rec = hlo_anatomy(trainer.step_compiled_text())
     try:
         ca = trainer.step_cost_analysis()
     except Exception:  # noqa: BLE001 — backend without analysis
@@ -228,18 +283,5 @@ def time_config(sg, cfg, tcfg, reps: int, blk: int,
     return float(np.median(times)), setup, compile_s
 
 
-def time_variants(sg, base_cfg, base_tcfg, variants, reps: int = 3
-                  ) -> Dict[str, float]:
-    """Time a list of (name, cfg, tcfg) ablation variants; returns
-    {name: median s/epoch}. The caller builds the variants (pp on/off,
-    fused on/off, dropout/norm ablations) — this is the loop."""
-    out: Dict[str, float] = {}
-    for name, cfg, tc in variants:
-        blk = max(1, int(getattr(tc, "fused_epochs", 1)))
-        s, _, _ = time_config(sg, cfg, tc, reps, blk)
-        out[name] = round(s, 6)
-    return out
-
-
-__all__ = ["PHASES", "hlo_anatomy", "step_anatomy", "time_config",
-           "time_variants"]
+__all__ = ["PHASES", "hlo_anatomy", "scope_coverage", "step_anatomy",
+           "time_config"]

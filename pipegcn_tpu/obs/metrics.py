@@ -275,8 +275,9 @@ class MetricsLogger:
                 **extra) -> Dict[str, Any]:
         """A captured profiling window's MEASURED device-time
         decomposition (obs/profiler.py): per-phase seconds + the
-        comm/compute overlap fraction. Extras: epoch_start/epoch_end,
-        trace_files, parser coverage counters."""
+        comm/compute overlap fraction. Extras (obs/schema.py
+        PROFILE_FIELDS): the window, self seconds by scope path, busy
+        time, the programs joined, idle gaps by host span."""
         return self.write({
             "event": "profile",
             "phases": dict(phases),

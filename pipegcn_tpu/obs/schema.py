@@ -107,11 +107,20 @@ RECOVERY_FIELDS: Dict[str, str] = {
 }
 
 # one record per captured profiling window (obs/profiler.py): MEASURED
-# per-phase device seconds folded from a jax.profiler trace, plus the
-# measured comm/compute overlap fraction — the report CLI prints it
-# next to (and flags divergence from) the host-side estimate. Extras:
-# epoch_start/epoch_end (the --profile-epochs window), trace_files,
-# n_device_events/n_matched_events (parser coverage).
+# device self seconds per phase, folded from a jax.profiler trace
+# (.xplane.pb) against the compiled HLO of every scan length the window
+# dispatched, plus the measured comm/compute overlap fraction: the
+# report CLI prints it next to (and flags divergence from) the
+# host-side estimate. Seconds are per device. Extras: epoch_start /
+# epoch_end (the window as dispatched), paths ({scope path: self
+# seconds}, `spmm/bwd/gather`), window_s / busy_s (union of the op
+# line's intervals), unscoped_s (self seconds under no named scope),
+# other_programs_s, programs ([{scan_length, modules, n_events,
+# matched}]), idle_gaps ([{span, inner, s, n}]: the longest gaps by the
+# program's host span open in them), t0_unix (the trace clock's zero
+# on the clock of the `span` / `tracesync` records) and first_event_s
+# behind it, fold_s, trace_files, trace_bytes, n_devices,
+# n_device_events / n_matched_events (parser coverage).
 PROFILE_FIELDS: Dict[str, str] = {
     "event": "string",             # "profile"
     "phases": "object",            # {spmm|dense|halo_comm|...: seconds}

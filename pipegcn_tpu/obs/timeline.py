@@ -3,7 +3,7 @@
 Multi-host runs leave one metrics JSONL stream per rank (PR 3's
 fault/recovery records are rank-attributed for exactly this reason).
 Reading N streams side by side in a text editor is how desync bugs
-hide; this module merges them into ONE ``trace.json`` readable in
+hide; this module merges them into ONE ``timeline.json`` readable in
 Perfetto (ui.perfetto.dev) or chrome://tracing:
 
   - each rank is a trace *process* (pid = rank), its epochs a track of

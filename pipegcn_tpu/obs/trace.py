@@ -5,7 +5,8 @@ flow into XLA op metadata, so a --profile-dir trace shows
 "layer0/halo_exchange"-style phases instead of anonymous fusions.
 `trace_span` labels HOST spans (`jax.profiler.TraceAnnotation`): a
 no-op unless a trace is being captured, so it is safe on every
-dispatch.
+dispatch. `SpanCursor` lays consecutive host spans over a long loop
+body.
 
 PhaseTimer is the host-side phase clock the epoch loop runs on —
 the generalization of the reference-parity CommTimer
@@ -47,6 +48,25 @@ def trace_span(name: str):
     import jax
 
     return jax.profiler.TraceAnnotation(name)
+
+
+class SpanCursor:
+    """Consecutive host spans over a long stretch of code: `to(name)`
+    closes the span that is open and opens the next, `to()` closes
+    only. `Trainer.fit` names the sections of its loop with it
+    (`fit/boundary`, `fit/harvest`, `fit/log`, ...) without nesting
+    hundreds of lines under `with`. Like `trace_span`, nothing is
+    written unless a trace is being captured."""
+
+    def __init__(self):
+        self._open = None
+
+    def to(self, name: str = "") -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+        self._open = trace_span(name) if name else None
+        if self._open is not None:
+            self._open.__enter__()
 
 
 class PhaseTimer:

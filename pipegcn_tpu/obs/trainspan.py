@@ -39,7 +39,7 @@ obs/health.py and ``pipegcn-report``):
 
 * ``overlap_spans`` — per-epoch MEASURED overlap fraction: the
   interval-union of comm spans covered by compute spans, the same
-  math as ``obs/profiler.fold_trace`` but from always-on spans (the
+  math as ``obs/profiler.fold_xplane`` but from always-on spans (the
   fraction of the measured comm cost the measured wall window
   absorbs; comm-bound epochs spill past the window start and read
   exposed).
@@ -54,7 +54,7 @@ import time
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 from ..serve.tracing import SpanWriter
-from .profiler import _overlap_with_union, _union_intervals
+from .profiler import _overlap_with_union, merge_intervals
 
 #: comm-phase span ops (the trainer-side mirror of profiler.COMM_PHASES)
 COMM_OPS = ("halo_exchange", "bgrad_return", "grad_reduce")
@@ -286,7 +286,7 @@ def fold_spans(records: Iterable[dict],
     """Fold training spans (+ tracesync anchors) into the derived
     verdicts: measured overlap fraction, per-rank comm-wait share, and
     straggler attribution — the always-on counterpart of
-    ``obs/profiler.fold_trace`` (same interval-union overlap math).
+    ``obs/profiler.fold_xplane`` (same interval-union overlap math).
 
     Returns a plain dict (all keys present, Nones when undecidable):
     ``overlap_spans`` (comm-weighted mean fraction), ``per_epoch``
@@ -320,7 +320,7 @@ def fold_spans(records: Iterable[dict],
     exposed: Dict[int, float] = {}
     per_epoch: Dict[int, dict] = {}
     for key, comm_iv in comm.items():
-        union = _union_intervals(comp.get(key, []))
+        union = merge_intervals(comp.get(key, []))
         cov = sum(_overlap_with_union(iv, union) for iv in comm_iv)
         tot = sum(b - a for a, b in comm_iv)
         covered_total += cov

@@ -508,13 +508,14 @@ def _apply_classes(classes, compute, per_row_elems, pads, inv, out_tile,
             out = chunks.reshape((n_chunks * rpc,)
                                  + chunks.shape[2:])[:n_w]
         outs.append(out.reshape(-1, out_tile, n_feat))
-    outs.append(jnp.zeros((1, out_tile, n_feat), jnp.float32))
     # mode='clip': indices in-bounds by construction (appended zero
     # rows are the sentinels) — fill-mode gathers are the one path
     # that can mint NaN from valid data (bucket_spmm rationale)
-    res = jnp.take(jnp.concatenate(outs, axis=0), inv, axis=0,
-                   mode="clip")
-    return res.reshape(-1, n_feat)[:out_rows]
+    with jax.named_scope("unpermute"):
+        outs.append(jnp.zeros((1, out_tile, n_feat), jnp.float32))
+        res = jnp.take(jnp.concatenate(outs, axis=0), inv, axis=0,
+                       mode="clip")
+        return res.reshape(-1, n_feat)[:out_rows]
 
 
 def _dense_apply(a_pad, groups, ginv, tiles, T, out_rows, n_feat,
@@ -539,13 +540,15 @@ def _dense_apply(a_pad, groups, ginv, tiles, T, out_rows, n_feat,
     s = a_pad.shape[-1] * 8 if packed else a_pad.shape[-1]
 
     def compute(bi, ti):  # [R, K] x2 -> [R, T, F] f32
-        blks = jnp.take(a_pad, bi, axis=0, mode="clip")
-        blks = _unpack_bits(blks, s, compute_dtype) if packed \
-            else blks.astype(compute_dtype)
-        tls = jnp.take(tiles, ti, axis=0,
-                       mode="clip")           # [R, K, S|T, F]
-        return jnp.einsum(spec, blks, tls,
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("unpack"):
+            blks = jnp.take(a_pad, bi, axis=0, mode="clip")
+            blks = _unpack_bits(blks, s, compute_dtype) if packed \
+                else blks.astype(compute_dtype)
+        with jax.named_scope("tile"):
+            tls = jnp.take(tiles, ti, axis=0,
+                           mode="clip")           # [R, K, S|T, F]
+            return jnp.einsum(spec, blks, tls,
+                              preferred_element_type=jnp.float32)
 
     # transients: unpacked A [R, K, T, S] + gathered tiles [R, K, S, F]
     return _apply_classes(
@@ -573,14 +576,16 @@ def _dense_apply_grouped(a_pad, classes, inv, tiles, T, out_rows,
     s = a_pad.shape[-1] * 8 if packed else a_pad.shape[-1]
 
     def compute(ai, ti):  # [R, group, U] + [R, U] -> [R, group, T|S, F]
-        blks = jnp.take(a_pad, ai, axis=0,
-                        mode="clip")          # [R, G, U, T, S(/8)]
-        blks = _unpack_bits(blks, s, compute_dtype) if packed \
-            else blks.astype(compute_dtype)
-        tls = jnp.take(tiles, ti, axis=0,
-                       mode="clip")           # [R, U, S|T, F]
-        return jnp.einsum(spec, blks, tls,
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("unpack"):
+            blks = jnp.take(a_pad, ai, axis=0,
+                            mode="clip")          # [R, G, U, T, S(/8)]
+            blks = _unpack_bits(blks, s, compute_dtype) if packed \
+                else blks.astype(compute_dtype)
+        with jax.named_scope("tile"):
+            tls = jnp.take(tiles, ti, axis=0,
+                           mode="clip")           # [R, U, S|T, F]
+            return jnp.einsum(spec, blks, tls,
+                              preferred_element_type=jnp.float32)
 
     # transients: unpacked A [R, G, U, T, S] + gathered union tiles
     # [R, U, S, F] (F can exceed G*T on wide input layers); square
@@ -610,7 +615,16 @@ def make_block_spmm_fn(
     gather transport only (bucket_spmm.transport_dtypes) — the dense
     MXU path keeps the activation dtype. `rem_amax` swaps the static
     saturating fp8 cast for the amax-clamped one (the de-scale applies
-    to the remainder alone, before it joins the dense partial)."""
+    to the remainder alone, before it joins the dense partial).
+
+    Named for the profiler (obs/profiler.py SCOPE_NAMES): `unpack` (the
+    A-block take, the bit unpack or cast to the compute dtype), `tile`
+    (the tiling of the operand, the tile take and the einsum),
+    `unpermute` (output-tile order restored), `rem_gather` /
+    `rem_reduce` / `rem_unpermute` (bucket_aggregate over the
+    remainder), `cast` (the remainder's transport casts, amax
+    included), `scale` (degree division, amax de-scale, the sum of the
+    two partials), the whole backward under `bwd`."""
     from .bucket_spmm import (amax_transport_cast, transport_cast,
                               transport_dtypes)
 
@@ -620,14 +634,16 @@ def make_block_spmm_fn(
     rem_fwd_dt, rem_bwd_dt = transport_dtypes(rem_dtype)
 
     def _rem_cast(x, dt):
-        if rem_amax:
-            return amax_transport_cast(x, dt)
-        return transport_cast(x, dt), None
+        with jax.named_scope("cast"):
+            if rem_amax:
+                return amax_transport_cast(x, dt)
+            return transport_cast(x, dt), None
 
     def tiles_of(x, n_tiles, S):
-        rpad = n_tiles * S - x.shape[0]
-        xp = jnp.pad(x, ((0, rpad + S), (0, 0)))  # + one zero tile
-        return xp.reshape(n_tiles + 1, S, x.shape[-1])
+        with jax.named_scope("tile"):
+            rpad = n_tiles * S - x.shape[0]
+            xp = jnp.pad(x, ((0, rpad + S), (0, 0)))  # + one zero tile
+            return xp.reshape(n_tiles + 1, S, x.shape[-1])
 
     def rem_mats(prefix):
         return [d[k] for k in sorted(d)
@@ -653,8 +669,9 @@ def make_block_spmm_fn(
         # int8/bf16/f32); the per-step unpack/cast to the compute dtype
         # lives in _dense_apply
         a = d["blk_a_bits"] if packed else d["blk_a"]
-        return jnp.concatenate(
-            [a, jnp.zeros((1,) + a.shape[1:], a.dtype)], axis=0)
+        with jax.named_scope("unpack"):
+            return jnp.concatenate(
+                [a, jnp.zeros((1,) + a.shape[1:], a.dtype)], axis=0)
 
     @jax.custom_vjp
     def f(fbuf):
@@ -674,17 +691,24 @@ def make_block_spmm_fn(
         rem = bucket_aggregate(
             rem_in, rem_mats("blkrem_fwd_"), d["blkrem_fwd_inv"],
             chunk_edges=chunk_edges,
-            run_plans=extract_run_plans(d, "blkrem_fwd"))
-        if rem_inv is not None:
-            rem = rem * rem_inv
-        return (dense + rem) / deg_col
+            run_plans=extract_run_plans(d, "blkrem_fwd"), scope="rem_")
+        with jax.named_scope("scale"):
+            if rem_inv is not None:
+                rem = rem * rem_inv
+            return (dense + rem) / deg_col
 
     def fwd(fbuf):
         return f(fbuf), jnp.zeros((0,), fbuf.dtype)
 
     def bwd(proto, g):
-        gd32 = g.astype(jnp.float32) / deg_col
-        gd = gd32.astype(proto.dtype)
+        with jax.named_scope("bwd"):
+            return _bwd(proto, g)
+
+    def _bwd(proto, g):
+        with jax.named_scope("scale"):
+            gd32 = g.astype(jnp.float32) / deg_col
+        with jax.named_scope("cast"):
+            gd = gd32.astype(proto.dtype)
         # transpose dense: per source tile, sum A^T @ g_tile
         n_d_tiles = -(-n_out // T)
         g_tiles = tiles_of(gd, n_d_tiles, T)
@@ -708,10 +732,11 @@ def make_block_spmm_fn(
         rem = bucket_aggregate(
             rem_in, rem_mats("blkrem_bwd_"), d["blkrem_bwd_inv"],
             chunk_edges=chunk_edges,
-            run_plans=extract_run_plans(d, "blkrem_bwd"))
-        if rem_inv is not None:
-            rem = rem * rem_inv
-        return ((dense + rem).astype(proto.dtype),)
+            run_plans=extract_run_plans(d, "blkrem_bwd"), scope="rem_")
+        with jax.named_scope("scale"):
+            if rem_inv is not None:
+                rem = rem * rem_inv
+            return ((dense + rem).astype(proto.dtype),)
 
     f.defvjp(fwd, bwd)
     return f

@@ -260,7 +260,7 @@ def build_tables_for_edges(
     return idx_mats, inv_perm.astype(np.int32), counts
 
 
-def _slab_gather_sum(fbuf_pad, plan, n_b, w, f):
+def _slab_gather_sum(fbuf_pad, plan, n_b, w, f, scope=""):
     """One bucket's messages via the streaming-slab plan: the residue
     table gathers the scattered entries (slab-covered positions point
     at the zero sentinel row — cheap repeated reads), then each slab is
@@ -270,21 +270,23 @@ def _slab_gather_sum(fbuf_pad, plan, n_b, w, f):
     shard_map's replication checker rejects); padded iterations write
     into the scratch slab appended past the stream end and are sliced
     off below."""
-    flat = jnp.take(fbuf_pad, plan["res"].reshape(-1), axis=0,
-                    mode="clip")
-    n_flat = flat.shape[0]
-    buf0 = jnp.concatenate(
-        [flat, jnp.zeros((SLAB_RUN, f), flat.dtype)], axis=0)
+    with jax.named_scope(scope + "gather"):
+        flat = jnp.take(fbuf_pad, plan["res"].reshape(-1), axis=0,
+                        mode="clip")
+        n_flat = flat.shape[0]
+        buf0 = jnp.concatenate(
+            [flat, jnp.zeros((SLAB_RUN, f), flat.dtype)], axis=0)
 
-    def body(i, buf):
-        blk = jax.lax.dynamic_slice(fbuf_pad, (plan["src"][i], 0),
-                                    (SLAB_RUN, f))
-        return jax.lax.dynamic_update_slice(buf, blk,
-                                            (plan["pos"][i], 0))
+        def body(i, buf):
+            blk = jax.lax.dynamic_slice(fbuf_pad, (plan["src"][i], 0),
+                                        (SLAB_RUN, f))
+            return jax.lax.dynamic_update_slice(buf, blk,
+                                                (plan["pos"][i], 0))
 
-    buf = jax.lax.fori_loop(0, plan["src"].shape[0], body, buf0)
-    return buf[:n_flat].reshape(n_b, w, f).astype(jnp.float32) \
-        .sum(axis=1)
+        buf = jax.lax.fori_loop(0, plan["src"].shape[0], body, buf0)
+    with jax.named_scope(scope + "reduce"):
+        return buf[:n_flat].reshape(n_b, w, f).astype(jnp.float32) \
+            .sum(axis=1)
 
 
 def bucket_aggregate(
@@ -295,6 +297,7 @@ def bucket_aggregate(
     chunk_edges: Optional[int] = None,
     slab: Optional[int] = None,
     run_plans: Optional[Sequence[Optional[dict]]] = None,
+    scope: str = "",
 ) -> jax.Array:
     """Scatter-free sum aggregation. fbuf [R, F] (any float dtype);
     returns f32 [n_out, F] where n_out = inv_perm length. idx_mats index
@@ -320,18 +323,25 @@ def bucket_aggregate(
     mode is the one component of this kernel that can FABRICATE NaN
     out of valid data — exactly the failure shape of the epoch-0
     products-scale NaN that appeared on the experimental TPU platform
-    but never on CPU (docs/RESILIENCE.md "Numerics")."""
+    but never on CPU (docs/RESILIENCE.md "Numerics").
+
+    The work is named for the profiler (obs/profiler.py SCOPE_NAMES):
+    `gather` (the row takes), `reduce` (the f32 cast and the sum over a
+    bucket's width), `unpermute` (concatenation and the inv_perm take),
+    `relayout` (the feature-slab transposes in and out). `scope`
+    prefixes them: the block kernel's remainder passes "rem_"."""
     f = fbuf.shape[-1]
     if slab is None:
         slab = SLAB_BYTES // fbuf.dtype.itemsize
     if slab and f > slab:
         return _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems,
-                                  chunk_edges, slab, run_plans)
+                                  chunk_edges, slab, run_plans, scope)
     if chunk_edges:
         chunk_elems = chunk_edges * f
-    fbuf_pad = jnp.concatenate(
-        [fbuf, jnp.zeros((1, f), fbuf.dtype)], axis=0
-    )
+    with jax.named_scope(scope + "gather"):
+        fbuf_pad = jnp.concatenate(
+            [fbuf, jnp.zeros((1, f), fbuf.dtype)], axis=0
+        )
 
     outs = []
     for b, mat in enumerate(idx_mats):
@@ -343,10 +353,13 @@ def bucket_aggregate(
         rows_per_chunk = max(1, chunk_elems // max(1, w * f))
         if n_b <= rows_per_chunk:
             if plan is not None:
-                outs.append(_slab_gather_sum(fbuf_pad, plan, n_b, w, f))
+                outs.append(_slab_gather_sum(fbuf_pad, plan, n_b, w, f,
+                                             scope))
                 continue
-            msgs = jnp.take(fbuf_pad, mat, axis=0, mode="clip")
-            outs.append(msgs.astype(jnp.float32).sum(axis=1))
+            with jax.named_scope(scope + "gather"):
+                msgs = jnp.take(fbuf_pad, mat, axis=0, mode="clip")
+            with jax.named_scope(scope + "reduce"):
+                outs.append(msgs.astype(jnp.float32).sum(axis=1))
             continue
         n_chunks = -(-n_b // rows_per_chunk)
         pad_rows = n_chunks * rows_per_chunk - n_b
@@ -355,17 +368,22 @@ def bucket_aggregate(
         mat_c = mat_p.reshape(n_chunks, rows_per_chunk, w)
 
         def body(_, m):
-            msgs = jnp.take(fbuf_pad, m, axis=0, mode="clip")
-            return None, msgs.astype(jnp.float32).sum(axis=1)
+            with jax.named_scope(scope + "gather"):
+                msgs = jnp.take(fbuf_pad, m, axis=0, mode="clip")
+            with jax.named_scope(scope + "reduce"):
+                return None, msgs.astype(jnp.float32).sum(axis=1)
 
         _, chunks = jax.lax.scan(body, None, mat_c)
-        outs.append(chunks.reshape(-1, f)[:n_b])
-    res = jnp.concatenate(outs + [jnp.zeros((1, f), jnp.float32)], axis=0)
-    return jnp.take(res, inv_perm, axis=0, mode="clip")
+        with jax.named_scope(scope + "reduce"):
+            outs.append(chunks.reshape(-1, f)[:n_b])
+    with jax.named_scope(scope + "unpermute"):
+        res = jnp.concatenate(outs + [jnp.zeros((1, f), jnp.float32)],
+                              axis=0)
+        return jnp.take(res, inv_perm, axis=0, mode="clip")
 
 
 def _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems, chunk_edges,
-                       slab, run_plans=None):
+                       slab, run_plans=None, scope=""):
     """Run bucket_aggregate per feature slab of `slab` elements, scanning
     over a [S, R, slab] re-layout so each slab is a compact operand.
     run_plans pass straight through: the streaming-slab plan is pure
@@ -373,18 +391,21 @@ def _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems, chunk_edges,
     r, f = fbuf.shape
     n_s = -(-f // slab)
     pad_f = n_s * slab - f
-    if pad_f:
-        fbuf = jnp.pad(fbuf, ((0, 0), (0, pad_f)))
-    slabs = fbuf.reshape(r, n_s, slab).swapaxes(0, 1)  # [S, R, slab]
+    with jax.named_scope(scope + "relayout"):
+        if pad_f:
+            fbuf = jnp.pad(fbuf, ((0, 0), (0, pad_f)))
+        slabs = fbuf.reshape(r, n_s, slab).swapaxes(0, 1)  # [S, R, slab]
 
     def one(_, sl):
         out = bucket_aggregate(sl, idx_mats, inv_perm, chunk_elems,
-                               chunk_edges, slab=0, run_plans=run_plans)
+                               chunk_edges, slab=0, run_plans=run_plans,
+                               scope=scope)
         return None, out
 
     _, outs = jax.lax.scan(one, None, slabs)  # [S, n_out, slab]
-    out = outs.swapaxes(0, 1).reshape(-1, n_s * slab)
-    return out[:, :f] if pad_f else out
+    with jax.named_scope(scope + "relayout"):
+        out = outs.swapaxes(0, 1).reshape(-1, n_s * slab)
+        return out[:, :f] if pad_f else out
 
 
 class BucketPlan:
@@ -510,22 +531,26 @@ def make_bucket_spmm_fn(
     amax-clamped one (amax_transport_cast): per-tensor power-of-two
     scaling into mid-range, inverse applied after aggregation.
     `fwd_plans`/`bwd_plans` are per-bucket streaming-slab plans
-    (bucket_aggregate run_plans)."""
+    (bucket_aggregate run_plans). The transport casts run under the
+    named scope `cast`, the degree division and the amax de-scale
+    under `scale`, the whole backward under `bwd`."""
     deg_col = in_deg[:, None]
     fwd_dt, bwd_dt = transport_dtypes(rem_dtype)
 
     def _cast(x, dt):
-        if rem_amax:
-            return amax_transport_cast(x, dt)
-        return transport_cast(x, dt), None
+        with jax.named_scope("cast"):
+            if rem_amax:
+                return amax_transport_cast(x, dt)
+            return transport_cast(x, dt), None
 
     @jax.custom_vjp
     def f(fbuf):
         y, inv = _cast(fbuf, fwd_dt)
         out = bucket_aggregate(y, fwd_mats, fwd_inv, chunk_elems,
-                               chunk_edges,
-                               run_plans=fwd_plans) / deg_col
-        return out * inv if inv is not None else out
+                               chunk_edges, run_plans=fwd_plans)
+        with jax.named_scope("scale"):
+            out = out / deg_col
+            return out * inv if inv is not None else out
 
     def fwd(fbuf):
         return f(fbuf), jnp.zeros((0,), fbuf.dtype)
@@ -537,16 +562,20 @@ def make_bucket_spmm_fn(
         # the halo exchange), while bucket_aggregate still accumulates
         # in f32. The transport cast comes straight from the f32
         # value — never through an intermediate rounding.
-        gd32 = g.astype(jnp.float32) / deg_col
-        if bwd_dt is not None:
-            gd, inv = _cast(gd32, bwd_dt)
-        else:
-            gd, inv = gd32.astype(proto.dtype), None
-        d_fbuf = bucket_aggregate(gd, bwd_mats, bwd_inv, chunk_elems,
-                                  chunk_edges, run_plans=bwd_plans)
-        if inv is not None:
-            d_fbuf = d_fbuf * inv
-        return (d_fbuf[:n_src_rows].astype(proto.dtype),)
+        with jax.named_scope("bwd"):
+            with jax.named_scope("scale"):
+                gd32 = g.astype(jnp.float32) / deg_col
+            if bwd_dt is not None:
+                gd, inv = _cast(gd32, bwd_dt)
+            else:
+                with jax.named_scope("cast"):
+                    gd, inv = gd32.astype(proto.dtype), None
+            d_fbuf = bucket_aggregate(gd, bwd_mats, bwd_inv, chunk_elems,
+                                      chunk_edges, run_plans=bwd_plans)
+            with jax.named_scope("scale"):
+                if inv is not None:
+                    d_fbuf = d_fbuf * inv
+                return (d_fbuf[:n_src_rows].astype(proto.dtype),)
 
     f.defvjp(fwd, bwd)
     return f

@@ -46,7 +46,8 @@ from ..models.sage import ModelConfig, forward, init_norm_state, init_params
 from ..obs import flight as flightrec
 from ..obs.format import epoch_line, reference_eval_line, reference_train_line
 from ..obs.metrics import device_info, memory_snapshot, mesh_info
-from ..obs.trace import PhaseTimer, named_phase
+from ..obs.profiler import ANCHOR_SPAN
+from ..obs.trace import PhaseTimer, SpanCursor, named_phase, trace_span
 from ..ops.spmm import spmm_mean
 from ..partition.halo import ShardedGraph
 from ..resilience import DivergenceError, PeerLost, Preempted, SentinelConfig
@@ -1128,8 +1129,9 @@ class Trainer:
                 for grp, bufs in state["comm"].items()
             }
             params, opt, norm = state["params"], state["opt"], state["norm"]
-            rank = jax.lax.axis_index(PARTS_AXIS)
-            rng = jax.random.fold_in(rng, rank)
+            with named_phase("dropout"):   # the epoch's key, per rank
+                rank = jax.lax.axis_index(PARTS_AXIS)
+                rng = jax.random.fold_in(rng, rank)
             psum = lambda x: jax.lax.psum(x, PARTS_AXIS)
 
             fresh_halo: Dict[str, jax.Array] = {}
@@ -1186,7 +1188,8 @@ class Trainer:
                         stale_bgrad = (stale_bgrad.astype(jnp.float32)
                                        * scale).astype(cdt)
                     op = make_stale_concat(d["send_idx"], d["send_mask"], n_max)
-                    fbuf = op(h, stale_halo, stale_bgrad, probes_in[k])
+                    with named_phase("halo_concat"):
+                        fbuf = op(h, stale_halo, stale_bgrad, probes_in[k])
                     # this epoch's exchange, consumed next epoch; aux
                     # only. Layer 0's was already issued at step top
                     # when prefetching (identical payload).
@@ -1226,8 +1229,9 @@ class Trainer:
                 counts = {ph: vz for ph in PHASES}
 
                 def nf_probe(name, x):
-                    counts[name] = counts[name] + jnp.sum(
-                        ~jnp.isfinite(x), dtype=jnp.int32)
+                    with named_phase("tripwire"):
+                        counts[name] = counts[name] + jnp.sum(
+                            ~jnp.isfinite(x), dtype=jnp.int32)
 
                 logits, new_norm = forward(
                     params, cfg, d["feat"], d["edge_src"], d["edge_dst"],
@@ -1237,14 +1241,15 @@ class Trainer:
                     gat_fn=gat_fn,
                     probe=nf_probe if tripwire else None,
                 )
-                if multilabel:
-                    loss = bce_logits_sum(logits, d["label"], d["train_mask"])
-                else:
-                    loss = cross_entropy_sum(logits, d["label"],
-                                             d["train_mask"])
+                with named_phase("loss"):
+                    if multilabel:
+                        loss = bce_logits_sum(logits, d["label"],
+                                              d["train_mask"])
+                    else:
+                        loss = cross_entropy_sum(logits, d["label"],
+                                                 d["train_mask"])
                 if tripwire:
-                    counts["loss"] = counts["loss"] + jnp.sum(
-                        ~jnp.isfinite(loss), dtype=jnp.int32)
+                    nf_probe("loss", loss)
                 # loss scaling happens HERE so every cotangent of this
                 # trace (param grads AND probe/halo cotangents) carries
                 # the scale; the reduction below divides it back out
@@ -1268,21 +1273,24 @@ class Trainer:
             # global l2 norm of the reduced gradient (telemetry; the
             # grads are replicated post-psum, so this is the true
             # distributed gradient's norm, not a per-device slice's)
-            gnorm = jnp.sqrt(sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree_util.tree_leaves(pgrads)))
+            with named_phase("grad_norm"):
+                gnorm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree_util.tree_leaves(pgrads)))
             # non-finite count over the REDUCED gradient: the tripwire's
             # 'grads' phase and (under loss scaling) the overflow flag
             # driving the in-graph step-skip
             if tripwire or ls_on:
-                gbad = sum(
-                    jnp.sum(~jnp.isfinite(g), dtype=jnp.int32)
-                    for g in jax.tree_util.tree_leaves(pgrads))
+                with named_phase("tripwire"):
+                    gbad = sum(
+                        jnp.sum(~jnp.isfinite(g), dtype=jnp.int32)
+                        for g in jax.tree_util.tree_leaves(pgrads))
             if tripwire:
                 # forward-phase counts are per-device partials; psum
                 # makes them the global counts (replicated, like the
                 # loss metric). The grads count is post-psum already.
-                nf_counts = {k: psum(v) for k, v in nf_counts.items()}
+                with named_phase("tripwire"):
+                    nf_counts = {k: psum(v) for k, v in nf_counts.items()}
                 nf_counts["grads"] = gbad
             with named_phase("adam_update"):
                 new_params, new_opt = adam_update(
@@ -1339,7 +1347,8 @@ class Trainer:
                     for grp, bufs in new_comm.items()
                 }
 
-            loss_out = psum(loss) / n_train
+            with named_phase("loss"):
+                loss_out = psum(loss) / n_train
             new_state = {
                 "params": new_params,
                 "opt": new_opt,
@@ -1602,9 +1611,10 @@ class Trainer:
             self.restore_state(snap)
 
     def train_epoch(self, epoch: int) -> float:
-        rng = jax.random.fold_in(self._epoch_rng_base(),
-                                 self._epoch_rng_fold(epoch))
-        scale = jnp.float32(self.loss_scaler.scale)
+        with trace_span("fit/keys"):
+            rng = jax.random.fold_in(self._epoch_rng_base(),
+                                     self._epoch_rng_fold(epoch))
+            scale = jnp.float32(self.loss_scaler.scale)
         self.state, m = self._dispatch(
             lambda: self._step(self.state, self.data, rng, scale))
         # per-step telemetry (loss + grad norm, scalars) for fit()'s
@@ -1620,7 +1630,8 @@ class Trainer:
         # blocking float() below), state and label are consistent and a
         # resume neither skips nor repeats an epoch.
         self.last_epoch = epoch + 1
-        return float(loss)
+        with trace_span("fit/wait"):
+            return float(loss)
 
     def train_epochs(self, start_epoch: int, k: int) -> np.ndarray:
         """Run epochs [start_epoch, start_epoch + k) as ONE compiled
@@ -1628,19 +1639,22 @@ class Trainer:
         train_epoch calls — same per-epoch rng fold — but a single
         dispatch, so host round-trip cost is amortized k-fold and XLA
         may overlap across epoch boundaries. Returns the k losses."""
-        base = self._epoch_rng_base()
-        rngs = jax.vmap(
-            lambda e: jax.random.fold_in(base, self._epoch_rng_fold(e)))(
-            jnp.arange(start_epoch, start_epoch + k)
-        )
-        scale = jnp.float32(self.loss_scaler.scale)
+        with trace_span("fit/keys"):
+            base = self._epoch_rng_base()
+            rngs = jax.vmap(
+                lambda e: jax.random.fold_in(base,
+                                             self._epoch_rng_fold(e)))(
+                jnp.arange(start_epoch, start_epoch + k)
+            )
+            scale = jnp.float32(self.loss_scaler.scale)
         self.state, ms = self._dispatch(
             lambda: self._multi_step(self.state, self.data, rngs, scale))
         # ONE host sync for the whole block: pull every [k]-metric in a
         # single device_get instead of per-array transfers when fit()
         # later indexes loss/grad_norm/numerics per epoch (the megastep
         # harvest half of the dispatch-amortization lever)
-        ms = jax.device_get(ms)
+        with trace_span("fit/wait"):
+            ms = jax.device_get(ms)
         self._last_metrics = ms  # [k] numpy arrays; see train_epoch
         self.last_epoch = start_epoch + k  # see train_epoch
         return np.asarray(ms["loss"])
@@ -1841,13 +1855,18 @@ class Trainer:
 
         `profile_epochs=(A, B)` with `profile_dir` captures a
         ``jax.profiler`` device trace around the dispatched blocks of
-        epochs [A, B) (epoch-granular inside the window), then folds
-        the captured trace against the step's compiled HLO into a
-        contracted ``profile`` record: MEASURED per-phase device time
-        (spmm / dense / halo collectives / optimizer / ...) and the
-        measured comm/compute overlap fraction — the quantity the
-        report CLI previously only estimated. Without `profile_epochs`
-        the legacy auto-window (epochs start+6..start+8) applies, and
+        epochs [A, B): blocks are cut at A and at B and are otherwise
+        dispatched as they would be, so the trace is of the fused
+        scans the run spends its time in. After the capture stops, the
+        trace (``.xplane.pb``) is folded against the compiled HLO of
+        every scan length dispatched inside the window into a
+        contracted ``profile`` record: MEASURED device self time per
+        phase (spmm / dense / halo collectives / optimizer / ...) and
+        per scope path (``spmm/bwd/gather``), busy time against the
+        window, the longest idle gaps by the host span of this loop
+        open in them (``fit/keys``, ``fit/harvest``, ...), and the
+        measured comm/compute overlap fraction. Without
+        `profile_epochs` the window is epochs start+6..start+8, and
         the same analysis runs on it. The record rides the metrics
         sink and the returned result dict ("profile").
 
@@ -2001,7 +2020,13 @@ class Trainer:
                 log_fn("warning: profile_epochs set without "
                        "profile_dir; no trace captured")
                 prof_window = None
+        elif profile_dir and n_epochs > start_epoch:
+            # no window given: a few epochs once the run is warm
+            prof_window = (min(start_epoch + 6, n_epochs - 1),
+                           min(start_epoch + 9, n_epochs))
         prof_started_at = None   # first epoch inside the live capture
+        prof_lengths = set()     # scan lengths dispatched inside it
+        prof_anchor = None       # unix time read inside the anchor span
         prof_record = None       # the parsed profile record (result)
         probe_every = max(int(staleness_probe_every), 0)
         if probe_every and not tcfg.enable_pipeline:
@@ -2010,21 +2035,39 @@ class Trainer:
                    "construction); probes disabled")
             probe_every = 0
 
+        def _start_profile():
+            """Start the capture and lay its zero on the wall clock the
+            stream's `span` / `tracesync` records use."""
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0  # a Python trace is a million
+            opts.host_tracer_level = 2    # events; the spans stay
+            jax.profiler.start_trace(profile_dir, profiler_options=opts)
+            with trace_span(ANCHOR_SPAN):
+                return time.time()
+
         def _finish_profile(window):
             """Stop + fold the live capture into a profile record."""
             jax.profiler.stop_trace()
             log_fn(f"profiler trace written to {profile_dir}")
+            t0 = time.perf_counter()
             try:
-                body = self._profile_analysis(profile_dir)
+                body = self._profile_analysis(profile_dir, prof_lengths)
             except Exception as exc:  # noqa: BLE001 — telemetry only
                 log_fn(f"profile analysis failed: {exc!r}")
                 return None
             if body is None:
-                log_fn("profile analysis found no parsable trace "
-                       "events (backend without Chrome-trace export?)")
+                log_fn("profile analysis found no device operation in "
+                       "the capture")
                 return None
             body["epoch_start"], body["epoch_end"] = window
+            # the trace clock's zero in unix seconds: the anchor span's
+            # start was read on both clocks
+            anchor_s = body.pop("anchor_s")
+            body["t0_unix"] = round(prof_anchor - (anchor_s or 0.0), 6)
+            body["fold_s"] = round(time.perf_counter() - t0, 3)
             log_fn(f"profile window [{window[0]}, {window[1]}): "
+                   f"scans of {sorted(prof_lengths)}, busy "
+                   f"{body['busy_s']:.4f}s of {body['window_s']:.4f}s, "
                    f"measured overlap "
                    f"{body['overlap_fraction']:.1%} "
                    f"(comm {body['comm_s']:.4f}s device, compute "
@@ -2053,6 +2096,7 @@ class Trainer:
             periods.append(checkpoint_every)
 
         epoch = start_epoch
+        hspan = SpanCursor()
         seen_chunks = set()  # scan lengths already compiled
         # True while a dispatched-but-unfinished eval occupies the device
         # stream (its time would contaminate the next block's timing)
@@ -2155,6 +2199,12 @@ class Trainer:
             stall_det = flightrec.StallDetector(frec, stall_s).start()
         try:
             while epoch < n_epochs:
+                # host spans of the loop's sections, for a profiler
+                # trace's idle gaps (nothing is written without one):
+                # fit/boundary, then `step` with fit/dispatch, fit/keys
+                # and fit/wait inside, then fit/harvest, fit/log,
+                # fit/eval, fit/checkpoint where those run
+                hspan.to("fit/boundary")
                 # ---- boundary faults / preemption: the one point where
                 # the donated state is consistent and labeled ----
                 frec.crumb("boundary", epoch=epoch)
@@ -2649,16 +2699,11 @@ class Trainer:
                         eval_in_stream = False
                         epoch = rollback_to
                         continue
-                if profile_dir and not profiling:
-                    if prof_window is not None:
-                        if prof_window[0] <= epoch < prof_window[1]:
-                            jax.profiler.start_trace(profile_dir)
-                            profiling = True
-                            prof_started_at = epoch
-                    elif epoch >= min(start_epoch + 6, n_epochs - 1):
-                        jax.profiler.start_trace(profile_dir)
-                        profiling = True
-                        prof_started_at = epoch
+                if prof_window is not None and not profiling and \
+                        prof_window[0] <= epoch < prof_window[1]:
+                    prof_anchor = _start_profile()
+                    profiling = True
+                    prof_started_at = epoch
                 chunk = min(fused, n_epochs - epoch)
                 for m in periods:
                     to_boundary = m - epoch % m
@@ -2668,13 +2713,13 @@ class Trainer:
                     nxt = stream_plan.next_epoch(epoch + 1)
                     if nxt is not None:
                         chunk = min(chunk, nxt - epoch)
-                if prof_window is not None and not profiling and \
-                        epoch < prof_window[0]:
-                    # a fused block must not straddle the window start
-                    chunk = min(chunk, prof_window[0] - epoch)
-                if profiling or (profile_dir and prof_window is None
-                                 and epoch < start_epoch + 10):
-                    chunk = 1  # epoch-granular around the profiled window
+                if prof_window is not None:
+                    # blocks are cut at the window's two ends and are
+                    # otherwise dispatched as they would be: the trace
+                    # is of the programs the run spends its time in
+                    for edge in prof_window:
+                        if epoch < edge:
+                            chunk = min(chunk, edge - epoch)
                 # staleness probe: snapshot the stale halo carry BEFORE
                 # the dispatch donates it (obs docs: drift is old vs
                 # new carry — exchange(h[e-1]) vs exchange(h[e]))
@@ -2703,6 +2748,7 @@ class Trainer:
                     frec.crumb("slow-rank-injected", epoch=epoch,
                                slow_ms=slow_ms)
                     time.sleep(slow_ms / 1000.0)
+                hspan.to()
                 timer.clear()
                 # dispatch span left OPEN across the step: if the
                 # program wedges inside (a dead collective), the crash
@@ -2711,14 +2757,17 @@ class Trainer:
                 # annotate=True: the host span shows up in --profile-dir
                 # traces next to the named device phases
                 with timer.phase("step", annotate=True):
-                    if chunk == 1:
-                        loss = self.train_epoch(epoch)
-                        blk_losses = np.asarray([loss])
-                    else:
-                        blk_losses = np.asarray(
-                            self.train_epochs(epoch, chunk))
-                        loss = float(blk_losses[-1])
-                    jax.block_until_ready(self.state["params"])
+                    with trace_span("fit/dispatch"):
+                        if chunk == 1:
+                            loss = self.train_epoch(epoch)
+                            blk_losses = np.asarray([loss])
+                        else:
+                            blk_losses = np.asarray(
+                                self.train_epochs(epoch, chunk))
+                            loss = float(blk_losses[-1])
+                    with trace_span("fit/wait"):
+                        jax.block_until_ready(self.state["params"])
+                hspan.to("fit/harvest")
                 frec.exit("dispatch", epoch=epoch)
                 if tspan is not None:
                     # the block's spans: the real dispatch->harvest wall
@@ -2726,14 +2775,14 @@ class Trainer:
                     # tail ending at the harvest barrier
                     tspan.block(epoch, chunk, timer.durations()["step"])
                 dur = timer.durations()["step"] / chunk
-                stop_profile = profiling and (
-                    epoch + chunk >= prof_window[1]
-                    if prof_window is not None
-                    else epoch >= start_epoch + 8)
-                if stop_profile:
+                if profiling:
+                    prof_lengths.add(chunk)
+                if profiling and epoch + chunk >= prof_window[1]:
                     profiling = False
+                    hspan.to()
                     prof_record = _finish_profile(
                         (prof_started_at, epoch + chunk)) or prof_record
+                    prof_window = None   # one capture per fit call
                 # first 5 epochs after (re)start excluded from averaged
                 # timings — they include jit compilation (the reference
                 # excludes epochs <5 and log epochs, train.py:364). A chunk
@@ -3117,6 +3166,7 @@ class Trainer:
                                "forward halo ring + cotangent return "
                                "ring (both modes move both)")
 
+                hspan.to("fit/log")
                 if reference_logs and (epoch + 1) % 10 == 0:
                     # reference log line format (train.py:369-371,
                     # pinned byte-exact in obs/format.py); rank is
@@ -3129,6 +3179,7 @@ class Trainer:
                 if (epoch + 1) % tcfg.log_every == 0:
                     do_eval = tcfg.eval and eval_graphs and "val" in eval_graphs
                     if do_eval:
+                        hspan.to("fit/eval")
                         if pending is not None:
                             _harvest_eval(pending)
                             pending = None
@@ -3151,6 +3202,7 @@ class Trainer:
                     # processes); only process 0 writes (reference
                     # semantics, and N-1 fewer multi-GB writes to the
                     # shared filesystem)
+                    hspan.to("fit/checkpoint")
                     frec.enter("checkpoint-io", epoch=epoch + 1)
                     ck_t0 = tspan.clock() if tspan is not None else 0.0
                     host = self.host_state()
@@ -3357,6 +3409,7 @@ class Trainer:
             # inherit a permanently "full" disk. The crash handler
             # above runs BEFORE this, still degraded — exactly like a
             # real host whose disk is full when it dies
+            hspan.to()
             for kind in list(io_armed):
                 FAULTY_IO.disarm(kind)
             io_armed.clear()
@@ -3442,22 +3495,37 @@ class Trainer:
 
     # ---------------- profiling / staleness ---------------------------
 
-    def step_compiled_text(self) -> str:
-        """Optimized-HLO text of the single-epoch train step (the
-        metadata op_name scopes are the join key between trace events
-        and named phases — obs/profiler.py / obs/anatomy.py). Hits
-        jax's compile cache when the step already ran unfused."""
-        rng = jax.random.fold_in(self._epoch_rng_base(), 0)
-        return self._step.lower(self.state, self.data, rng,
-                                jnp.float32(self.loss_scaler.scale)) \
-            .compile().as_text()
+    def step_compiled_text(self, length: int = 1) -> str:
+        """Optimized-HLO text of the program `fit` dispatches for a
+        block of `length` epochs: the single-epoch step, or the scan of
+        that length (the metadata op_name scopes are the join key
+        between trace events and named phases, obs/profiler.py).
+        Compiled past the persistent cache, whose entries carry the
+        scope names of the checkout that wrote them
+        (backend.compiled_text_uncached): one real compile."""
+        from ..backend import compiled_text_uncached
 
-    def _profile_analysis(self, profile_dir: str):
+        base = self._epoch_rng_base()
+        scale = jnp.float32(self.loss_scaler.scale)
+        if length == 1:
+            lowered = self._step.lower(
+                self.state, self.data, jax.random.fold_in(base, 0), scale)
+        else:
+            rngs = jax.vmap(lambda e: jax.random.fold_in(base, e))(
+                jnp.arange(length))
+            lowered = self._multi_step.lower(self.state, self.data, rngs,
+                                             scale)
+        return compiled_text_uncached(lowered)
+
+    def _profile_analysis(self, profile_dir: str, lengths):
         """Fold the newest capture under `profile_dir` against the
-        compiled step; returns a profile-record body or None."""
+        compiled program of every scan length dispatched inside the
+        window; returns a profile-record body or None."""
         from ..obs.profiler import analyze_trace_dir
 
-        return analyze_trace_dir(profile_dir, self.step_compiled_text())
+        return analyze_trace_dir(
+            profile_dir,
+            {k: self.step_compiled_text(k) for k in sorted(lengths)})
 
     def _staleness_drift(self, old_halo, new_halo):
         """Per-layer relative drift between the stale halo carry

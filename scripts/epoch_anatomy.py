@@ -15,7 +15,7 @@ dropout=0 (no RNG, no mask traffic) | norm=None (no LayerNorm
 fwd/bwd) | n_linear tail only dispatch floor probe: fused=1 vs 4.
 
 The ablation clock itself lives in pipegcn_tpu/obs/anatomy.py
-(`time_config` / `time_variants`) next to the structural HLO
+(`time_config`) next to the structural HLO
 attribution (`step_anatomy`, the CLI's --anatomy flag); this script is
 the chip-window wrapper that picks the headline config's variants and
 writes results/epoch_anatomy.json.

@@ -65,6 +65,57 @@ def test_compile_cache_env_set_code_sets_nothing_and_second_run_hits(
     assert second["hits"] > 0 and second["misses"] == 0
 
 
+_SCOPE_PROBE = """
+import json, re, sys
+import jax, jax.numpy as jnp
+from pipegcn_tpu import backend
+backend.place_compile_cache()
+def f(x):
+    with jax.named_scope(sys.argv[1]):
+        return jnp.sin(x) @ x.T
+jf = jax.jit(f)
+x = jnp.ones((64, 64))
+jf(x).block_until_ready()          # as a run dispatches before its window
+low = jf.lower(x)
+def scopes(text):
+    return sorted(set(re.findall(r'op_name="jit\\(f\\)/(\\w+)/', text)))
+n = {"hits": 0}
+def on_event(name, **kw):
+    if name.endswith("/cache_hits"): n["hits"] += 1
+jax.monitoring.register_event_listener(on_event)
+print(json.dumps({"cached": scopes(low.compile().as_text()),
+                  "uncached": scopes(backend.compiled_text_uncached(low)),
+                  "hits_after": n["hits"],
+                  "still_on": jax.config.jax_enable_compilation_cache}))
+"""
+
+
+def test_a_cached_executable_names_the_scopes_of_who_compiled_it(tmp_path):
+    """The cache's key leaves metadata out, so a second checkout that
+    renamed a scope is served the first one's names: the text for the
+    scope join is compiled past the cache, and the cache stays on."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_ENABLE_COMPILATION_CACHE"}
+    env.update(PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+
+    def run(scope):
+        r = subprocess.run([sys.executable, "-c", _SCOPE_PROBE, scope],
+                           env=env, capture_output=True, text=True,
+                           timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return json.loads(r.stdout.strip().splitlines()[-1])
+
+    first = run("before")
+    assert first["cached"] == first["uncached"] == ["before"]
+    second = run("after")
+    assert second["cached"] == ["before"]       # stale: the first's names
+    assert second["uncached"] == ["after"]
+    assert second["still_on"] is True
+
+
 def test_require_tpu_refuses_the_cpu_and_names_it():
     from pipegcn_tpu import backend
 

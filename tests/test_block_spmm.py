@@ -391,3 +391,48 @@ def test_trainer_headline_stack_fused():
     losses = list(t.train_epochs(0, 4)) + list(t.train_epochs(4, 16))
     assert np.isfinite(losses).all()
     assert np.mean(losses[-4:]) < np.mean(losses[:4])
+
+
+# ---------------- named scopes inside the kernel ---------------------------
+
+@pytest.mark.parametrize("kernel", [
+    dict(),
+    dict(block_group=4, rem_dtype="float8"),
+], ids=["block", "block-u4-f8"])
+def test_scan_names_the_kernels_work(kernel):
+    """In the compiled 2-epoch scan, what runs under `spmm` names a
+    second-level scope (unpack, tile, rem_gather, rem_reduce, cast,
+    unpermute, ...), forward and under `bwd`: at least 95% of the bytes
+    its instructions move."""
+    from pipegcn_tpu.obs.anatomy import scope_coverage
+    from pipegcn_tpu.obs.profiler import hlo_op_map, scope_path
+    from pipegcn_tpu.partition import locality_clusters
+
+    g = synthetic_graph(num_nodes=600, avg_degree=10, n_feat=12,
+                        n_class=4, homophily=0.9, seed=25)
+    parts = partition_graph(g, 4, seed=0)
+    cluster = locality_clusters(g, target_size=64, seed=0)
+    sg = ShardedGraph.build(g, parts, n_parts=4, cluster=cluster)
+    cfg = ModelConfig(layer_sizes=(12, 16, 16, 4), norm="layer",
+                      dropout=0.2, train_size=sg.n_train_global,
+                      spmm_impl="block", block_tile=32, dtype="bfloat16",
+                      use_pp=True, **kernel)
+    t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
+    grouped = any(k.startswith("blk_fwdu_g") for k in t._block_tables)
+    assert grouped == ("block_group" in kernel)
+    txt = t.step_compiled_text(2)
+    cov = scope_coverage(txt)
+    for direction in ("fwd", "bwd"):
+        assert cov[direction]["n_ops"] > 0
+        assert cov[direction]["fraction"] >= 0.95, cov
+    paths = {scope_path(op) for op, _ in hlo_op_map(txt).values()}
+    want = {"unpack", "tile", "unpermute", "rem_gather", "rem_reduce",
+            "rem_unpermute", "scale"}
+    if "rem_dtype" in kernel:
+        want.add("cast")             # fp8 e4m3 forward, e5m2 backward
+    assert {"spmm/" + w for w in want} <= paths
+    assert {"spmm/bwd/" + w for w in want} <= paths
+    below = {tok for p in paths if p.startswith("spmm/")
+             for tok in p.split("/")[1:]}
+    assert below <= want | {"bwd", "cast", "rem_relayout", "tripwire"}, \
+        below

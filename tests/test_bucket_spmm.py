@@ -237,3 +237,82 @@ def test_transport_cast_saturates_not_nan():
     assert y[0] == 448.0 and y[1] == -448.0
     # identity when no transport dtype
     assert transport_cast(x, None) is x
+
+
+# ---------------- named scopes inside the kernel ---------------------------
+
+def _window_graph(n=256, deg=12, n_feat=12, n_class=4, seed=0):
+    """Every node aggregates a contiguous id window below it: index
+    runs long enough for streaming-slab plans (tests/test_reorder.py)."""
+    from pipegcn_tpu.graph.csr import Graph
+
+    src = [j for i in range(n) for j in range(max(0, i - deg), i)]
+    dst = [i for i in range(n) for j in range(max(0, i - deg), i)]
+    rng = np.random.default_rng(seed)
+    ar = np.arange(n)
+    return Graph(
+        num_nodes=n, src=np.asarray(src, np.int64),
+        dst=np.asarray(dst, np.int64),
+        ndata={"feat": rng.normal(size=(n, n_feat)).astype(np.float32),
+               "label": rng.integers(0, n_class, size=n).astype(np.int64),
+               "train_mask": ar < n // 2,
+               "val_mask": (ar >= n // 2) & (ar < 3 * n // 4),
+               "test_mask": ar >= 3 * n // 4})
+
+
+@pytest.mark.parametrize("slab", ["off", "on"])
+def test_scan_names_the_kernels_work(slab):
+    """In the compiled 2-epoch scan, what runs under `spmm` names a
+    second-level scope (gather, reduce, unpermute, ...), forward and
+    under `bwd`: at least 95% of the bytes its instructions move. A
+    kernel change that leaves work unnamed shows here."""
+    from pipegcn_tpu.obs.anatomy import scope_coverage
+    from pipegcn_tpu.obs.profiler import hlo_op_map, scope_path
+
+    g = _window_graph()
+    sg = ShardedGraph.build(g, np.zeros(g.num_nodes, np.int32), n_parts=1)
+    cfg = ModelConfig(layer_sizes=(12, 16, 16, 4), norm="layer",
+                      dropout=0.2, train_size=sg.n_train_global,
+                      spmm_impl="bucket", slab=slab, dtype="bfloat16",
+                      use_pp=True)
+    t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
+    assert t._slab_active() == (slab == "on")
+    txt = t.step_compiled_text(2)
+    cov = scope_coverage(txt)
+    for direction in ("fwd", "bwd"):
+        assert cov[direction]["n_ops"] > 0
+        assert cov[direction]["fraction"] >= 0.95, cov
+    paths = {scope_path(op) for op, _ in hlo_op_map(txt).values()}
+    assert {"spmm/gather", "spmm/reduce", "spmm/unpermute",
+            "spmm/bwd/gather", "spmm/bwd/reduce",
+            "spmm/bwd/unpermute"} <= paths
+    # every path below spmm is made of the kernel's own names
+    kernel = {"bwd", "gather", "reduce", "unpermute", "relayout", "cast",
+              "scale"}
+    below = {tok for p in paths if p.startswith("spmm/")
+             for tok in p.split("/")[1:]}
+    assert below <= kernel | {"tripwire"}, below
+
+
+def test_slabbed_aggregate_names_its_relayout(edges):
+    """Rows wider than a slab go through the feature-slab transposes:
+    their copies are `relayout`, and a prefix (the block kernel's
+    remainder) renames all four scopes."""
+    import re
+
+    src, dst, n_out, n_src = edges
+    plan = BucketPlan(src, dst, n_out, n_src)
+    mats = [jnp.asarray(m) for m in plan.fwd_mats]
+    inv = jnp.asarray(plan.fwd_inv)
+
+    def paths(scope):
+        fn = jax.jit(lambda x: bucket_aggregate(x, mats, inv, slab=4,
+                                                scope=scope))
+        txt = fn.lower(jnp.ones((n_src, 10), jnp.float32)).as_text(
+            debug_info=True)
+        return set(re.findall(r"(?:rem_)?(?:gather|reduce|unpermute"
+                              r"|relayout)(?=/)", txt))
+
+    assert paths("") == {"gather", "reduce", "unpermute", "relayout"}
+    assert paths("rem_") == {"rem_gather", "rem_reduce", "rem_unpermute",
+                             "rem_relayout"}

@@ -30,10 +30,14 @@ from pipegcn_tpu.cli.report import main as report_main
 from pipegcn_tpu.cli.timeline import main as timeline_main
 from pipegcn_tpu.obs import MetricsLogger, read_metrics, validate_record
 from pipegcn_tpu.obs.profiler import (
+    ANCHOR_SPAN,
     classify_op,
-    fold_trace,
+    fold_xplane,
     hlo_op_map,
+    module_name,
     parse_profile_epochs,
+    scope_path,
+    self_times,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -63,33 +67,74 @@ def test_classify_op_phases():
         == "dropout_rng"
     assert classify_op("", "collective-permute") == "halo_comm"
     assert classify_op("jit(step)/something_else/add") == "other"
+    # the scopes this step gained: the loss, the tripwire's counts and
+    # the gradient norm, the stale concat; a kernel's inner scopes stay
+    # in their kernel's phase
+    assert classify_op("jit(step)/shard_map/jvp(loss)/reduce_sum") \
+        == "loss"
+    assert classify_op("jit(step)/jvp(layer1)/tripwire/reduce_sum") \
+        == "numerics"
+    assert classify_op("jit(step)/grad_norm/sqrt") == "numerics"
+    assert classify_op("jit(step)/jvp(layer1)/halo_concat/concatenate") \
+        == "halo_concat"
+    assert classify_op(
+        "jit(multi)/while/body/transpose(jvp(layer1))/spmm/bwd/"
+        "rem_gather/jit(_take)/gather") == "spmm"
+    # a primitive named like a scope is no scope
+    assert classify_op("jit(step)/jvp(layer1)/loss_weights") == "other"
 
 
-def test_fold_trace_overlap_math():
-    """Synthetic timeline: comm [0, 10] with compute covering [0, 6] on
-    the same pid -> 60% overlap; phases fold by classified scope."""
-    op_map = {"cp.1": ("jit(s)/layer0/halo_exchange/ppermute",
-                       "collective-permute"),
-              "dot.1": ("jit(s)/layer0/spmm/dot_general", "dot"),
-              "dot.2": ("jit(s)/layer0/dense/dot_general", "dot")}
-    events = [
-        {"ph": "X", "pid": 1, "tid": 7, "ts": 0.0, "dur": 10.0,
-         "name": "cp.1", "args": {"hlo_op": "cp.1"}},
-        {"ph": "X", "pid": 1, "tid": 8, "ts": 0.0, "dur": 4.0,
-         "name": "dot.1", "args": {"hlo_op": "dot.1"}},
-        {"ph": "X", "pid": 1, "tid": 9, "ts": 4.0, "dur": 2.0,
-         "name": "dot.2", "args": {"hlo_op": "dot.2"}},
-        # a different pid's compute must NOT count toward pid 1's comm
-        {"ph": "X", "pid": 2, "tid": 1, "ts": 0.0, "dur": 100.0,
-         "name": "dot.1", "args": {"hlo_op": "dot.1"}},
-    ]
-    out = fold_trace(events, op_map)
+@pytest.mark.parametrize("op_name, path", [
+    ("jit(step)/shard_map/transpose(jvp(layer1))/spmm/bwd/gather/"
+     "jit(_take)/gather", "spmm/bwd/gather"),
+    ("jit(multi)/shard_map/while/body/closed_call/jvp(layer0)/spmm/"
+     "reduce/reduce_sum", "spmm/reduce"),
+    # the last component is the primitive, whatever its name
+    ("jit(step)/shard_map/jvp(layer1)/jit(_take)/gather", ""),
+    ("jit(step)/shard_map/jvp(layer1)/dense/dot_general", "dense"),
+    ("jit(step)/shard_map/jvp(loss)/jit(log_softmax)/exp", "loss"),
+    # a fusion that lists two instructions is named by the first
+    ("transpose(jvp())/norm/mul;transpose(jvp())/broadcast_in_dim",
+     "norm"),
+    ("", ""),
+])
+def test_scope_path(op_name, path):
+    assert scope_path(op_name) == path
+
+
+def _program(scan_length, ops, module="jit_multi"):
+    return {"scan_length": scan_length, "module": module,
+            "map": {k: (v, k.split(".")[0]) for k, v in ops.items()}}
+
+
+def test_fold_xplane_overlap_math():
+    """Synthetic lines: comm [0, 10] with compute covering [0, 6] on
+    the same device -> 60% overlap; phases fold by classified scope;
+    seconds are the mean over the two devices."""
+    prog = _program(1, {
+        "collective-permute.1": "jit(s)/layer0/halo_exchange/ppermute",
+        "dot.1": "jit(s)/layer0/spmm/tile/dot_general",
+        "dot.2": "jit(s)/layer0/dense/dot_general"}, module="jit_s")
+    tr = {"host": [], "lines": [
+        {"device": 0, "events": [
+            ["collective-permute.1", 0.0, 10.0, "jit_s(1)"]]},
+        {"device": 0, "events": [["dot.1", 0.0, 4.0, "jit_s(1)"],
+                                 ["dot.2", 4.0, 2.0, "jit_s(1)"]]},
+        # another device's compute must NOT cover device 0's comm
+        {"device": 1, "events": [["dot.1", 0.0, 100.0, "jit_s(1)"]]},
+    ]}
+    out = fold_xplane(tr, [prog])
     assert out["overlap_fraction"] == pytest.approx(0.6)
-    assert out["comm_s"] == pytest.approx(10.0 / 1e6)
-    assert out["phases"]["halo_comm"] == pytest.approx(10.0 / 1e6)
-    assert out["phases"]["spmm"] == pytest.approx(104.0 / 1e6)
-    assert out["phases"]["dense"] == pytest.approx(2.0 / 1e6)
-    assert out["n_device_events"] == 4
+    assert out["comm_s"] == pytest.approx(10.0 / 2 * 1e-9)
+    assert out["phases"]["halo_comm"] == pytest.approx(5e-9)
+    assert out["phases"]["spmm"] == pytest.approx(52e-9)
+    assert out["phases"]["dense"] == pytest.approx(1e-9)
+    assert out["paths"]["spmm/tile"] == pytest.approx(52e-9)
+    assert out["n_device_events"] == 4 and out["n_matched_events"] == 4
+    assert out["n_devices"] == 2
+    # device 0 is busy over [0, 10], device 1 over [0, 100]
+    assert out["busy_s"] == pytest.approx(55e-9)
+    assert out["window_s"] == pytest.approx(100e-9)
 
 
 def test_hlo_op_map_parses_metadata():
@@ -98,16 +143,137 @@ def test_hlo_op_map_parses_metadata():
         'ENTRY %main.5 () -> f32[2] {\n'
         '  %dot.1 = f32[2]{0} dot(f32[2,3]{1,0} %a, f32[3]{0} %b), '
         'lhs_contracting_dims={1}, rhs_contracting_dims={0}, '
-        'metadata={op_name="jit(step)/layer0/spmm/dot_general" '
+        'metadata={op_name="jit(step)/layer0/spmm/tile/dot_general" '
         'source_file="x.py" source_line=1}\n'
         '  ROOT %cp.2 = f32[2]{0} collective-permute(f32[2]{0} %dot.1), '
         'metadata={op_name="jit(step)/layer0/halo_exchange/ppermute"}\n'
         '}\n')
     m = hlo_op_map(txt)
-    assert m["dot.1"] == ("jit(step)/layer0/spmm/dot_general", "dot")
+    assert m["dot.1"] == ("jit(step)/layer0/spmm/tile/dot_general", "dot")
+    assert scope_path(m["dot.1"][0]) == "spmm/tile"
     assert m["cp.2"][1] == "collective-permute"
-    from pipegcn_tpu.obs.profiler import module_name
     assert module_name(txt) == "jit_step"
+
+
+# ---------------- the fold on a nested op line ----------------------------
+# A chip's op line as recorded: a `while` spans its body, whose fusions
+# span nothing. Times in ns; [op, start, duration, module].
+
+_SCAN_OPS = {
+    "while.1": "jit(multi)/while",
+    "fusion.1": "jit(multi)/while/body/jvp(layer1)/spmm/gather/"
+                "jit(_take)/gather",
+    "fusion.2": "jit(multi)/while/body/jvp(layer1)/spmm/reduce/reduce_sum",
+    "fusion.3": "jit(multi)/while/body/transpose(jvp(layer1))/spmm/bwd/"
+                "gather/jit(_take)/gather",
+    "copy.1": "",
+}
+
+
+def _scan_line(start, module, n_body):
+    """One run of a scan program: copy.1, then while.1 over `n_body`
+    rounds of fusion.1 (30), fusion.2 (20), fusion.3 (40) with 10 of
+    the loop's own time between rounds."""
+    evs = [["copy.1", start, 50.0, module]]
+    t = start + 60.0
+    w0 = t
+    body = []
+    for _ in range(n_body):
+        t += 10.0
+        for name, dur in (("fusion.1", 30.0), ("fusion.2", 20.0),
+                          ("fusion.3", 40.0)):
+            body.append([name, t, dur, module])
+            t += dur
+    evs.append(["while.1", w0, t - w0, module])
+    return evs + body, t
+
+
+def test_fold_nested_while_self_times_and_union():
+    evs, end = _scan_line(1000.0, "jit_multi(7)", 2)
+    selfs, leaves = self_times(evs)
+    by_name = {}
+    for e, ns in zip(evs, selfs):
+        by_name[e[0]] = by_name.get(e[0], 0.0) + ns
+    # the while's own time is what its body leaves: 2 x 10
+    assert by_name["while.1"] == pytest.approx(20.0)
+    assert by_name["fusion.1"] == pytest.approx(60.0)
+    assert leaves[evs.index(next(e for e in evs if e[0] == "while.1"))] \
+        is False
+    out = fold_xplane({"host": [], "lines": [{"device": 0, "events": evs}]},
+                      [_program(2, _SCAN_OPS)])
+    # busy is the union: the while and its body are counted once
+    assert out["busy_s"] == pytest.approx((50.0 + 200.0) * 1e-9)
+    assert out["window_s"] == pytest.approx((end - 1000.0) * 1e-9)
+    assert out["busy_s"] <= out["window_s"]
+    assert sum(out["phases"].values()) + out["other_programs_s"] \
+        == pytest.approx(out["busy_s"])
+    assert out["paths"] == pytest.approx({
+        "spmm/gather": 60e-9, "spmm/reduce": 40e-9,
+        "spmm/bwd/gather": 80e-9})
+    assert out["phases"]["spmm"] == pytest.approx(180e-9)
+    # the while and the copy name no scope of the program
+    assert out["unscoped_s"] == pytest.approx(70e-9)
+    assert out["programs"][0]["matched"] == 1.0
+
+
+def test_fold_two_programs_with_clashing_instruction_numbers():
+    """A scan of 2 and a scan of 3 epochs are two programs in which
+    `fusion.2` is another operation: each module is read through the
+    map of its own program, told apart by the rounds its body ran."""
+    ops3 = dict(_SCAN_OPS)
+    ops3["fusion.2"] = _SCAN_OPS["fusion.3"]      # the numbers clash
+    ops3["fusion.3"] = _SCAN_OPS["fusion.2"]
+    a, end_a = _scan_line(0.0, "jit_multi(11)", 2)
+    b, _ = _scan_line(end_a + 100.0, "jit_multi(22)", 3)
+    out = fold_xplane(
+        {"host": [], "lines": [{"device": 0, "events": a + b}]},
+        [_program(2, _SCAN_OPS), _program(3, ops3)])
+    progs = {p["scan_length"]: p for p in out["programs"]}
+    assert progs[2]["modules"] == ["jit_multi(11)"]
+    assert progs[3]["modules"] == ["jit_multi(22)"]
+    assert progs[2]["matched"] == progs[3]["matched"] == 1.0
+    # scan 2: reduce 2 x 20; scan 3: its `fusion.3` is the reduce, 3 x 40
+    assert out["paths"]["spmm/reduce"] == pytest.approx((40 + 120) * 1e-9)
+    assert out["paths"]["spmm/bwd/gather"] == pytest.approx(
+        (80 + 60) * 1e-9)
+
+
+def test_fold_leaves_another_modules_events_out():
+    """An eval program or the eager key building inside the window:
+    busy, but in no phase of the step."""
+    evs, end = _scan_line(0.0, "jit_multi(7)", 2)
+    foreign = [["fusion.1", end + 50.0, 500.0, "jit_eval(9)"],
+               ["threefry.4", end + 600.0, 30.0, "jit__threefry(3)"]]
+    out = fold_xplane(
+        {"host": [], "lines": [{"device": 0, "events": evs + foreign}]},
+        [_program(2, _SCAN_OPS)])
+    assert out["other_programs_s"] == pytest.approx(530e-9)
+    assert out["phases"]["spmm"] == pytest.approx(180e-9)
+    assert out["busy_s"] == pytest.approx((250.0 + 530.0) * 1e-9)
+    assert out["busy_s"] <= out["window_s"]
+    assert out["programs"][0]["modules"] == ["jit_multi(7)"]
+    assert out["n_device_events"] == len(evs) + 2
+
+
+def test_fold_idle_gaps_named_by_program_span():
+    """A gap is named by the innermost span of the program open at its
+    middle; a runtime event nested deeper stands beside it."""
+    a, end_a = _scan_line(0.0, "jit_multi(7)", 2)
+    b, _ = _scan_line(end_a + 1000.0, "jit_multi(7)", 2)
+    mid = end_a + 500.0
+    host = [[ANCHOR_SPAN, 5.0, 1.0],
+            ["step", end_a + 300.0, 5000.0],
+            ["fit/keys", mid - 100.0, 300.0],
+            ["PjitFunction(_threefry_fold_in)", mid - 10.0, 50.0],
+            ["fit/harvest", end_a + 10.0, 200.0]]
+    out = fold_xplane({"host": host,
+                       "lines": [{"device": 0, "events": a + b}]},
+                      [_program(2, _SCAN_OPS)])
+    top = out["idle_gaps"][0]
+    assert top["span"] == "fit/keys"
+    assert top["inner"] == "PjitFunction(_threefry_fold_in)"
+    assert top["s"] == pytest.approx(1000e-9) and top["n"] == 1
+    assert out["anchor_s"] == pytest.approx(5e-9)
 
 
 # ---------------- end-to-end CPU-mesh smoke (the acceptance gate) ---------
@@ -174,7 +340,8 @@ def test_profile_smoke_all_record_kinds(profiled_run):
 def test_profile_record_measures_overlap(profiled_run):
     """The profile record carries a measured overlap fraction in
     [0, 1], a phase decomposition with real device time in the comm
-    phases (P=4 -> halo collectives exist), and the capture window."""
+    phases (P=4 -> halo collectives exist), busy time inside the
+    window, and the capture window."""
     _, mpath, res = profiled_run
     profs = [r for r in read_metrics(mpath) if r["event"] == "profile"]
     assert len(profs) == 1
@@ -187,8 +354,98 @@ def test_profile_record_measures_overlap(profiled_run):
         p["comm_s"] + p["compute_s"], rel=1e-6)
     assert (p["epoch_start"], p["epoch_end"]) == (1, 2)
     assert p["n_matched_events"] > 0
+    assert 0 < p["busy_s"] <= p["window_s"]
+    assert p["n_devices"] == 4
+    # one epoch inside the window: the single-epoch step, joined
+    assert [q["scan_length"] for q in p["programs"]] == [1]
+    assert p["programs"][0]["matched"] > 0.9
+    assert p["paths"].get("halo_exchange", 0) > 0
+    # the trace clock's zero lies on the stream's clock, in this run
+    spans = [r for r in read_metrics(mpath) if r["event"] == "span"]
+    assert spans and abs(p["t0_unix"] - spans[-1]["t_start"]) < 600
     # the same record rides the fit result
     assert res is not None
+
+
+@pytest.fixture(scope="module")
+def fused_run(tmp_path_factory):
+    """The same job over 12 epochs in scans of 3 (blocks of 3, 2, 3, 2,
+    2 under --log-every 5), once with a window over epochs [3, 9) and
+    once with profiling off."""
+    from pipegcn_tpu.cli.main import run
+
+    out = {}
+    for name, extra in (("off", []),
+                        ("on", ["--profile-epochs", "3:9"])):
+        tmp_path = tmp_path_factory.mktemp("fused_" + name)
+        mpath = tmp_path / "metrics.jsonl"
+        if extra:
+            extra += ["--profile-dir", str(tmp_path / "trace")]
+        args = _cli_args(tmp_path, [
+            "--enable-pipeline", "--n-epochs", "12",
+            "--fused-epochs", "3", "--spmm-impl", "bucket",
+            "--metrics-out", str(mpath)] + extra)
+        out[name] = (mpath, run(args))
+    return out
+
+
+@pytest.mark.profile
+def test_profile_window_traces_the_fused_scans(fused_run):
+    """The window is cut at 3 and at 9 and otherwise dispatched as the
+    run would be: blocks [3, 5), [5, 8), [8, 9), so the record joins
+    the scans of 2 and 3 epochs and the single step, each against its
+    own compiled text, and reads the kernels' scopes."""
+    mpath, res = fused_run["on"]
+    profs = [r for r in read_metrics(mpath) if r["event"] == "profile"]
+    assert len(profs) == 1
+    p = profs[0]
+    validate_record(p)
+    assert (p["epoch_start"], p["epoch_end"]) == (3, 9)
+    progs = {q["scan_length"]: q for q in p["programs"]}
+    assert set(progs) == {1, 2, 3}
+    for q in progs.values():
+        assert q["n_events"] > 0 and q["matched"] > 0.9
+        assert len(q["modules"]) == 1
+    assert p["paths"]["spmm/gather"] > 0
+    assert any(k.startswith("spmm/bwd/") for k in p["paths"])
+    under = sum(v for k, v in p["paths"].items() if k.startswith("spmm"))
+    # (each path is rounded to a nanosecond)
+    assert under == pytest.approx(p["phases"]["spmm"], abs=1e-7)
+    assert 0 < p["busy_s"] <= p["window_s"]
+    # the gaps are named by this loop's host spans
+    assert p["idle_gaps"]
+    assert {g["span"] for g in p["idle_gaps"]} & {
+        "fit/dispatch", "fit/keys", "fit/wait", "fit/harvest",
+        "fit/log", "fit/boundary"}
+
+
+def test_profiling_off_compiles_what_the_plan_needs(fused_run):
+    """With profiling off the run dispatches scans of 3 and 2 and never
+    the single step: two compiled step programs, as before the scopes
+    and the spans were there; no profile record."""
+    mpath, res = fused_run["off"]
+    t = res["trainer"]
+    assert t._multi_step._cache_size() == 2
+    assert t._step._cache_size() == 0
+    assert not [r for r in read_metrics(mpath) if r["event"] == "profile"]
+    # the window adds one program: the single step of block [8, 9)
+    t_on = fused_run["on"][1]["trainer"]
+    assert t_on._multi_step._cache_size() == 2
+    assert t_on._step._cache_size() == 1
+
+
+def test_report_prints_paths_under_the_phase_table(fused_run, capsys):
+    mpath, _ = fused_run["on"]
+    assert report_main([str(mpath)]) == 0
+    out = capsys.readouterr().out
+    assert out.index("profiled device time") \
+        < out.index("device busy (profiled)") < out.index("spmm/gather")
+    assert "scans of [1, 2, 3]" in out
+    assert "device idle (profiled)" in out
+    assert report_main([str(mpath), "--json"]) == 0
+    s = json.loads(capsys.readouterr().out)
+    assert s["profile_scan_lengths"] == [1, 2, 3]
+    assert s["profile_paths"]["spmm/gather"] > 0
 
 
 @pytest.mark.profile

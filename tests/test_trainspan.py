@@ -11,7 +11,7 @@ cli/report.py, docs/OBSERVABILITY.md "Training traces"):
     tracesync barrier anchors (and from grad_reduce span ends when no
     tracesync landed);
   - fold_spans' interval-union overlap agrees with the profiler's
-    fold_trace on a shared interval fixture — one overlap definition,
+    fold_xplane on a shared interval fixture — one overlap definition,
     two sources;
   - straggler attribution names the rank whose compute window started
     last ON THE ALIGNED CLOCK (a big wall-clock skew must not fool it);
@@ -48,7 +48,7 @@ import pytest
 from pipegcn_tpu.obs.health import AlertEngine, load_rules, prometheus_text
 from pipegcn_tpu.obs.live import LiveAggregator
 from pipegcn_tpu.obs.metrics import MetricsLogger, read_metrics
-from pipegcn_tpu.obs.profiler import fold_trace
+from pipegcn_tpu.obs.profiler import fold_xplane
 from pipegcn_tpu.obs.timeline import build_timeline
 from pipegcn_tpu.obs.trainspan import (
     COMM_OPS,
@@ -182,7 +182,7 @@ def test_estimate_offsets_recovers_planted_skew():
 # ---------------- overlap agrees with the profiler fold ---------------
 
 
-def test_fold_spans_overlap_agrees_with_fold_trace():
+def test_fold_spans_overlap_agrees_with_fold_xplane():
     """One overlap definition, two sources: the span fold and the
     device-trace fold produce the SAME fraction on the same intervals
     (compute [0,10]s; halo [6,8] covered; grad_reduce [9,11] half
@@ -201,18 +201,18 @@ def test_fold_spans_overlap_agrees_with_fold_trace():
     fold = fold_spans(spans)
     assert fold["overlap_spans"] == pytest.approx(0.75)
 
-    events = [
-        {"ph": "X", "pid": 1, "ts": 0.0, "dur": 10e6, "name": "fusion",
-         "args": {"hlo_op": "op.c"}},
-        {"ph": "X", "pid": 1, "ts": 6e6, "dur": 2e6, "name": "all-gather",
-         "args": {"hlo_op": "op.h"}},
-        {"ph": "X", "pid": 1, "ts": 9e6, "dur": 2e6, "name": "all-reduce",
-         "args": {"hlo_op": "op.r"}},
-    ]
-    op_map = {"op.c": ("layer0/spmm", "fusion"),
-              "op.h": ("halo_exchange", "all-gather"),
-              "op.r": ("grad_reduce", "all-reduce")}
-    meas = fold_trace(events, op_map)
+    # the same intervals as a device's executor threads (ns): compute
+    # on one line, the two collectives on lines of their own
+    mod = "jit_step(1)"
+    tr = {"host": [], "lines": [
+        {"device": 0, "events": [["op.c", 0.0, 10e9, mod]]},
+        {"device": 0, "events": [["op.h", 6e9, 2e9, mod]]},
+        {"device": 0, "events": [["op.r", 9e9, 2e9, mod]]}]}
+    prog = {"scan_length": 1, "module": "jit_step",
+            "map": {"op.c": ("layer0/spmm/x", "fusion"),
+                    "op.h": ("halo_exchange/x", "all-gather"),
+                    "op.r": ("grad_reduce/x", "all-reduce")}}
+    meas = fold_xplane(tr, [prog])
     assert meas["overlap_fraction"] == pytest.approx(
         fold["overlap_spans"])
 
