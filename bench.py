@@ -155,8 +155,9 @@ def main():
                          "micro-bench tuner; fall back to the "
                          "deterministic default when no persisted "
                          "tuning table is trusted")
-    ap.add_argument("--tuner-samples", type=int, default=200_000,
-                    help="edge budget for the tuner's sampled slice")
+    ap.add_argument("--tuner-samples", type=int, default=1_000_000,
+                    help="edge budget for the tuner's sample of whole "
+                         "destination tile-rows")
     ap.add_argument("--rem-dtype", default="none",
                     choices=["none", "bfloat16", "float8"],
                     help="gather-transport dtype for the remainder "
@@ -506,12 +507,15 @@ def _measure(args, backend, device_kind, n_parts, sg,
     if getattr(trainer, "tuning", None):
         # the auto-tuner's decision + the full measured per-candidate
         # micro-bench table: WHY this kernel produced the number
+        from pipegcn_tpu.ops.tuner import SAMPLE_FIELDS
+
         tu = trainer.tuning
         extras["tuning"] = {
             "winner": dict(tu["winner"]),
             "source": tu["source"],
             "stale_reason": tu.get("stale_reason"),
             "costs": list(tu.get("costs", [])),
+            **{k: tu.get(k) for k in SAMPLE_FIELDS},
         }
 
     # The headline number is in hand from here on: the optional extras

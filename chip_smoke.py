@@ -346,7 +346,11 @@ def train_leg(args, log=print, require_memory_stats: bool = False) -> dict:
     log(f"  derived inputs: partition artifact {leg['artifact_source']}"
         f"; kernel tables {trainer.tables_source}; tuning {tuned}")
     log(f"  kernel that ran: {tuning['winner']['name']} "
-        f"(impl={facts['kernel']}); tuner cost table:")
+        f"(impl={facts['kernel']}); the tuner's sample: "
+        f"{tuning.get('sample_tile_rows')} tile-rows, dense coverage "
+        f"{tuning.get('sample_dense_coverage')} (shard "
+        f"{tuning.get('shard_dense_coverage')}), empty call "
+        f"{tuning.get('call_overhead_s')} s; cost table:")
     for c in tuning["costs"]:
         log(f"    {c['name']:<18}"
             f"{c['spmm_fwdbwd_s'] * 1e3:>10.2f} ms   est epoch SpMM "

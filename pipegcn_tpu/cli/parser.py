@@ -157,9 +157,16 @@ def create_parser() -> argparse.ArgumentParser:
                              "deterministic default kernel with a loud "
                              "record")
     parser.add_argument("--tuner-samples", "--tuner_samples", type=int,
-                        default=200_000,
-                        help="edge budget of the tuner's sampled "
-                             "degree-distribution slice")
+                        default=1_000_000,
+                        help="edge budget of the tuner's sample: whole "
+                             "blocks of destination tile-rows (1024 rows "
+                             "at the default tile), each row with all "
+                             "its in-edges and the source ids in place, "
+                             "so block candidates meet the shard's "
+                             "dense tiles; a shard under the budget is "
+                             "taken whole. Single rows drawn uniformly "
+                             "carried no dense tile, and 200k edges "
+                             "were one host round trip of device work")
     parser.add_argument("--rem-dtype", "--rem_dtype",
                         choices=["none", "bfloat16", "float8"],
                         default="none",

@@ -86,8 +86,9 @@ class ModelConfig:
     # to a persisted tuning table (falling back to the deterministic
     # default kernel when none exists — never a live measurement)
     tune: bool = True
-    # edge budget of the tuner's sampled degree-distribution slice
-    tuner_samples: int = 200_000
+    # edge budget of the tuner's sample of whole destination tile-rows
+    # (ops/tuner.py DEFAULT_EDGE_BUDGET)
+    tuner_samples: int = 1_000_000
     # gather-transport dtype for the bucket kernel / block remainder /
     # GAT attention kernel's wide value+cotangent gathers
     # (bucket_spmm.transport_dtypes): None = activation dtype;

@@ -350,18 +350,27 @@ class MetricsLogger:
         return rec
 
     def tuning(self, winner: Dict[str, Any], source: str,
-               costs, **extra) -> Dict[str, Any]:
+               costs, sample_dense_coverage: Optional[float] = None,
+               shard_dense_coverage: Optional[float] = None,
+               sample_tile_rows: Optional[int] = None,
+               call_overhead_s: Optional[float] = None,
+               **extra) -> Dict[str, Any]:
         """The SpMM auto-tuner's dispatch decision (ops/tuner.py +
         Trainer._resolve_auto): the winning kernel config, where the
-        decision came from (artifact | live | default), and the full
-        measured per-candidate cost table — the record that says WHY
-        this kernel dispatches."""
+        decision came from (artifact | live | default), the full
+        measured per-candidate cost table, and what the timed sample
+        carried (null where nothing was timed) — the record that says
+        WHY this kernel dispatches."""
         extra.setdefault("time_unix", time.time())
         return self.write({
             "event": "tuning",
             "winner": dict(winner),
             "source": str(source),
             "costs": list(costs),
+            "sample_dense_coverage": sample_dense_coverage,
+            "shard_dense_coverage": shard_dense_coverage,
+            "sample_tile_rows": sample_tile_rows,
+            "call_overhead_s": call_overhead_s,
             **extra,
         })
 

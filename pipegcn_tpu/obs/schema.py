@@ -17,7 +17,11 @@ from __future__ import annotations
 
 from typing import Dict, Mapping
 
-SCHEMA_VERSION = 15  # v15: journal record kind (write-ahead delta
+SCHEMA_VERSION = 16  # v16: the tuning record says what its sample
+#                      carried (sample_dense_coverage beside
+#                      shard_dense_coverage, sample_tile_rows,
+#                      call_overhead_s — ops/tuner.py sample_slice)
+#                 v15: journal record kind (write-ahead delta
 #                      journal lifecycle: append / watermark / replay /
 #                      truncate / verify / degraded / recovered / skew
 #                      — stream/journal.py, docs/STREAMING.md
@@ -192,11 +196,22 @@ FALLBACK_FIELDS: Dict[str, str] = {
 #                was rejected — the LOUD part of the stale-table path)
 #   "default"  — no table and no live tune allowed (multi-process or
 #                --no-tune): the tuner's fixed deterministic default
+# v16: what the timed sample carried (null for "default", which times
+# nothing). The sample is whole blocks of destination tile-rows; its
+# dense coverage (block_spmm._part_block_stats at the block candidates'
+# tile, threshold and budget) beside the whole shard's says whether the
+# block candidates met the shard's dense tiles. Extras: est_epoch_spmm_s
+# (the winner's estimate, call_overhead_s taken off, to hold against a
+# traced spmm_s).
 TUNING_FIELDS: Dict[str, str] = {
     "event": "string",             # "tuning"
     "winner": "object",            # the dispatched kernel config
     "source": "string",            # artifact | live | default
     "costs": "array",              # measured per-candidate cost table
+    "sample_dense_coverage": "number?",  # of the sampled tile-rows
+    "shard_dense_coverage": "number?",   # of the heaviest shard
+    "sample_tile_rows": "integer?",      # destination tile-rows timed
+    "call_overhead_s": "number?",        # an empty timed call
 }
 
 # one record per serving report window (serve/loadgen.run_serving_loop,

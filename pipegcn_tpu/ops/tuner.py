@@ -5,22 +5,40 @@ dense-coverage cutoffs in ``parallel/trainer.py``) were invalidated by
 the very first second shape they met (the products-shape block-kernel
 crash). This module instead *times* each viable kernel configuration —
 {sorted-XLA, bucket, block} x remainder transport dtype
-{none, bf16, fp8, fp8+amax} x block group size — on a sampled slice of
-the real degree distribution, and persists the winner plus the full
-measured cost table into the partition artifact (``tuning.json``
-sidecar, valid for both the v2 npz and v3 mmap directory formats).
+{none, bf16, fp8, fp8+amax} x block group size — on a sample of the
+heaviest shard, and persists the winner plus the full measured cost
+table into the partition artifact (``tuning.json`` sidecar, valid for
+both the v2 npz and v3 mmap directory formats).
 
-Sampling keeps the *shape* the kernels are sensitive to: destination
-rows are drawn uniformly but each keeps its FULL in-edge list, so the
-sampled in-degree distribution matches the shard's. The per-SpMM cost
-is scaled back by full_edges / sample_edges for reporting; the argmin
-is taken on the measured numbers directly.
+What is sampled (``sample_slice``): whole blocks of destination rows,
+aligned to ``block_tile`` x the grid's largest group (1024 rows at the
+defaults), spread over the row range, each row with its FULL in-edge
+list, the source ids left where they are. Every (destination tile,
+source tile) pair of a sampled tile-row then holds exactly the edges it
+holds on the shard, so a block candidate meets the shard's dense tiles
+and the bucket ladder the shard's in-degrees. Why: the sample this
+replaces drew ~400 single rows uniformly and renumbered them, which
+left no tile dense enough for the MXU path, and its 200k edges were
+4 ms of device work, the size of one host round trip — on the chip it
+ranked the 3.4x faster block kernel 1.6x slower (PERF.md, PR 21).
+The per-SpMM cost is scaled back by shard_edges / sample_edges for
+reporting; the ranking is taken on the measured numbers directly, and
+a near-tie (candidates the spread of the fastest one's reps cannot
+tell from it) falls to the fixed preference order, ``DEFAULT_IMPL``'s
+family first.
 
 Timing follows the microbench idiom (scripts/spmm_microbench.py):
 tables ride as jit ARGUMENTS, never closure constants (closed-over
-arrays embed into the HLO as constants), and every sample ends in a
-device->host scalar read (`float(jnp.sum(...))`), so the clock stops
-after the device does.
+arrays embed into the HLO as constants), and every call ends in the
+device->host read of the one scalar the program returns, so the clock
+stops after the device does. That scalar is fed by the forward's
+output AND by the gradient under a real cotangent, over the widest
+operand an aggregation inside the step sees: the aggregation is
+linear, so a program that returns the gradient alone has its forward
+removed as dead code (what format 1 timed). The fixed cost of a call
+is measured once per campaign with an empty program
+(``call_overhead_s`` of the record) and taken off the per-epoch
+estimates.
 
 Staleness: a persisted table is trusted only when its tuner format,
 source-graph edge checksum AND config signature (backend, feature
@@ -39,22 +57,47 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-TUNER_FORMAT = 1
+# 2: the sample keeps whole destination tile-rows (sample_slice); a
+# table timed on the row-wise sample of format 1 is stale
+TUNER_FORMAT = 2
 TUNING_FILE = "tuning.json"
 
-# destination-row sampling stops once this many edges are covered; the
-# CLI surfaces it as --tuner-samples
-DEFAULT_EDGE_BUDGET = 200_000
+# the sample takes whole blocks of destination rows until this many
+# edges are covered; the CLI surfaces it as --tuner-samples. Sized
+# from both ends on a v5e (PERF.md section 6, PR 27): an empty call
+# costs 1.3 to 4.1 ms, and at 1M edges the fastest candidate's call is
+# about five times that and the slowest thirty; the compile of a
+# candidate's program grows with the sample's shapes (16 of them took
+# 227 to 342 s at 2M edges), so no larger than the clock needs
+DEFAULT_EDGE_BUDGET = 1_000_000
+
+# what a tuning record says about its sample beside the cost table
+# (the contracted `tuning` record of the metrics stream carries them):
+# the dense coverage the block candidates met on the sample against the
+# whole shard's, the destination tile-rows sampled, the fixed cost of a
+# timed call, and the winner's estimate, to hold against a traced
+# spmm_s (it reads high by what a call costs whatever its edges: the
+# backward visits every source row of the shard, and shard / sample
+# edges multiplies that too)
+SAMPLE_FIELDS = ("sample_dense_coverage", "shard_dense_coverage",
+                 "sample_tile_rows", "call_overhead_s",
+                 "est_epoch_spmm_s")
+
+# tiles per sampled block when the grid is not at hand (choose_reorder,
+# tests): candidate_grid's largest default group
+_SAMPLE_GROUP = 4
 
 # deterministic no-measurement fallback: the scatter-free bucket kernel
 # is in-domain at every shard size (unlike block, which needs a dense
 # tile structure worth the table bytes). Used when tuning is disabled
-# and no persisted table exists, and when every candidate errors. This
-# is a fixed preference order, NOT a shape threshold.
+# and no persisted table exists, when every candidate errors, and as
+# the head of the preference order a near-tie falls to (pick_winner).
+# This is a fixed preference order, NOT a shape threshold.
 DEFAULT_IMPL = "bucket"
 
-# SpMM invocations per epoch of the 4-layer use_pp bench stack: 3 graph
-# layers, each one forward + one backward aggregation
+# in-step aggregations per epoch where the caller does not say: the
+# 4-layer use_pp bench stack's 3 graph layers, each one forward + one
+# backward
 _SPMM_PER_EPOCH = 3
 
 # in-process memo of live tuning runs keyed by (checksum, signature):
@@ -73,66 +116,78 @@ def clear_memo() -> None:
 
 
 def sample_slice(sg, edge_budget: int = DEFAULT_EDGE_BUDGET,
-                 seed: int = 0):
-    """A 1-part ShardedGraph-shaped view of the heaviest shard's edges.
+                 seed: int = 0, block_rows: int = 256 * _SAMPLE_GROUP):
+    """A 1-part ShardedGraph-shaped view of the heaviest shard's edges
+    that keeps the shard's tile structure.
 
-    Destination rows are sampled uniformly, each keeping its full
-    in-edge list, until `edge_budget` edges are covered — preserving
-    the in-degree distribution the bucket ladder and the block tiling
-    both key on. Row ids are compacted (sampled destinations first, so
-    every dst id < n_max; remaining source rows follow) and the result
-    quacks like a ShardedGraph for the sharded table builders:
-    num_parts=1, halo_size=0, all rows inner.
+    Destinations are whole blocks of `block_rows` consecutive rows
+    (`block_tile` x the grid's largest group), one drawn from each of k
+    equal strata of the occupied row range (seeded), k sized so the
+    blocks cover about `edge_budget` edges and never under one block;
+    every sampled row keeps its FULL in-edge list. The blocks are
+    packed in order into rows 0..n_max of the sample — whole blocks, so
+    a destination tile stays a tile — and source ids are left as they
+    are: a halo source stays behind the shard's n_max, and every
+    (destination tile, source tile) pair of a sampled tile-row holds
+    the edges it holds on the shard. A shard under the budget is taken
+    whole, ids in place.
 
-    Returns (sample, info) where info carries sample_edges /
-    full_edges / scale.
+    The result quacks like a ShardedGraph for the sharded table
+    builders: num_parts=1, n_max = the sampled rows, halo_size = what
+    is left of the shard's source id space behind them.
+
+    Returns (sample, info); info carries sample_edges / shard_edges /
+    full_edges / scale and the sampled blocks' first rows.
     """
     r = int(np.argmax(np.asarray(sg.edge_count)))
     ec = int(sg.edge_count[r])
-    es = np.asarray(sg.edge_src[r][:ec], dtype=np.int64)
-    ed = np.asarray(sg.edge_dst[r][:ec], dtype=np.int64)
+    es = np.asarray(sg.edge_src[r][:ec])
+    ed = np.asarray(sg.edge_dst[r][:ec])
     real = ed < sg.n_max
-    es, ed = es[real], ed[real]
-    full_edges = int(np.sum(np.asarray(sg.edge_count)))
+    if not real.all():
+        es, ed = es[real], ed[real]
+    shard_edges = int(ed.size)
+    n_src = int(sg.n_max + sg.halo_size)
 
-    if es.size > edge_budget:
-        deg = np.bincount(ed, minlength=sg.n_max)
-        rows = np.flatnonzero(deg > 0)
+    block_rows = int(block_rows)
+    n_blocks = -(-int(sg.n_max) // block_rows)
+    blk = ed // block_rows
+    occupied = np.flatnonzero(np.bincount(blk, minlength=n_blocks))
+    if shard_edges > edge_budget and occupied.size > 1:
+        k = -(-int(edge_budget) * occupied.size // shard_edges)
+        k = min(max(k, 1), int(occupied.size))
         rng = np.random.default_rng(seed)
-        rng.shuffle(rows)
-        cum = np.cumsum(deg[rows])
-        n_keep = max(1, int(np.searchsorted(cum, edge_budget) + 1))
-        chosen = rows[:n_keep]
-        sel = np.zeros(sg.n_max, dtype=bool)
-        sel[chosen] = True
-        keep = sel[ed]
-        es, ed = es[keep], ed[keep]
+        chosen = np.array([rng.choice(stratum) for stratum
+                           in np.array_split(occupied, k)])
     else:
-        chosen = np.unique(ed)
+        chosen = np.arange(n_blocks)
 
-    # compact ids: sampled destinations first, then the remaining
-    # source rows (halo slots and unsampled inner rows alike)
-    chosen = np.sort(chosen)
-    n_dst = int(chosen.size)
-    src_space = sg.n_max + sg.halo_size
-    remap = np.full(src_space, -1, dtype=np.int64)
-    remap[chosen] = np.arange(n_dst)
-    extra = np.unique(es[remap[es] < 0])
-    remap[extra] = n_dst + np.arange(extra.size)
-    n_rows = n_dst + int(extra.size)
-
-    new_src = remap[es].astype(np.int32)
-    new_dst = remap[ed].astype(np.int32)
+    # pack the chosen blocks in order; only the shard's last block can
+    # be short, and it then comes last, so every block start stays a
+    # multiple of block_rows
+    starts = chosen.astype(np.int64) * block_rows
+    lens = np.minimum(starts + block_rows, int(sg.n_max)) - starts
+    n_dst = int(lens.sum())
+    shift = np.zeros(n_blocks, dtype=np.int64)
+    shift[chosen] = np.cumsum(lens) - lens - starts
+    if chosen.size < n_blocks:
+        sel = np.zeros(n_blocks, dtype=bool)
+        sel[chosen] = True
+        keep = sel[blk]
+        es, ed, blk = es[keep], ed[keep], blk[keep]
+    new_dst = (ed + shift[blk]).astype(np.int32)
+    new_src = es.astype(np.int32)
     # CSR order (dst ascending) so the sorted-XLA candidate times the
     # same formulation the trainer dispatches
-    order = np.argsort(new_dst, kind="stable")
-    new_src, new_dst = new_src[order], new_dst[order]
+    if new_dst.size and np.any(new_dst[1:] < new_dst[:-1]):
+        order = np.argsort(new_dst, kind="stable")
+        new_src, new_dst = new_src[order], new_dst[order]
 
     in_deg = np.maximum(
-        np.bincount(new_dst, minlength=n_rows), 1).astype(np.float32)
+        np.bincount(new_dst, minlength=n_dst), 1).astype(np.float32)
 
     sample = SimpleNamespace(
-        num_parts=1, n_max=n_rows, b_max=0, halo_size=0,
+        num_parts=1, n_max=n_dst, b_max=0, halo_size=n_src - n_dst,
         e_max=int(new_src.size),
         edge_count=np.array([new_src.size], dtype=np.int64),
         edge_src=new_src[None, :], edge_dst=new_dst[None, :],
@@ -141,10 +196,15 @@ def sample_slice(sg, edge_budget: int = DEFAULT_EDGE_BUDGET,
     )
     info = {
         "sample_edges": int(new_src.size),
-        "sample_rows": n_rows,
-        "full_edges": full_edges,
-        "scale": full_edges / max(1, int(new_src.size)),
+        "sample_rows": n_dst,
+        "shard_edges": shard_edges,
+        "full_edges": int(np.sum(np.asarray(sg.edge_count))),
+        # a device runs its own shard: the heaviest one's edges, not
+        # the graph's, are what a per-epoch estimate scales to
+        "scale": shard_edges / max(1, int(new_src.size)),
         "sampled_rank": r,
+        "block_rows": block_rows,
+        "block_starts": [int(x) for x in starts],
     }
     return sample, info
 
@@ -216,23 +276,84 @@ def candidate_grid(*, block_group: int = 0,
 # timing
 
 
+def _operand(sample, width: int):
+    """The bf16 feature operand every candidate of a campaign gathers
+    from, made once and on the device: the sample keeps the shard's
+    source id space, so a host-made one would cost seconds per
+    candidate."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.random.normal(
+        jax.random.PRNGKey(0),
+        (sample.n_max + sample.halo_size, width), jnp.bfloat16)
+
+
+def _timed_reps(program, args, reps: int) -> List[float]:
+    """Wall seconds of `reps` calls of a jitted `program` that returns
+    one scalar, after a call that compiles and settles. Each call ends
+    in the device->host read of that scalar, so the clock stops after
+    the device does."""
+    float(program(*args))
+    ts = []
+    for _ in range(max(1, reps)):
+        t0 = time.perf_counter()
+        float(program(*args))
+        ts.append(time.perf_counter() - t0)
+    return ts
+
+
+def call_overhead(fbuf, reps: int = 5) -> float:
+    """The fixed cost of one timed call: a program that only reads the
+    operand, dispatched and read back the way _time_candidate does."""
+    import jax
+    import jax.numpy as jnp
+
+    empty = jax.jit(lambda f: f.astype(jnp.float32).sum())
+    return min(_timed_reps(empty, (fbuf,), reps))
+
+
 def _time_candidate(sample, cand: Dict[str, Any], width: int, *,
-                    block_tile: int, block_nnz: Optional[int],
-                    chunk_edges: Optional[int], bucket_merge: int,
-                    reps: int) -> float:
-    """Measured seconds for ONE forward+backward SpMM of this candidate
-    on the sample (min over reps). Raises on kernel failure — the
-    caller records the error in the cost table."""
+                    reps: int, **kw) -> List[float]:
+    """Measured seconds of `reps` forward+backward SpMMs of this
+    candidate on the sample (_candidate_program's arguments). Raises
+    on kernel failure — the caller records the error in the cost
+    table."""
+    return _timed_reps(*_candidate_program(sample, cand, width, **kw),
+                       reps)
+
+
+def _candidate_program(sample, cand: Dict[str, Any], width: int, *,
+                       fbuf, block_tile: int, block_nnz: Optional[int],
+                       chunk_edges: Optional[int], bucket_merge: int,
+                       table_width: Optional[int] = None,
+                       byte_budget: Optional[int] = None,
+                       tables: Optional[Dict[Tuple, Any]] = None):
+    """(jitted program, its arguments) of one forward+backward SpMM of
+    this candidate on the sample, over the operand `fbuf` (_operand),
+    `width` wide.
+
+    The program returns one scalar: the sum of the forward's output
+    AND of the gradient under a cotangent of its own. Both halves
+    matter: the aggregation is linear, so its backward needs nothing
+    of its forward, and a program that returns the gradient alone (the
+    `grad` of a `.sum()`, which is what was timed until tuner format 2)
+    has its forward removed as dead code: it times the transposed
+    tables only.
+
+    `table_width` is the width the trainer builds the block tables for
+    (their dense threshold), where that is not the timed one. `tables`
+    memoizes the host tables over a campaign (the transport variants of
+    one kernel share them); `byte_budget` is the block builder's
+    dense-A budget, which the caller scales to the sampled share of the
+    shard's rows."""
     import jax
     import jax.numpy as jnp
 
     n_max = sample.n_max
-    n_src = n_max  # 1-part sample: halo_size == 0, all rows inner
-    rng = np.random.default_rng(0)
-    fbuf = jnp.asarray(
-        rng.standard_normal((n_src, width)).astype(np.float32)
-    ).astype(jnp.bfloat16)
+    n_src = n_max + sample.halo_size
     in_deg = jnp.asarray(sample.in_deg[0])
+    tables = {} if tables is None else tables
 
     impl = cand["impl"]
     if impl == "xla":
@@ -250,10 +371,11 @@ def _time_candidate(sample, cand: Dict[str, Any], width: int, *,
         from .bucket_spmm import (build_sharded_bucket_tables,
                                   make_device_bucket_spmm_fn)
 
-        tables = build_sharded_bucket_tables(
-            sample, min_width=bucket_merge,
-            slab=bool(cand.get("slab")))
-        tabs = {k: jnp.asarray(v[0]) for k, v in tables.items()}
+        key = ("bucket", bool(cand.get("slab")))
+        if key not in tables:
+            tables[key] = build_sharded_bucket_tables(
+                sample, min_width=bucket_merge, slab=key[1])
+        tabs = {k: jnp.asarray(v[0]) for k, v in tables[key].items()}
 
         def apply(tabs, deg, f):
             fn = make_device_bucket_spmm_fn(
@@ -261,14 +383,19 @@ def _time_candidate(sample, cand: Dict[str, Any], width: int, *,
                 rem_dtype=cand["rem_dtype"], rem_amax=cand["rem_amax"])
             return fn(f)
     elif impl == "block":
-        from .block_spmm import (build_sharded_block_tables,
+        from .block_spmm import (DENSE_A_BYTE_BUDGET,
+                                 build_sharded_block_tables,
                                  make_device_block_spmm_fn)
 
-        tables, tile = build_sharded_block_tables(
-            sample, tile=block_tile, n_feat_hint=width,
-            nnz_threshold=block_nnz, group=cand["block_group"],
-            slab=bool(cand.get("slab")))
-        tabs = {k: jnp.asarray(v[0]) for k, v in tables.items()}
+        key = ("block", cand["block_group"], bool(cand.get("slab")))
+        if key not in tables:
+            tables[key] = build_sharded_block_tables(
+                sample, tile=block_tile, n_feat_hint=table_width or width,
+                byte_budget=byte_budget or DENSE_A_BYTE_BUDGET,
+                nnz_threshold=block_nnz, group=cand["block_group"],
+                slab=key[2])
+        host, tile = tables[key]
+        tabs = {k: jnp.asarray(v[0]) for k, v in host.items()}
 
         def apply(tabs, deg, f):
             fn = make_device_block_spmm_fn(
@@ -278,15 +405,74 @@ def _time_candidate(sample, cand: Dict[str, Any], width: int, *,
     else:
         raise ValueError(f"unknown tuner candidate impl {impl!r}")
 
-    grad_fn = jax.jit(lambda t, deg, f: jax.grad(
-        lambda ff: apply(t, deg, ff).astype(jnp.float32).sum())(f))
-    float(jnp.sum(grad_fn(tabs, in_deg, fbuf)))  # compile + settle
-    ts = []
-    for _ in range(max(1, reps)):
-        t0 = time.perf_counter()
-        float(jnp.sum(grad_fn(tabs, in_deg, fbuf)))
-        ts.append(time.perf_counter() - t0)
-    return min(ts)
+    def fwd_bwd(t, deg, f, g):
+        out, vjp = jax.vjp(lambda ff: apply(t, deg, ff), f)
+        return (out.astype(jnp.float32).sum()
+                + vjp(g.astype(out.dtype))[0].astype(jnp.float32).sum())
+
+    cot = jax.random.normal(jax.random.PRNGKey(1), (n_max, width),
+                            jnp.float32)
+    return jax.jit(fwd_bwd), (tabs, in_deg, fbuf, cot)
+
+
+def shard_size_refusal(sg, r: int, width: int,
+                       chunk_edges: Optional[int]) -> Optional[str]:
+    """Why the raw-edge-list kernel (`xla`) cannot run shard `r` at its
+    own size, in the compiler's words, or None where it can.
+
+    The table-driven kernels work in bounded chunks whatever the shard
+    holds; this one materializes a message per edge ([edges, width]
+    f32 unless `chunk_edges` bounds it), so the sample says nothing of
+    whether the shard fits: a sample's 1M edges are 2 GB where Yelp's
+    7.9M are 16. Nothing is modelled: the forward+backward is compiled
+    (never run) at the shard's shapes, and a compiler that cannot place
+    it says so."""
+    import jax
+    import jax.numpy as jnp
+
+    from .spmm import spmm_mean
+
+    n_out, e = int(sg.n_max), int(sg.edge_count[r])
+
+    def fwd_bwd(es, ed, deg, f, g):
+        out, vjp = jax.vjp(
+            lambda ff: spmm_mean(ff, es, ed, deg, n_out,
+                                 chunk=chunk_edges, sorted_edges=True), f)
+        return out.sum() + vjp(g)[0].astype(jnp.float32).sum()
+
+    shape = jax.ShapeDtypeStruct
+    try:
+        jax.jit(fwd_bwd).lower(
+            shape((e,), jnp.int32), shape((e,), jnp.int32),
+            shape((n_out,), jnp.float32),
+            shape((n_out + int(sg.halo_size), width), jnp.bfloat16),
+            shape((n_out, width), jnp.float32)).compile()
+    except Exception as exc:  # noqa: BLE001 — the refusal is the result
+        return repr(exc)[:200]
+    return None
+
+
+def pick_winner(costs: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """The measured argmin, unless it is a near-tie: a candidate whose
+    best rep is no slower than the argmin's slowest (the ranges of
+    their reps overlap) cannot be told from it by this clock, and the
+    choice among those falls to the fixed preference order —
+    DEFAULT_IMPL's family first, then the order of the grid (plain
+    transport before the narrower ones). A candidate's own noise never
+    makes it a tie: only the argmin's spread widens the set. A
+    candidate that cannot run the shard at its own size
+    (`out_of_domain`) keeps its time in the table and is never picked.
+    No candidate left: the default kernel."""
+    ok = [c for c in costs if c.get("error") is None
+          and not c.get("out_of_domain")]
+    if not ok:
+        return {"name": DEFAULT_IMPL, "impl": DEFAULT_IMPL,
+                "rem_dtype": None, "rem_amax": False, "block_group": 1,
+                "slab": False}
+    best = min(ok, key=lambda c: c["spmm_fwdbwd_s"])
+    slowest = best["spmm_fwdbwd_s"] + best.get("spread_s", 0.0)
+    tied = [c for c in ok if c["spmm_fwdbwd_s"] <= slowest]
+    return min(tied, key=lambda c: c["impl"] != DEFAULT_IMPL)
 
 
 # ---------------------------------------------------------------------
@@ -299,7 +485,8 @@ def signature_for(*, width: int, block_tile: int, bucket_merge: int,
                   halo_dtype: str = "none",
                   epoch_block: int = 0,
                   reorder: str = "none",
-                  layout_version: int = 1) -> Dict[str, Any]:
+                  layout_version: int = 1,
+                  step_width: Optional[int] = None) -> Dict[str, Any]:
     """Config signature a persisted table must match to be trusted.
     Backend is part of it: CPU timings say nothing about the TPU. The
     floor-lever knobs (rng_impl / halo_dtype / epoch_block) are part of
@@ -310,12 +497,17 @@ def signature_for(*, width: int, block_tile: int, bucket_merge: int,
     gather streams must not pick kernels for the reordered ones.
     Tables persisted before these keys existed mismatch (exact-dict
     compare) and re-tune once — deliberate; the keyword defaults match
-    TrainConfig's / pre-reorder artifacts' for older call sites."""
+    TrainConfig's / pre-reorder artifacts' for older call sites.
+    `width` is the widest operand of any aggregation (what the block
+    tables' dense threshold is built for), `step_width` the widest one
+    an in-step aggregation sees (what is timed; `width` unless the
+    first layer's aggregation is precomputed)."""
     import jax
 
     return {
         "backend": jax.default_backend(),
         "width": int(width),
+        "step_width": int(step_width or width),
         "block_tile": int(block_tile),
         "bucket_merge": int(bucket_merge),
         "chunk_edges": int(chunk_edges) if chunk_edges else 0,
@@ -334,13 +526,23 @@ def tune(sg, width: int, *, block_tile: int = 256,
          rng_impl: str = "threefry", halo_dtype: str = "none",
          epoch_block: int = 0, slab: str = "auto",
          edge_budget: int = DEFAULT_EDGE_BUDGET, reps: int = 2,
-         seed: int = 0,
+         seed: int = 0, step_width: Optional[int] = None,
+         spmm_per_epoch: int = _SPMM_PER_EPOCH,
          log: Optional[Callable[[str], None]] = None) -> Dict[str, Any]:
     """Run the micro-benchmark campaign and return the tuning record
     (winner + full measured cost table). Results are memoized
     in-process by (source checksum, signature, budget) so repeated
-    trainer constructions over the same artifact pay once."""
-    sig = signature_for(width=width, block_tile=block_tile,
+    trainer constructions over the same artifact pay once.
+
+    `width` is the widest operand of any aggregation: the block tables
+    are built for it. The candidates are timed over `step_width`
+    columns, the widest operand an aggregation INSIDE the step sees
+    (under use_pp the first layer's, the widest on a wide-feature
+    graph, is precomputed once), and the per-epoch estimates count
+    `spmm_per_epoch` such aggregations."""
+    step_width = int(step_width or width)
+    sig = signature_for(width=width, step_width=step_width,
+                        block_tile=block_tile,
                         bucket_merge=bucket_merge,
                         chunk_edges=chunk_edges,
                         rng_impl=rng_impl, halo_dtype=halo_dtype,
@@ -351,33 +553,83 @@ def tune(sg, width: int, *, block_tile: int = 256,
         & ((1 << 64) - 1)
     memo_key = (checksum, json.dumps(sig, sort_keys=True),
                 int(edge_budget), int(block_group),
-                str(rem_dtype), bool(rem_amax), str(slab))
+                str(rem_dtype), bool(rem_amax), str(slab),
+                int(spmm_per_epoch))
     hit = _MEMO.get(memo_key)
     if hit is not None:
         return hit
 
-    sample, info = sample_slice(sg, edge_budget=edge_budget, seed=seed)
+    from .block_spmm import (DENSE_A_BYTE_BUDGET, _part_block_stats,
+                             budget_block_cap)
+
     cands = candidate_grid(block_group=block_group, rem_dtype=rem_dtype,
                            rem_amax=rem_amax, slab=slab)
+    group = max(c["block_group"] for c in cands)
+    sample, info = sample_slice(sg, edge_budget=edge_budget, seed=seed,
+                                block_rows=block_tile * group)
+    # the block builder's dense-A budget is the shard's: the sample
+    # gets the share its rows are of the shard's, so a budget that
+    # spills tiles on the shard spills them on the sample too
+    byte_budget = max(1, int(DENSE_A_BYTE_BUDGET * sample.n_max
+                             / max(1, sg.n_max)))
+    # did the sample carry the tiles? Dense coverage of the sampled
+    # rows against the whole shard's, at the tile, threshold and
+    # budget the block candidates are built with
+    thr = block_nnz if block_nnz is not None else max(
+        1, block_tile * block_tile // max(width, 1))
+    n_src_tiles = -(-(sg.n_max + sg.halo_size) // block_tile)
+    bits = 1 if block_tile % 8 == 0 else 8
+    shard_cov = _part_block_stats(
+        sg, info["sampled_rank"], block_tile, n_src_tiles, thr,
+        max_blocks=budget_block_cap(DENSE_A_BYTE_BUDGET, block_tile,
+                                    bits))[0]
+    sample_cov = _part_block_stats(
+        sample, 0, block_tile, n_src_tiles, thr,
+        max_blocks=budget_block_cap(byte_budget, block_tile, bits))[0]
+
+    fbuf = _operand(sample, step_width)
+    overhead = call_overhead(fbuf)
+    if log:
+        log(f"# tuner: sample {info['sample_edges']} edges in "
+            f"{len(info['block_starts'])} blocks of "
+            f"{info['block_rows']} rows; dense coverage "
+            f"{sample_cov:.3f} (shard {shard_cov:.3f}); empty call "
+            f"{overhead * 1e3:.2f} ms")
+
+    def est_epoch(s: float) -> float:
+        return round(max(s - overhead, 0.0) * info["scale"]
+                     * spmm_per_epoch, 6)
+
+    tables: Dict[Tuple, Any] = {}
     costs: List[Dict[str, Any]] = []
     for cand in cands:
         entry = dict(cand)
         try:
-            s = _time_candidate(
-                sample, cand, width, block_tile=block_tile,
+            ts = _time_candidate(
+                sample, cand, step_width, block_tile=block_tile,
                 block_nnz=block_nnz, chunk_edges=chunk_edges,
-                bucket_merge=bucket_merge, reps=reps)
+                bucket_merge=bucket_merge, reps=reps, fbuf=fbuf,
+                table_width=width, byte_budget=byte_budget,
+                tables=tables)
+            s = min(ts)
             entry["spmm_fwdbwd_s"] = s
-            entry["est_epoch_spmm_s"] = round(
-                s * info["scale"] * _SPMM_PER_EPOCH, 6)
+            entry["spread_s"] = max(ts) - s
+            entry["est_epoch_spmm_s"] = est_epoch(s)
             entry["error"] = None
+            if cand["impl"] == "xla" and info["scale"] > 1:
+                entry["out_of_domain"] = shard_size_refusal(
+                    sg, info["sampled_rank"], step_width, chunk_edges)
             if log:
                 log(f"# tuner: {cand['name']:16s} {s * 1e3:8.2f} ms "
                     f"(est epoch SpMM "
-                    f"{entry['est_epoch_spmm_s']:.3f} s)")
+                    f"{entry['est_epoch_spmm_s']:.3f} s)"
+                    + (f" OUT OF DOMAIN at the shard's size: "
+                       f"{entry['out_of_domain']}"
+                       if entry.get("out_of_domain") else ""))
         except Exception as exc:  # noqa: BLE001 — a crashing candidate
             # is a RESULT (out-of-domain config), not a tuner failure
             entry["spmm_fwdbwd_s"] = None
+            entry["spread_s"] = None
             entry["est_epoch_spmm_s"] = None
             entry["error"] = repr(exc)[:200]
             if log:
@@ -385,22 +637,18 @@ def tune(sg, width: int, *, block_tile: int = 256,
                     f"{entry['error']}")
         costs.append(entry)
 
-    ok = [c for c in costs if c["error"] is None]
-    if ok:
-        best = min(ok, key=lambda c: c["spmm_fwdbwd_s"])
-    else:
-        best = {"name": DEFAULT_IMPL, "impl": DEFAULT_IMPL,
-                "rem_dtype": None, "rem_amax": False, "block_group": 1,
-                "slab": False}
+    best = pick_winner(costs)
     # the sample's gather-contiguity stat rides in the record: the
     # number the reorder lever is supposed to move, next to the
-    # measured winner it produced (host numpy on the sample tables —
-    # noise next to the candidate compiles)
+    # measured winner it produced (host numpy on the bucket
+    # candidates' own tables)
     try:
         from .bucket_spmm import (build_sharded_bucket_tables,
                                   gather_contiguity)
         contig = gather_contiguity(
-            build_sharded_bucket_tables(sample), sample.n_max)
+            tables.get(("bucket", False))
+            or build_sharded_bucket_tables(sample),
+            sample.n_max + sample.halo_size)
     except Exception:  # noqa: BLE001 — a stat, never a tuner failure
         contig = None
     record = {
@@ -412,7 +660,13 @@ def tune(sg, width: int, *, block_tile: int = 256,
                     "block_group", "slab")},
         "costs": costs,
         "reps": int(reps),
+        "spmm_per_epoch": int(spmm_per_epoch),
         "gather_contiguity": contig,
+        "sample_dense_coverage": round(sample_cov, 6),
+        "shard_dense_coverage": round(shard_cov, 6),
+        "sample_tile_rows": -(-info["sample_rows"] // block_tile),
+        "call_overhead_s": overhead,
+        "est_epoch_spmm_s": best.get("est_epoch_spmm_s"),
         "time_unix": time.time(),
         **info,
     }
@@ -486,9 +740,9 @@ def choose_reorder(g, *, modes: Tuple[str, ...] = ("none", "degree-bfs"),
                    ) -> Tuple[str, Dict[str, float]]:
     """Pick the artifact reorder mode for ``--reorder auto`` by
     MEASUREMENT: build a 1-part layout of ``g`` under each candidate
-    mode, sample a degree-distribution-preserving slice, and time the
-    bucket kernel's forward+backward on it — under the reordered
-    layouts both with and without the streaming-slab plan (the path
+    mode, sample whole blocks of its destination rows (sample_slice),
+    and time the bucket kernel's forward+backward on them — under the
+    reordered layouts both with and without the streaming-slab plan (the path
     the reorder exists to enable), keeping each mode's best. Returns
     (winning mode, {mode: seconds}); an unmeasurable campaign (every
     candidate erroring) falls back to "none" — the layout every
@@ -501,15 +755,17 @@ def choose_reorder(g, *, modes: Tuple[str, ...] = ("none", "degree-bfs"),
     for mode in modes:
         sg1 = ShardedGraph.build(g, parts, n_parts=1, reorder=mode)
         sample, _ = sample_slice(sg1, edge_budget=edge_budget)
+        fbuf = _operand(sample, width)
         best = None
         for sl in ([False] if mode == "none" else [False, True]):
             cand = {"name": "bucket-slab" if sl else "bucket",
                     "impl": "bucket", "rem_dtype": None,
                     "rem_amax": False, "block_group": 1, "slab": sl}
             try:
-                t = _time_candidate(sample, cand, width, block_tile=256,
-                                    block_nnz=None, chunk_edges=None,
-                                    bucket_merge=0, reps=reps)
+                t = min(_time_candidate(
+                    sample, cand, width, block_tile=256, block_nnz=None,
+                    chunk_edges=None, bucket_merge=0, reps=reps,
+                    fbuf=fbuf))
             except Exception as exc:  # noqa: BLE001 — out-of-domain
                 if log:
                     log(f"# choose_reorder: {mode} "

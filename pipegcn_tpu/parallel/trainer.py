@@ -48,6 +48,7 @@ from ..obs.format import epoch_line, reference_eval_line, reference_train_line
 from ..obs.metrics import device_info, memory_snapshot, mesh_info
 from ..obs.profiler import ANCHOR_SPAN
 from ..obs.trace import PhaseTimer, SpanCursor, named_phase, trace_span
+from ..ops import tuner
 from ..ops.spmm import spmm_mean
 from ..partition.halo import ShardedGraph
 from ..resilience import DivergenceError, PeerLost, Preempted, SentinelConfig
@@ -507,12 +508,19 @@ class Trainer:
         for fit()/bench to emit as a contracted `tuning` record."""
         import warnings
 
-        from ..ops import tuner
-
         cfg = self.cfg
         width = max(cfg.layer_sizes[:cfg.n_graph_layers])
+        # what an epoch runs: under use_pp the first layer's
+        # aggregation (the widest, on a wide-feature graph) is
+        # precomputed once, so the candidates are timed over the widest
+        # operand an in-step aggregation sees, and the estimate counts
+        # those aggregations
+        in_step = [self._layer_width(i)
+                   for i in self._graph_layer_range()]
+        step_width = max(in_step, default=width)
         sig = tuner.signature_for(
-            width=width, block_tile=cfg.block_tile,
+            width=width, step_width=step_width,
+            block_tile=cfg.block_tile,
             bucket_merge=getattr(cfg, "bucket_merge", 0),
             chunk_edges=cfg.spmm_chunk,
             rng_impl=getattr(self.tcfg, "rng_impl", "threefry"),
@@ -550,6 +558,8 @@ class Trainer:
                         epoch_block=int(getattr(self.tcfg,
                                                 "epoch_block", 0)),
                         slab=str(getattr(cfg, "slab", "auto")),
+                        step_width=step_width,
+                        spmm_per_epoch=max(1, len(in_step)),
                         edge_budget=int(getattr(
                             cfg, "tuner_samples",
                             tuner.DEFAULT_EDGE_BUDGET)))
@@ -590,6 +600,10 @@ class Trainer:
             "costs": rec.get("costs", []),
             "gather_contiguity": rec.get("gather_contiguity"),
             "emitted": False,
+            # did the sample carry the shard's tiles, and how far is
+            # the estimate from a traced spmm_s (null without a
+            # measured table)
+            **{k: rec.get(k) for k in tuner.SAMPLE_FIELDS},
         }
         # fill tuner-chosen transport/group defaults — never override
         # an explicit user pin (a pinned value restricted the grid)
@@ -613,8 +627,9 @@ class Trainer:
         constructing the full trainer — no full-graph device uploads,
         no pp precompute. The O(E) host builds run ahead of time, so
         the next real run only loads npz. spmm_impl='auto'
-        additionally runs the tuner's micro-bench campaign (small
-        sampled slice on the current backend) and persists tuning.json
+        additionally runs the tuner's micro-bench campaign (a sample
+        of whole destination tile-rows, on the current backend) and
+        persists tuning.json
         into the artifact, then warms the winner's tables — this is
         the artifact-build-time tuning entry point."""
         if getattr(sg, "cache_dir", None) is None:
@@ -1914,7 +1929,9 @@ class Trainer:
                 metrics.tuning(
                     winner=dict(w), source=self.tuning["source"],
                     stale_reason=self.tuning.get("stale_reason"),
-                    costs=self.tuning.get("costs", []))
+                    costs=self.tuning.get("costs", []),
+                    **{k: self.tuning.get(k)
+                       for k in tuner.SAMPLE_FIELDS})
         halo_bytes = self.est_halo_bytes_per_epoch()
         # with --halo-dtype compression active, record the uncompressed
         # figure alongside so the report can print the wire ratio

@@ -153,7 +153,7 @@ def resolve_reorder(n_parts: int, cluster_size: int, small: bool,
     Preference order: (1) any already-built bench artifact for this
     shape (cheapest — reuse what exists, reordered variants first);
     (2) otherwise a MEASURED decision: build the dataset graph once,
-    time a degree-distribution-preserving sampled slice under the
+    time a sample of whole destination tile-rows under the
     'none' and 'degree-bfs' layouts (ops.tuner.choose_reorder) and
     take the winner. Concrete modes pass through unchanged, so
     callers can always treat the return value as artifact identity.

@@ -10,8 +10,8 @@ configuration; the cache key (Trainer._cached_tables) encodes
 (impl, tile, width, nnz, group, merge).
 
 --impl auto additionally runs the SpMM auto-tuner's micro-bench
-campaign on the current backend (small sampled slice — the one part of
-prewarm that does touch the device) and persists the tuning.json
+campaign on the current backend (a sample of whole destination
+tile-rows — the one part of prewarm that does touch the device) and persists the tuning.json
 sidecar into the artifact, then warms the winner's tables. Run it on
 the backend you will train on: the table signature pins the backend,
 so a CPU-prewarmed table is (correctly) rejected on TPU.
@@ -39,7 +39,7 @@ def main():
     ap.add_argument("--group", type=int, default=1)
     ap.add_argument("--block-nnz", type=int, default=0)
     ap.add_argument("--bucket-merge", type=int, default=0)
-    ap.add_argument("--tuner-samples", type=int, default=200_000)
+    ap.add_argument("--tuner-samples", type=int, default=1_000_000)
     ap.add_argument("--retune", action="store_true",
                     help="with --impl auto: delete any persisted "
                          "tuning.json first and force a fresh "

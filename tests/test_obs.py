@@ -242,6 +242,27 @@ def test_schema_v15_drift_guard():
         assert obs_schema.SCHEMA_VERSION > 15
 
 
+# FROZEN copy of the v16 additions (v15 + what the tuning record says
+# about its sample: the tile-structure-keeping sampler of
+# ops/tuner.py). Same contract as the earlier guards.
+_V16_TUNING_FIELDS = {
+    "event": "string", "winner": "object", "source": "string",
+    "costs": "array", "sample_dense_coverage": "number?",
+    "shard_dense_coverage": "number?", "sample_tile_rows": "integer?",
+    "call_overhead_s": "number?",
+}
+
+
+def test_schema_v16_drift_guard():
+    if obs_schema.SCHEMA_VERSION == 16:
+        for name, tag in _V16_TUNING_FIELDS.items():
+            assert obs_schema.TUNING_FIELDS.get(name) == tag, (
+                f"schema field tuning.{name} removed or retyped "
+                f"without bumping SCHEMA_VERSION")
+    else:
+        assert obs_schema.SCHEMA_VERSION > 16
+
+
 def test_validate_record():
     validate_record({"event": "epoch", "epoch": 0, "step_time_s": 0.1,
                      "loss": 1.0, "grad_norm": 0.5, "halo_bytes": 128,
@@ -264,17 +285,27 @@ def test_validate_record():
 
 
 def test_validate_tuning_record():
+    sample = {"sample_dense_coverage": 0.79,
+              "shard_dense_coverage": 0.8, "sample_tile_rows": 16,
+              "call_overhead_s": 1e-3}
     validate_record({"event": "tuning",
                      "winner": {"name": "block-u4-bf16",
                                 "impl": "block"},
                      "source": "artifact", "costs": [],
-                     "stale_reason": None})
+                     "stale_reason": None, **sample})
+    # the no-measurement default timed no sample: nulls, never absent
+    validate_record({"event": "tuning", "winner": {},
+                     "source": "default", "costs": [],
+                     **dict.fromkeys(sample)})
     with pytest.raises(ValueError, match="winner"):
         validate_record({"event": "tuning", "source": "live",
-                         "costs": []})
+                         "costs": [], **sample})
     with pytest.raises(ValueError, match="expected array"):
         validate_record({"event": "tuning", "winner": {},
-                         "source": "live", "costs": {}})
+                         "source": "live", "costs": {}, **sample})
+    with pytest.raises(ValueError, match="sample_dense_coverage"):
+        validate_record({"event": "tuning", "winner": {},
+                         "source": "live", "costs": []})
 
 
 def test_validate_serving_record():

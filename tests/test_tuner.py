@@ -11,13 +11,14 @@ fallback on stale/corrupt tables.
 
 import json
 import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from pipegcn_tpu.graph import synthetic_graph
 from pipegcn_tpu.models import ModelConfig
-from pipegcn_tpu.ops import tuner
+from pipegcn_tpu.ops import block_spmm, tuner
 from pipegcn_tpu.parallel import TrainConfig, Trainer
 from pipegcn_tpu.partition import ShardedGraph, partition_graph
 
@@ -77,8 +78,13 @@ def test_candidate_grid_full_and_pinned():
 
 def test_sample_slice_preserves_degree_distribution():
     sg = _sharded(num_nodes=2000, avg_degree=10, seed=7)
-    sample, info = tuner.sample_slice(sg, edge_budget=3000)
-    assert sample.num_parts == 1 and sample.halo_size == 0
+    sample, info = tuner.sample_slice(sg, edge_budget=3000,
+                                      block_rows=256)
+    # one part whose source id space is the shard's own: the sampled
+    # rows first, what is left of the space behind them
+    assert sample.num_parts == 1
+    assert sample.n_max + sample.halo_size == sg.n_max + sg.halo_size
+    assert 0 < sample.n_max < sg.n_max
     assert info["sample_edges"] == int(sample.edge_count[0])
     assert info["full_edges"] >= info["sample_edges"]
     assert info["scale"] >= 1.0
@@ -91,6 +97,318 @@ def test_sample_slice_preserves_degree_distribution():
     samp_dst = np.asarray(sample.edge_dst[0])
     samp_deg = np.bincount(samp_dst)
     assert set(samp_deg[samp_deg > 0].tolist()) <= full_counts
+
+
+# ---------------- the sample keeps the shard's tile structure ---------
+
+_TILE, _GROUP = 16, 4          # small tiles keep the planted shard small
+_BLOCK = _TILE * _GROUP
+
+
+def _planted_shard(n_clusters=40, halo=0, seed=5):
+    """A 1-part ShardedGraph-shaped shard whose clusters are whole
+    tiles: dense diagonal blocks (every cluster its own density, so
+    one block is not a sample) over a thin uniform background, the
+    last block short. `halo` source rows sit behind n_max."""
+    rng = np.random.default_rng(seed)
+    n = n_clusters * _TILE - 5
+    src, dst = [], []
+    for c in range(n_clusters):
+        lo, hi = c * _TILE, min((c + 1) * _TILE, n)
+        m = rng.random((hi - lo, hi - lo)) < rng.uniform(0.5, 0.95)
+        d, s_ = np.nonzero(m)
+        dst.append(d + lo)
+        src.append(s_ + lo)
+    n_bg = 3 * n
+    dst.append(rng.integers(0, n, n_bg))
+    src.append(rng.integers(0, n + halo, n_bg))
+    key = np.unique(np.concatenate(dst) * (n + halo)
+                    + np.concatenate(src))      # simple graph, CSR order
+    ed, es = (key // (n + halo)).astype(np.int32), \
+        (key % (n + halo)).astype(np.int32)
+    return SimpleNamespace(
+        num_parts=1, n_max=n, halo_size=halo, b_max=0,
+        e_max=int(ed.size), edge_count=np.array([ed.size]),
+        edge_src=es[None, :], edge_dst=ed[None, :], n_feat=8,
+        in_deg=np.maximum(np.bincount(ed, minlength=n), 1)
+        .astype(np.float32)[None, :])
+
+
+def _stats(sg, thr=24):
+    n_src_tiles = -(-(sg.n_max + sg.halo_size) // _TILE)
+    return block_spmm._part_block_stats(sg, 0, _TILE, n_src_tiles, thr)
+
+
+def _rowwise_sample(sg, edge_budget, seed=0):
+    """What the sampler of tuner format 1 did: single destination rows
+    drawn uniformly and renumbered 0..k, every source row they touch
+    packed behind them. Kept here as the contrast: it carries no
+    tile."""
+    es, ed = sg.edge_src[0].astype(np.int64), \
+        sg.edge_dst[0].astype(np.int64)
+    rows = np.random.default_rng(seed).permutation(sg.n_max)
+    deg = np.bincount(ed, minlength=sg.n_max)
+    rows = np.sort(
+        rows[:np.searchsorted(np.cumsum(deg[rows]), edge_budget) + 1])
+    keep = np.isin(ed, rows)
+    remap = np.full(sg.n_max + sg.halo_size, -1)
+    remap[rows] = np.arange(rows.size)
+    extra = np.unique(es[keep][remap[es[keep]] < 0])
+    remap[extra] = rows.size + np.arange(extra.size)
+    return SimpleNamespace(
+        n_max=int(rows.size + extra.size), halo_size=0,
+        edge_count=np.array([int(keep.sum())]),
+        edge_dst=remap[ed[keep]][None, :],
+        edge_src=remap[es[keep]][None, :])
+
+
+def _restricted(sg, info):
+    """The shard's own edges into the sampled blocks, ids in place."""
+    ed = sg.edge_dst[0]
+    keep = np.isin(ed // info["block_rows"],
+                   np.asarray(info["block_starts"]) // info["block_rows"])
+    return SimpleNamespace(
+        n_max=sg.n_max, halo_size=sg.halo_size,
+        edge_count=np.array([int(keep.sum())]),
+        edge_src=sg.edge_src[0][keep][None, :],
+        edge_dst=ed[keep][None, :])
+
+
+def test_sample_carries_the_shards_dense_tiles():
+    """(a) On a clustered shard the sample's dense/remainder split is
+    EXACTLY the shard's over the sampled tile-rows, and close to the
+    whole shard's — where rows drawn one by one read nothing."""
+    sg = _planted_shard()
+    budget = int(sg.edge_count[0]) // 3
+    sample, info = tuner.sample_slice(sg, edge_budget=budget,
+                                      block_rows=_BLOCK)
+    assert 1 < len(info["block_starts"]) < -(-sg.n_max // _BLOCK)
+    assert _stats(sample) == _stats(_restricted(sg, info))
+    # tile by tile, not only in sum: every (destination tile, source
+    # tile) pair holds the edges it holds on the shard
+    new_tile_of = {s // _TILE + j: i * _GROUP + j
+                   for i, s in enumerate(info["block_starts"])
+                   for j in range(_GROUP)}
+    rs = _restricted(sg, info)
+    want = sorted((new_tile_of[d // _TILE], s_ // _TILE) for d, s_ in
+                  zip(rs.edge_dst[0].tolist(), rs.edge_src[0].tolist()))
+    got = sorted((d // _TILE, s_ // _TILE) for d, s_ in
+                 zip(sample.edge_dst[0].tolist(),
+                     sample.edge_src[0].tolist()))
+    assert got == want
+    whole = _stats(sg)[0]
+    assert whole > 0.5
+    assert abs(_stats(sample)[0] - whole) < 0.1
+    # the old budget was 0.17% of Reddit's edges; a fortieth here
+    assert _stats(_rowwise_sample(sg, budget // 13))[0] < whole / 4
+
+
+def test_sampled_blocks_are_aligned_whole_and_spread():
+    """(b) Blocks start on multiples of block_tile x group, keep every
+    in-edge of every row, are packed whole and in order, and come one
+    from each stratum of the row range."""
+    sg = _planted_shard()
+    sample, info = tuner.sample_slice(
+        sg, edge_budget=int(sg.edge_count[0]) // 3, block_rows=_BLOCK)
+    starts = np.asarray(info["block_starts"])
+    assert info["block_rows"] == _BLOCK
+    assert np.all(starts % _BLOCK == 0)
+    assert np.all(np.diff(starts) > 0)
+    assert sample.n_max == len(starts) * _BLOCK == info["sample_rows"]
+    ed, sd = sg.edge_dst[0], sample.edge_dst[0]
+    for i, s0 in enumerate(starts):
+        theirs = np.bincount(ed[(ed >= s0) & (ed < s0 + _BLOCK)] - s0,
+                             minlength=_BLOCK)
+        ours = np.bincount(
+            sd[(sd >= i * _BLOCK) & (sd < (i + 1) * _BLOCK)]
+            - i * _BLOCK, minlength=_BLOCK)
+        assert np.array_equal(ours, theirs)       # row by row, in place
+    assert np.array_equal(sample.in_deg[0],
+                          np.maximum(np.bincount(sd), 1))
+    n_blocks = -(-sg.n_max // _BLOCK)
+    assert starts[0] // _BLOCK < n_blocks / len(starts)
+    assert starts[-1] // _BLOCK >= n_blocks - n_blocks / len(starts) - 1
+    # the seed moves the draw, the same seed repeats it
+    again = tuner.sample_slice(sg, edge_budget=int(sg.edge_count[0]) // 3,
+                               block_rows=_BLOCK)[1]["block_starts"]
+    other = tuner.sample_slice(sg, edge_budget=int(sg.edge_count[0]) // 3,
+                               seed=1, block_rows=_BLOCK)[1]["block_starts"]
+    assert again == info["block_starts"] != other
+    # never under one block, however small the budget
+    one, info1 = tuner.sample_slice(sg, edge_budget=1, block_rows=_BLOCK)
+    assert len(info1["block_starts"]) == 1 and one.n_max == _BLOCK
+
+
+def test_shard_under_budget_is_taken_whole():
+    """(c) As before the change: nothing is dropped, and now nothing is
+    renumbered either."""
+    sg = _planted_shard(halo=24)
+    sample, info = tuner.sample_slice(
+        sg, edge_budget=int(sg.edge_count[0]), block_rows=_BLOCK)
+    assert sample.n_max == sg.n_max and sample.halo_size == sg.halo_size
+    assert np.array_equal(sample.edge_src[0], sg.edge_src[0])
+    assert np.array_equal(sample.edge_dst[0], sg.edge_dst[0])
+    assert info["scale"] == 1.0
+    assert _stats(sample) == _stats(sg)
+
+
+def test_multipart_shard_keeps_halo_sources_behind_n_max():
+    """(d) P=2: the heaviest shard's halo sources keep their ids, behind
+    the shard's n_max, and the kernels take the sample's geometry."""
+    import jax.numpy as jnp
+
+    from pipegcn_tpu.ops.bucket_spmm import (build_sharded_bucket_tables,
+                                             make_device_bucket_spmm_fn)
+    from pipegcn_tpu.ops.spmm import spmm_mean
+
+    sg = _sharded(num_nodes=1500, avg_degree=10, seed=9, n_parts=2)
+    assert sg.halo_size > 0
+    sample, info = tuner.sample_slice(sg, edge_budget=2000,
+                                      block_rows=128)
+    r = info["sampled_rank"]
+    assert r == int(np.argmax(sg.edge_count))
+    assert info["scale"] == int(sg.edge_count[r]) / info["sample_edges"]
+    assert sample.n_max < sg.n_max
+    assert sample.n_max + sample.halo_size == sg.n_max + sg.halo_size
+    ec = int(sg.edge_count[r])
+    es, ed = sg.edge_src[r][:ec], sg.edge_dst[r][:ec]
+    keep = np.isin(ed // 128, np.asarray(info["block_starts"]) // 128) \
+        & (ed < sg.n_max)
+    halo_src = np.sort(es[keep][es[keep] >= sg.n_max])
+    assert halo_src.size > 0
+    ss = sample.edge_src[0]
+    assert np.array_equal(np.sort(ss[ss >= sg.n_max]), halo_src)
+    assert np.array_equal(np.sort(ss), np.sort(es[keep]))
+    # the bucket kernel on the sample's tables agrees with the plain
+    # edge-list aggregation over the same operand
+    n_src = sample.n_max + sample.halo_size
+    f = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (n_src, 8)).astype(np.float32))
+    deg = jnp.asarray(sample.in_deg[0])
+    tabs = {k: jnp.asarray(v[0]) for k, v in
+            build_sharded_bucket_tables(sample).items()}
+    got = make_device_bucket_spmm_fn(tabs, deg, n_src)(f)
+    want = spmm_mean(f, jnp.asarray(ss), jnp.asarray(sample.edge_dst[0]),
+                     deg, sample.n_max, sorted_edges=True)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("name", ["xla", "bucket", "block-u4"])
+def test_timed_program_keeps_forward_and_backward(name):
+    """What is timed is what a step runs: the forward over the whole
+    in-edge lists AND the backward under a real cotangent. The
+    aggregation is linear, so a program that returns its gradient
+    alone loses the forward as dead code; this one returns a scalar
+    both halves feed, checked here against the mean aggregation's
+    closed form (operand of ones: every row with an in-edge reads 1)."""
+    import jax.numpy as jnp
+
+    sg = _planted_shard(halo=24)
+    sample, _ = tuner.sample_slice(
+        sg, edge_budget=int(sg.edge_count[0]) // 3, block_rows=_BLOCK)
+    cand = next(c for c in tuner.candidate_grid() if c["name"] == name)
+    width = 8
+    n_src = sample.n_max + sample.halo_size
+    rows = np.bincount(sample.edge_dst[0], minlength=sample.n_max) > 0
+    for fill in (0.0, 1.0):
+        program, args = tuner._candidate_program(
+            sample, cand, width, block_tile=_TILE, block_nnz=None,
+            chunk_edges=None, bucket_merge=0,
+            fbuf=jnp.full((n_src, width), fill, jnp.bfloat16))
+        cot = np.asarray(args[-1])
+        assert cot.shape == (sample.n_max, width) and cot.std() > 0.5
+        backward = float(cot[rows].sum())
+        forward = fill * width * int(rows.sum())
+        assert forward == 0 or forward > 20 * abs(backward)
+        np.testing.assert_allclose(float(program(*args)),
+                                   forward + backward,
+                                   rtol=5e-3, atol=0.5)
+
+
+def _cost(name, impl, s, spread, **kw):
+    return dict({"name": name, "impl": impl, "rem_dtype": None,
+                 "rem_amax": False, "block_group": 1, "slab": False,
+                 "spmm_fwdbwd_s": s, "spread_s": spread,
+                 "est_epoch_spmm_s": s, "error": None}, **kw)
+
+
+@pytest.mark.parametrize("costs,want", [
+    # (f) where the ranges of their reps overlap the clock cannot tell
+    # them apart: the fixed preference order decides, DEFAULT_IMPL's
+    # family first
+    ([_cost("xla", "xla", 1.01e-2, 1e-4),
+      _cost("bucket", "bucket", 1.04e-2, 0.0),
+      _cost("block-u4", "block", 1.00e-2, 5e-4)], "bucket"),
+    # a slower candidate's own noise does not make it a tie
+    ([_cost("bucket-slab", "bucket", 1.5e-2, 9e-3),
+      _cost("block-u4", "block", 1.0e-2, 1e-4)], "block-u4"),
+    # outside it the measurement decides, whatever the family
+    ([_cost("bucket", "bucket", 3.4e-2, 5e-4),
+      _cost("bucket-bf16", "bucket", 3.3e-2, 5e-4),
+      _cost("block-u4-f8", "block", 1.0e-2, 5e-4)], "block-u4-f8"),
+    # a tie inside one family falls to the grid's order: the plain
+    # transport before the narrower one
+    ([_cost("bucket", "bucket", 3.4e-2, 1e-4),
+      _cost("block-u4", "block", 1.02e-2, 1e-4),
+      _cost("block-u4-f8", "block", 1.00e-2, 4e-4)], "block-u4"),
+    # identical reps (spread 0) still leave the argmin standing
+    ([_cost("bucket", "bucket", 2.0e-2, 0.0),
+      _cost("block", "block", 1.0e-2, 0.0)], "block"),
+    # a failed candidate is never picked, however it would have ranked
+    ([_cost("bucket", "bucket", None, None, error="boom"),
+      _cost("block", "block", 1.0e-2, 1e-4)], "block"),
+    # the fastest on the sample cannot run the shard at its own size:
+    # its time stays in the table, the next one dispatches
+    ([_cost("xla", "xla", 0.7e-2, 1e-4, out_of_domain="RESOURCE_EXHAUSTED"),
+      _cost("bucket-f8", "bucket", 1.1e-2, 1e-4),
+      _cost("block", "block", 1.2e-2, 1e-4)], "bucket-f8"),
+    # nothing timed: the default kernel
+    ([_cost("bucket", "bucket", None, None, error="boom")],
+     tuner.DEFAULT_IMPL),
+])
+def test_near_tie_falls_to_the_preference_order(costs, want):
+    win = tuner.pick_winner(costs)
+    assert win["name"] == want
+    assert win["impl"] in ("xla", "bucket", "block")
+
+
+def test_raw_edge_kernel_is_asked_at_the_shards_size(monkeypatch):
+    """The raw-edge-list kernel materializes a message per edge, so a
+    sample cannot say whether the shard fits. The campaign asks the
+    compiler at the shard's own shapes: a refusal keeps the candidate's
+    time in the table and takes it out of the choice; a shard taken
+    whole is its own proof and is not asked."""
+    sg = _planted_shard()
+    assert tuner.shard_size_refusal(sg, 0, 8, None) is None   # it fits
+    asked = []
+
+    def refuse(sg_, r, width, chunk):
+        asked.append((r, width, chunk))
+        return "RESOURCE_EXHAUSTED: 18.57G of 15.75G hbm"
+
+    monkeypatch.setattr(tuner, "shard_size_refusal", refuse)
+    rec = tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16",
+                     slab="off", block_group=4,
+                     edge_budget=int(sg.edge_count[0]) // 2)
+    assert asked == [(0, 8, None)]
+    xla = next(c for c in rec["costs"] if c["name"] == "xla")
+    assert xla["error"] is None and xla["spmm_fwdbwd_s"] > 0
+    assert xla["out_of_domain"].startswith("RESOURCE_EXHAUSTED")
+    assert rec["winner"]["impl"] != "xla"
+    tuner.clear_memo()
+    tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16", slab="off",
+               block_group=4, edge_budget=10 ** 9)
+    assert len(asked) == 1
+
+
+def test_edge_budget_defaults_agree():
+    """One number, several places that cannot import each other's:
+    the CLI's, the model config's and the tuner's default budget."""
+    from pipegcn_tpu.cli.parser import create_parser
+
+    assert create_parser().get_default("tuner_samples") \
+        == ModelConfig(layer_sizes=(4, 4)).tuner_samples \
+        == tuner.DEFAULT_EDGE_BUDGET
 
 
 # ---------------- round-trip through the artifact ---------------------
@@ -116,8 +434,12 @@ def test_cost_table_roundtrip_artifact(tmp_path, mmap):
         (c["spmm_fwdbwd_s"] is None) == (c["error"] is not None)
         for c in costs)
     ok = [c for c in costs if c["error"] is None]
-    assert win["name"] == min(
-        ok, key=lambda c: c["spmm_fwdbwd_s"])["name"]  # measured argmin
+    best = min(ok, key=lambda c: c["spmm_fwdbwd_s"])  # measured argmin
+    # the winner is the argmin or a near-tie of it (pick_winner)
+    assert win["name"] == tuner.pick_winner(costs)["name"]
+    chosen = next(c for c in ok if c["name"] == win["name"])
+    assert chosen["spmm_fwdbwd_s"] - best["spmm_fwdbwd_s"] \
+        <= best["spread_s"]
     assert os.path.exists(tuner.tuning_path(path))
     assert np.isfinite(t1.train_epoch(0))
 
@@ -259,16 +581,107 @@ def test_truncated_sidecar_degrades_to_live_retune(tmp_path):
     assert why2 is None and rec2["winner"] == t1.tuning["winner"]
 
 
-def test_tuning_record_schema_contract():
+def _emitted_tuning_record(trainer):
+    """The `tuning` record fit() writes for this trainer, through the
+    logger that validates it."""
+    import io
+
+    from pipegcn_tpu.obs import MetricsLogger
+
+    buf = io.StringIO()
+    ml = MetricsLogger(buf)
+    tu = trainer.tuning
+    ml.tuning(winner=tu["winner"], source=tu["source"],
+              stale_reason=tu["stale_reason"], costs=tu["costs"],
+              **{k: tu[k] for k in tuner.SAMPLE_FIELDS})
+    return json.loads(buf.getvalue().splitlines()[-1])
+
+
+@pytest.mark.parametrize("tune", [False, True])
+def test_tuning_record_schema_contract(tune):
     """The trainer-emitted tuning dict must satisfy the contracted
-    obs record kind (tests/test_obs.py pins the v4 field list)."""
-    from pipegcn_tpu.obs.schema import validate_record
+    obs record kind (tests/test_obs.py pins the field list): beside
+    winner / source / costs, what the timed sample carried — numbers
+    from a live campaign, nulls from the no-measurement default."""
+    from pipegcn_tpu.obs.schema import TUNING_FIELDS, validate_record
 
     sg = _sharded(seed=41)
-    t = Trainer(sg, _cfg(sg, tune=False), TrainConfig(seed=0))
-    tu = t.tuning
-    validate_record({"event": "tuning", "winner": tu["winner"],
-                     "source": tu["source"], "costs": tu["costs"],
-                     "stale_reason": tu["stale_reason"]})
-    # and it is JSON-serializable end to end (lands in metrics JSONL)
-    json.dumps(tu["winner"]), json.dumps(tu["costs"])
+    t = Trainer(sg, _cfg(sg, tune=tune), TrainConfig(seed=0))
+    rec = _emitted_tuning_record(t)
+    validate_record(rec)
+    assert set(tuner.SAMPLE_FIELDS) - {"est_epoch_spmm_s"} \
+        < set(TUNING_FIELDS)
+    if not tune:
+        assert rec["source"] == "default"
+        assert all(rec[k] is None for k in tuner.SAMPLE_FIELDS)
+        return
+    assert rec["source"] == "live"
+    # a 400-node shard is under any budget: taken whole, so the sample
+    # reads the shard's coverage to the digit
+    assert rec["sample_dense_coverage"] == rec["shard_dense_coverage"]
+    assert 0.0 <= rec["shard_dense_coverage"] <= 1.0
+    assert rec["sample_tile_rows"] == -(-sg.n_max // t.cfg.block_tile)
+    assert rec["call_overhead_s"] > 0
+    win = next(c for c in rec["costs"]
+               if c["name"] == rec["winner"]["name"])
+    assert rec["est_epoch_spmm_s"] == win["est_epoch_spmm_s"] >= 0
+    assert all(c["spread_s"] >= 0 for c in rec["costs"]
+               if c["error"] is None)
+
+
+def test_tuner_times_what_the_step_runs(tmp_path):
+    """Under use_pp the first layer's aggregation, the widest on a
+    wide-feature graph, is precomputed once: the candidates are timed
+    over the widest operand an IN-STEP aggregation sees, the estimate
+    counts those aggregations, and the block tables keep the
+    threshold of the widest operand of all (what _use_block builds)."""
+    sg = _sharded(n_feat=48, seed=61)
+    path = str(tmp_path / "art")
+    sg.save(path)
+    sgl = ShardedGraph.load(path)
+    cfg = ModelConfig(layer_sizes=(sgl.n_feat, 16, 16, sgl.n_class),
+                      norm="layer", dropout=0.0, use_pp=True,
+                      train_size=sgl.n_train_global, spmm_impl="auto",
+                      tuner_samples=5000)
+    t = Trainer(sgl, cfg, TrainConfig(seed=0))
+    rec, why = tuner.load_tuning(path)
+    assert why is None and t.tuning["source"] == "live"
+    assert rec["signature"]["width"] == 48
+    assert rec["signature"]["step_width"] == 16
+    assert rec["spmm_per_epoch"] == 2            # layers 1 and 2
+    for c in rec["costs"]:
+        want = max(c["spmm_fwdbwd_s"] - rec["call_overhead_s"], 0.0) \
+            * rec["scale"] * 2
+        assert c["est_epoch_spmm_s"] == pytest.approx(want, abs=1e-6)
+    # a table timed over another operand is another table
+    assert tuner.signature_for(
+        width=48, block_tile=cfg.block_tile, bucket_merge=0,
+        chunk_edges=cfg.spmm_chunk) != rec["signature"]
+    assert np.isfinite(t.train_epoch(0))
+
+
+def test_format_1_table_is_refused_and_retuned(tmp_path):
+    """(e) A tuning.json timed on the row-wise sample (tuner format 1)
+    is stale whatever its checksum and signature say: refused with the
+    reason, re-tuned once, replaced on disk."""
+    assert tuner.TUNER_FORMAT == 2
+    sg = _sharded(seed=51)
+    path = str(tmp_path / "art")
+    sg.save(path)
+    sgl = ShardedGraph.load(path)
+    cfg = _cfg(sgl)
+    rec = _plant_table(path, sgl, cfg, {
+        "name": "xla", "impl": "xla", "rem_dtype": None,
+        "rem_amax": False, "block_group": 1})
+    assert tuner.load_tuning(path)[1] is None       # trusted as planted
+    rec["tuner_format"] = 1
+    tuner.save_tuning(path, rec)
+    got, reason = tuner.load_tuning(path)
+    assert got is None and reason == "format 1 != 2"
+    t = Trainer(sgl, cfg, TrainConfig(seed=0))
+    assert t.tuning["source"] == "live"
+    assert "format 1" in t.tuning["stale_reason"]
+    healed, why = tuner.load_tuning(path)
+    assert why is None and healed["tuner_format"] == 2
+    assert healed["winner"] == t.tuning["winner"]
+    assert healed["sample_dense_coverage"] is not None
