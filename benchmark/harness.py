@@ -36,7 +36,7 @@ import time
 
 # keys of a configuration file that are not flags of the program
 CONFIG_META = {"name", "source", "graph_seed", "published", "assumed",
-               "notes"}
+               "notes", "work"}
 
 
 class BenchmarkError(RuntimeError):
@@ -74,8 +74,21 @@ def load_spec(root: str, workload: str) -> dict:
     # the plain reference is a file of its own, found by the name the job
     # gives (`reference`) or, without one, by the configuration's model
     ref_name = job.get("reference", config.get("model", "graphsage"))
+    # the count of a step's work is the model's, a file of its own too,
+    # found by the configuration's `work` key or by its model. A model
+    # without one gets no result line: never another model's count
+    work_name = config.get("work", config.get("model"))
+    work_file = os.path.join(bench_dir, "model_work", f"{work_name}.py")
+    if not os.path.exists(work_file):
+        raise BenchmarkError(
+            f"no count of a step's work for configuration "
+            f"{cell['config']!r} (work {config.get('work')!r}, model "
+            f"{config.get('model')!r}): add {work_file} with "
+            f"epoch_work(facts, flags, itemsize) (benchmark/README.md, "
+            f"point 6)")
     return {"cell": cell, "config": config, "job": job, "end_to_end": e2e,
             "per_layer": per_layer, "bench_dir": bench_dir, "root": root,
+            "work_file": work_file,
             "reference_file": os.path.join(bench_dir, "references",
                                            ref_name + ".py"),
             "limits_file": os.path.join(bench_dir, "limits",
@@ -268,10 +281,16 @@ def load_module(path: str, name: str):
     return mod
 
 
+def load_named(path: str, prefix: str):
+    """`load_module` under a module name made of `prefix` and the file's
+    own name."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    return load_module(path, prefix + stem.replace(".", "_")
+                       .replace("-", "_"))
+
+
 def load_reference(spec: dict):
-    stem = os.path.splitext(os.path.basename(spec["reference_file"]))[0]
-    return load_module(spec["reference_file"], "benchmark_reference_"
-                       + stem.replace(".", "_").replace("-", "_"))
+    return load_named(spec["reference_file"], "benchmark_reference_")
 
 
 def reference_graph(spec: dict, args) -> dict:
@@ -318,9 +337,8 @@ def link_tree(src: str, dst: str) -> None:
 
 
 def load_reader(bench_dir: str, name: str):
-    path = os.path.join(bench_dir, "layer_metrics", name + ".py")
-    return load_module(path, "layer_metric_" + name.replace(".", "_")
-                       .replace("-", "_")).read
+    return load_named(os.path.join(bench_dir, "layer_metrics",
+                                   name + ".py"), "layer_metric_").read
 
 
 def first_dispatch(spec: dict, seed: int, log) -> dict:
@@ -451,11 +469,44 @@ def measure_window(su: dict, seconds: float, trace_dir) -> dict:
     return {"window_s": window_s, "n_epochs": n_epochs}
 
 
+def reader_context(spec: dict, su: dict, win: dict, red: dict, dev: dict,
+                   records: list) -> dict:
+    """What a per-layer metric's reader is given (README, point 5): the
+    reduced trace, the set-up split, the cell as the program built it and
+    its work as its model's file counts it."""
+    from . import work
+
+    args = su["args"]
+    flags = dict(vars(args))
+    # the scalars and shapes of the cell: not the per-node arrays, not the
+    # parameters
+    facts = {k: v for k, v in su["facts"].items()
+             if isinstance(v, (bool, int, float, str, tuple))}
+    stream: dict = {}
+    for r in records:
+        stream.setdefault(r.get("event"), []).append(r)
+    epochs = stream.get("epoch", [])
+    return {
+        "setup": su["split"], "trace": red, "epochs_traced": win["n_epochs"],
+        "work": load_named(spec["work_file"], "model_work_").epoch_work(
+            facts, flags, 2 if args.dtype == "bfloat16" else 4),
+        "work_module": work,
+        "peaks": work.peaks_for(dev["kind"]),   # an unknown kind raises
+        "chips": int(spec["cell"]["chips"]),
+        "warm_epochs": su["warm_epochs"],
+        "warm_dispatch_s": sum(float(r["step_time_s"])
+                               for r in epochs[:su["n_warm_records"]]),
+        "epoch_s": win["window_s"] / win["n_epochs"],
+        "facts": facts, "flags": flags, "cell": spec["cell"],
+        "config": spec["config"], "job": spec["job"], "stream": stream,
+    }
+
+
 def traced_metrics(spec: dict, su: dict, win: dict, trace_dir: str,
-                   hlo_texts: list, dev: dict, epochs: list, log) -> dict:
+                   hlo_texts: list, dev: dict, records: list, log) -> dict:
     """The per-layer metrics of the cell, `busy_s` / `window_s` and the
     breakdown, from the trace of the window and the set-up split."""
-    from . import trace_reduce, work
+    from . import trace_reduce
 
     path = trace_reduce.newest_xplane(trace_dir)
     if path is None:
@@ -463,30 +514,26 @@ def traced_metrics(spec: dict, su: dict, win: dict, trace_dir: str,
     t0 = time.perf_counter()
     raw = trace_reduce.load_xplane(path)
     t1 = time.perf_counter()
-    chips = int(spec["cell"]["chips"])
     red = trace_reduce.reduce_trace(
-        raw, chips, [trace_reduce.hlo_scope_map(t) for t in hlo_texts])
+        raw, int(spec["cell"]["chips"]),
+        [trace_reduce.hlo_scope_map(t) for t in hlo_texts])
     scopes = {k: round(v, 4) for k, v in red.get("scope_s", {}).items()}
     log(f"trace: {os.path.getsize(path) / 1e6:.1f} MB read in "
         f"{t1 - t0:.1f} s, reduced in {time.perf_counter() - t1:.1f} s, "
         f"{red.get('n_events')} op events; self seconds by scope {scopes}")
+    log("self seconds per epoch by scope path " + json.dumps(
+        {k: round(v / win["n_epochs"], 6) for k, v in sorted(
+            red.get("path_s", {}).items(), key=lambda kv: -kv[1])}))
     shutil.rmtree(trace_dir, ignore_errors=True)   # hundreds of MB
     if not red or not red["busy_s"] > 0:
         raise BenchmarkError("the trace holds no device operation")
-    args, facts = su["args"], su["facts"]
-    ctx = {
-        "setup": su["split"], "trace": red, "epochs_traced": win["n_epochs"],
-        "work": work.epoch_work(
-            facts["n_nodes"], facts["n_edges"], facts["layer_sizes"],
-            args.n_linear, args.use_pp,
-            2 if args.dtype == "bfloat16" else 4),
-        "work_module": work,
-        "peaks": work.peaks_for(dev["kind"]),   # an unknown kind raises
-        "chips": chips, "warm_epochs": su["warm_epochs"],
-        "warm_dispatch_s": sum(float(r["step_time_s"])
-                               for r in epochs[:su["n_warm_records"]]),
-        "epoch_s": win["window_s"] / win["n_epochs"],
-    }
+    ctx = reader_context(spec, su, win, red, dev, records)
+    tuning = (ctx["stream"].get("tuning") or [{}])[-1]
+    est, spmm_s = tuning.get("est_epoch_spmm_s"), red["scope_s"].get("spmm")
+    if est and spmm_s:
+        log(f"the tuner's est_epoch_spmm_s {est:.4f} over the traced "
+            f"spmm_s {spmm_s / win['n_epochs']:.4f}: "
+            f"{est / (spmm_s / win['n_epochs']):.3f}")
     metrics = {}
     for m in spec["per_layer"]:
         value = load_reader(spec["bench_dir"], m["name"])(ctx)
@@ -562,7 +609,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
     device = dict(dev, memory_peak_bytes=peak)
     if trace:
         traced = traced_metrics(spec, su, win, trace_dir, hlo_texts, dev,
-                                epochs, log)
+                                records, log)
         result["metrics"] = traced["metrics"]
         device.update(traced["device"])
         result["breakdown"] = traced["breakdown"]
@@ -579,20 +626,12 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
 def multi_step_hlo(trainer, length: int):
     """Optimized HLO text of the fused program the window ran, for the
     join from trace events to named scopes where the trace itself carries
-    no scope. Reads the program's compiled step; returns None if that
-    cannot be done."""
-    import jax
-    import jax.numpy as jnp
-
+    no scope. The trainer compiles it past the persistent cache (one real
+    compile): a cache hit would hand back the text, and so the `op_name`
+    scopes, of whichever checkout compiled the program first. Returns
+    None if that cannot be done."""
     try:
-        base = trainer._epoch_rng_base()
-        rngs = jax.vmap(lambda e: jax.random.fold_in(base, e))(
-            jnp.arange(length))
-        scale = jnp.float32(trainer.loss_scaler.scale)
-        fn = trainer._multi_step if length > 1 else trainer._step
-        rng_arg = rngs if length > 1 else rngs[0]
-        return fn.lower(trainer.state, trainer.data, rng_arg,
-                        scale).compile().as_text()
+        return trainer.step_compiled_text(length)
     except Exception as exc:  # noqa: BLE001 - the join is optional
         print(f"benchmark: no HLO text for the scope join: {exc!r}",
               file=sys.stderr)

@@ -31,7 +31,8 @@ def make_tiny_root(tmp_path, limits=None) -> str:
     root = str(tmp_path)
     shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
     dst = os.path.join(root, "benchmark")
-    for sub in ("configs", "jobs", "layer_metrics", "limits", "references"):
+    for sub in ("configs", "jobs", "layer_metrics", "limits", "model_work",
+                "references"):
         shutil.copytree(os.path.join(BENCH, sub), os.path.join(dst, sub))
     for name in os.listdir(os.path.join(dst, "configs")):
         path = os.path.join(dst, "configs", name)

@@ -13,6 +13,11 @@ from conftest import REPO, run_cell
 with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     BENCHMARK = json.load(f)
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+# readers that may find nothing to read on the CPU at the tiny shape: its
+# compiler fuses a bucket's sum into the operation behind it (the seconds
+# read `spmm/scale` here), and 1,500 nodes leave the block kernel no dense
+# tile. On the chip the driver refuses a traced line that lacks them
+SILENT_ON_CPU = {"spmm_reduce_s", "spmm_tile_s"}
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -28,6 +33,10 @@ def test_result_line(tiny_root, monkeypatch, cell, trace):
     kind = "per_layer" if trace else "end_to_end"
     listed = [m for m in BENCHMARK[kind]
               if cell in m.get("workloads", [cell])]
+    if trace:
+        assert SILENT_ON_CPU <= {m["name"] for m in BENCHMARK[kind]}
+        listed = [m for m in listed if m["name"] in line["metrics"]
+                  or m["name"] not in SILENT_ON_CPU]
     for m in listed:
         got = line["metrics"][m["name"]]
         assert got["unit"] == m["unit"]

@@ -1,11 +1,13 @@
 """Small parts of the harness on made-up inputs: the feed's rows by
 partition, the dispatch plan and which of its scans runs first, and the
-prepared artifact shared between the cells of a configuration."""
+prepared artifact shared between the cells of a configuration, whose HLO
+text the scope join reads, and the host line `load_xplane` keeps."""
 
 import os
 from types import SimpleNamespace
 
 import numpy as np
+import pytest
 
 from benchmark import harness
 
@@ -47,3 +49,47 @@ def test_a_second_cell_links_the_prepared_artifact_and_owns_what_it_adds(
     harness.link_tree(str(src), str(dst))             # again: leaves it
     assert not (src / "g" / "tuning.json").exists()
     assert sorted(os.listdir(dst / "g")) == ["shard.npy", "tuning.json"]
+
+
+def test_the_scope_join_reads_the_text_the_trainer_compiles_past_the_cache():
+    """`multi_step_hlo` asks the trainer for `step_compiled_text`, which
+    compiles past the persistent cache: a cache hit would name the scopes
+    of whichever checkout compiled the program first."""
+    asked = []
+
+    def step_compiled_text(length):
+        asked.append(length)
+        return f"HloModule scan_of_{length}"
+
+    trainer = SimpleNamespace(step_compiled_text=step_compiled_text)
+    assert harness.multi_step_hlo(trainer, 4) == "HloModule scan_of_4"
+    assert harness.multi_step_hlo(trainer, 1) == "HloModule scan_of_1"
+    assert asked == [4, 1]
+    # a trainer that cannot give it: no text, and no crash of the run
+    assert harness.multi_step_hlo(SimpleNamespace(), 2) is None
+
+
+@pytest.mark.parametrize("thread_line", ["python", "python3", "MainThread"])
+def test_the_main_threads_host_line_is_kept_whatever_it_is_called(
+        monkeypatch, thread_line):
+    """The main thread's line is named by how the interpreter was started.
+    What goes is the Python tracer's call events (`$file.py:12 fn`), by
+    what they are; the program's spans on that line stay."""
+    import jax.profiler
+
+    from benchmark import trace_reduce as T
+
+    def ev(name, start, dur):
+        return SimpleNamespace(name=name, start_ns=start, duration_ns=dur,
+                               stats=[])
+
+    host = SimpleNamespace(name="/host:CPU", lines=[SimpleNamespace(
+        name=thread_line, events=[
+            ev("$profiler.py:101 start_trace", 0.0, 5.0),
+            ev("fit/keys", 10.0, 50.0), ev("$<unknown> __exit__", 70.0, 1.0),
+            ev("step", 5.0, 90.0)])])
+    fake = SimpleNamespace(from_file=lambda path: SimpleNamespace(
+        planes=[host]))
+    monkeypatch.setattr(jax.profiler, "ProfileData", fake)
+    raw = T.load_xplane("nowhere.xplane.pb")
+    assert raw["host"] == [["fit/keys", 10.0, 50.0], ["step", 5.0, 90.0]]

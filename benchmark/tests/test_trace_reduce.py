@@ -1,5 +1,6 @@
-"""`trace_reduce.py` and `work.py` against hand-worked numbers, and on the
-small recorded chip trace under `testdata/`."""
+"""`trace_reduce.py`, `work.py` and GraphSAGE's count of a step's work
+against hand-worked numbers, and on the small recorded chip traces under
+`testdata/`."""
 
 import glob
 import os
@@ -8,8 +9,12 @@ import pytest
 
 from conftest import BENCH
 
+from benchmark import harness
 from benchmark import trace_reduce as T
 from benchmark import work
+
+sage_work = harness.load_named(
+    os.path.join(BENCH, "model_work", "graphsage.py"), "model_work_")
 
 
 def test_busy_is_the_union_of_one_devices_op_line():
@@ -62,10 +67,82 @@ def test_scope_join_through_hlo_metadata():
     assert T.scope_of("jit(f)/normalize/mul") == "other"   # not `norm`
 
 
+def test_scope_path_keeps_what_the_program_named():
+    assert T.scope_path(
+        "jit(multi)/while/body/closed_call/jvp(layer1)/spmm/bwd/rem_gather/"
+        "jit(_take)/gather") == "spmm/bwd/rem_gather"
+    assert T.scope_path("jit(multi)/while/body/transpose(jvp(layer2))/spmm/"
+                        "reduce/reduce_sum") == "spmm/reduce"
+    # JAX's structure goes wherever it stands, a bare scope stays
+    assert T.scope_path("jit(step)/shard_map/layer0/spmm/gather/while/body/"
+                        "cond/branch_1_fun/custom_vjp_call_jaxpr/checkpoint/"
+                        "pjit/custom_jvp_call/mul") == "layer0/spmm/gather"
+    # the list that decides is JAX's: a name nobody listed is a scope
+    assert T.scope_path("jit(multi)/while/body/jvp(layer1)/attention/"
+                        "edge_softmax/exp") == "attention/edge_softmax"
+    # an einsum names its call by its spec: JAX's, not the program's
+    assert T.scope_path("jit(multi)/while/body/closed_call/jvp(layer1)/spmm/"
+                        "tile/rduts,rusf->rdtf/dot_general") == "spmm/tile"
+    assert T.scope_path("jit(multi)/while/body/add") == ""
+    assert T.scope_path("") == "" and T.scope_path("copy") == ""
+    # a fusion may list several instructions' names: the first counts
+    assert T.scope_path("jit(f)/dense/dot_general;jit(f)/norm/mul") \
+        == "dense"
+
+
+def test_path_s_splits_what_scope_s_holds_as_one():
+    """Self seconds by scope path: they add up to `scope_s`'s, the paths
+    below `spmm` to `scope_s["spmm"]`, and a scope this file has never
+    heard of shows as a path of its own."""
+    pre = "jit(multi)/while/body/closed_call/"
+    scopes = {
+        "fusion.1": pre + "jvp(layer1)/spmm/gather/jit(_take)/gather",
+        "fusion.2": pre + "jvp(layer1)/spmm/reduce/reduce_sum",
+        "fusion.3": pre + "transpose(jvp(layer1))/spmm/bwd/rem_gather/"
+                          "jit(_take)/gather",
+        "fusion.4": pre + "jvp(layer1)/spmm/tile/dot_general",
+        "fusion.5": pre + "jvp(layer1)/spmm/convert_element_type",
+        "fusion.6": pre + "jvp(layer1)/edge_softmax/exp",
+        "dot.7": pre + "jvp(layer1)/dense/dot_general",
+    }
+    durations = {"fusion.1": 4e9, "fusion.2": 2e9, "fusion.3": 3e9,
+                 "fusion.4": 1e9, "fusion.5": 5e8, "fusion.6": 7e8,
+                 "dot.7": 1.5e9, "copy.8": 2.5e8}
+    t, events = 0.0, [["while.0", 0.0, sum(durations.values()) + 1e9]]
+    for name, d in durations.items():
+        events.append([name, t, d])
+        t += d
+    tr = {"devices": {0: events, 1: [list(e) for e in events]},
+          "op_text": {}, "host": [], "layout": []}
+    red = T.reduce_trace(tr, 2, [scopes])
+    path_s, scope_s = red["path_s"], red["scope_s"]
+    assert path_s["spmm/gather"] == pytest.approx(4.0)
+    assert path_s["spmm/bwd/rem_gather"] == pytest.approx(3.0)
+    assert path_s["spmm"] == pytest.approx(0.5)        # bare: no finer scope
+    assert path_s["edge_softmax"] == pytest.approx(0.7)
+    assert scope_s["other"] == pytest.approx(0.7 + 0.25 + 1.0)
+    assert path_s[""] == pytest.approx(0.25 + 1.0)     # copy.8, the while
+    assert sum(path_s.values()) == pytest.approx(sum(scope_s.values()),
+                                                 rel=1e-12)
+    under = sum(v for p, v in path_s.items() if "spmm" in p.split("/"))
+    assert under == pytest.approx(scope_s["spmm"], rel=1e-12)
+    assert T.path_seconds(path_s, "spmm", ("gather", "rem_gather")) \
+        == pytest.approx(7.0)
+    assert T.path_seconds(path_s, "spmm", ("tile", "unpack")) \
+        == pytest.approx(1.0)
+    assert T.path_seconds(path_s, "spmm", ("relayout",)) == 0
+    # without the join there is no path, and every second is still there
+    bare = T.reduce_trace(tr, 2)
+    assert set(bare["path_s"]) == {""}
+    assert bare["path_s"][""] == pytest.approx(sum(scope_s.values()))
+
+
 def test_work_counts_for_a_three_node_graph():
     # a path 0-1-2 with self loops: 7 directed edges; sizes 5 -> 4 -> 3,
     # no dense tail, first aggregation precomputed
-    w = work.epoch_work(3, 7, (5, 4, 3), 0, True, 2)
+    w = sage_work.epoch_work(
+        {"n_nodes": 3, "n_edges": 7, "layer_sizes": (5, 4, 3)},
+        {"n_linear": 0, "use_pp": True}, 2)
     # layer 0: one product over concat (10 wide), no input gradient:
     #   2*3*10*4 * 2 = 480; layer 1: two products of 4x3, three passes:
     #   2 * (2*3*4*3) * 3 = 432
@@ -80,7 +157,7 @@ def test_work_counts_for_a_three_node_graph():
     least = work.aggregation_least_s(w, peaks)
     assert least == {"least_s": pytest.approx(10.0), "bound": "bytes"}
     # without the precompute both layers aggregate in the step
-    assert work.in_step_aggregations((5, 4, 3), 0, False) == [5, 4]
+    assert sage_work.in_step_aggregations((5, 4, 3), 0, False) == [5, 4]
     with pytest.raises(LookupError):
         work.peaks_for("cpu")
 
@@ -103,9 +180,38 @@ def test_recorded_chip_trace(path):
     assert 0 < red["busy_s"] <= red["window_s"]
     assert sum(red["scope_s"].values()) <= red["busy_s"] * (1 + 1e-9)
     assert red["scope_s"].get("spmm", 0) > 0, "the scope join found no spmm"
+    assert sum(red["path_s"].values()) == pytest.approx(
+        sum(red["scope_s"].values()), rel=1e-12)
+    assert sum(v for p, v in red["path_s"].items()
+               if "spmm" in p.split("/")) == pytest.approx(
+        red["scope_s"]["spmm"], rel=1e-12)
     plain_sum = sum(e[2] for e in tr["devices"][0]) * 1e-9
     assert plain_sum >= red["busy_s"]
     assert len(red["ops"]) > 10
+
+
+@pytest.mark.parametrize("reader, seconds", [
+    ("spmm_gather_s", 0.246551732), ("spmm_reduce_s", 0.35261015),
+    ("spmm_tile_s", 0.036304549)])
+def test_the_kernels_layers_on_the_block_cells_recorded_trace(reader,
+                                                              seconds):
+    """`reddit_p1_block` under PR 26's scopes, the first 6000 events of
+    the chip's op line (my chip run, PR 28): forward passes of the first
+    scan's first epoch, so `rem_gather`, `rem_reduce`, `tile` (with the
+    einsum below it, named by its spec) and `unpack`."""
+    tr = T.load_recorded(os.path.join(
+        BENCH, "testdata", "chip_trace_reddit_p1_block.json.gz"))
+    red = T.reduce_trace(tr, 1, tr["hlo_scopes"])
+    read = harness.load_reader(BENCH, reader)
+    assert read({"trace": red, "epochs_traced": 1}) == pytest.approx(
+        seconds, rel=1e-9)
+    assert read({"trace": red, "epochs_traced": 4}) == pytest.approx(
+        seconds / 4, rel=1e-9)
+    # nothing to read: no trace, or one whose operations carry no such path
+    assert read({"trace": {}, "epochs_traced": 1}) is None
+    assert read({"trace": T.reduce_trace(tr, 1), "epochs_traced": 1}) is None
+    assert "spmm/tile" in red["path_s"] and not any(
+        "->" in p for p in red["path_s"])
 
 
 def test_a_recorded_chip_trace_is_kept():

@@ -1,5 +1,6 @@
 """From a profiler trace (`.xplane.pb`) to device busy time, per-operation
-self times, the named scope of each operation and the longest idle gaps.
+self times, the named scope and the scope path of each operation and the
+longest idle gaps.
 
 Kept with the benchmark so that every PR computes the same numbers in the
 same way. Reading is split from reducing: `load_xplane` turns the file into
@@ -16,6 +17,7 @@ children cover. Neither can pass the window by construction.
 from __future__ import annotations
 
 import bisect
+import functools
 import glob
 import gzip
 import json
@@ -34,6 +36,14 @@ SCOPES = ("spmm", "dense", "dropout", "norm", "adam_update", "grad_reduce",
           "halo", "bgrad")
 _TEXT_STATS = ("tf_op", "long_name", "hlo_op", "name", "tf_op_name",
                "source")
+# the components of an `op_name` that JAX itself puts there, beside those
+# with parentheses (`jit(..)`, `jvp(..)`, `transpose(..)`): its structural
+# names, and the spec `jnp.einsum` names its call by (`rduts,rusf->rdtf`).
+# Whatever else a path holds is a scope the program named. JAX's list, not
+# the program's: a scope a later PR opens shows in `path_s` with no edit
+_JAX_STRUCTURE = re.compile(
+    r"^(while|body|cond|branch_\w+|closed_call|shard_map|checkpoint|"
+    r"custom_vjp_call\w*|custom_jvp_call|pjit|.*->.*)$")
 
 
 def newest_xplane(trace_dir: str) -> Optional[str]:
@@ -47,6 +57,27 @@ def scope_of(text: str) -> str:
         if re.search(rf"(^|[/(\s\"]){s}([/)\s\"]|$)", text):
             return s
     return "other"
+
+
+@functools.lru_cache(maxsize=None)   # a few thousand names, 10^5 events
+def scope_path(op_name: str) -> str:
+    """`spmm/bwd/rem_gather` of `jit(multi)/while/body/closed_call/
+    jvp(layer1)/spmm/bwd/rem_gather/jit(_take)/gather`: the named scopes
+    an instruction's `op_name` metadata carries, in order. The last
+    component is the primitive and goes; so does every component with
+    parentheses, JAX's own structural names and an einsum's spec. "" where
+    the program named none."""
+    first = op_name.split(";", 1)[0].strip()   # a fusion may list several
+    return "/".join(c for c in first.split("/")[:-1]
+                    if c and "(" not in c and ")" not in c
+                    and not _JAX_STRUCTURE.match(c))
+
+
+def path_seconds(path_s: Dict[str, float], under: str, last) -> float:
+    """Seconds of `path_s` on the paths that have `under` among their
+    components and end in one of `last`."""
+    return sum(s for path, s in path_s.items()
+               if under in path.split("/") and path.split("/")[-1] in last)
 
 
 def hlo_scope_map(hlo_text: str) -> Dict[str, str]:
@@ -99,8 +130,14 @@ def load_xplane(path: str, max_host_events: int = 200_000) -> dict:
                 elif m and line.name == MODULE_LINE:
                     modules.setdefault(int(m.group(1)), []).append(
                         [ev.name, float(ev.start_ns), float(ev.duration_ns)])
-                elif plane.name.startswith("/host:") and \
-                        line.name != "python":
+                elif plane.name.startswith("/host:"):
+                    if ev.name.startswith("$"):
+                        # a call event of the Python tracer (`$file.py:12
+                        # fn`): a million of them where it is on. The
+                        # thread's line is named by the interpreter
+                        # (`python`, `python3`) and is the main thread's
+                        # own: its other events (`fit/...`) are kept
+                        continue
                     if _has_stat(ev, "hlo_op"):
                         # the CPU backend runs its thunks on host threads:
                         # they stand in for a device line (tests only)
@@ -202,17 +239,19 @@ def pick_scope_maps(events, mods, scope_maps) -> Dict[str, Dict[str, str]]:
 
 def reduce_trace(tr: dict, n_devices: int,
                  scope_maps: Optional[List[Dict[str, str]]] = None) -> dict:
-    """window_s / busy_s (mean over the devices used), self seconds by op
-    and by scope (mean over devices), and the longest idle gaps labelled by
-    what the host was doing. `scope_maps`: `hlo_scope_map` of each program
-    the window ran, for traces that carry no scope themselves."""
+    """window_s / busy_s (mean over the devices used), self seconds by op,
+    by scope and by scope path (`path_s`, keyed by `scope_path` of the
+    joined `op_name`; mean over devices), and the longest idle gaps
+    labelled by what the host was doing. `scope_maps`: `hlo_scope_map` of
+    each program the window ran, for traces that carry no scope
+    themselves."""
     ids = sorted(tr["devices"])[:n_devices]
     if not ids:
         return {}
     text = dict(tr["op_text"])
     lo = min(e[1] for i in ids for e in tr["devices"][i])
     hi = max(e[1] + e[2] for i in ids for e in tr["devices"][i])
-    busy_ns, op_ns, scope_ns, gaps = 0.0, {}, {}, []
+    busy_ns, op_ns, scope_ns, path_ns, gaps = 0.0, {}, {}, {}, []
     for i in ids:
         evs = tr["devices"][i]
         merged = merge_intervals([(e[1], e[1] + e[2]) for e in evs
@@ -221,11 +260,13 @@ def reduce_trace(tr: dict, n_devices: int,
         mods = module_of_events(evs, tr.get("modules", {}).get(i, []))
         maps = pick_scope_maps(evs, mods, scope_maps or [])
         for ev, mod, ns in zip(evs, mods, self_times(evs)):
-            sc = scope_of(text.get(ev[0], ev[0]) + " "
-                          + maps.get(mod, {}).get(ev[0], ""))
+            op_name = maps.get(mod, {}).get(ev[0], "")
+            sc = scope_of(text.get(ev[0], ev[0]) + " " + op_name)
             key = (ev[0], sc)
             op_ns[key] = op_ns.get(key, 0.0) + ns
             scope_ns[sc] = scope_ns.get(sc, 0.0) + ns
+            path = scope_path(op_name)
+            path_ns[path] = path_ns.get(path, 0.0) + ns
         for (_, e0), (s1, _) in zip(merged, merged[1:]):
             gaps.append((s1 - e0, e0, s1))
     k = len(ids)
@@ -237,6 +278,7 @@ def reduce_trace(tr: dict, n_devices: int,
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy_ns / k * 1e-9,
         "scope_s": {s: v / k * 1e-9 for s, v in scope_ns.items()},
+        "path_s": {p: v / k * 1e-9 for p, v in path_ns.items()},
         "ops": sorted(([f"{n} [{sc}] {_shape_of(text.get(n, ''))}".strip(),
                         v / k * 1e-9] for (n, sc), v in op_ns.items()),
                       key=lambda x: -x[1]),
