@@ -52,6 +52,7 @@ from .bucket_spmm import (
     build_tables_for_edges,
     extract_run_plans,
     ladder_prefix,
+    stack_to_caps,
 )
 
 # HBM budget for the per-device dense-A tensor (see
@@ -454,10 +455,10 @@ class BlockPlan:
         self.rem_bwd_widths = list(
             bwd_widths if bwd_widths is not None
             else _bucket_widths(max(max_out, 1)))
-        self.rem_fwd_mats, self.rem_fwd_inv, self.rem_fwd_counts = \
+        self.rem_fwd_mats, self.rem_fwd_inv, _ = \
             build_tables_for_edges(r_src, r_dst, n_out, n_src_rows,
                                    self.rem_fwd_widths)
-        self.rem_bwd_mats, self.rem_bwd_inv, self.rem_bwd_counts = \
+        self.rem_bwd_mats, self.rem_bwd_inv, _ = \
             build_tables_for_edges(r_dst, r_src, n_src_rows, n_out,
                                    self.rem_bwd_widths)
 
@@ -767,11 +768,13 @@ def plan_to_arrays(p: BlockPlan) -> Dict[str, np.ndarray]:
                 if a_mat.shape[0]:
                     arrs[f"blk_{direction}_g{w_i:02d}b"] = a_mat
                     arrs[f"blk_{direction}_g{w_i:02d}t"] = b_mat
+    # remainder tables are slot-major [w, rows] (bucket_spmm): a
+    # bucket with no row has no column
     for b, m in enumerate(p.rem_fwd_mats):
-        if m.shape[0]:
+        if m.shape[1]:
             arrs[f"blkrem_fwd_{b:02d}"] = m
     for b, m in enumerate(p.rem_bwd_mats):
-        if m.shape[0]:
+        if m.shape[1]:
             arrs[f"blkrem_bwd_{b:02d}"] = m
     return arrs
 
@@ -868,10 +871,6 @@ def build_sharded_block_tables(sg, tile: int = 256,
         plans = build_plans(cap_for(bits), fw=fw, bw=bw, fk=fk, bk=bk)
 
     B_max = max(p.a_blocks.shape[0] for p in plans)
-    fwd_caps = [max(p.rem_fwd_counts[b] for p in plans)
-                for b in range(fw_len)]
-    bwd_caps = [max(p.rem_bwd_counts[b] for p in plans)
-                for b in range(bw_len)]
 
     def dense_counts(p, direction):
         if group > 1:
@@ -905,10 +904,6 @@ def build_sharded_block_tables(sg, tile: int = 256,
             ("blk_a_bits" if emit_bits == 1 else "blk_a"):
                 pack_a_blocks(a_pad) if emit_bits == 1
                 else a_pad.astype(a_dtype),
-            "blkrem_fwd_inv": reoffset_inv(p.rem_fwd_inv,
-                                           p.rem_fwd_counts, fwd_caps),
-            "blkrem_bwd_inv": reoffset_inv(p.rem_bwd_inv,
-                                           p.rem_bwd_counts, bwd_caps),
         }
         if group > 1:
             # inv entries encode r * group + d; reoffset the row part
@@ -957,17 +952,16 @@ def build_sharded_block_tables(sg, tile: int = 256,
                         b_mat, caps[w_i],
                         p.n_src_tiles if direction == "fwd"
                         else p.n_dst_tiles).astype(np.int32)
-        for b in range(fw_len):
-            if fwd_caps[b]:
-                arrs[f"blkrem_fwd_{b:02d}"] = _pad_rows(
-                    p.rem_fwd_mats[b], fwd_caps[b], n_src_rows)
-        for b in range(bw_len):
-            if bwd_caps[b]:
-                arrs[f"blkrem_bwd_{b:02d}"] = _pad_rows(
-                    p.rem_bwd_mats[b], bwd_caps[b], sg.n_max)
         for k, v in arrs.items():
             tables.setdefault(k, []).append(v)
     stacked = {k: np.stack(v) for k, v in tables.items()}
+    # the remainder's bucket tables, slot-major at shared row caps
+    stacked.update(stack_to_caps(
+        [(p.rem_fwd_mats, p.rem_fwd_inv) for p in plans], n_src_rows,
+        "blkrem_fwd"))
+    stacked.update(stack_to_caps(
+        [(p.rem_bwd_mats, p.rem_bwd_inv) for p in plans], sg.n_max,
+        "blkrem_bwd"))
     if slab:
         add_slab_plans(stacked, ("blkrem_fwd", n_src_rows),
                        ("blkrem_bwd", sg.n_max))

@@ -7,13 +7,30 @@ does NOT fit VMEM. XLA lowers
 formulation removes every scatter from both the forward AND the backward:
 
   1. Host: bucket destination rows by ~x1.5-ladder local degree. Each
-     bucket b holds a padded neighbor-index matrix idx_b of shape
-     [n_b, D_b] (D_b = bucket width; pad entries point at a zero
-     sentinel row appended to fbuf).
-  2. Device: per bucket, out_b = sum over axis 1 of fbuf_pad[idx_b]
-     — a gather followed by a dense reduction the TPU vectorizes.
+     bucket b holds a padded neighbor-index table idx_b laid out
+     SLOT-MAJOR, [D_b, n_b] (D_b = bucket width, n_b = its rows
+     rounded up to ROW_TILE): row j of the table is neighbor slot j of
+     every destination of the bucket. Pad entries point at a zero
+     sentinel row appended to fbuf.
+  2. Device: per bucket, the gather streams slot 0 of every row, then
+     slot 1, ...: msgs = fbuf_pad[idx_b] is [D_b, n_b, F] in the
+     transport dtype, and out_b = sum over axis 0 of it, widened to
+     f32 inside the reduction: a sum of D_b slices [n_b, F] that the
+     TPU runs as plain vector adds. The only f32 tensor written is the
+     [n_b, F] result.
   3. Results concatenate in bucket order; one final gather by a
      precomputed inverse permutation restores destination order.
+
+Why slot-major, and why n_b is a multiple of ROW_TILE = 32: the chip's
+gather yields the flat [D_b * n_b, F] stream, and the 3-D view the
+reduction needs is free only if its second-minor axis is a whole
+number of sublane tiles (8 rows of f32, 16 of bf16, 32 of fp8). The
+ladder's widths (13, 19, 141, ...) never are, so a destination-major
+[n_b, D_b, F] view was a physical copy of every message, widened to
+f32 on the way: 10 to 17 bytes of HBM traffic an element where the
+reduction itself needs 1 to 4 (PERF.md section 6, PR 30). Rounding
+the ROWS of a bucket up costs at most 31 sentinel rows a bucket;
+rounding its width up would cost up to a quarter more gathered rows.
 
 The backward needs d_fbuf[src] += g[dst]/deg[dst] summed over edges —
 itself an SpMM with edge roles swapped — so the host also builds
@@ -22,7 +39,7 @@ the same scatter-free kernel in the other direction, accumulating in f32.
 
 Padding overhead is bounded by 1.5x (the _ladder_rungs width steps)
 and is ~1.2x on real degree distributions. All shapes are static; per-device tables
-are padded to shared maxima so one traced program serves every device in
+are padded to shared row caps so one traced program serves every device in
 shard_map.
 """
 
@@ -35,9 +52,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# bound on the materialized [rows, D_b, F] gather per bucket chunk
-# (elements, not bytes): 32M elems = 128 MB in f32, 64 MB in bf16
+# bound on the materialized [D_b, rows, F] gather per bucket chunk
+# (elements, not bytes, and in the TRANSPORT dtype: no f32 copy of it
+# exists): 32M elems = 128 MB in f32, 64 MB in bf16, 32 MB in fp8. A
+# chunk is a slice of the slot-major table along its rows, a multiple
+# of ROW_TILE of them, so every chunk keeps the free 3-D view
 DEFAULT_CHUNK_ELEMS = 32 * 1024 * 1024
+
+# rows of a bucket (and of a chunk) come in multiples of the largest
+# sublane tile of the three transport dtypes (f32 8, bf16 16, fp8 32):
+# the [D_b * rows, F] gather stream then views as [D_b, rows, F] with
+# no copy in any of them (module docstring)
+ROW_TILE = 32
+
+
+def row_cap(n: int) -> int:
+    """`n` rows rounded up to a whole number of ROW_TILEs (0 stays 0)."""
+    return -(-int(n) // ROW_TILE) * ROW_TILE
 
 # TPU row-gather fast path: measured on v5e, gathering rows of <= 256
 # bytes runs at ~400-460M rows/s while wider rows fall off a cliff to
@@ -72,11 +103,15 @@ def _find_runs(flat: np.ndarray, sentinel: int):
 
 def build_slab_plan(stacked: np.ndarray, sentinel: int,
                     slab_len: int = SLAB_RUN):
-    """Streaming-slab plan for one bucket's stacked gather table
-    [P, cap, w] — the table-build-time half of the slab-gather path.
+    """Streaming-slab plan for one bucket's stacked gather table in
+    DESTINATION-major order, [P, cap, w] (add_slab_plans hands it the
+    transpose of the slot-major plain table) — the table-build-time
+    half of the slab-gather path. Runs are a destination's consecutive
+    neighbor ids, so this path keeps the destination-major stream, and
+    with it the [cap, w, F] reduction the plain path left behind.
 
     Detects contiguous index runs in each part's row-major flattened
-    stream (the order the device materializes messages in) and chops
+    stream (the order the slab path materializes messages in) and chops
     runs of >= slab_len into fixed-length slabs. Returns None when no
     part has a qualifying run, else a dict of arrays:
 
@@ -140,11 +175,13 @@ def build_slab_plan(stacked: np.ndarray, sentinel: int,
 
 def gather_contiguity(tables, n_src_rows: int,
                       slab_len: int = SLAB_RUN):
-    """Host-side contiguity stat of the forward gather streams of a
-    sharded table dict (bucket or block-remainder): mean +1-run length
-    and the fraction of real gather entries a slab plan of `slab_len`
-    would cover. Cheap O(tables) — the number the reorder lever is
-    supposed to move, reported by bench next to the epoch anatomy."""
+    """Host-side contiguity stat of the forward neighbor lists of a
+    sharded table dict (bucket or block-remainder, slot-major
+    [P, w, cap]), read destination by destination as a slab plan reads
+    them: mean +1-run length and the fraction of real gather entries a
+    slab plan of `slab_len` would cover. Cheap O(tables) — the number
+    the reorder lever is supposed to move, reported by bench next to
+    the epoch anatomy."""
     n_real = n_runs = covered = 0
     for k in sorted(tables):
         if not (k.startswith("bkt_fwd_") or k.startswith("blkrem_fwd_")) \
@@ -152,7 +189,7 @@ def gather_contiguity(tables, n_src_rows: int,
             continue
         t = np.asarray(tables[k])
         for p in range(t.shape[0]):
-            _, lens = _find_runs(t[p].reshape(-1).astype(np.int64),
+            _, lens = _find_runs(t[p].T.reshape(-1).astype(np.int64),
                                  n_src_rows)
             n_real += int(lens.sum())
             n_runs += int(lens.shape[0])
@@ -211,11 +248,15 @@ def build_tables_for_edges(
     must have dst == n_out and are dropped).
 
     Returns (idx_mats, inv_perm, counts):
-      idx_mats[b]: [n_b, widths[b]] int32 into fbuf_pad rows, pad =
-        n_src_rows (the zero sentinel row);
-      inv_perm: [n_out] int32 into the concatenated bucket output (rows
-        with zero degree point at its final zero sentinel row);
-      counts[b]: real rows in bucket b.
+      idx_mats[b]: [widths[b], row_cap(n_b)] int32 into fbuf_pad rows,
+        slot-major (module docstring): column i holds the neighbors of
+        the bucket's i-th row; pad = n_src_rows (the zero sentinel
+        row), which fills the slots past a row's degree and the whole
+        columns past n_b;
+      inv_perm: [n_out] int32 into the concatenated bucket output, each
+        bucket row_cap(n_b) rows of it (rows with zero degree point at
+        its final zero sentinel row);
+      counts[b]: real rows n_b in bucket b.
     """
     real = edge_dst < n_out
     src = edge_src[real].astype(np.int64)
@@ -239,29 +280,88 @@ def build_tables_for_edges(
     for b, w in enumerate(widths):
         rows = np.nonzero((bid == b) & (deg > 0))[0]
         n_b = rows.shape[0]
-        mat = np.full((n_b, w), n_src_rows, dtype=np.int32)
-        # fill each row's neighbors from CSR
+        mat = np.full((w, row_cap(n_b)), n_src_rows, dtype=np.int32)
+        # fill each row's neighbors from CSR, down its column
         if n_b:
-            starts = row_ptr[rows]
-            lens = deg[rows]
-            # vectorized ragged fill: flat positions (i, j<lens[i])
-            j = np.arange(w)[None, :]
-            mask = j < lens[:, None]
-            flat_src_pos = (starts[:, None] + j)[mask]
-            mat[np.nonzero(mask)[0], np.nonzero(mask)[1]] = src[
-                flat_src_pos
-            ].astype(np.int32)
+            # vectorized ragged fill: positions (slot j < deg[i], row i)
+            slot, col = np.nonzero(
+                np.arange(w)[:, None] < deg[rows][None, :])
+            mat[slot, col] = src[row_ptr[rows][col] + slot].astype(
+                np.int32)
             inv_perm[rows] = offset + np.arange(n_b)
         idx_mats.append(mat)
         counts.append(n_b)
-        offset += n_b
+        offset += mat.shape[1]
     # zero-degree rows -> final zero sentinel row of the concat output
     inv_perm[inv_perm < 0] = offset
     return idx_mats, inv_perm.astype(np.int32), counts
 
 
+def pad_to_caps(mats: Sequence[np.ndarray], inv: np.ndarray,
+                caps: Sequence[int], sentinel: int):
+    """One device's slot-major tables (build_tables_for_edges) widened
+    to row caps shared across devices, so one traced program serves
+    them all: all-sentinel columns are appended up to each cap (their
+    output is ignored: no inv_perm entry points into the pad range) and
+    inv_perm, built on this device's own bucket offsets, moves to the
+    shared ones. Returns (mats [w_b, cap_b], inv)."""
+    inv = inv.astype(np.int64)
+    out = np.full_like(inv, sum(caps))  # default: zero sentinel row
+    padded = []
+    off_old = off_new = 0
+    for m, cap in zip(mats, caps):
+        n_b = m.shape[1]
+        in_b = (inv >= off_old) & (inv < off_old + n_b)
+        out[in_b] = inv[in_b] - off_old + off_new
+        off_old += n_b
+        off_new += cap
+        padded.append(m if n_b == cap else np.pad(
+            m, ((0, 0), (0, cap - n_b)), constant_values=sentinel))
+    return padded, out.astype(np.int32)
+
+
+def stack_to_caps(parts: Sequence[Tuple[Sequence[np.ndarray], np.ndarray]],
+                  sentinel: int, stem: str) -> Dict[str, np.ndarray]:
+    """Every device's (mats, inv) of one direction stacked for
+    shard_map under the keys '<stem>_<b>' [P, w_b, cap_b] and
+    '<stem>_inv' [P, n]: cap_b is the largest row count any device's
+    table of bucket b has (each a multiple of ROW_TILE already); a
+    bucket empty on every device gets no key. The zero-padded bucket
+    index keeps lexicographic key order == width order (bucket ladders
+    are < 100 wide: 2^99 degrees is beyond any graph)."""
+    caps = [max(m.shape[1] for m in bucket)
+            for bucket in zip(*(mats for mats, _ in parts))]
+    padded = [pad_to_caps(mats, inv, caps, sentinel) for mats, inv in parts]
+    tables = {f"{stem}_inv": np.stack([inv for _, inv in padded])}
+    for b, cap in enumerate(caps):
+        if cap:
+            tables[f"{stem}_{b:02d}"] = np.stack([m[b] for m, _ in padded])
+    return tables
+
+
+def _gather_sum(fbuf_pad, mat, scope=""):
+    """One slot-major table's (or chunk's) messages gathered and summed:
+    mat [w, rows] -> f32 [rows, F].
+
+    The barrier keeps the widening to f32 INSIDE the reduction. The
+    chip's gather yields the flat [w * rows, F] stream and a free view
+    of it as [w, rows, F] (rows is a multiple of ROW_TILE); left alone,
+    XLA hoists the convert above that view, where it becomes a pass of
+    its own that writes every message to HBM in f32 (a convert whose
+    consumer is a view does not fuse into the reduce behind it). With
+    the gathered messages pinned in their transport dtype, convert and
+    reduce compile to one fusion that reads 1, 2 or 4 bytes an element
+    and writes [rows, F] (tests/test_tpu_compile.py holds it there)."""
+    with jax.named_scope(scope + "gather"):
+        msgs = jax.lax.optimization_barrier(
+            jnp.take(fbuf_pad, mat, axis=0, mode="clip"))
+    with jax.named_scope(scope + "reduce"):
+        return msgs.astype(jnp.float32).sum(axis=0)
+
+
 def _slab_gather_sum(fbuf_pad, plan, n_b, w, f, scope=""):
-    """One bucket's messages via the streaming-slab plan: the residue
+    """One bucket's messages via the streaming-slab plan, in
+    DESTINATION-major order (plan["res"] is [n_b, w]): the residue
     table gathers the scattered entries (slab-covered positions point
     at the zero sentinel row — cheap repeated reads), then each slab is
     one lax.dynamic_slice streaming copy of SLAB_RUN contiguous source
@@ -300,12 +400,18 @@ def bucket_aggregate(
     scope: str = "",
 ) -> jax.Array:
     """Scatter-free sum aggregation. fbuf [R, F] (any float dtype);
-    returns f32 [n_out, F] where n_out = inv_perm length. idx_mats index
-    into fbuf with R itself as the zero-row sentinel.
+    returns f32 [n_out, F] where n_out = inv_perm length. idx_mats are
+    slot-major, [w_b, rows_b] with rows_b a multiple of ROW_TILE
+    (build_tables_for_edges), and index into fbuf with R itself as the
+    zero-row sentinel.
 
     `chunk_edges` (the --spmm-chunk edge budget) overrides the default
     element budget: each gather materializes at most ~chunk_edges
-    messages.
+    messages. A bucket over the budget runs as a lax.scan over slices
+    of its table along the rows, ROW_TILE-aligned, each writing its
+    rows of the bucket's [rows, F] result; the last slice is moved back
+    to end at the table's end, so no padded copy of the int32 table
+    and no stacked copy of the result exist in the step.
 
     Rows wider than SLAB_BYTES are processed per feature slab (see
     SLAB_BYTES note above); `slab` overrides the element width (0
@@ -326,8 +432,9 @@ def bucket_aggregate(
     but never on CPU (docs/RESILIENCE.md "Numerics").
 
     The work is named for the profiler (obs/profiler.py SCOPE_NAMES):
-    `gather` (the row takes), `reduce` (the f32 cast and the sum over a
-    bucket's width), `unpermute` (concatenation and the inv_perm take),
+    `gather` (the row takes), `reduce` (the sum over a bucket's width,
+    widened to f32 as it reads), `unpermute` (concatenation and the
+    inv_perm take),
     `relayout` (the feature-slab transposes in and out). `scope`
     prefixes them: the block kernel's remainder passes "rem_"."""
     f = fbuf.shape[-1]
@@ -346,36 +453,41 @@ def bucket_aggregate(
     outs = []
     for b, mat in enumerate(idx_mats):
         plan = run_plans[b] if run_plans is not None else None
-        n_b, w = mat.shape
+        w, n_b = mat.shape
         if n_b == 0:
             outs.append(jnp.zeros((0, f), jnp.float32))
             continue
-        rows_per_chunk = max(1, chunk_elems // max(1, w * f))
+        rows_per_chunk = max(
+            ROW_TILE, chunk_elems // (w * f) // ROW_TILE * ROW_TILE)
         if n_b <= rows_per_chunk:
             if plan is not None:
                 outs.append(_slab_gather_sum(fbuf_pad, plan, n_b, w, f,
                                              scope))
-                continue
-            with jax.named_scope(scope + "gather"):
-                msgs = jnp.take(fbuf_pad, mat, axis=0, mode="clip")
-            with jax.named_scope(scope + "reduce"):
-                outs.append(msgs.astype(jnp.float32).sum(axis=1))
+            else:
+                outs.append(_gather_sum(fbuf_pad, mat, scope))
             continue
         n_chunks = -(-n_b // rows_per_chunk)
-        pad_rows = n_chunks * rows_per_chunk - n_b
-        mat_p = jnp.pad(mat, ((0, pad_rows), (0, 0)),
-                        constant_values=fbuf.shape[0])
-        mat_c = mat_p.reshape(n_chunks, rows_per_chunk, w)
 
-        def body(_, m):
-            with jax.named_scope(scope + "gather"):
-                msgs = jnp.take(fbuf_pad, m, axis=0, mode="clip")
+        def body(out, i):
+            # the ragged last chunk starts early enough to be whole: it
+            # writes some rows of the chunk before it again, the same
+            start = jnp.minimum(i * rows_per_chunk, n_b - rows_per_chunk)
+            m = jax.lax.dynamic_slice_in_dim(mat, start, rows_per_chunk,
+                                             axis=1)
+            part = _gather_sum(fbuf_pad, m, scope)
             with jax.named_scope(scope + "reduce"):
-                return None, msgs.astype(jnp.float32).sum(axis=1)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    out, part, start, axis=0), None
 
-        _, chunks = jax.lax.scan(body, None, mat_c)
         with jax.named_scope(scope + "reduce"):
-            outs.append(chunks.reshape(-1, f)[:n_b])
+            out0 = jnp.zeros((n_b, f), jnp.float32)
+            # a scan's carry keeps its type: under shard_map the result
+            # varies over the mesh axes its inputs vary over, from the
+            # first iteration on
+            vma = jax.typeof(fbuf_pad).vma | jax.typeof(mat).vma
+            if vma:
+                out0 = jax.lax.pcast(out0, tuple(vma), to="varying")
+        outs.append(jax.lax.scan(body, out0, jnp.arange(n_chunks))[0])
     with jax.named_scope(scope + "unpermute"):
         res = jnp.concatenate(outs + [jnp.zeros((1, f), jnp.float32)],
                               axis=0)
@@ -413,8 +525,9 @@ class BucketPlan:
 
     fwd aggregates src->dst (the training SpMM over the [R=n_inner+halo]
     source rows into n_out destination rows); bwd aggregates dst->src for
-    the gradient. Tables are numpy; `device_tables()` returns a dict of
-    arrays to ship (optionally padded to caps shared across devices).
+    the gradient. Tables are numpy, slot-major and ready for
+    bucket_aggregate as they are; pad_to_caps widens them to row caps
+    shared across devices.
     """
 
     def __init__(self, edge_src: np.ndarray, edge_dst: np.ndarray,
@@ -608,8 +721,9 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
     the global max degree moves the ladder, every plan rebuilds (the
     resulting tables are identical to a cache-free build either way).
 
-    Returns {'bkt_fwd_<b>': [P, cap_b, w_b], 'bkt_fwd_inv': [P, n_max],
-             'bkt_bwd_<b>': ..., 'bkt_bwd_inv': [P, R]}.
+    Returns {'bkt_fwd_<b>': [P, w_b, cap_b], 'bkt_fwd_inv': [P, n_max],
+             'bkt_bwd_<b>': ..., 'bkt_bwd_inv': [P, R]}: slot-major
+    (module docstring), cap_b a multiple of ROW_TILE.
     """
     P = sg.num_parts
     n_src_rows = sg.n_max + sg.halo_size
@@ -654,55 +768,12 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
         plan_cache.update(
             shape=(sg.n_max, n_src_rows), min_width=min_width,
             widths=(tuple(fw), tuple(bw)), degs=degs, plans=plans)
-    fwd_caps = [max(p.fwd_counts[b] for p in plans) for b in range(len(fw))]
-    bwd_caps = [max(p.bwd_counts[b] for p in plans) for b in range(len(bw))]
-
-    def pad_to_cap(mat: np.ndarray, cap: int, sentinel: int) -> np.ndarray:
-        # append all-sentinel rows up to the shared cap (their output is
-        # ignored: no inv_perm entry points into the pad range)
-        if mat.shape[0] == cap:
-            return mat
-        return np.pad(mat, ((0, cap - mat.shape[0]), (0, 0)),
-                      constant_values=sentinel)
-
-    def reoffset_inv(inv: np.ndarray, counts: Sequence[int],
-                     caps: Sequence[int]) -> np.ndarray:
-        # inv_perm was built with per-device bucket offsets (cumsum of
-        # counts); shift each bucket's range to the shared cap layout
-        inv = inv.astype(np.int64)
-        out = np.full_like(inv, sum(caps))  # default: zero sentinel row
-        off_old = 0
-        off_new = 0
-        for n_b, cap in zip(counts, caps):
-            in_b = (inv >= off_old) & (inv < off_old + n_b)
-            out[in_b] = inv[in_b] - off_old + off_new
-            off_old += n_b
-            off_new += cap
-        return out.astype(np.int32)
-
-    tables: Dict[str, np.ndarray] = {
-        "bkt_fwd_inv": np.stack([
-            reoffset_inv(p.fwd_inv, p.fwd_counts, fwd_caps) for p in plans
-        ]),
-        "bkt_bwd_inv": np.stack([
-            reoffset_inv(p.bwd_inv, p.bwd_counts, bwd_caps) for p in plans
-        ]),
+    tables = {
+        **stack_to_caps([(p.fwd_mats, p.fwd_inv) for p in plans],
+                        n_src_rows, "bkt_fwd"),
+        **stack_to_caps([(p.bwd_mats, p.bwd_inv) for p in plans],
+                        sg.n_max, "bkt_bwd"),
     }
-    # zero-padded bucket index keeps lexicographic key order == width
-    # order (bucket ladders are < 100 wide: 2^99 degrees is beyond any
-    # graph)
-    for b in range(len(fw)):
-        if fwd_caps[b]:
-            tables[f"bkt_fwd_{b:02d}"] = np.stack(
-                [pad_to_cap(p.fwd_mats[b], fwd_caps[b], n_src_rows)
-                 for p in plans]
-            )
-    for b in range(len(bw)):
-        if bwd_caps[b]:
-            tables[f"bkt_bwd_{b:02d}"] = np.stack(
-                [pad_to_cap(p.bwd_mats[b], bwd_caps[b], sg.n_max)
-                 for p in plans]
-            )
     if slab:
         add_slab_plans(tables, ("bkt_fwd", n_src_rows),
                        ("bkt_bwd", sg.n_max))
@@ -721,7 +792,10 @@ def add_slab_plans(tables: Dict[str, np.ndarray], *stems) -> int:
         for k in [k for k in tables if k.startswith(f"{stem}_")
                   and not k.endswith("inv")]:
             b = k.rsplit("_", 1)[1]
-            plan = build_slab_plan(tables[k], sentinel)
+            # runs are found destination by destination: the plan reads
+            # (and keeps) the destination-major transpose
+            plan = build_slab_plan(
+                np.ascontiguousarray(tables[k].transpose(0, 2, 1)), sentinel)
             if plan is None:
                 continue
             tables[f"{stem}res_{b}"] = plan["res"]
@@ -754,7 +828,8 @@ def extract_run_plans(d: Dict[str, jax.Array], stem: str):
 def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
                            n_src_rows: int) -> None:
     """Host-side bounds check of sharded bucket tables ([P, ...] device
-    axis leading): every index must lie in [0, bound] where bound is
+    axis leading; plain tables slot-major [P, w, cap], so a bucket's
+    rows are its LAST axis): every index must lie in [0, bound] where bound is
     the consuming gather's zero-sentinel row. The device kernel gathers
     with mode='clip' ON THE STRENGTH OF THIS CHECK — an out-of-bounds
     index from a build bug or a rotted cache must surface HERE as a
@@ -762,9 +837,9 @@ def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
     wrong row (or, under the previous fill-mode gathers, a NaN minted
     mid-epoch). O(tables) numpy min/max — noise next to the O(E)
     build."""
-    fwd_rows = sum(int(t.shape[-2]) for k, t in tables.items()
+    fwd_rows = sum(int(t.shape[-1]) for k, t in tables.items()
                    if k.startswith("bkt_fwd_") and not k.endswith("inv"))
-    bwd_rows = sum(int(t.shape[-2]) for k, t in tables.items()
+    bwd_rows = sum(int(t.shape[-1]) for k, t in tables.items()
                    if k.startswith("bkt_bwd_") and not k.endswith("inv"))
     for k, t in tables.items():
         if k == "bkt_fwd_inv":

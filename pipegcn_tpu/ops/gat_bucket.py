@@ -105,8 +105,14 @@ def build_sharded_gat_tables(sg) -> Dict[str, np.ndarray]:
                    fwd_widths=fw, bwd_widths=bw)
         for r in range(P)
     ]
-    fwd_caps = [max(p.fwd_counts[b] for p in plans) for b in range(len(fw))]
-    bwd_caps = [max(p.bwd_counts[b] for p in plans) for b in range(len(bw))]
+    # BucketPlan's tables are slot-major [w, rows rounded up to 32]
+    # (bucket_spmm, the mean kernel's layout); this kernel still reads
+    # them destination-major, so the builder below takes each table's
+    # transpose and the plan's own (rounded) row counts as its offsets
+    fwd_n = [[m.shape[1] for m in p.fwd_mats] for p in plans]
+    bwd_n = [[m.shape[1] for m in p.bwd_mats] for p in plans]
+    fwd_caps = [max(n[b] for n in fwd_n) for b in range(len(fw))]
+    bwd_caps = [max(n[b] for n in bwd_n) for b in range(len(bw))]
 
     def pad_mat(mat, cap, sentinel):
         if mat.shape[0] == cap:
@@ -140,15 +146,17 @@ def build_sharded_gat_tables(sg) -> Dict[str, np.ndarray]:
 
     tables: Dict[str, np.ndarray] = {
         "gat_fwd_inv": np.stack([
-            reoffset(p.fwd_inv, p.fwd_counts, fwd_caps) for p in plans]),
+            reoffset(p.fwd_inv, n, fwd_caps)
+            for p, n in zip(plans, fwd_n)]),
         "gat_bwd_inv": np.stack([
-            reoffset(p.bwd_inv, p.bwd_counts, bwd_caps) for p in plans]),
+            reoffset(p.bwd_inv, n, bwd_caps)
+            for p, n in zip(plans, bwd_n)]),
     }
     for b in range(len(fw)):
         if not fwd_caps[b]:
             continue
         tables[f"gat_fwd_{b:02d}"] = np.stack(
-            [pad_mat(p.fwd_mats[b], fwd_caps[b], n_src_rows)
+            [pad_mat(p.fwd_mats[b].T, fwd_caps[b], n_src_rows)
              for p in plans])
         tables[f"gat_fwd_rows_{b:02d}"] = np.stack(
             [pad_rows(r[b], fwd_caps[b], sg.n_max) for r in fwd_rows])
@@ -156,7 +164,7 @@ def build_sharded_gat_tables(sg) -> Dict[str, np.ndarray]:
         if not bwd_caps[b]:
             continue
         tables[f"gat_bwd_{b:02d}"] = np.stack(
-            [pad_mat(p.bwd_mats[b], bwd_caps[b], sg.n_max)
+            [pad_mat(p.bwd_mats[b].T, bwd_caps[b], sg.n_max)
              for p in plans])
         tables[f"gat_bwd_rows_{b:02d}"] = np.stack(
             [pad_rows(r[b], bwd_caps[b], n_src_rows) for r in bwd_rows])

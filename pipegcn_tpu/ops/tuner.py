@@ -57,9 +57,13 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-# 2: the sample keeps whole destination tile-rows (sample_slice); a
-# table timed on the row-wise sample of format 1 is stale
-TUNER_FORMAT = 2
+# 3: the bucket and remainder kernels gather slot-major and widen to
+# f32 inside the reduction (ops/bucket_spmm.py): a table timed on the
+# destination-major kernels of format 2 ranks transports by a cost
+# they no longer have. 2: the sample keeps whole destination tile-rows
+# (sample_slice); a table timed on the row-wise sample of format 1 is
+# stale
+TUNER_FORMAT = 3
 TUNING_FILE = "tuning.json"
 
 # the sample takes whole blocks of destination rows until this many

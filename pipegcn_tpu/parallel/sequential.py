@@ -54,7 +54,7 @@ def _ladder_caps(edge_src_by_rank, edge_dst_by_rank, P, n_max,
     """Shared bucket ladders + per-bucket row caps WITHOUT building any
     tables: one cheap degree-histogram pass per rank (the streamed
     analogue of build_sharded_bucket_tables's cap scan)."""
-    from ..ops.bucket_spmm import _bucket_widths
+    from ..ops.bucket_spmm import _bucket_widths, row_cap
 
     max_in = max_out = 1
     hists = []
@@ -82,7 +82,8 @@ def _ladder_caps(edge_src_by_rank, edge_dst_by_rank, P, n_max,
     for di, do in hists:
         fwd_caps = np.maximum(fwd_caps, counts(di, fw))
         bwd_caps = np.maximum(bwd_caps, counts(do, bw))
-    return fw, bw, fwd_caps.tolist(), bwd_caps.tolist()
+    return (fw, bw, [row_cap(c) for c in fwd_caps],
+            [row_cap(c) for c in bwd_caps])
 
 
 def _rank_bucket_tables(edge_src, edge_dst, n_max, n_src_rows, fw, bw,
@@ -90,40 +91,19 @@ def _rank_bucket_tables(edge_src, edge_dst, n_max, n_src_rows, fw, bw,
     """One rank's bucket tables padded to the shared caps — same
     layout/keys as build_sharded_bucket_tables minus the leading device
     axis, so one traced program serves every rank."""
-    from ..ops.bucket_spmm import BucketPlan
+    from ..ops.bucket_spmm import BucketPlan, pad_to_caps
 
     p = BucketPlan(edge_src, edge_dst, n_max, n_src_rows,
                    fwd_widths=fw, bwd_widths=bw)
-
-    def pad_to_cap(mat, cap, sentinel):
-        if mat.shape[0] == cap:
-            return mat
-        return np.pad(mat, ((0, cap - mat.shape[0]), (0, 0)),
-                      constant_values=sentinel)
-
-    def reoffset_inv(inv, cnts, caps):
-        inv = inv.astype(np.int64)
-        out = np.full_like(inv, sum(caps))
-        off_old = off_new = 0
-        for n_b, cap in zip(cnts, caps):
-            in_b = (inv >= off_old) & (inv < off_old + n_b)
-            out[in_b] = inv[in_b] - off_old + off_new
-            off_old += n_b
-            off_new += cap
-        return out.astype(np.int32)
-
-    t = {
-        "bkt_fwd_inv": reoffset_inv(p.fwd_inv, p.fwd_counts, fwd_caps),
-        "bkt_bwd_inv": reoffset_inv(p.bwd_inv, p.bwd_counts, bwd_caps),
-    }
-    for b in range(len(fw)):
-        if fwd_caps[b]:
-            t[f"bkt_fwd_{b:02d}"] = pad_to_cap(p.fwd_mats[b],
-                                               fwd_caps[b], n_src_rows)
-    for b in range(len(bw)):
-        if bwd_caps[b]:
-            t[f"bkt_bwd_{b:02d}"] = pad_to_cap(p.bwd_mats[b],
-                                               bwd_caps[b], n_max)
+    fwd_mats, fwd_inv = pad_to_caps(p.fwd_mats, p.fwd_inv, fwd_caps,
+                                    n_src_rows)
+    bwd_mats, bwd_inv = pad_to_caps(p.bwd_mats, p.bwd_inv, bwd_caps,
+                                    n_max)
+    t = {"bkt_fwd_inv": fwd_inv, "bkt_bwd_inv": bwd_inv}
+    t.update((f"bkt_fwd_{b:02d}", m)
+             for b, m in enumerate(fwd_mats) if m.shape[1])
+    t.update((f"bkt_bwd_{b:02d}", m)
+             for b, m in enumerate(bwd_mats) if m.shape[1])
     return t
 
 

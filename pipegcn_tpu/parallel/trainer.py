@@ -318,7 +318,9 @@ class Trainer:
     # ---------------- spmm kernel selection ---------------------------
 
     # bump when any kernel-table layout changes: stale caches must miss
-    _TABLES_FORMAT = 6  # v6: slab-gather run plans (res/src/pos/cnt keys)
+    # v7: bucket and block-remainder tables slot-major [P, w, cap], cap a
+    # multiple of 32 (ops/bucket_spmm.py)
+    _TABLES_FORMAT = 7
 
     def _cached_tables(self, kind: str, build_fn):
         """Disk-cache derived kernel tables next to the partition
@@ -328,7 +330,8 @@ class Trainer:
         source_edge_checksum) and validated on load — a regenerated
         artifact or a format change must rebuild, never silently load
         tables for a different graph. Corrupt/mismatched caches fall
-        back to the build. bfloat16 arrays round-trip as uint16 bit
+        back to the build, and `tables_source` says why the file was
+        refused. bfloat16 arrays round-trip as uint16 bit
         views (npz stores bf16 as raw void and cannot restore it);
         writes go to a temp file + atomic rename so a killed run (or a
         shared-filesystem race between hosts, halo.py save()) can never
@@ -339,7 +342,8 @@ class Trainer:
         return tables
 
     def _load_or_build_tables(self, kind: str, build_fn):
-        """(tables, "loaded from <file>" | "built in this run")."""
+        """(tables, "loaded from <file>" | "built in this run" |
+        "built in this run (refused <file>: <reason>)")."""
         from ml_dtypes import bfloat16 as bf16
 
         cd = getattr(self.sg, "cache_dir", None)
@@ -348,11 +352,12 @@ class Trainer:
             [self._TABLES_FORMAT,
              int(self.sg.source_edge_checksum) & ((1 << 64) - 1)],
             dtype=np.uint64)
+        source = "built in this run"
         if fname and os.path.exists(fname):
             try:
                 z = np.load(fname)
-                if "__stamp__" in z.files and \
-                        np.array_equal(z["__stamp__"], stamp):
+                found = z["__stamp__"] if "__stamp__" in z.files else None
+                if found is not None and np.array_equal(found, stamp):
                     bf16_keys = set(z["__bf16_keys__"].tolist())
                     return {
                         k: z[k].view(bf16)
@@ -360,8 +365,17 @@ class Trainer:
                         for k in z.files
                         if k not in ("__bf16_keys__", "__stamp__")
                     }, f"loaded from {fname}"
-            except Exception:  # truncated/corrupt cache: rebuild below
-                pass
+                if found is None or found.shape != stamp.shape:
+                    why = "no stamp"
+                elif int(found[0]) != self._TABLES_FORMAT:
+                    why = (f"table format {int(found[0])} != "
+                           f"{self._TABLES_FORMAT}")
+                else:
+                    why = ("stale: source_edge_checksum mismatch "
+                           "(artifact rebuilt from a different graph)")
+            except Exception as exc:  # truncated/corrupt cache
+                why = f"corrupt: {exc!r}"[:200]
+            source += f" (refused {fname}: {why})"
         tables = build_fn()
         if fname:
             bf16_keys = [k for k, v in tables.items()
@@ -387,7 +401,7 @@ class Trainer:
                         # genuinely-optional (storage-fault audit):
                         # orphaned temp in a cache dir, never read
                         pass
-        return tables, "built in this run"
+        return tables, source
 
     def _setup_spmm(self) -> None:
         """Resolve cfg.spmm_impl: 'bucket' builds the scatter-free

@@ -93,7 +93,10 @@ def test_block_fn_grad_matches_reference(edges):
                                rtol=1e-4, atol=1e-5)
 
 
-def test_trainer_block_matches_xla():
+@pytest.mark.parametrize("spmm_chunk", [None, 40])
+def test_trainer_block_matches_xla(spmm_chunk):
+    """Under shard_map on four devices; an edge budget of 40 cuts the
+    remainder's buckets of more than 32 rows into chunks."""
     g = synthetic_graph(num_nodes=300, avg_degree=7, n_feat=10, n_class=4,
                         seed=21)
     parts = partition_graph(g, 4, seed=0)
@@ -102,9 +105,13 @@ def test_trainer_block_matches_xla():
     for impl in ("xla", "block"):
         cfg = ModelConfig(layer_sizes=(10, 16, 4), norm="layer",
                           dropout=0.0, train_size=sg.n_train_global,
-                          spmm_impl=impl)
+                          spmm_impl=impl, spmm_chunk=spmm_chunk)
         t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
         losses[impl] = [t.train_epoch(e) for e in range(6)]
+    if spmm_chunk:
+        assert max(v.shape[-1] for k, v in t._block_tables.items()
+                   if k.startswith("blkrem_")
+                   and not k.endswith("inv")) > 32
     np.testing.assert_allclose(losses["xla"], losses["block"], rtol=2e-4)
 
 
@@ -436,3 +443,89 @@ def test_scan_names_the_kernels_work(kernel):
              for tok in p.split("/")[1:]}
     assert below <= want | {"bwd", "cast", "rem_relayout", "tripwire"}, \
         below
+
+
+# ---------------- the remainder's slot-major gather stream ------------------
+
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("rem_dtype", [None, "bfloat16", "float8"])
+def test_remainder_is_slot_major_and_matches_dense(edges, rem_dtype, group):
+    """The block kernel's remainder runs bucket_aggregate under the
+    `rem_` scope over slot-major tables ([P, w, cap], cap % 32 == 0):
+    forward and gradient against the dense mean within the transport's
+    rounding, and in the jaxpr every `rem_reduce` sum reads the
+    gathered stream in the transport dtype."""
+    from types import SimpleNamespace
+
+    from pipegcn_tpu.ops.block_spmm import (
+        build_sharded_block_tables,
+        make_device_block_spmm_fn,
+    )
+    from pipegcn_tpu.ops.bucket_spmm import ROW_TILE, transport_dtypes
+    from test_bucket_spmm import assert_reduce_reads_transport
+
+    src, dst, n_out, n_src = edges
+    order = np.argsort(dst, kind="stable")
+    sg = SimpleNamespace(
+        num_parts=1, n_max=n_out, halo_size=n_src - n_out,
+        edge_src=src[order][None].astype(np.int32),
+        edge_dst=dst[order][None].astype(np.int32))
+    f = 8
+    tabs, tile = build_sharded_block_tables(sg, tile=16, n_feat_hint=f,
+                                            nnz_threshold=4, group=group)
+    rem = [k for k in tabs if k.startswith("blkrem_")
+           and not k.endswith("inv")]
+    assert {k[:10] for k in rem} == {"blkrem_fwd", "blkrem_bwd"}
+    for k in rem:
+        p, w, cap = tabs[k].shape
+        assert p == 1 and cap % ROW_TILE == 0 and cap > 0
+    deg = np.maximum(np.bincount(dst, minlength=n_out), 1)
+    d = {k: jnp.asarray(v[0]) for k, v in tabs.items()}
+
+    def fn(x):
+        return make_device_block_spmm_fn(
+            d, jnp.asarray(deg, jnp.float32), n_out, n_src, tile,
+            rem_dtype=rem_dtype)(x)
+
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((n_out, f)), jnp.float32)
+    out, vjp = jax.vjp(fn, x)
+    a = np.zeros((n_out, n_src))
+    np.add.at(a, (dst, src), 1.0)
+    a /= deg[:, None]
+    tol = {None: 1e-5, "bfloat16": 2e-2, "float8": 0.3}[rem_dtype]
+    np.testing.assert_allclose(np.asarray(out), a @ np.asarray(x),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(vjp(c)[0]), a.T @ np.asarray(c),
+                               rtol=tol, atol=tol)
+
+    fwd_dt, bwd_dt = transport_dtypes(rem_dtype)
+    seen = assert_reduce_reads_transport(
+        jax.make_jaxpr(fn)(x).jaxpr, fwd_dt or jnp.float32, f)
+    assert sorted(seen) == sorted(
+        tabs[k].shape[1:] for k in rem if k.startswith("blkrem_fwd"))
+    seen = assert_reduce_reads_transport(
+        jax.make_jaxpr(vjp)(c).jaxpr, bwd_dt or jnp.float32, f)
+    assert sorted(seen) == sorted(
+        tabs[k].shape[1:] for k in rem if k.startswith("blkrem_bwd"))
+
+
+def test_remainder_scopes_carry_the_rem_prefix(edges):
+    """plan_to_arrays hands the single-device remainder tables over as
+    the builder made them (slot-major), and the lowered kernel names the
+    remainder's work rem_gather / rem_reduce / rem_unpermute."""
+    import re
+
+    src, dst, n_out, n_src = edges
+    deg = jnp.asarray(
+        np.maximum(np.bincount(dst, minlength=n_out), 1).astype(np.float32))
+    plan, fn = _make_fn(src, dst, n_out, n_src, deg, 16, 4)
+    arrs = plan_to_arrays(plan)
+    for b, m in enumerate(plan.rem_fwd_mats):
+        assert m.shape[0] == plan.rem_fwd_widths[b] and m.shape[1] % 32 == 0
+        assert (f"blkrem_fwd_{b:02d}" in arrs) == bool(m.shape[1])
+    txt = jax.jit(fn).lower(jnp.ones((n_src, 8), jnp.float32)).as_text(
+        debug_info=True)
+    names = set(re.findall(r"rem_(?:gather|reduce|unpermute)(?=/)", txt))
+    assert names == {"rem_gather", "rem_reduce", "rem_unpermute"}

@@ -125,7 +125,11 @@ def test_bucket_mean_fn_grad_matches_reference(edges):
                                rtol=1e-4, atol=1e-5)
 
 
-def test_trainer_bucket_matches_xla():
+@pytest.mark.parametrize("spmm_chunk", [None, 40])
+def test_trainer_bucket_matches_xla(spmm_chunk):
+    """Under shard_map on four devices; an edge budget of 40 cuts every
+    bucket of more than 32 rows into chunks (the scan whose carry is
+    the bucket's result)."""
     g = synthetic_graph(num_nodes=300, avg_degree=7, n_feat=10, n_class=4,
                         seed=21)
     parts = partition_graph(g, 4, seed=0)
@@ -134,9 +138,12 @@ def test_trainer_bucket_matches_xla():
     for impl in ("xla", "bucket"):
         cfg = ModelConfig(layer_sizes=(10, 16, 4), norm="layer",
                           dropout=0.0, train_size=sg.n_train_global,
-                          spmm_impl=impl)
+                          spmm_impl=impl, spmm_chunk=spmm_chunk)
         t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
         losses[impl] = [t.train_epoch(e) for e in range(6)]
+    if spmm_chunk:
+        assert max(v.shape[-1] for k, v in t._bucket_tables.items()
+                   if not k.endswith("inv")) > 32
     np.testing.assert_allclose(losses["xla"], losses["bucket"], rtol=2e-4)
 
 
@@ -316,3 +323,204 @@ def test_slabbed_aggregate_names_its_relayout(edges):
     assert paths("") == {"gather", "reduce", "unpermute", "relayout"}
     assert paths("rem_") == {"rem_gather", "rem_reduce", "rem_unpermute",
                              "rem_relayout"}
+
+
+# ---------------- the slot-major gather stream ------------------------------
+
+def _ladder_upto(top):
+    from pipegcn_tpu.ops.bucket_spmm import _ladder_rungs
+
+    out = []
+    for w in _ladder_rungs():
+        if w > top:
+            return out
+        out.append(w)
+
+
+LADDER = _ladder_upto(211)
+TRANSPORTS = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
+              "e4m3": jnp.float8_e4m3fn, "e5m2": jnp.float8_e5m2}
+
+
+def _bucket_edges(width, seed, n_rows=150, n_src=260, n_zero=9, n_low=20):
+    """Edges whose destinations fill the bucket of `width`: n_rows
+    destinations with a degree in (rung below, width] (at least one at
+    each end), n_low of degree 1 or 2, n_zero with none."""
+    rng = np.random.default_rng(seed)
+    lo = max([w for w in LADDER if w < width], default=0) + 1
+    degs = np.concatenate([
+        [lo, width], rng.integers(lo, width + 1, n_rows - 2),
+        rng.integers(1, 3, n_low), np.zeros(n_zero, np.int64)])
+    degs = degs[rng.permutation(degs.size)]
+    dst = np.repeat(np.arange(degs.size), degs)
+    src = np.concatenate([rng.choice(n_src, d, replace=False)
+                          for d in degs]) if dst.size else dst
+    return src.astype(np.int64), dst.astype(np.int64), degs.size, n_src
+
+
+def _dense(src, dst, n_out, n_src):
+    a = np.zeros((n_out, n_src), np.float64)
+    np.add.at(a, (dst, src), 1.0)
+    return a
+
+
+def _assert_slot_major(mats, width_of=None):
+    from pipegcn_tpu.ops.bucket_spmm import ROW_TILE
+
+    for m in mats:
+        assert m.ndim == 2 and m.shape[1] % ROW_TILE == 0, m.shape
+    if width_of is not None:
+        assert [m.shape[0] for m in mats] == list(width_of)
+
+
+@pytest.mark.parametrize("transport", sorted(TRANSPORTS))
+@pytest.mark.parametrize("width", LADDER)
+def test_slot_major_forward_and_vjp_match_dense(width, transport):
+    """Every ladder width up to 211, every transport dtype: the forward
+    over the slot-major tables and its VJP (the same kernel over the
+    transpose tables) are the dense float32 products of the operand AS
+    TRANSPORTED, unchunked and over three chunks with a ragged last
+    one; rows without an edge read zero."""
+    dt = TRANSPORTS[transport]
+    f = 8
+    src, dst, n_out, n_src = _bucket_edges(width, seed=width)
+    plan = BucketPlan(src, dst, n_out, n_src)
+    _assert_slot_major(plan.fwd_mats, plan.fwd_widths)
+    _assert_slot_major(plan.bwd_mats, plan.bwd_widths)
+    b = plan.fwd_widths.index(width)
+    # widths 1 and 2 also hold the low-degree rows: 150 rows, or a few more
+    n_b = plan.fwd_counts[b]
+    assert 150 <= n_b <= 170 and plan.fwd_mats[b].shape == (
+        width, 160 if n_b <= 160 else 192)
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32).astype(dt)
+    g = jnp.asarray(rng.standard_normal((n_out, f)), jnp.float32).astype(dt)
+    a = _dense(src, dst, n_out, n_src)
+    want_f = a @ np.asarray(x.astype(jnp.float32), np.float64)
+    want_b = a.T @ np.asarray(g.astype(jnp.float32), np.float64)
+    fm = [jnp.asarray(m) for m in plan.fwd_mats]
+    bm = [jnp.asarray(m) for m in plan.bwd_mats]
+    # 64 rows of the bucket a chunk: 160 rows are chunks of 64, 64 and a
+    # last one moved back to rows 96..160
+    for chunk_elems in (1 << 30, 64 * width * f):
+        out = bucket_aggregate(x, fm, jnp.asarray(plan.fwd_inv),
+                               chunk_elems=chunk_elems)
+        assert out.dtype == jnp.float32 and out.shape == (n_out, f)
+        np.testing.assert_allclose(np.asarray(out), want_f, rtol=2e-6,
+                                   atol=1e-5)
+        zero = np.bincount(dst, minlength=n_out) == 0
+        assert zero.sum() == 9 and not np.asarray(out)[zero].any()
+        back = bucket_aggregate(g, bm, jnp.asarray(plan.bwd_inv),
+                                chunk_elems=chunk_elems)
+        np.testing.assert_allclose(np.asarray(back), want_b, rtol=2e-6,
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("rem_dtype", [None, "bfloat16", "float8"])
+@pytest.mark.parametrize("min_width", [0, 4, 19])
+def test_slot_major_closure_with_min_width_merging(min_width, rem_dtype):
+    """The differentiable closure over sharded tables whose narrow
+    buckets are merged into the first rung >= min_width: forward and
+    gradient against the dense mean, within the transport's rounding."""
+    from pipegcn_tpu.ops.bucket_spmm import (
+        build_sharded_bucket_tables,
+        make_device_bucket_spmm_fn,
+    )
+    from types import SimpleNamespace
+
+    src, dst, n_out, n_src = _bucket_edges(28, seed=min_width + 3,
+                                           n_src=200)
+    order = np.argsort(dst, kind="stable")
+    sg = SimpleNamespace(
+        num_parts=1, n_max=n_out, halo_size=n_src - n_out,
+        edge_src=src[order][None].astype(np.int32),
+        edge_dst=dst[order][None].astype(np.int32))
+    tabs = build_sharded_bucket_tables(sg, min_width=min_width)
+    fwd = sorted(k for k in tabs if k.startswith("bkt_fwd_")
+                 and not k.endswith("inv"))
+    assert min(tabs[k].shape[1] for k in fwd) >= max(min_width, 1)
+    _assert_slot_major([tabs[k][0] for k in fwd])
+    deg = np.maximum(np.bincount(dst, minlength=n_out), 1)
+    fn = make_device_bucket_spmm_fn(
+        {k: jnp.asarray(v[0]) for k, v in tabs.items()},
+        jnp.asarray(deg, jnp.float32), n_src, rem_dtype=rem_dtype)
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.standard_normal((n_src, 16)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((n_out, 16)), jnp.float32)
+    out, vjp = jax.vjp(fn, x)
+    a = _dense(src, dst, n_out, n_src) / deg[:, None]
+    tol = {None: 1e-5, "bfloat16": 2e-2, "float8": 0.3}[rem_dtype]
+    np.testing.assert_allclose(np.asarray(out), a @ np.asarray(x),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(vjp(c)[0]), a.T @ np.asarray(c),
+                               rtol=tol, atol=tol)
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (scan bodies,
+    pjit calls) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
+def assert_reduce_reads_transport(jaxpr, dt, f):
+    """In `jaxpr` every sum under the `reduce` / `rem_reduce` scope (a
+    bucket's width) reads [w, rows, f] in
+    the transport dtype `dt`, rows a multiple of 32, widened at most by
+    the convert feeding it, and no f32 widening of a message tensor
+    feeds a reshape. Returns the [w, rows] seen."""
+    from pipegcn_tpu.ops.bucket_spmm import ROW_TILE
+
+    eqns = list(_walk_eqns(jaxpr))
+    made_by = {id(o): e for e in eqns for o in e.outvars}
+    seen = []
+    for e in eqns:
+        scope = str(e.source_info.name_stack).split("/")[-1]
+        if e.primitive.name == "reduce_sum" and \
+                scope in ("reduce", "rem_reduce"):
+            assert tuple(e.params["axes"]) == (0,), e
+            src = e.invars[0]
+            maker = made_by.get(id(src))
+            if maker is not None and \
+                    maker.primitive.name == "convert_element_type":
+                src = maker.invars[0]
+            w, rows, ff = src.aval.shape
+            assert src.aval.dtype == dt, (src.aval, dt)
+            assert ff == f and rows % ROW_TILE == 0, src.aval
+            assert e.outvars[0].aval.dtype == jnp.float32
+            seen.append((w, rows))
+        if e.primitive.name == "reshape":
+            maker = made_by.get(id(e.invars[0]))
+            widened = (maker is not None
+                       and maker.primitive.name == "convert_element_type"
+                       and maker.outvars[0].aval.dtype == jnp.float32
+                       and maker.invars[0].aval.dtype != jnp.float32)
+            assert not (widened and e.invars[0].aval.size >= 32 * f), e
+    return seen
+
+
+@pytest.mark.parametrize("chunked", [False, True])
+@pytest.mark.parametrize("transport", sorted(TRANSPORTS))
+def test_reduce_operand_is_the_transported_stream(transport, chunked):
+    """The jaxpr of the kernel: the reduction's operand is the gathered
+    [width, rows, F] stream in its transport dtype (rows % 32 == 0),
+    and no float32 copy of rows * width * F elements is reshaped."""
+    dt = TRANSPORTS[transport]
+    f = 8
+    src, dst, n_out, n_src = _bucket_edges(63, seed=2)
+    plan = BucketPlan(src, dst, n_out, n_src)
+    mats = [jnp.asarray(m) for m in plan.fwd_mats]
+    chunk = 64 * 63 * f if chunked else 1 << 30
+    jaxpr = jax.make_jaxpr(
+        lambda x: bucket_aggregate(x, mats, jnp.asarray(plan.fwd_inv),
+                                   chunk_elems=chunk)
+    )(jnp.zeros((n_src, f), dt)).jaxpr
+    seen = assert_reduce_reads_transport(jaxpr, dt, f)
+    live = [m.shape for m in plan.fwd_mats if m.shape[1]]
+    want = [(w, 64 if chunked and w == 63 else n) for w, n in live]
+    assert sorted(seen) == sorted(want)

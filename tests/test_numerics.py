@@ -372,12 +372,18 @@ def test_downgraded_trainer_keeps_trajectory(sharded):
 # ---------------- kernel-table bounds validation ----------------------
 
 
-def test_bucket_table_validation_catches_oob(sharded):
+@pytest.mark.parametrize("where", ["first", "last"])
+@pytest.mark.parametrize("side", ["fwd", "bwd"])
+def test_bucket_table_validation_catches_oob(sharded, side, where):
     """The kernels gather with mode='clip' on the strength of the
     host-side bounds check: an out-of-bounds index (build bug, rotted
     cache) must raise a NAMED error at build/load time — under the old
-    fill-mode gathers it minted NaN silently mid-epoch."""
+    fill-mode gathers it minted NaN silently mid-epoch. Holds in the
+    slot-major layout, forward and transpose tables, first slot of the
+    first row and last slot of the last (a padding column), and for an
+    inverse permutation that points past the buckets' rows."""
     from pipegcn_tpu.ops.bucket_spmm import (
+        ROW_TILE,
         build_sharded_bucket_tables,
         validate_bucket_tables,
     )
@@ -386,13 +392,23 @@ def test_bucket_table_validation_catches_oob(sharded):
     tables = build_sharded_bucket_tables(sg)  # validates internally
     n_src = sg.n_max + sg.halo_size
     validate_bucket_tables(tables, sg.n_max, n_src)
+    bound = n_src if side == "fwd" else sg.n_max
+    at = 0 if where == "first" else -1
+    plain = [k for k in tables if k.startswith(f"bkt_{side}_")
+             and not k.endswith("inv")]
+    assert all(tables[k].shape[-1] % ROW_TILE == 0 for k in plain)
     bad = {k: np.array(v) for k, v in tables.items()}
-    key = next(k for k in bad
-               if k.startswith("bkt_fwd_") and not k.endswith("inv"))
-    bad[key].reshape(-1)[0] = n_src + 7
+    key = plain[at]
+    bad[key].reshape(-1)[at] = bound + 7
     with pytest.raises(ValueError, match="out-of-bounds"):
         validate_bucket_tables(bad, sg.n_max, n_src)
-    bad[key].reshape(-1)[0] = -3
+    bad[key].reshape(-1)[at] = -3
+    with pytest.raises(ValueError, match="out-of-bounds"):
+        validate_bucket_tables(bad, sg.n_max, n_src)
+    bad[key].reshape(-1)[at] = bound          # the sentinel is in bounds
+    validate_bucket_tables(bad, sg.n_max, n_src)
+    rows = sum(tables[k].shape[-1] for k in plain)
+    bad[f"bkt_{side}_inv"].reshape(-1)[at] = rows + 1
     with pytest.raises(ValueError, match="out-of-bounds"):
         validate_bucket_tables(bad, sg.n_max, n_src)
 

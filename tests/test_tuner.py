@@ -660,11 +660,13 @@ def test_tuner_times_what_the_step_runs(tmp_path):
     assert np.isfinite(t.train_epoch(0))
 
 
-def test_format_1_table_is_refused_and_retuned(tmp_path):
+@pytest.mark.parametrize("old_format", [1, 2])
+def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
     """(e) A tuning.json timed on the row-wise sample (tuner format 1)
-    is stale whatever its checksum and signature say: refused with the
-    reason, re-tuned once, replaced on disk."""
-    assert tuner.TUNER_FORMAT == 2
+    or on the destination-major bucket kernels (format 2) is stale
+    whatever its checksum and signature say: refused with the reason,
+    re-tuned once, replaced on disk."""
+    assert tuner.TUNER_FORMAT == 3
     sg = _sharded(seed=51)
     path = str(tmp_path / "art")
     sg.save(path)
@@ -674,14 +676,53 @@ def test_format_1_table_is_refused_and_retuned(tmp_path):
         "name": "xla", "impl": "xla", "rem_dtype": None,
         "rem_amax": False, "block_group": 1})
     assert tuner.load_tuning(path)[1] is None       # trusted as planted
-    rec["tuner_format"] = 1
+    rec["tuner_format"] = old_format
     tuner.save_tuning(path, rec)
     got, reason = tuner.load_tuning(path)
-    assert got is None and reason == "format 1 != 2"
+    assert got is None and reason == f"format {old_format} != 3"
     t = Trainer(sgl, cfg, TrainConfig(seed=0))
     assert t.tuning["source"] == "live"
-    assert "format 1" in t.tuning["stale_reason"]
+    assert f"format {old_format}" in t.tuning["stale_reason"]
     healed, why = tuner.load_tuning(path)
-    assert why is None and healed["tuner_format"] == 2
+    assert why is None and healed["tuner_format"] == 3
     assert healed["winner"] == t.tuning["winner"]
     assert healed["sample_dense_coverage"] is not None
+
+
+@pytest.mark.parametrize("impl", ["bucket", "block"])
+def test_format_6_tables_are_refused_and_rebuilt(tmp_path, impl):
+    """A `*_tables.npz` stamped with table format 6 (bucket and
+    remainder tables destination-major, [P, cap, w]) is refused by
+    name, rebuilt slot-major and replaced on disk; the next trainer
+    loads the rebuilt file."""
+    sg = _sharded(seed=52)
+    path = str(tmp_path / "art")
+    sg.save(path)
+    sgl = ShardedGraph.load(path)
+    assert Trainer._TABLES_FORMAT == 7
+    t0 = Trainer(sgl, _cfg(sgl, spmm_impl=impl), TrainConfig(seed=0))
+    assert t0.tables_source == "built in this run"
+    fname, = [os.path.join(path, f) for f in os.listdir(path)
+              if f.endswith("_tables.npz")]
+    z = dict(np.load(fname))
+    stem = "bkt_fwd_" if impl == "bucket" else "blkrem_fwd_"
+    plain = [k for k in z if k.startswith(stem) and not k.endswith("inv")]
+    assert plain and all(z[k].shape[-1] % 32 == 0 for k in plain)
+    slot_major = {k: z[k].shape for k in plain}
+    # what PR 28's code left there: format 6, destination-major
+    z["__stamp__"] = np.asarray([6, z["__stamp__"][1]], np.uint64)
+    for k in plain:
+        z[k] = np.ascontiguousarray(z[k].transpose(0, 2, 1))
+    with open(fname, "wb") as f:
+        np.savez(f, **z)
+    t1 = Trainer(ShardedGraph.load(path), _cfg(sgl, spmm_impl=impl),
+                 TrainConfig(seed=0))
+    assert t1.tables_source == (
+        f"built in this run (refused {fname}: table format 6 != 7)")
+    healed = np.load(fname)
+    assert int(healed["__stamp__"][0]) == 7
+    assert {k: healed[k].shape for k in plain} == slot_major
+    assert np.isfinite(t1.train_epoch(0))
+    t2 = Trainer(ShardedGraph.load(path), _cfg(sgl, spmm_impl=impl),
+                 TrainConfig(seed=0))
+    assert t2.tables_source == f"loaded from {fname}"
