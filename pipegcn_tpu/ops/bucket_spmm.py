@@ -17,7 +17,12 @@ formulation removes every scatter from both the forward AND the backward:
      transport dtype, and out_b = sum over axis 0 of it, widened to
      f32 inside the reduction: a sum of D_b slices [n_b, F] that the
      TPU runs as plain vector adds. The only f32 tensor written is the
-     [n_b, F] result.
+     [n_b, F] result. A one-byte transport (fp8) is gathered as 16-bit
+     words, [D_b, n_b, F/2] uint16 from a word view of fbuf_pad packed
+     once a call: the same rows in the same order and the same bytes,
+     in the element type whose requests the chip serves at half the
+     price (SLAB_BYTES note), split back into bytes inside the
+     reduction.
   3. Results concatenate in bucket order; one final gather by a
      precomputed inverse permutation restores destination order.
 
@@ -76,6 +81,19 @@ def row_cap(n: int) -> int:
 # of SLAB_BYTES, each slab materialized as its own compact [R, slab]
 # operand (a strided slice of the wide buffer does NOT trigger the fast
 # path) via a lax.scan over the slab axis.
+#
+# Inside the fast path the gather is request-bound, and what a request
+# costs depends on the ELEMENT TYPE of the row, not only on its bytes
+# (docs/PERF_NOTES.md "The row-gather cliff", PERF.md section 6, PR 31;
+# one chunk of 130,848 rows of 256 bytes from a 233k-row table, which
+# the compiled program keeps in memory space S(1)): bf16[.,128] 1.97 ns,
+# u16[.,128] 2.09 ns, f8e5m2[.,256] 3.63 ns, f8e4m3fn[.,256] 4.07 ns.
+# An fp8 row of 256 features is half the REQUESTS of a bf16 one, at
+# twice the price each: the gather gained nothing from it until the
+# row was handed over as 128 sixteen-bit words (_pack_words). A table
+# too large for that memory space (Yelp's 717k rows, 183 MB a slab)
+# is read from HBM at 9 to 12.5 ns a request whatever the type.
+# The slab width stays 256 BYTES for every dtype.
 SLAB_BYTES = 256
 
 # streaming-slab run length: a maximal +1-consecutive run in a gather
@@ -339,9 +357,50 @@ def stack_to_caps(parts: Sequence[Tuple[Sequence[np.ndarray], np.ndarray]],
     return tables
 
 
-def _gather_sum(fbuf_pad, mat, scope=""):
+def _rides_as_words(dtype, f: int) -> bool:
+    """Whether a [R, f] operand of this dtype is gathered as 16-bit
+    words: one-byte elements (the fp8 transports) and an even width.
+    Everything else (bf16, f32, an odd width) is gathered as it is."""
+    return jnp.dtype(dtype).itemsize == 1 and f % 2 == 0
+
+
+def _pack_words(x):
+    """One-byte elements [R, f] -> uint16 [R, f/2], byte for byte:
+    column j rides in the low byte of word j, column f/2 + j in its
+    high byte, so each byte plane widens to a contiguous half of the
+    row (_widen_sum) and no message is ever shuffled. Unsigned: no bit
+    pattern is touched and an all-zero row stays all zero. One pass
+    over the table a call, never over the messages: each half is
+    sliced BEFORE it is widened, which the chip's compiler makes one
+    fusion of (widening the whole row first costs a second pass)."""
+    h = x.shape[-1] // 2
+
+    def plane(cols):
+        return jax.lax.bitcast_convert_type(cols, jnp.uint8).astype(
+            jnp.uint16)
+
+    return plane(x[:, :h]) | (plane(x[:, h:]) << 8)
+
+
+def _widen_sum(msgs, dt):
+    """Gathered words uint16 [w, rows, f/2] -> the f32 [rows, f] sum
+    over w of the `dt` elements they carry (_pack_words' layout): the
+    same values `astype(float32)` gives for every one of the 256 byte
+    patterns, NaN included, since each byte goes back through the
+    dtype's own convert. Byte split, convert and sum compile to one
+    fusion that reads the words and writes two [rows, f/2] halves."""
+    def plane(b):
+        return jax.lax.bitcast_convert_type(
+            b.astype(jnp.uint8), dt).astype(jnp.float32).sum(axis=0)
+
+    return jnp.concatenate([plane(msgs & 0xFF), plane(msgs >> 8)], axis=-1)
+
+
+def _gather_sum(table, mat, scope="", dt=None):
     """One slot-major table's (or chunk's) messages gathered and summed:
-    mat [w, rows] -> f32 [rows, F].
+    mat [w, rows] -> f32 [rows, F]. `table` is fbuf_pad itself, or (dt
+    given) its uint16 word view from _pack_words, whose rows carry
+    F elements of `dt` in F/2 words.
 
     The barrier keeps the widening to f32 INSIDE the reduction. The
     chip's gather yields the flat [w * rows, F] stream and a free view
@@ -354,8 +413,10 @@ def _gather_sum(fbuf_pad, mat, scope=""):
     and writes [rows, F] (tests/test_tpu_compile.py holds it there)."""
     with jax.named_scope(scope + "gather"):
         msgs = jax.lax.optimization_barrier(
-            jnp.take(fbuf_pad, mat, axis=0, mode="clip"))
+            jnp.take(table, mat, axis=0, mode="clip"))
     with jax.named_scope(scope + "reduce"):
+        if dt is not None:
+            return _widen_sum(msgs, dt)
         return msgs.astype(jnp.float32).sum(axis=0)
 
 
@@ -417,6 +478,15 @@ def bucket_aggregate(
     SLAB_BYTES note above); `slab` overrides the element width (0
     disables slabbing).
 
+    One-byte elements and an even (slab) width ride the plain gathers
+    as 16-bit words (_rides_as_words): fbuf_pad is packed into uint16
+    [R + 1, f/2] once a call and a slab (_pack_words), the takes read
+    that, and the reduction splits the words back into the two byte
+    planes as it widens them (_widen_sum). Rows, order, chunking,
+    tables and the f32 sums are the element path's, to the bit; which
+    path runs follows from fbuf's dtype and width alone. A bucket with
+    a streaming-slab plan keeps the element operand.
+
     `run_plans` (per bucket, None entries allowed) switches a bucket to
     the streaming-slab path (_slab_gather_sum) when it fits one chunk;
     chunked buckets keep the original table — the plan's flat
@@ -449,6 +519,12 @@ def bucket_aggregate(
         fbuf_pad = jnp.concatenate(
             [fbuf, jnp.zeros((1, f), fbuf.dtype)], axis=0
         )
+        # the operand the plain gathers read: fbuf_pad, or its 16-bit
+        # word view where the elements are one byte wide (SLAB_BYTES
+        # note); `dt` tells the reduction what the words carry
+        table, dt = fbuf_pad, None
+        if _rides_as_words(fbuf.dtype, f):
+            table, dt = _pack_words(fbuf_pad), fbuf.dtype
 
     outs = []
     for b, mat in enumerate(idx_mats):
@@ -464,7 +540,7 @@ def bucket_aggregate(
                 outs.append(_slab_gather_sum(fbuf_pad, plan, n_b, w, f,
                                              scope))
             else:
-                outs.append(_gather_sum(fbuf_pad, mat, scope))
+                outs.append(_gather_sum(table, mat, scope, dt))
             continue
         n_chunks = -(-n_b // rows_per_chunk)
 
@@ -474,7 +550,7 @@ def bucket_aggregate(
             start = jnp.minimum(i * rows_per_chunk, n_b - rows_per_chunk)
             m = jax.lax.dynamic_slice_in_dim(mat, start, rows_per_chunk,
                                              axis=1)
-            part = _gather_sum(fbuf_pad, m, scope)
+            part = _gather_sum(table, m, scope, dt)
             with jax.named_scope(scope + "reduce"):
                 return jax.lax.dynamic_update_slice_in_dim(
                     out, part, start, axis=0), None
@@ -560,11 +636,16 @@ def transport_dtypes(rem_dtype: Optional[str]):
     """(forward, backward) gather-transport dtypes for a remainder/
     bucket transport spec. The gather path is request-rate-bound at
     256-byte rows (SLAB_BYTES note), so BYTES PER FEATURE set the
-    row count: fp8 packs 256 features into one 256 B slab — half the
-    gathered rows of bf16 at F=256. Activations travel e4m3 (range
-    +-448 suits post-norm activations), cotangents e5m2 (gradient
-    dynamic range needs exponent bits); accumulation stays f32 either
-    way. None = no cast (the activation dtype)."""
+    row count: fp8 packs 256 features into one 256 B slab, half the
+    gathered rows of bf16 at F=256. Half the rows is half the time
+    only because bucket_aggregate hands such a row to the gather as
+    128 16-bit words: as 256 one-byte elements a request cost twice a
+    bf16 row's and the gather took as long as under bf16 (the ledger's
+    PR 30 lines). The reduction reads a quarter of f32's bytes either
+    way. Activations travel e4m3 (range +-448 suits post-norm
+    activations), cotangents e5m2 (gradient dynamic range needs
+    exponent bits); accumulation stays f32 either way. None = no cast
+    (the activation dtype)."""
     if rem_dtype in (None, "", "none"):
         return None, None
     if rem_dtype == "float8":

@@ -57,13 +57,16 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+# 4: one-byte transports are gathered as 16-bit words (bucket_spmm
+# _pack_words): every fp8 candidate's time changed, the others' did
+# not, so a table of format 3 ranks them by a cost fp8 no longer has.
 # 3: the bucket and remainder kernels gather slot-major and widen to
 # f32 inside the reduction (ops/bucket_spmm.py): a table timed on the
 # destination-major kernels of format 2 ranks transports by a cost
 # they no longer have. 2: the sample keeps whole destination tile-rows
 # (sample_slice); a table timed on the row-wise sample of format 1 is
 # stale
-TUNER_FORMAT = 3
+TUNER_FORMAT = 4
 TUNING_FILE = "tuning.json"
 
 # the sample takes whole blocks of destination rows until this many

@@ -93,26 +93,45 @@ def test_block_fn_grad_matches_reference(edges):
                                rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("spmm_chunk", [None, 40])
-def test_trainer_block_matches_xla(spmm_chunk):
+@pytest.mark.parametrize("spmm_chunk,rem_dtype",
+                         [(None, None), (40, None), (40, "float8")])
+def test_trainer_block_matches_xla(spmm_chunk, rem_dtype, monkeypatch):
     """Under shard_map on four devices; an edge budget of 40 cuts the
-    remainder's buckets of more than 32 rows into chunks."""
+    remainder's buckets of more than 32 rows into chunks. Under fp8
+    transport the remainder's messages are gathered as 16-bit words:
+    the losses are those of the same trainer gathering element by
+    element, to the bit, and the float32 kernel's within fp8's
+    rounding."""
+    from pipegcn_tpu.ops import bucket_spmm as bs
+
     g = synthetic_graph(num_nodes=300, avg_degree=7, n_feat=10, n_class=4,
                         seed=21)
     parts = partition_graph(g, 4, seed=0)
     sg = ShardedGraph.build(g, parts, n_parts=4)
     losses = {}
-    for impl in ("xla", "block"):
+    for impl in ("xla", "block") + (("elements",) if rem_dtype else ()):
+        if impl == "elements":
+            monkeypatch.setattr(bs, "_rides_as_words", lambda dt, f: False)
         cfg = ModelConfig(layer_sizes=(10, 16, 4), norm="layer",
                           dropout=0.0, train_size=sg.n_train_global,
-                          spmm_impl=impl, spmm_chunk=spmm_chunk)
+                          spmm_impl="xla" if impl == "xla" else "block",
+                          spmm_chunk=spmm_chunk,
+                          rem_dtype=None if impl == "xla" else rem_dtype)
         t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
         losses[impl] = [t.train_epoch(e) for e in range(6)]
+        if rem_dtype and impl != "xla":
+            # the compiled step holds 16-bit words, or none at all
+            assert ("u16[" in t.step_compiled_text(1)) == (
+                impl != "elements")
     if spmm_chunk:
         assert max(v.shape[-1] for k, v in t._block_tables.items()
                    if k.startswith("blkrem_")
                    and not k.endswith("inv")) > 32
-    np.testing.assert_allclose(losses["xla"], losses["block"], rtol=2e-4)
+    np.testing.assert_allclose(losses["xla"], losses["block"],
+                               rtol=5e-2 if rem_dtype else 2e-4)
+    if rem_dtype:
+        assert losses["block"] == losses["elements"]
+        assert losses["block"] != losses["xla"]
 
 
 def test_block_budget_spill_and_wide_counts_stay_exact():

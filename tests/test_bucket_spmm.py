@@ -125,26 +125,44 @@ def test_bucket_mean_fn_grad_matches_reference(edges):
                                rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("spmm_chunk", [None, 40])
-def test_trainer_bucket_matches_xla(spmm_chunk):
+@pytest.mark.parametrize("spmm_chunk,rem_dtype",
+                         [(None, None), (40, None), (40, "float8")])
+def test_trainer_bucket_matches_xla(spmm_chunk, rem_dtype, monkeypatch):
     """Under shard_map on four devices; an edge budget of 40 cuts every
     bucket of more than 32 rows into chunks (the scan whose carry is
-    the bucket's result)."""
+    the bucket's result). Under fp8 transport the chunks' messages are
+    gathered as 16-bit words: the losses are those of the same trainer
+    gathering element by element, to the bit, and the float32 kernel's
+    within fp8's rounding."""
+    from pipegcn_tpu.ops import bucket_spmm as bs
+
     g = synthetic_graph(num_nodes=300, avg_degree=7, n_feat=10, n_class=4,
                         seed=21)
     parts = partition_graph(g, 4, seed=0)
     sg = ShardedGraph.build(g, parts, n_parts=4)
     losses = {}
-    for impl in ("xla", "bucket"):
+    for impl in ("xla", "bucket") + (("elements",) if rem_dtype else ()):
+        if impl == "elements":
+            monkeypatch.setattr(bs, "_rides_as_words", lambda dt, f: False)
         cfg = ModelConfig(layer_sizes=(10, 16, 4), norm="layer",
                           dropout=0.0, train_size=sg.n_train_global,
-                          spmm_impl=impl, spmm_chunk=spmm_chunk)
+                          spmm_impl="xla" if impl == "xla" else "bucket",
+                          spmm_chunk=spmm_chunk,
+                          rem_dtype=None if impl == "xla" else rem_dtype)
         t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
         losses[impl] = [t.train_epoch(e) for e in range(6)]
+        if rem_dtype and impl != "xla":
+            # the compiled step holds 16-bit words, or none at all
+            assert ("u16[" in t.step_compiled_text(1)) == (
+                impl != "elements")
     if spmm_chunk:
         assert max(v.shape[-1] for k, v in t._bucket_tables.items()
                    if not k.endswith("inv")) > 32
-    np.testing.assert_allclose(losses["xla"], losses["bucket"], rtol=2e-4)
+    np.testing.assert_allclose(losses["xla"], losses["bucket"],
+                               rtol=5e-2 if rem_dtype else 2e-4)
+    if rem_dtype:
+        assert losses["bucket"] == losses["elements"]
+        assert losses["bucket"] != losses["xla"]
 
 
 def test_trainer_bucket_bf16_fused():
@@ -468,17 +486,26 @@ def _walk_eqns(jaxpr):
                     yield from _walk_eqns(inner)
 
 
+# what stands between the gathered words and a byte plane's sum in
+# _widen_sum: the split, the narrowing and the view as the fp8 dtype
+_WORD_SPLIT = ("and", "shift_right_logical", "convert_element_type",
+               "bitcast_convert_type")
+
+
 def assert_reduce_reads_transport(jaxpr, dt, f):
     """In `jaxpr` every sum under the `reduce` / `rem_reduce` scope (a
-    bucket's width) reads [w, rows, f] in
-    the transport dtype `dt`, rows a multiple of 32, widened at most by
-    the convert feeding it, and no f32 widening of a message tensor
-    feeds a reshape. Returns the [w, rows] seen."""
-    from pipegcn_tpu.ops.bucket_spmm import ROW_TILE
+    bucket's width) reads the gathered stream as the gather left it,
+    rows a multiple of 32: [w, rows, f] in the transport dtype `dt`,
+    widened at most by the convert feeding it, or (one-byte `dt`, even
+    f) uint16 [w, rows, f/2] words, split and widened a byte plane at a
+    time on the way into two sums. No f32 widening of a message tensor
+    feeds a reshape. Returns the [w, rows] of each stream seen."""
+    from pipegcn_tpu.ops.bucket_spmm import ROW_TILE, _rides_as_words
 
+    words = _rides_as_words(dt, f)
     eqns = list(_walk_eqns(jaxpr))
     made_by = {id(o): e for e in eqns for o in e.outvars}
-    seen = []
+    seen = {}
     for e in eqns:
         scope = str(e.source_info.name_stack).split("/")[-1]
         if e.primitive.name == "reduce_sum" and \
@@ -489,11 +516,17 @@ def assert_reduce_reads_transport(jaxpr, dt, f):
             if maker is not None and \
                     maker.primitive.name == "convert_element_type":
                 src = maker.invars[0]
-            w, rows, ff = src.aval.shape
             assert src.aval.dtype == dt, (src.aval, dt)
-            assert ff == f and rows % ROW_TILE == 0, src.aval
+            # back through the byte split to the words as gathered
+            while words and id(src) in made_by and \
+                    made_by[id(src)].primitive.name in _WORD_SPLIT:
+                src = made_by[id(src)].invars[0]
+            w, rows, ff = src.aval.shape
+            assert src.aval.dtype == (jnp.uint16 if words else dt), src.aval
+            assert ff == (f // 2 if words else f), src.aval
+            assert rows % ROW_TILE == 0, src.aval
             assert e.outvars[0].aval.dtype == jnp.float32
-            seen.append((w, rows))
+            seen.setdefault(id(src), []).append((w, rows))
         if e.primitive.name == "reshape":
             maker = made_by.get(id(e.invars[0]))
             widened = (maker is not None
@@ -501,7 +534,9 @@ def assert_reduce_reads_transport(jaxpr, dt, f):
                        and maker.outvars[0].aval.dtype == jnp.float32
                        and maker.invars[0].aval.dtype != jnp.float32)
             assert not (widened and e.invars[0].aval.size >= 32 * f), e
-    return seen
+    # a word stream feeds exactly two sums, one a byte plane
+    assert all(len(v) == (2 if words else 1) for v in seen.values()), seen
+    return [v[0] for v in seen.values()]
 
 
 @pytest.mark.parametrize("chunked", [False, True])
@@ -524,3 +559,119 @@ def test_reduce_operand_is_the_transported_stream(transport, chunked):
     live = [m.shape for m in plan.fwd_mats if m.shape[1]]
     want = [(w, 64 if chunked and w == 63 else n) for w, n in live]
     assert sorted(seen) == sorted(want)
+
+
+# ---------------- fp8 rows ride the gather as 16-bit words -------------------
+
+FP8 = {"e4m3": jnp.float8_e4m3fn, "e5m2": jnp.float8_e5m2}
+
+
+def _gather_operands(fn, *args):
+    """(dtype, shape) of the table every row take under a `gather` scope
+    reads, in the jaxpr of fn(*args)."""
+    return [(e.invars[0].aval.dtype, e.invars[0].aval.shape)
+            for e in _walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.params.get("name") == "_take" and
+            str(e.source_info.name_stack).split("/")[-1] == "gather"]
+
+
+@pytest.mark.parametrize("chunked", [False, True])
+@pytest.mark.parametrize("f", [256, 512])
+@pytest.mark.parametrize("fmt", sorted(FP8))
+def test_word_path_equals_element_path_bit_for_bit(fmt, f, chunked,
+                                                   monkeypatch):
+    """An fp8 operand of F = 256 (one slab) or 512 (two) is gathered as
+    uint16 [R + 1, 128] words, and the sums over the forward tables and
+    over the transpose tables (the VJP's direction) equal, bit for bit,
+    those of the same operand gathered element by element: the widening
+    is exact and the sums are the same sums in the same order.
+    Unchunked, and in chunks of 64 rows with a ragged last one; then the
+    differentiable closure under `rem_dtype='float8'`, forward and VJP."""
+    from pipegcn_tpu.ops import bucket_spmm as bs
+
+    dt = FP8[fmt]
+    width = 13
+    src, dst, n_out, n_src = _bucket_edges(width, seed=31)
+    plan = BucketPlan(src, dst, n_out, n_src)
+    fm = [jnp.asarray(m) for m in plan.fwd_mats]
+    bm = [jnp.asarray(m) for m in plan.bwd_mats]
+    finv, binv = jnp.asarray(plan.fwd_inv), jnp.asarray(plan.bwd_inv)
+    chunk = 64 * width * 256 if chunked else 1 << 30
+    rng = np.random.default_rng(f)
+    # small and large magnitudes: subnormals of both formats are sent
+    scale = np.exp2(rng.integers(-12, 5, (n_src, 1)))
+    x = jnp.asarray(rng.standard_normal((n_src, f)) * scale,
+                    jnp.float32).astype(dt)
+    g = jnp.asarray(rng.standard_normal((n_out, f)) * scale[:n_out],
+                    jnp.float32).astype(dt)
+    deg = jnp.asarray(np.maximum(np.bincount(dst, minlength=n_out), 1),
+                      jnp.float32)
+
+    def forward(a):
+        return bucket_aggregate(a, fm, finv, chunk_elems=chunk)
+
+    def run():
+        fn = make_bucket_spmm_fn(fm, finv, bm, binv, deg, n_src,
+                                 chunk_elems=chunk, rem_dtype="float8")
+        out, vjp = jax.vjp(fn, x.astype(jnp.float32))
+        return [np.asarray(a) for a in (
+            forward(x), bucket_aggregate(g, bm, binv, chunk_elems=chunk),
+            out, vjp(g.astype(jnp.float32))[0])]
+
+    # (a fresh lambda each time: a function's trace is cached)
+    live = sum(1 for m in fm if m.shape[1])
+    assert _gather_operands(lambda a: forward(a), x) == [
+        (jnp.uint16, (n_src + 1, 128))] * live
+    words = run()
+    monkeypatch.setattr(bs, "_rides_as_words", lambda dtype, f: False)
+    assert _gather_operands(lambda a: forward(a), x) == [
+        (dt, (n_src + 1, 256))] * live
+    for a, b in zip(words, run()):
+        assert a.any() and np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("fmt", sorted(FP8))
+def test_every_byte_widens_as_astype(fmt):
+    """All 256 byte values of the format come out of the packed words
+    as `astype(float32)` gives them, from either byte of a word. None
+    is excepted: the NaN patterns (e4m3fn 0x7F and 0xFF; e5m2 0x7D to
+    0x7F and 0xFD to 0xFF) read NaN and e5m2's 0x7C / 0xFC read
+    infinity, since each byte goes back through the dtype's convert."""
+    from pipegcn_tpu.ops.bucket_spmm import _pack_words, _widen_sum
+
+    dt = FP8[fmt]
+    every = jax.lax.bitcast_convert_type(jnp.arange(256, dtype=jnp.uint8),
+                                         dt)
+    want = np.asarray(every.astype(jnp.float32))
+    assert np.isnan(want).sum() == {"e4m3": 2, "e5m2": 6}[fmt]
+    table = jnp.stack([every, every[::-1]], axis=1)     # [256, 2]
+    words = _pack_words(table)
+    assert words.dtype == jnp.uint16 and words.shape == (256, 1)
+    got = np.asarray(_widen_sum(words[None], dt))       # one slot: no sum
+    np.testing.assert_array_equal(got[:, 0], want)
+    np.testing.assert_array_equal(got[:, 1], want[::-1])
+    # unsigned and byte for byte: the sentinel's zero row stays zero
+    assert not np.asarray(_pack_words(jnp.zeros((3, 8), dt))).any()
+
+
+@pytest.mark.parametrize("case", ["e4m3-odd", "e5m2-odd", "bfloat16",
+                                  "float32"])
+def test_other_operands_are_gathered_as_they_are(case):
+    """An odd width has no whole number of words, and two- and four-byte
+    elements need none: the gather reads the operand itself."""
+    dt, f = {"e4m3-odd": (jnp.float8_e4m3fn, 255),
+             "e5m2-odd": (jnp.float8_e5m2, 7),
+             "bfloat16": (jnp.bfloat16, 128),
+             "float32": (jnp.float32, 64)}[case]
+    src, dst, n_out, n_src = _bucket_edges(6, seed=8)
+    plan = BucketPlan(src, dst, n_out, n_src)
+    fm = [jnp.asarray(m) for m in plan.fwd_mats]
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((n_src, f)),
+                    jnp.float32).astype(dt)
+    ops = _gather_operands(
+        lambda a: bucket_aggregate(a, fm, jnp.asarray(plan.fwd_inv)), x)
+    assert ops and all(o == (dt, (n_src + 1, f)) for o in ops), ops
+    out = bucket_aggregate(x, fm, jnp.asarray(plan.fwd_inv))
+    want = _dense(src, dst, n_out, n_src) @ np.asarray(
+        x.astype(jnp.float32), np.float64)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-6, atol=1e-5)

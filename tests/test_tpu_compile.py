@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from pipegcn_tpu.ops.bucket_spmm import ROW_TILE, bucket_aggregate
+from pipegcn_tpu.ops.bucket_spmm import (ROW_TILE, _rides_as_words,
+                                         bucket_aggregate)
 
 
 @pytest.fixture(scope="module")
@@ -45,7 +46,9 @@ def _scheduled(hlo: str):
                 name.startswith(("fused_computation", "region_"))
                 or "scalar_add" in name or "clamp" in name)
             continue
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        # a fusion of several outputs has a tuple's shape, "(f32[..], ..)"
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(",
+                     line)
         if live and m:
             op = re.search(r'op_name="([^"]*)"', line)
             out.append((m.group(1), m.group(2), m.group(3),
@@ -53,12 +56,21 @@ def _scheduled(hlo: str):
     return out
 
 
-def _elements(shape: str) -> int:
-    dims = re.match(r"\w+\[([\d,]*)\]", shape)
-    n = 1
-    for d in (dims.group(1).split(",") if dims and dims.group(1) else []):
-        n *= int(d)
-    return n
+_BYTES = {"f8e4m3fn": 1, "f8e5m2": 1, "u8": 1, "s8": 1, "pred": 1,
+          "bf16": 2, "f16": 2, "u16": 2, "s16": 2,
+          "f32": 4, "u32": 4, "s32": 4}
+
+
+def _arrays(shape: str):
+    """(dtype, bytes an element, elements) of every array in an
+    instruction's shape, a tuple's members each."""
+    out = []
+    for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", shape):
+        n = 1
+        for d in (dims.split(",") if dims else []):
+            n *= int(d)
+        out.append((dt, _BYTES[dt], n))
+    return out
 
 
 # Reddit's remainder at width 256 and Yelp's buckets at width 512, rows
@@ -79,10 +91,14 @@ CASES = {
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_reduce_reads_the_transport_dtype_on_the_chip(one_chip, case):
-    """No float32 tensor of message size is an operation's output under
-    `rem_reduce` / `rem_gather`, and every chunk's sum is ONE fusion (or
-    a bare reduce, for float32) whose operand is the gathered stream in
-    its transport dtype: the widening lives inside the reduction."""
+    """Under `rem_gather` / `rem_reduce` no operation writes a chunk's
+    messages in anything wider than they were gathered in, and every
+    chunk's sum is ONE fusion (or a bare reduce, for float32) whose
+    operand is the gathered stream itself: the widening lives inside the
+    reduction. fp8 rows are gathered as 16-bit words of 128 columns
+    (half the elements, the same bytes), split and widened a byte plane
+    at a time inside that fusion, whose output is two [rows, F/2]
+    halves."""
     shapes, n_src, f, dt = CASES[case]
     assert all(r % ROW_TILE == 0 for _, r in shapes)
     sds = jax.ShapeDtypeStruct
@@ -96,11 +112,18 @@ def test_reduce_reads_the_transport_dtype_on_the_chip(one_chip, case):
            if "rem_reduce" in o[3] or "rem_gather" in o[3]]
     assert ops
     # a chunk's messages, one feature slab wide: [w, rows, slab] elements
-    slab = min(f, 256 // jnp.dtype(dt).itemsize)
-    messages = {w * min(r, 32 * 1024 * 1024 // (w * slab) // 32 * 32) * slab
-                for w, r in shapes}
-    wide = [o for o in ops if o[1].startswith("f32[")
-            and _elements(o[1]) in messages]
+    item = jnp.dtype(dt).itemsize
+    slab = min(f, 256 // item)
+    words = _rides_as_words(dt, slab)
+    assert words == (item == 1)
+    chunks = [(w, min(r, 32 * 1024 * 1024 // (w * slab) // 32 * 32))
+              for w, r in shapes]
+    messages = {w * r * slab for w, r in chunks}
+    # per element a message is `item` bytes; as words, half as many
+    # elements of two bytes. Anything wider of either count is a copy
+    wide = [o for o in ops for _, size, n in _arrays(o[1])
+            if (n in messages and size > item)
+            or (words and 2 * n in messages and size > 2)]
     if dt != jnp.float32:
         assert not wide, wide
     sums = [o for o in ops if "rem_reduce" in o[3]
@@ -109,9 +132,27 @@ def test_reduce_reads_the_transport_dtype_on_the_chip(one_chip, case):
     if dt != jnp.float32:
         assert all(o[2] == "fusion" and "convert_reduce" in o[0]
                    for o in sums), sums
-    # the gathers' outputs stay in the transport dtype
-    short = {jnp.float8_e4m3fn: "f8e4m3fn", jnp.float8_e5m2: "f8e5m2",
-             jnp.bfloat16: "bf16", jnp.float32: "f32"}[dt]
+    # the gathers' outputs: the transport dtype, or its words
+    short = "u16" if words else {jnp.bfloat16: "bf16",
+                                 jnp.float32: "f32"}[dt]
+    stream = {n // 2 for n in messages} if words else messages
     gathers = [o for o in ops if o[2] == "fusion" and "rem_gather" in o[3]
-               and _elements(o[1]) in messages]
-    assert len(gathers) == len(shapes) and all(o[1].startswith(short + "[") for o in gathers)
+               and any(n in stream for _, _, n in _arrays(o[1]))]
+    assert len(gathers) == len(shapes), gathers
+    for o in gathers:
+        (got, _, n), = _arrays(o[1])
+        assert got == short, o
+        if words:
+            assert o[1].startswith(f"u16[{n // 128},128]"), o
+    if words:
+        # a chunk's sums are two [rows, F/2] halves, one a byte plane
+        # (one fusion of two outputs, or two that read the same words)
+        halves = [a for o in sums for a in _arrays(o[1])]
+        assert len(halves) >= 2 * len(shapes), sums
+        assert all(got == "f32" and n in {r * slab // 2 for _, r in chunks}
+                   for got, _, n in halves), sums
+        # and the table is packed in one pass a call, not once a chunk
+        packs = [o for o in ops if "rem_gather" in o[3]
+                 and any(got == "u16" and n == (n_src + 1) * slab // 2
+                         for got, _, n in _arrays(o[1]))]
+        assert len(packs) == 1, packs

@@ -660,13 +660,14 @@ def test_tuner_times_what_the_step_runs(tmp_path):
     assert np.isfinite(t.train_epoch(0))
 
 
-@pytest.mark.parametrize("old_format", [1, 2])
+@pytest.mark.parametrize("old_format", [1, 2, 3])
 def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
-    """(e) A tuning.json timed on the row-wise sample (tuner format 1)
-    or on the destination-major bucket kernels (format 2) is stale
-    whatever its checksum and signature say: refused with the reason,
-    re-tuned once, replaced on disk."""
-    assert tuner.TUNER_FORMAT == 3
+    """(e) A tuning.json timed on the row-wise sample (tuner format 1),
+    on the destination-major bucket kernels (format 2) or on fp8 rows
+    gathered element by element (format 3) is stale whatever its
+    checksum and signature say: refused with the reason, re-tuned once,
+    replaced on disk."""
+    assert tuner.TUNER_FORMAT == 4
     sg = _sharded(seed=51)
     path = str(tmp_path / "art")
     sg.save(path)
@@ -679,12 +680,12 @@ def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
     rec["tuner_format"] = old_format
     tuner.save_tuning(path, rec)
     got, reason = tuner.load_tuning(path)
-    assert got is None and reason == f"format {old_format} != 3"
+    assert got is None and reason == f"format {old_format} != 4"
     t = Trainer(sgl, cfg, TrainConfig(seed=0))
     assert t.tuning["source"] == "live"
     assert f"format {old_format}" in t.tuning["stale_reason"]
     healed, why = tuner.load_tuning(path)
-    assert why is None and healed["tuner_format"] == 3
+    assert why is None and healed["tuner_format"] == 4
     assert healed["winner"] == t.tuning["winner"]
     assert healed["sample_dense_coverage"] is not None
 
