@@ -138,16 +138,6 @@ def main():
                          "gather-index runs). 'auto' reuses an existing "
                          "artifact or takes the measured winner "
                          "(ops/tuner.choose_reorder)")
-    ap.add_argument("--slab", default="auto",
-                    choices=["auto", "on", "off"],
-                    help="slab-gather streaming plans over contiguous "
-                         "index runs in the bucket/block-remainder "
-                         "tables ('auto' = the tuner's measured "
-                         "reorder x slab winner)")
-    ap.add_argument("--lane-pad", action="store_true",
-                    help="zero-pad input features to the 128-lane "
-                         "boundary (whole-tile feature reads; outputs "
-                         "unchanged, layer-0 init draw differs)")
     ap.add_argument("--tune", action="store_true", dest="tune",
                     default=True, help=argparse.SUPPRESS)
     ap.add_argument("--no-tune", action="store_false", dest="tune",
@@ -382,8 +372,6 @@ def _measure(args, backend, device_kind, n_parts, sg,
         tuner_samples=args.tuner_samples,
         rem_dtype=args.rem_dtype,  # 'none' normalized by ModelConfig
         dropout_bits=args.dropout_bits,
-        slab=args.slab,
-        lane_pad=args.lane_pad,
     )
     if getattr(args, "serve", False):
         if getattr(args, "autoscale", False) \
@@ -471,23 +459,7 @@ def _measure(args, backend, device_kind, n_parts, sg,
         "halo_dtype": args.halo_dtype if headline_pipeline else "none",
         "epoch_block": args.epoch_block,
         "reorder": getattr(args, "reorder_resolved", args.reorder),
-        "slab": args.slab,
     }
-    if args.lane_pad:
-        extras["lane_pad"] = True
-    try:
-        # how contiguous the resolved layout's gather streams actually
-        # are — the number the reorder lever is supposed to move,
-        # reported next to the anatomy's non-SpMM share
-        tabs = trainer._bucket_tables or trainer._block_tables
-        if tabs:
-            from pipegcn_tpu.ops.bucket_spmm import gather_contiguity
-
-            extras["gather_contiguity"] = gather_contiguity(
-                tabs, sg.n_max + sg.halo_size)
-    except Exception as exc:  # stats are best-effort diagnostics
-        print(f"# gather_contiguity unavailable: {exc!r}",
-              file=sys.stderr)
     try:
         ca = trainer.step_cost_analysis()
         if ca:
@@ -832,60 +804,6 @@ def _measure(args, backend, device_kind, n_parts, sg,
             if tspan_t.get("spans-on") and tspan_t.get("spans-off"):
                 extras["train_traces_delta_s"] = round(
                     tspan_t["spans-on"] - tspan_t["spans-off"], 4)
-
-        # ---- reorder x slab before/after pass -------------------------
-        # The locality lever's evidence: the SAME bucket program timed
-        # on (1) the unreordered artifact, (2) the reordered one, and
-        # (3) the reordered one with slab-gather streaming plans.
-        # reorder_delta_s / slab_delta_s isolate each lever's
-        # contribution (positive = the lever saves time). Crash-isolated
-        # per variant like the floor levers: one broken layout never
-        # costs the others or the in-hand headline.
-        if (((backend == "tpu" and not args.small)
-             or args.force_candidate)
-                and args.slab == "auto" and not args.lane_pad):
-            from pipegcn_tpu.partition.bench_artifact import (
-                artifact_path as _apath, ensure as _ensure)
-
-            rmode = getattr(args, "reorder_resolved", "none")
-            if rmode == "none":
-                rmode = "degree-bfs"
-            rs = {}
-            for name, mode, slab in (("none", "none", "off"),
-                                     ("reorder", rmode, "off"),
-                                     ("reorder-slab", rmode, "on")):
-                try:
-                    t0 = time.perf_counter()
-                    sg_v = _ensure(
-                        _apath(n_parts, args.cluster_size,
-                               small=args.small,
-                               root=os.path.join(REPO, "partitions"),
-                               reorder=mode),
-                        log=lambda m: print(m, file=sys.stderr))
-                    tr_v = Trainer(sg_v, dataclasses.replace(
-                        cfg, spmm_impl="bucket", slab=slab,
-                        block_group=1, rem_dtype=None), TrainConfig(
-                            lr=0.01, n_epochs=args.blocks * blk,
-                            enable_pipeline=headline_pipeline, seed=0,
-                            eval=False, fused_epochs=blk))
-                    s, _ = time_trainer(
-                        tr_v, max(3, args.blocks // 2))
-                    rs[name] = round(s, 4)
-                    print(f"# reorder_slab {name}: {s:.4f}s/epoch "
-                          f"(total {time.perf_counter()-t0:.0f}s)",
-                          file=sys.stderr)
-                    del tr_v, sg_v
-                except Exception as exc:  # noqa: BLE001
-                    rs[name] = None
-                    print(f"# reorder_slab {name} failed: {exc!r}",
-                          file=sys.stderr)
-            extras["reorder_slab"] = rs
-            if rs.get("none") and rs.get("reorder"):
-                extras["reorder_delta_s"] = round(
-                    rs["none"] - rs["reorder"], 4)
-            if rs.get("reorder") and rs.get("reorder-slab"):
-                extras["slab_delta_s"] = round(
-                    rs["reorder"] - rs["reorder-slab"], 4)
 
         # ---- optional SpMM implementation sweep -----------------------
         if args.sweep_spmm:

@@ -236,8 +236,7 @@ def check_reference(winner: dict, nodes: int = 8_000, degree: int = 200,
         spmm_impl=winner["impl"],
         block_group=int(winner.get("block_group") or 1),
         rem_dtype=winner.get("rem_dtype"),
-        rem_amax=bool(winner.get("rem_amax")),
-        slab="on" if winner.get("slab") else "off")
+        rem_amax=bool(winner.get("rem_amax")))
     tr = Trainer(sg, cfg, TrainConfig(n_epochs=0, eval=False))
     check(tr._current_impl() == winner["impl"],
           f"reference trainer built {tr._current_impl()}")

@@ -68,20 +68,9 @@ def summarize_run(records: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
         out["vs_baseline"] = bench.get("vs_baseline")
         if "pipeline" in bench:
             out["pipeline"] = bool(bench["pipeline"])
-        # --reorder layout lever: which layout produced the number, how
-        # contiguous its gather streams were, and (when the bench ran
-        # its reorder_slab before/after pass) the measured deltas
+        # --reorder layout lever: which layout produced the number
         if bench.get("reorder") is not None:
             out["reorder"] = bench["reorder"]
-        gc = bench.get("gather_contiguity")
-        if isinstance(gc, dict):
-            if isinstance(gc.get("mean_run_len"), (int, float)):
-                out["gather_mean_run_len"] = round(gc["mean_run_len"], 4)
-            if isinstance(gc.get("slab_frac"), (int, float)):
-                out["gather_slab_frac"] = round(gc["slab_frac"], 4)
-        for k in ("reorder_delta_s", "slab_delta_s"):
-            if isinstance(bench.get(k), (int, float)):
-                out[k] = bench[k]
 
     steps = [r["step_time_s"] for r in epochs
              if isinstance(r.get("step_time_s"), (int, float))]
@@ -550,15 +539,7 @@ def format_summary(path: str, s: Dict[str, Any]) -> str:
         row("non-SpMM floor share", "anatomy_non_spmm_share", "{:.1%}")
         row("anatomy attributed", "anatomy_attributed_flops_fraction",
             "{:.1%}")
-    # gather-stream contiguity sits beside the non-SpMM floor: the
-    # reorder lever moves this number, the slab path cashes it in
-    if s.get("gather_mean_run_len") is not None:
-        tail = f" (reorder={s['reorder']})" if s.get("reorder") else ""
-        lines.append("  {:<26} mean run {:.2f}, slab-able {:.1%}{}".format(
-            "gather contiguity", s["gather_mean_run_len"],
-            s.get("gather_slab_frac", 0.0), tail))
-    row("reorder delta", "reorder_delta_s", "{:+.4f} s/epoch")
-    row("slab delta", "slab_delta_s", "{:+.4f} s/epoch")
+    row("reorder", "reorder")
     row("MFU", "mfu_pct", "{:.2f} %")
     if s.get("n_faults"):
         kinds = ", ".join(f"{k}x{n}" for k, n in

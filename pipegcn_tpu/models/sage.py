@@ -114,21 +114,6 @@ class ModelConfig:
     # 1/256 (invisible at the usual 0.5). Masks differ from 32-bit mode
     # at the same seed (equally valid dropout noise).
     dropout_bits: int = 32
-    # slab-gather streaming plans (ops/bucket_spmm.build_slab_plan):
-    # 'on' rewrites contiguous gather-index runs in the bucket/block-
-    # remainder tables into dynamic_slice streaming copies (pays off
-    # only on reordered layouts where runs exist), 'off' keeps plain
-    # clipped-take gathers, 'auto' defers to the tuner's measured
-    # reorder x slab winner (ops/tuner.py candidate_grid).
-    slab: str = "auto"
-    # lane-pad the input feature slab to the TPU 128-lane boundary:
-    # the trainer appends zero columns on the feature axis and rewrites
-    # layer_sizes[0] to the padded width, so the per-epoch HBM feature
-    # reads (and the slab-gather dynamic_slice copies) move whole
-    # (8, 128) tiles instead of ragged rows. Zero columns contribute
-    # nothing to any matmul, so outputs are unchanged; only the layer-0
-    # weight init draw differs (different shape, different RNG stream).
-    lane_pad: bool = False
     dtype: str = "float32"         # compute dtype: 'float32' | 'bfloat16'
 
     def __post_init__(self):
@@ -148,9 +133,6 @@ class ModelConfig:
         if self.dropout_bits not in (8, 32):
             raise ValueError(
                 f"dropout_bits must be 8 or 32, got {self.dropout_bits}")
-        if self.slab not in ("auto", "on", "off"):
-            raise ValueError(
-                f"unknown slab mode: {self.slab!r} (auto | on | off)")
         if self.model in ("gcn", "gat") and self.use_pp:
             # the pp precompute caches SAGE's mean-neighbor concat;
             # gcn/gat first layers aggregate like every other layer

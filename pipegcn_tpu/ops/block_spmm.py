@@ -47,10 +47,8 @@ import numpy as np
 
 from .bucket_spmm import (
     _bucket_widths,
-    add_slab_plans,
     bucket_aggregate,
     build_tables_for_edges,
-    extract_run_plans,
     ladder_prefix,
     stack_to_caps,
 )
@@ -691,8 +689,7 @@ def make_block_spmm_fn(
         rem_in, rem_inv = _rem_cast(fbuf, rem_fwd_dt)
         rem = bucket_aggregate(
             rem_in, rem_mats("blkrem_fwd_"), d["blkrem_fwd_inv"],
-            chunk_edges=chunk_edges,
-            run_plans=extract_run_plans(d, "blkrem_fwd"), scope="rem_")
+            chunk_edges=chunk_edges, scope="rem_")
         with jax.named_scope("scale"):
             if rem_inv is not None:
                 rem = rem * rem_inv
@@ -732,8 +729,7 @@ def make_block_spmm_fn(
             rem_in, rem_inv = gd, None
         rem = bucket_aggregate(
             rem_in, rem_mats("blkrem_bwd_"), d["blkrem_bwd_inv"],
-            chunk_edges=chunk_edges,
-            run_plans=extract_run_plans(d, "blkrem_bwd"), scope="rem_")
+            chunk_edges=chunk_edges, scope="rem_")
         with jax.named_scope("scale"):
             if rem_inv is not None:
                 rem = rem * rem_inv
@@ -784,13 +780,11 @@ def build_sharded_block_tables(sg, tile: int = 256,
                                byte_budget: int = DENSE_A_BYTE_BUDGET,
                                nnz_threshold: Optional[int] = None,
                                group: int = 1,
-                               slab: bool = False,
                                ) -> Tuple[Dict[str, np.ndarray], int]:
     """Stacked per-device hybrid plans (leading device axis), padded to
     shared shapes: same B (dense block count), same K (per-tile block
-    list width), same remainder bucket ladders/caps. `slab` emits
-    streaming-slab plans for the remainder tables (bucket_spmm
-    add_slab_plans). Returns (tables, tile)."""
+    list width), same remainder bucket ladders/caps. Returns
+    (tables, tile)."""
     P = sg.num_parts
     n_src_rows = sg.n_max + sg.halo_size
     # HBM budget for the per-device dense-A tensor: keep the densest
@@ -962,9 +956,6 @@ def build_sharded_block_tables(sg, tile: int = 256,
     stacked.update(stack_to_caps(
         [(p.rem_bwd_mats, p.rem_bwd_inv) for p in plans], sg.n_max,
         "blkrem_bwd"))
-    if slab:
-        add_slab_plans(stacked, ("blkrem_fwd", n_src_rows),
-                       ("blkrem_bwd", sg.n_max))
     return stacked, tile
 
 

@@ -96,127 +96,6 @@ def row_cap(n: int) -> int:
 # The slab width stays 256 BYTES for every dtype.
 SLAB_BYTES = 256
 
-# streaming-slab run length: a maximal +1-consecutive run in a gather
-# index stream is chopped into fixed slabs of this many rows, each
-# executed as one lax.dynamic_slice streaming copy instead of 8 random
-# row gathers. Fixed length keeps every slab the same shape (one
-# traced copy loop); 8 rows x 256 B is one fast-path gather row's
-# worth of contiguous HBM traffic per issued copy.
-SLAB_RUN = 8
-
-
-def _find_runs(flat: np.ndarray, sentinel: int):
-    """(starts, lengths) of the maximal +1-consecutive runs among
-    non-sentinel entries of a flat gather index stream (host-side).
-    Sentinel entries (table padding) break runs and are not counted."""
-    real = flat < sentinel
-    n = flat.shape[0]
-    chain = np.zeros(n, bool)
-    if n > 1:
-        chain[1:] = real[1:] & real[:-1] & (flat[1:] == flat[:-1] + 1)
-    starts = np.nonzero(real & ~chain)[0]
-    ends = np.nonzero(real & ~np.concatenate([chain[1:], [False]]))[0]
-    return starts, ends - starts + 1
-
-
-def build_slab_plan(stacked: np.ndarray, sentinel: int,
-                    slab_len: int = SLAB_RUN):
-    """Streaming-slab plan for one bucket's stacked gather table in
-    DESTINATION-major order, [P, cap, w] (add_slab_plans hands it the
-    transpose of the slot-major plain table) — the table-build-time
-    half of the slab-gather path. Runs are a destination's consecutive
-    neighbor ids, so this path keeps the destination-major stream, and
-    with it the [cap, w, F] reduction the plain path left behind.
-
-    Detects contiguous index runs in each part's row-major flattened
-    stream (the order the slab path materializes messages in) and chops
-    runs of >= slab_len into fixed-length slabs. Returns None when no
-    part has a qualifying run, else a dict of arrays:
-
-      res [P, cap, w] — the residue table: slab-covered entries
-        replaced by the zero-row sentinel, so the clipped-take path
-        reads them as cheap repeated sentinel rows and the slab copies
-        overwrite them with the real data;
-      src [P, S] / pos [P, S] — each slab's first source row and its
-        flat position in the [cap*w] message stream (S = max slab
-        count across parts; padding entries write src row 0 into the
-        scratch slab PAST the stream end — _slab_gather_sum appends
-        one, so the loop bound stays static and shard_map-legal);
-      cnt [P] — real slab count per part (validation/stats only; the
-        device loop runs all S iterations, padding lands in scratch).
-    """
-    P, cap, w = stacked.shape
-    res = np.array(stacked, copy=True)
-    srcs, poss = [], []
-    for p in range(P):
-        flat = stacked[p].reshape(-1).astype(np.int64)
-        starts, lens = _find_runs(flat, sentinel)
-        ks = lens // slab_len
-        sel = ks > 0
-        starts, ks = starts[sel], ks[sel]
-        if starts.size:
-            within = (np.arange(int(ks.sum()))
-                      - np.repeat(np.cumsum(ks) - ks, ks)) * slab_len
-            pos = np.repeat(starts, ks) + within
-            rflat = res[p].reshape(-1)
-            cov = (pos[:, None]
-                   + np.arange(slab_len)[None, :]).reshape(-1)
-            src = flat[pos]
-            rflat[cov] = sentinel
-            res[p] = rflat.reshape(cap, w)
-        else:
-            pos = np.zeros(0, np.int64)
-            src = np.zeros(0, np.int64)
-        srcs.append(src)
-        poss.append(pos)
-    s_cap = max(s.shape[0] for s in srcs)
-    if s_cap == 0:
-        return None
-
-    def pad(a, fill):
-        if a.shape[0] < s_cap:
-            a = np.concatenate(
-                [a, np.full(s_cap - a.shape[0], fill, np.int64)])
-        return a.astype(np.int32)
-
-    # padding slabs copy source row 0 into the scratch slab at flat
-    # position cap*w (one past the stream; the device buffer appends
-    # SLAB_RUN scratch rows there), so every part runs the same static
-    # S iterations and the dead writes land out of band
-    return {
-        "res": res,
-        "src": np.stack([pad(s, 0) for s in srcs]),
-        "pos": np.stack([pad(p_, cap * w) for p_ in poss]),
-        "cnt": np.asarray([s.shape[0] for s in srcs], np.int32),
-    }
-
-
-def gather_contiguity(tables, n_src_rows: int,
-                      slab_len: int = SLAB_RUN):
-    """Host-side contiguity stat of the forward neighbor lists of a
-    sharded table dict (bucket or block-remainder, slot-major
-    [P, w, cap]), read destination by destination as a slab plan reads
-    them: mean +1-run length and the fraction of real gather entries a
-    slab plan of `slab_len` would cover. Cheap O(tables) — the number
-    the reorder lever is supposed to move, reported by bench next to
-    the epoch anatomy."""
-    n_real = n_runs = covered = 0
-    for k in sorted(tables):
-        if not (k.startswith("bkt_fwd_") or k.startswith("blkrem_fwd_")) \
-                or k.endswith("inv"):
-            continue
-        t = np.asarray(tables[k])
-        for p in range(t.shape[0]):
-            _, lens = _find_runs(t[p].T.reshape(-1).astype(np.int64),
-                                 n_src_rows)
-            n_real += int(lens.sum())
-            n_runs += int(lens.shape[0])
-            covered += int(((lens // slab_len) * slab_len).sum())
-    return {
-        "mean_run_len": round(n_real / max(n_runs, 1), 4),
-        "slab_frac": round(covered / max(n_real, 1), 6),
-    }
-
 
 def _ladder_rungs():
     """The single source of the bucket-width progression: ~x1.5 steps
@@ -420,36 +299,6 @@ def _gather_sum(table, mat, scope="", dt=None):
         return msgs.astype(jnp.float32).sum(axis=0)
 
 
-def _slab_gather_sum(fbuf_pad, plan, n_b, w, f, scope=""):
-    """One bucket's messages via the streaming-slab plan, in
-    DESTINATION-major order (plan["res"] is [n_b, w]): the residue
-    table gathers the scattered entries (slab-covered positions point
-    at the zero sentinel row — cheap repeated reads), then each slab is
-    one lax.dynamic_slice streaming copy of SLAB_RUN contiguous source
-    rows written over its flat position. The trip count is the STATIC
-    cross-part slab cap S (a traced bound would lower to `while`, which
-    shard_map's replication checker rejects); padded iterations write
-    into the scratch slab appended past the stream end and are sliced
-    off below."""
-    with jax.named_scope(scope + "gather"):
-        flat = jnp.take(fbuf_pad, plan["res"].reshape(-1), axis=0,
-                        mode="clip")
-        n_flat = flat.shape[0]
-        buf0 = jnp.concatenate(
-            [flat, jnp.zeros((SLAB_RUN, f), flat.dtype)], axis=0)
-
-        def body(i, buf):
-            blk = jax.lax.dynamic_slice(fbuf_pad, (plan["src"][i], 0),
-                                        (SLAB_RUN, f))
-            return jax.lax.dynamic_update_slice(buf, blk,
-                                                (plan["pos"][i], 0))
-
-        buf = jax.lax.fori_loop(0, plan["src"].shape[0], body, buf0)
-    with jax.named_scope(scope + "reduce"):
-        return buf[:n_flat].reshape(n_b, w, f).astype(jnp.float32) \
-            .sum(axis=1)
-
-
 def bucket_aggregate(
     fbuf: jax.Array,
     idx_mats: Sequence[jax.Array],
@@ -457,7 +306,6 @@ def bucket_aggregate(
     chunk_elems: int = DEFAULT_CHUNK_ELEMS,
     chunk_edges: Optional[int] = None,
     slab: Optional[int] = None,
-    run_plans: Optional[Sequence[Optional[dict]]] = None,
     scope: str = "",
 ) -> jax.Array:
     """Scatter-free sum aggregation. fbuf [R, F] (any float dtype);
@@ -478,19 +326,14 @@ def bucket_aggregate(
     SLAB_BYTES note above); `slab` overrides the element width (0
     disables slabbing).
 
-    One-byte elements and an even (slab) width ride the plain gathers
+    One-byte elements and an even (slab) width ride the gathers
     as 16-bit words (_rides_as_words): fbuf_pad is packed into uint16
     [R + 1, f/2] once a call and a slab (_pack_words), the takes read
     that, and the reduction splits the words back into the two byte
     planes as it widens them (_widen_sum). Rows, order, chunking,
     tables and the f32 sums are the element path's, to the bit; which
-    path runs follows from fbuf's dtype and width alone. A bucket with
-    a streaming-slab plan keeps the element operand.
-
-    `run_plans` (per bucket, None entries allowed) switches a bucket to
-    the streaming-slab path (_slab_gather_sum) when it fits one chunk;
-    chunked buckets keep the original table — the plan's flat
-    positions only align to the unchunked message stream.
+    path runs follows from fbuf's dtype and width alone. Every bucket,
+    chunked or not, goes through _gather_sum.
 
     Every gather runs with mode='clip' (clamped, no bounds-check
     select): the table indices are in-bounds BY CONSTRUCTION (pad
@@ -512,14 +355,14 @@ def bucket_aggregate(
         slab = SLAB_BYTES // fbuf.dtype.itemsize
     if slab and f > slab:
         return _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems,
-                                  chunk_edges, slab, run_plans, scope)
+                                  chunk_edges, slab, scope)
     if chunk_edges:
         chunk_elems = chunk_edges * f
     with jax.named_scope(scope + "gather"):
         fbuf_pad = jnp.concatenate(
             [fbuf, jnp.zeros((1, f), fbuf.dtype)], axis=0
         )
-        # the operand the plain gathers read: fbuf_pad, or its 16-bit
+        # the operand the gathers read: fbuf_pad, or its 16-bit
         # word view where the elements are one byte wide (SLAB_BYTES
         # note); `dt` tells the reduction what the words carry
         table, dt = fbuf_pad, None
@@ -527,8 +370,7 @@ def bucket_aggregate(
             table, dt = _pack_words(fbuf_pad), fbuf.dtype
 
     outs = []
-    for b, mat in enumerate(idx_mats):
-        plan = run_plans[b] if run_plans is not None else None
+    for mat in idx_mats:
         w, n_b = mat.shape
         if n_b == 0:
             outs.append(jnp.zeros((0, f), jnp.float32))
@@ -536,11 +378,7 @@ def bucket_aggregate(
         rows_per_chunk = max(
             ROW_TILE, chunk_elems // (w * f) // ROW_TILE * ROW_TILE)
         if n_b <= rows_per_chunk:
-            if plan is not None:
-                outs.append(_slab_gather_sum(fbuf_pad, plan, n_b, w, f,
-                                             scope))
-            else:
-                outs.append(_gather_sum(table, mat, scope, dt))
+            outs.append(_gather_sum(table, mat, scope, dt))
             continue
         n_chunks = -(-n_b // rows_per_chunk)
 
@@ -571,11 +409,9 @@ def bucket_aggregate(
 
 
 def _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems, chunk_edges,
-                       slab, run_plans=None, scope=""):
+                       slab, scope=""):
     """Run bucket_aggregate per feature slab of `slab` elements, scanning
-    over a [S, R, slab] re-layout so each slab is a compact operand.
-    run_plans pass straight through: the streaming-slab plan is pure
-    row structure, independent of the feature split."""
+    over a [S, R, slab] re-layout so each slab is a compact operand."""
     r, f = fbuf.shape
     n_s = -(-f // slab)
     pad_f = n_s * slab - f
@@ -586,8 +422,7 @@ def _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems, chunk_edges,
 
     def one(_, sl):
         out = bucket_aggregate(sl, idx_mats, inv_perm, chunk_elems,
-                               chunk_edges, slab=0, run_plans=run_plans,
-                               scope=scope)
+                               chunk_edges, slab=0, scope=scope)
         return None, out
 
     _, outs = jax.lax.scan(one, None, slabs)  # [S, n_out, slab]
@@ -713,8 +548,6 @@ def make_bucket_spmm_fn(
     chunk_edges: Optional[int] = None,
     rem_dtype: Optional[str] = None,
     rem_amax: bool = False,
-    fwd_plans: Optional[Sequence[Optional[dict]]] = None,
-    bwd_plans: Optional[Sequence[Optional[dict]]] = None,
 ):
     """Differentiable mean-aggregation closure: f(fbuf [R, F]) ->
     f32 [n_out, F]; backward is the transpose bucket aggregation, f32
@@ -723,11 +556,10 @@ def make_bucket_spmm_fn(
     the one cast before aggregation halves gathered rows at F=256.
     `rem_amax` swaps the static saturating fp8 cast for the
     amax-clamped one (amax_transport_cast): per-tensor power-of-two
-    scaling into mid-range, inverse applied after aggregation.
-    `fwd_plans`/`bwd_plans` are per-bucket streaming-slab plans
-    (bucket_aggregate run_plans). The transport casts run under the
-    named scope `cast`, the degree division and the amax de-scale
-    under `scale`, the whole backward under `bwd`."""
+    scaling into mid-range, inverse applied after aggregation. The
+    transport casts run under the named scope `cast`, the degree
+    division and the amax de-scale under `scale`, the whole backward
+    under `bwd`."""
     deg_col = in_deg[:, None]
     fwd_dt, bwd_dt = transport_dtypes(rem_dtype)
 
@@ -741,7 +573,7 @@ def make_bucket_spmm_fn(
     def f(fbuf):
         y, inv = _cast(fbuf, fwd_dt)
         out = bucket_aggregate(y, fwd_mats, fwd_inv, chunk_elems,
-                               chunk_edges, run_plans=fwd_plans)
+                               chunk_edges)
         with jax.named_scope("scale"):
             out = out / deg_col
             return out * inv if inv is not None else out
@@ -765,7 +597,7 @@ def make_bucket_spmm_fn(
                 with jax.named_scope("cast"):
                     gd, inv = gd32.astype(proto.dtype), None
             d_fbuf = bucket_aggregate(gd, bwd_mats, bwd_inv, chunk_elems,
-                                      chunk_edges, run_plans=bwd_plans)
+                                      chunk_edges)
             with jax.named_scope("scale"):
                 if inv is not None:
                     d_fbuf = d_fbuf * inv
@@ -776,7 +608,7 @@ def make_bucket_spmm_fn(
 
 
 def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
-                                min_width: int = 0, slab: bool = False,
+                                min_width: int = 0,
                                 plan_cache: Optional[dict] = None,
                                 dirty: Optional[Sequence[int]] = None
                                 ) -> Dict[str, np.ndarray]:
@@ -787,11 +619,6 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
     `min_width` merges every bucket narrower than it into the first
     surviving ladder rung (see _bucket_widths) — the bucket-merge
     launch-overhead lever, surfaced as --bucket-merge.
-
-    `slab` additionally emits streaming-slab plans (build_slab_plan)
-    for every bucket with a qualifying contiguous run, under keys
-    'bkt_{fwd,bwd}{res,src,pos,cnt}_<b>' (no underscore after the
-    side, so the plain-table key predicates never match them).
 
     `plan_cache` (a mutable dict, updated in place) with `dirty` (shard
     ids whose edges changed) is the streaming-delta fast path: per-
@@ -855,61 +682,14 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
         **stack_to_caps([(p.bwd_mats, p.bwd_inv) for p in plans],
                         sg.n_max, "bkt_bwd"),
     }
-    if slab:
-        add_slab_plans(tables, ("bkt_fwd", n_src_rows),
-                       ("bkt_bwd", sg.n_max))
     validate_bucket_tables(tables, sg.n_max, n_src_rows)
     return tables
-
-
-def add_slab_plans(tables: Dict[str, np.ndarray], *stems) -> int:
-    """Emit streaming-slab plan keys into a stacked table dict for every
-    plain bucket table under the given (stem, sentinel) pairs, e.g.
-    ('bkt_fwd', n_src_rows). A table 'bkt_fwd_03' with qualifying runs
-    gains 'bkt_fwdres_03' / 'bkt_fwdsrc_03' / 'bkt_fwdpos_03' /
-    'bkt_fwdcnt_03'. Returns the number of buckets that got a plan."""
-    emitted = 0
-    for stem, sentinel in stems:
-        for k in [k for k in tables if k.startswith(f"{stem}_")
-                  and not k.endswith("inv")]:
-            b = k.rsplit("_", 1)[1]
-            # runs are found destination by destination: the plan reads
-            # (and keeps) the destination-major transpose
-            plan = build_slab_plan(
-                np.ascontiguousarray(tables[k].transpose(0, 2, 1)), sentinel)
-            if plan is None:
-                continue
-            tables[f"{stem}res_{b}"] = plan["res"]
-            tables[f"{stem}src_{b}"] = plan["src"]
-            tables[f"{stem}pos_{b}"] = plan["pos"]
-            tables[f"{stem}cnt_{b}"] = plan["cnt"]
-            emitted += 1
-    return emitted
-
-
-def extract_run_plans(d: Dict[str, jax.Array], stem: str):
-    """Per-bucket run_plans list (for bucket_aggregate) from a device
-    table dict, aligned with the `{stem}_<b>` plain tables in sorted
-    key order; None when no bucket under this stem has a plan."""
-    plans = []
-    for k in sorted(d):
-        if not k.startswith(f"{stem}_") or k.endswith("inv"):
-            continue
-        b = k.rsplit("_", 1)[1]
-        if f"{stem}res_{b}" in d:
-            plans.append({"res": d[f"{stem}res_{b}"],
-                          "src": d[f"{stem}src_{b}"],
-                          "pos": d[f"{stem}pos_{b}"],
-                          "cnt": d[f"{stem}cnt_{b}"]})
-        else:
-            plans.append(None)
-    return plans if any(p is not None for p in plans) else None
 
 
 def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
                            n_src_rows: int) -> None:
     """Host-side bounds check of sharded bucket tables ([P, ...] device
-    axis leading; plain tables slot-major [P, w, cap], so a bucket's
+    axis leading; tables slot-major [P, w, cap], so a bucket's
     rows are its LAST axis): every index must lie in [0, bound] where bound is
     the consuming gather's zero-sentinel row. The device kernel gathers
     with mode='clip' ON THE STRENGTH OF THIS CHECK — an out-of-bounds
@@ -931,21 +711,6 @@ def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
             hi = n_src_rows        # fbuf_pad's zero sentinel row
         elif k.startswith("bkt_bwd_"):
             hi = n_max
-        elif k.startswith("bkt_fwdres_"):
-            hi = n_src_rows
-        elif k.startswith("bkt_bwdres_"):
-            hi = n_max
-        elif k.startswith(("bkt_fwdsrc_", "bkt_bwdsrc_")):
-            # a slab streams SLAB_RUN real rows starting at src
-            base = n_src_rows if "fwd" in k else n_max
-            hi = max(0, base - SLAB_RUN)
-        elif k.startswith(("bkt_fwdpos_", "bkt_bwdpos_")):
-            # real slabs end inside the [cap*w] stream; padding points
-            # AT cap*w exactly — the appended scratch slab
-            res = tables[k.replace("pos_", "res_")]
-            hi = int(res.shape[-2]) * int(res.shape[-1])
-        elif k.startswith(("bkt_fwdcnt_", "bkt_bwdcnt_")):
-            hi = int(tables[k.replace("cnt_", "src_")].shape[-1])
         else:
             continue
         a = np.asarray(t)
@@ -975,6 +740,4 @@ def make_device_bucket_spmm_fn(d: Dict[str, jax.Array], in_deg: jax.Array,
     return make_bucket_spmm_fn(
         fwd_mats, d["bkt_fwd_inv"], bwd_mats, d["bkt_bwd_inv"],
         in_deg, n_src_rows, chunk_elems, chunk_edges, rem_dtype,
-        rem_amax, fwd_plans=extract_run_plans(d, "bkt_fwd"),
-        bwd_plans=extract_run_plans(d, "bkt_bwd"),
-    )
+        rem_amax)

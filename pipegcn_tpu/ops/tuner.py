@@ -57,16 +57,19 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-# 4: one-byte transports are gathered as 16-bit words (bucket_spmm
-# _pack_words): every fp8 candidate's time changed, the others' did
-# not, so a table of format 3 ranks them by a cost fp8 no longer has.
+# 5: the grid lost its three streaming-slab twins with the path they
+# timed (PR 32): a table of format 4 was ranked over another grid and
+# may name a candidate that no longer exists. 4: one-byte transports
+# are gathered as 16-bit words (bucket_spmm _pack_words): every fp8
+# candidate's time changed, the others' did not, so a table of format
+# 3 ranks them by a cost fp8 no longer has.
 # 3: the bucket and remainder kernels gather slot-major and widen to
 # f32 inside the reduction (ops/bucket_spmm.py): a table timed on the
 # destination-major kernels of format 2 ranks transports by a cost
 # they no longer have. 2: the sample keeps whole destination tile-rows
 # (sample_slice); a table timed on the row-wise sample of format 1 is
 # stale
-TUNER_FORMAT = 4
+TUNER_FORMAT = 5
 TUNING_FILE = "tuning.json"
 
 # the sample takes whole blocks of destination rows until this many
@@ -222,29 +225,19 @@ def sample_slice(sg, edge_budget: int = DEFAULT_EDGE_BUDGET,
 
 def candidate_grid(*, block_group: int = 0,
                    rem_dtype: str = "auto",
-                   rem_amax: bool = False,
-                   slab: str = "auto") -> List[Dict[str, Any]]:
+                   rem_amax: bool = False) -> List[Dict[str, Any]]:
     """Viable kernel configs to time. An explicitly-pinned transport
     dtype (`rem_dtype` other than "auto") or group size (`block_group`
     > 1) restricts the grid to the pinned value — the tuner never
-    overrides an explicit user choice, it only fills defaults.
-
-    `slab` extends the grid with the streaming-slab gather path
-    (bucket_spmm build_slab_plan): "auto" adds one measured slab twin
-    per kernel family (on the first transport variant — a full slab x
-    transport cross product would double the compile bill for a
-    row-structure lever that is independent of the cast); "on"/"off"
-    pin every candidate."""
+    overrides an explicit user choice, it only fills defaults."""
     if rem_dtype == "auto":
         rems = [(None, False), ("bfloat16", False), ("float8", False),
                 ("float8", True)]
     else:
         rems = [(rem_dtype, rem_amax)]
     groups = [block_group] if block_group and block_group > 1 else [1, 4]
-    pin_slab = {"on": True, "off": False}.get(slab)
-    base_slab = bool(pin_slab)
 
-    def name(impl, rd, ra, g, sl=False):
+    def cand(impl, rd=None, ra=False, g=1):
         parts = [impl]
         if impl == "block" and g > 1:
             parts.append(f"u{g}")
@@ -252,31 +245,13 @@ def candidate_grid(*, block_group: int = 0,
             parts.append("bf16")
         elif rd == "float8":
             parts.append("f8amax" if ra else "f8")
-        if sl:
-            parts.append("slab")
-        return "-".join(parts)
+        return {"name": "-".join(parts), "impl": impl, "rem_dtype": rd,
+                "rem_amax": ra, "block_group": g}
 
-    cands = [{"name": "xla", "impl": "xla", "rem_dtype": None,
-              "rem_amax": False, "block_group": 1, "slab": False}]
-    for i, (rd, ra) in enumerate(rems):
-        slabs = [base_slab]
-        if pin_slab is None and i == 0:
-            slabs = [False, True]
-        for sl in slabs:
-            cands.append({"name": name("bucket", rd, ra, 1, sl),
-                          "impl": "bucket", "rem_dtype": rd,
-                          "rem_amax": ra, "block_group": 1, "slab": sl})
-    for i, (rd, ra) in enumerate(rems):
-        for g in groups:
-            slabs = [base_slab]
-            if pin_slab is None and i == 0:
-                slabs = [False, True]
-            for sl in slabs:
-                cands.append({"name": name("block", rd, ra, g, sl),
-                              "impl": "block", "rem_dtype": rd,
-                              "rem_amax": ra, "block_group": g,
-                              "slab": sl})
-    return cands
+    return ([cand("xla")]
+            + [cand("bucket", rd, ra) for rd, ra in rems]
+            + [cand("block", rd, ra, g) for rd, ra in rems
+               for g in groups])
 
 
 # ---------------------------------------------------------------------
@@ -378,10 +353,10 @@ def _candidate_program(sample, cand: Dict[str, Any], width: int, *,
         from .bucket_spmm import (build_sharded_bucket_tables,
                                   make_device_bucket_spmm_fn)
 
-        key = ("bucket", bool(cand.get("slab")))
+        key = ("bucket",)
         if key not in tables:
             tables[key] = build_sharded_bucket_tables(
-                sample, min_width=bucket_merge, slab=key[1])
+                sample, min_width=bucket_merge)
         tabs = {k: jnp.asarray(v[0]) for k, v in tables[key].items()}
 
         def apply(tabs, deg, f):
@@ -394,13 +369,12 @@ def _candidate_program(sample, cand: Dict[str, Any], width: int, *,
                                  build_sharded_block_tables,
                                  make_device_block_spmm_fn)
 
-        key = ("block", cand["block_group"], bool(cand.get("slab")))
+        key = ("block", cand["block_group"])
         if key not in tables:
             tables[key] = build_sharded_block_tables(
                 sample, tile=block_tile, n_feat_hint=table_width or width,
                 byte_budget=byte_budget or DENSE_A_BYTE_BUDGET,
-                nnz_threshold=block_nnz, group=cand["block_group"],
-                slab=key[2])
+                nnz_threshold=block_nnz, group=cand["block_group"])
         host, tile = tables[key]
         tabs = {k: jnp.asarray(v[0]) for k, v in host.items()}
 
@@ -474,8 +448,7 @@ def pick_winner(costs: List[Dict[str, Any]]) -> Dict[str, Any]:
           and not c.get("out_of_domain")]
     if not ok:
         return {"name": DEFAULT_IMPL, "impl": DEFAULT_IMPL,
-                "rem_dtype": None, "rem_amax": False, "block_group": 1,
-                "slab": False}
+                "rem_dtype": None, "rem_amax": False, "block_group": 1}
     best = min(ok, key=lambda c: c["spmm_fwdbwd_s"])
     slowest = best["spmm_fwdbwd_s"] + best.get("spread_s", 0.0)
     tied = [c for c in ok if c["spmm_fwdbwd_s"] <= slowest]
@@ -531,7 +504,7 @@ def tune(sg, width: int, *, block_tile: int = 256,
          rem_dtype: str = "auto", rem_amax: bool = False,
          chunk_edges: Optional[int] = None, bucket_merge: int = 0,
          rng_impl: str = "threefry", halo_dtype: str = "none",
-         epoch_block: int = 0, slab: str = "auto",
+         epoch_block: int = 0,
          edge_budget: int = DEFAULT_EDGE_BUDGET, reps: int = 2,
          seed: int = 0, step_width: Optional[int] = None,
          spmm_per_epoch: int = _SPMM_PER_EPOCH,
@@ -560,8 +533,7 @@ def tune(sg, width: int, *, block_tile: int = 256,
         & ((1 << 64) - 1)
     memo_key = (checksum, json.dumps(sig, sort_keys=True),
                 int(edge_budget), int(block_group),
-                str(rem_dtype), bool(rem_amax), str(slab),
-                int(spmm_per_epoch))
+                str(rem_dtype), bool(rem_amax), int(spmm_per_epoch))
     hit = _MEMO.get(memo_key)
     if hit is not None:
         return hit
@@ -570,7 +542,7 @@ def tune(sg, width: int, *, block_tile: int = 256,
                              budget_block_cap)
 
     cands = candidate_grid(block_group=block_group, rem_dtype=rem_dtype,
-                           rem_amax=rem_amax, slab=slab)
+                           rem_amax=rem_amax)
     group = max(c["block_group"] for c in cands)
     sample, info = sample_slice(sg, edge_budget=edge_budget, seed=seed,
                                 block_rows=block_tile * group)
@@ -645,30 +617,16 @@ def tune(sg, width: int, *, block_tile: int = 256,
         costs.append(entry)
 
     best = pick_winner(costs)
-    # the sample's gather-contiguity stat rides in the record: the
-    # number the reorder lever is supposed to move, next to the
-    # measured winner it produced (host numpy on the bucket
-    # candidates' own tables)
-    try:
-        from .bucket_spmm import (build_sharded_bucket_tables,
-                                  gather_contiguity)
-        contig = gather_contiguity(
-            tables.get(("bucket", False))
-            or build_sharded_bucket_tables(sample),
-            sample.n_max + sample.halo_size)
-    except Exception:  # noqa: BLE001 — a stat, never a tuner failure
-        contig = None
     record = {
         "tuner_format": TUNER_FORMAT,
         "source_edge_checksum": checksum,
         "signature": sig,
         "winner": {k: best.get(k, False) for k in
                    ("name", "impl", "rem_dtype", "rem_amax",
-                    "block_group", "slab")},
+                    "block_group")},
         "costs": costs,
         "reps": int(reps),
         "spmm_per_epoch": int(spmm_per_epoch),
-        "gather_contiguity": contig,
         "sample_dense_coverage": round(sample_cov, 6),
         "shard_dense_coverage": round(shard_cov, 6),
         "sample_tile_rows": -(-info["sample_rows"] // block_tile),
@@ -748,9 +706,7 @@ def choose_reorder(g, *, modes: Tuple[str, ...] = ("none", "degree-bfs"),
     """Pick the artifact reorder mode for ``--reorder auto`` by
     MEASUREMENT: build a 1-part layout of ``g`` under each candidate
     mode, sample whole blocks of its destination rows (sample_slice),
-    and time the bucket kernel's forward+backward on them — under the
-    reordered layouts both with and without the streaming-slab plan (the path
-    the reorder exists to enable), keeping each mode's best. Returns
+    and time the bucket kernel's forward+backward on them. Returns
     (winning mode, {mode: seconds}); an unmeasurable campaign (every
     candidate erroring) falls back to "none" — the layout every
     artifact already has."""
@@ -759,30 +715,23 @@ def choose_reorder(g, *, modes: Tuple[str, ...] = ("none", "degree-bfs"),
     width = int(g.ndata["feat"].shape[-1]) if "feat" in g.ndata else 64
     parts = np.zeros(g.num_nodes, dtype=np.int32)
     timings: Dict[str, float] = {}
+    cand = {"name": "bucket", "impl": "bucket", "rem_dtype": None,
+            "rem_amax": False, "block_group": 1}
     for mode in modes:
         sg1 = ShardedGraph.build(g, parts, n_parts=1, reorder=mode)
         sample, _ = sample_slice(sg1, edge_budget=edge_budget)
         fbuf = _operand(sample, width)
-        best = None
-        for sl in ([False] if mode == "none" else [False, True]):
-            cand = {"name": "bucket-slab" if sl else "bucket",
-                    "impl": "bucket", "rem_dtype": None,
-                    "rem_amax": False, "block_group": 1, "slab": sl}
-            try:
-                t = min(_time_candidate(
-                    sample, cand, width, block_tile=256, block_nnz=None,
-                    chunk_edges=None, bucket_merge=0, reps=reps,
-                    fbuf=fbuf))
-            except Exception as exc:  # noqa: BLE001 — out-of-domain
-                if log:
-                    log(f"# choose_reorder: {mode} "
-                        f"({cand['name']}) FAILED: {exc!r}"[:160])
-                continue
-            best = t if best is None else min(best, t)
-        if best is not None:
-            timings[mode] = round(best, 6)
+        try:
+            t = min(_time_candidate(
+                sample, cand, width, block_tile=256, block_nnz=None,
+                chunk_edges=None, bucket_merge=0, reps=reps, fbuf=fbuf))
+        except Exception as exc:  # noqa: BLE001 — out-of-domain
             if log:
-                log(f"# choose_reorder: {mode:10s} {best * 1e3:8.2f} ms")
+                log(f"# choose_reorder: {mode} FAILED: {exc!r}"[:160])
+            continue
+        timings[mode] = round(t, 6)
+        if log:
+            log(f"# choose_reorder: {mode:10s} {t * 1e3:8.2f} ms")
     if not timings:
         return "none", timings
     return min(timings, key=timings.get), timings

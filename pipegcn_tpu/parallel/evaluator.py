@@ -199,13 +199,9 @@ class ShardedEvaluator:
         if parts is None:
             parts = partition_graph(g, trainer.P, method="metis",
                                     obj="vol", seed=0)
-        from .trainer import _pad_cols
-
         sg = ShardedGraph.build(g, parts, n_parts=trainer.P)
         arrs = {
-            # lane_pad trainers rewrote layer_sizes[0]; this foreign
-            # graph's features must be padded to the same width
-            "feat": _pad_cols(sg.feat, getattr(trainer, "_feat_pad", 0)),
+            "feat": sg.feat,
             "label": sg.label,
             "in_deg": sg.in_deg,
             "edge_src": sg.edge_src.astype(np.int32),

@@ -71,18 +71,6 @@ from .mesh import PARTS_AXIS, make_mesh
 # campaign — there are no hand-coded shape thresholds.
 
 
-def _pad_cols(a: np.ndarray, pad: int) -> np.ndarray:
-    """Zero-pad the trailing (feature) axis by `pad` columns — the
-    lane_pad 128-lane alignment. Zero columns contribute nothing to any
-    matmul or mean aggregation, so the padded program computes the same
-    outputs on the original columns."""
-    if not pad:
-        return a
-    a = np.asarray(a)
-    return np.concatenate(
-        [a, np.zeros(a.shape[:-1] + (pad,), a.dtype)], axis=-1)
-
-
 @dataclasses.dataclass
 class TrainConfig:
     lr: float = 1e-2
@@ -180,22 +168,6 @@ class Trainer:
         # training arrays from ShardedGraph are CSR-ordered per device
         self.cfg = dataclasses.replace(cfg, sorted_edges=True)
         self._eval_cfg = dataclasses.replace(cfg, sorted_edges=True)
-        # lane_pad: align the input feature slab to the 128-lane TPU
-        # boundary. Zero columns are appended host-side (see _pad_cols)
-        # and layer_sizes[0] grows to match, so every feature buffer the
-        # step donates — and every slab-gather dynamic_slice — moves
-        # whole (8, 128) tiles. Eval paths pad identically.
-        self._feat_pad = 0
-        if getattr(cfg, "lane_pad", False):
-            pad = (-cfg.layer_sizes[0]) % 128
-            if pad:
-                self._feat_pad = pad
-                sizes = (cfg.layer_sizes[0] + pad,) \
-                    + tuple(cfg.layer_sizes[1:])
-                self.cfg = dataclasses.replace(self.cfg,
-                                               layer_sizes=sizes)
-                self._eval_cfg = dataclasses.replace(self._eval_cfg,
-                                                     layer_sizes=sizes)
         self.tcfg = tcfg
         self.P = sg.num_parts
         self.emulated = tcfg.emulate_parts
@@ -250,7 +222,6 @@ class Trainer:
             self.data["edge_dst"] = jax.device_put(dummy, self._shard)
 
         rng = jax.random.PRNGKey(tcfg.seed)
-        # self.cfg, not the ctor arg: lane_pad rewrote layer_sizes[0]
         params = init_params(rng, self.cfg)
         if self.emulated:
             # replicated-by-construction: stacked copies stand in for
@@ -454,36 +425,19 @@ class Trainer:
         elif impl == "block":
             self._use_block()
 
-    def _slab_flag(self) -> bool:
-        """Resolve cfg.slab to a concrete on/off for table builds:
-        'on'/'off' are user pins, 'auto' takes the tuner winner's
-        measured slab decision when one exists (self.tuning set by
-        _resolve_auto) and stays off otherwise — slab plans only pay
-        off when the layout has contiguous runs, which is exactly what
-        the tuner measures per (reorder, shape)."""
-        mode = str(getattr(self.cfg, "slab", "auto"))
-        if mode == "on":
-            return True
-        if mode == "off":
-            return False
-        win = (self.tuning or {}).get("winner") or {}
-        return bool(win.get("slab"))
-
     def _use_bucket(self, dirty=None) -> None:
         from ..ops.bucket_spmm import (build_sharded_bucket_tables,
                                        validate_bucket_tables)
 
         merge = int(getattr(self.cfg, "bucket_merge", 0))
-        slab_on = self._slab_flag()
-        kind = ("bucket" + (f"_m{merge}" if merge else "")
-                + ("_slab" if slab_on else ""))
+        kind = "bucket" + (f"_m{merge}" if merge else "")
         # streaming (enable_stream) keeps a per-shard BucketPlan cache
         # so a delta batch rebuilds plans only for its dirty shards
         cache = getattr(self, "_bucket_plan_cache", None)
         self._bucket_tables = self._cached_tables(
             kind, lambda: build_sharded_bucket_tables(
-                self.sg, min_width=merge, slab=slab_on,
-                plan_cache=cache, dirty=dirty))
+                self.sg, min_width=merge, plan_cache=cache,
+                dirty=dirty))
         # the kernel's clip-mode gathers are sound only for
         # in-bounds tables; a rotted cache must fail HERE, loudly,
         # not clamp to wrong rows mid-epoch
@@ -497,15 +451,13 @@ class Trainer:
         tile = self.cfg.block_tile
         nnz = self.cfg.block_nnz
         grp = self.cfg.block_group
-        slab_on = self._slab_flag()
         key = (f"block_{tile}_{w_hint}" + (f"_n{nnz}" if nnz else "")
-               + (f"_u{grp}" if grp > 1 else "")
-               + ("_slab" if slab_on else ""))
+               + (f"_u{grp}" if grp > 1 else ""))
         self._block_tables = self._cached_tables(
             key,
             lambda: build_sharded_block_tables(
                 self.sg, tile=tile, n_feat_hint=w_hint,
-                nnz_threshold=nnz, group=grp, slab=slab_on)[0])
+                nnz_threshold=nnz, group=grp)[0])
         self._block_tile = tile
 
     def _resolve_auto(self) -> str:
@@ -571,7 +523,6 @@ class Trainer:
                                            "none"),
                         epoch_block=int(getattr(self.tcfg,
                                                 "epoch_block", 0)),
-                        slab=str(getattr(cfg, "slab", "auto")),
                         step_width=step_width,
                         spmm_per_epoch=max(1, len(in_step)),
                         edge_budget=int(getattr(
@@ -604,7 +555,7 @@ class Trainer:
                 rec = {"winner": {"name": tuner.DEFAULT_IMPL,
                                   "impl": tuner.DEFAULT_IMPL,
                                   "rem_dtype": None, "rem_amax": False,
-                                  "block_group": 1, "slab": False},
+                                  "block_group": 1},
                        "costs": []}
         win = dict(rec["winner"])
         self.tuning = {
@@ -612,7 +563,6 @@ class Trainer:
             "source": source,
             "stale_reason": None if source == "artifact" else reason,
             "costs": rec.get("costs", []),
-            "gather_contiguity": rec.get("gather_contiguity"),
             "emitted": False,
             # did the sample carry the shard's tiles, and how far is
             # the estimate from a traced spmm_s (null without a
@@ -673,7 +623,7 @@ class Trainer:
         sg = self.sg
         edge_dummy = np.zeros((self.P, 8), np.int32)
         arrs = {
-            "feat": _pad_cols(sg.feat, self._feat_pad),
+            "feat": sg.feat,
             "label": sg.label,
             "train_mask": sg.train_mask,
             "in_deg": sg.in_deg,
@@ -1515,17 +1465,6 @@ class Trainer:
             return "gat-bucket"
         return "xla"
 
-    def _slab_active(self) -> bool:
-        """True when the current kernel tables carry slab-gather run
-        plans (bkt_*res_/blkrem_*res_ keys) — the fallback ladder then
-        has an extra rung ABOVE the impl downgrade: same kernel, slab
-        plans stripped (cfg.slab='off'), so a dynamic_slice-path crash
-        does not cost the whole bucket/block kernel."""
-        for t in (self._bucket_tables, self._block_tables):
-            if t is not None and any("res_" in k for k in t):
-                return True
-        return False
-
     def downgrade_kernel(self, to_impl: str, reason: str) -> dict:
         """Rebuild the trainer one rung down the kernel fallback ladder
         (resilience/numerics.fallback_ladder): swap the kernel tables on
@@ -1591,8 +1530,7 @@ class Trainer:
         inject = self._inject_kernel_crash
         armed = ((not self._kernel_proven or inject)
                  and jax.process_count() == 1
-                 and (inject or fallback_ladder(self._current_impl())
-                      or self._slab_active()))
+                 and (inject or fallback_ladder(self._current_impl())))
         if not armed:
             # multi-process / ladder-exhausted: the injection flag must
             # not survive to poison an unrelated later dispatch
@@ -1618,17 +1556,6 @@ class Trainer:
                     err = exc
             # the full original error, before any record truncates it
             traceback.print_exception(err, file=sys.stderr)
-            if self._slab_active():
-                # first rung: same kernel, slab plans stripped — the
-                # streaming dynamic_slice path is the newest code and
-                # the cheapest thing to give up
-                self.cfg = dataclasses.replace(self.cfg, slab="off")
-                self._eval_cfg = dataclasses.replace(self._eval_cfg,
-                                                     slab="off")
-                self.downgrade_kernel(self._current_impl(),
-                                      "slab-off: " + repr(err)[:280])
-                self.restore_state(snap)
-                continue
             rungs = fallback_ladder(self._current_impl())
             if not rungs:
                 raise KernelFallbackError(
@@ -3818,10 +3745,7 @@ class Trainer:
             order = stable_argsort(g.dst)
             self._eval_cache[key] = {
                 "graph": g,  # strong ref: keeps id(g) valid while cached
-                # lane_pad trainers rewrote layer_sizes[0]; eval input
-                # must be padded to the same width
-                "feat": jnp.asarray(_pad_cols(
-                    g.ndata["feat"], getattr(self, "_feat_pad", 0))),
+                "feat": jnp.asarray(g.ndata["feat"]),
                 "label": g.ndata["label"],
                 "edge_src": jnp.asarray(g.src[order].astype(np.int32)),
                 "edge_dst": jnp.asarray(g.dst[order].astype(np.int32)),

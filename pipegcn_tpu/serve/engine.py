@@ -12,7 +12,7 @@ never recompiles (pinned by the TRACE_COUNTS test in test_serve.py).
 
 Serving inherits every training-side kernel win by construction: the
 forward program aggregates through `trainer.make_device_spmm_closure`
-(the tuner's measured kernel choice over the PR-9 slab/reorder layout)
+(the tuner's measured kernel choice)
 and exchanges boundaries through the same send-lists as training.
 
 State owned by the engine (per device, sharded over PARTS_AXIS):
@@ -46,7 +46,6 @@ from ..models.sage import forward
 from ..obs.trace import named_phase
 from ..parallel.halo import exchange_blocks, halo_exchange
 from ..parallel.mesh import PARTS_AXIS
-from ..parallel.trainer import _pad_cols
 from ..utils.checkpoint import (CheckpointCorrupt, _generations,
                                 load_checkpoint)
 from .batcher import MicroBatcher, ServingStats, bucket_for, bucket_ladder
@@ -428,7 +427,6 @@ class ServingEngine:
         if ids.size and (ids.min() < 0
                          or ids.max() >= self.num_global_nodes):
             raise ValueError("node id out of range")
-        wide = _pad_cols(vals, self.trainer._feat_pad)
         parts = self._q_part[ids]
         local = self._q_local[ids]
         touched = 0
@@ -439,8 +437,8 @@ class ServingEngine:
             b = bucket_for(n, self.update_ladder)
             up = np.full(b, -1, np.int32)
             ul = np.zeros(b, np.int32)
-            uv = np.zeros((b, wide.shape[1]), np.float32)
-            up[:n], ul[:n], uv[:n] = parts[sl], local[sl], wide[sl]
+            uv = np.zeros((b, vals.shape[1]), np.float32)
+            up[:n], ul[:n], uv[:n] = parts[sl], local[sl], vals[sl]
             self._feat = self._patch_prog(self._feat, up, ul, uv)
         self.freshness.mark(parts, local)
         touched = self.cache.invalidate_rows(parts, local)
@@ -497,7 +495,6 @@ class ServingEngine:
         if report.new_rows is not None and report.new_rows.any():
             pp, rr = np.nonzero(report.new_rows)
             vals = np.asarray(sg.feat)[pp, rr].astype(np.float32)
-            wide = _pad_cols(vals, self.trainer._feat_pad)
             top = self.update_ladder[-1]
             for i0 in range(0, pp.size, top):
                 sl = slice(i0, min(i0 + top, pp.size))
@@ -505,10 +502,10 @@ class ServingEngine:
                 b = bucket_for(n, self.update_ladder)
                 up = np.full(b, -1, np.int32)
                 ul = np.zeros(b, np.int32)
-                uv = np.zeros((b, wide.shape[1]), np.float32)
+                uv = np.zeros((b, vals.shape[1]), np.float32)
                 up[:n], ul[:n] = pp[sl].astype(np.int32), \
                     rr[sl].astype(np.int32)
-                uv[:n] = wide[sl]
+                uv[:n] = vals[sl]
                 self._feat = self._patch_prog(self._feat, up, ul, uv)
         # ---- layer-0 cache: rebuild the ledger on the patched
         # send-lists, carrying over hit accounting and still-valid

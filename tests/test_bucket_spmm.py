@@ -267,8 +267,7 @@ def test_transport_cast_saturates_not_nan():
 # ---------------- named scopes inside the kernel ---------------------------
 
 def _window_graph(n=256, deg=12, n_feat=12, n_class=4, seed=0):
-    """Every node aggregates a contiguous id window below it: index
-    runs long enough for streaming-slab plans (tests/test_reorder.py)."""
+    """Every node aggregates a contiguous id window below it."""
     from pipegcn_tpu.graph.csr import Graph
 
     src = [j for i in range(n) for j in range(max(0, i - deg), i)]
@@ -285,8 +284,7 @@ def _window_graph(n=256, deg=12, n_feat=12, n_class=4, seed=0):
                "test_mask": ar >= 3 * n // 4})
 
 
-@pytest.mark.parametrize("slab", ["off", "on"])
-def test_scan_names_the_kernels_work(slab):
+def test_scan_names_the_kernels_work():
     """In the compiled 2-epoch scan, what runs under `spmm` names a
     second-level scope (gather, reduce, unpermute, ...), forward and
     under `bwd`: at least 95% of the bytes its instructions move. A
@@ -298,10 +296,8 @@ def test_scan_names_the_kernels_work(slab):
     sg = ShardedGraph.build(g, np.zeros(g.num_nodes, np.int32), n_parts=1)
     cfg = ModelConfig(layer_sizes=(12, 16, 16, 4), norm="layer",
                       dropout=0.2, train_size=sg.n_train_global,
-                      spmm_impl="bucket", slab=slab, dtype="bfloat16",
-                      use_pp=True)
+                      spmm_impl="bucket", dtype="bfloat16", use_pp=True)
     t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
-    assert t._slab_active() == (slab == "on")
     txt = t.step_compiled_text(2)
     cov = scope_coverage(txt)
     for direction in ("fwd", "bwd"):

@@ -353,6 +353,18 @@ def test_fallback_ladder_exhaustion_raises(sharded):
         t.train_epoch(0)
 
 
+def test_bucket_crash_falls_straight_to_xla(sharded):
+    """Under spmm_impl="bucket" the FIRST fallback is the impl ladder's
+    next rung (no same-kernel rung stands before it) and the retried
+    epoch trains on."""
+    t = _trainer(sharded, mkw={"spmm_impl": "bucket"})
+    t._inject_kernel_crash = True
+    loss = t.train_epoch(0)
+    assert t.fallbacks[0]["from_impl"] == "bucket"
+    assert t.fallbacks[0]["to_impl"] == "xla"
+    assert np.isfinite(loss)
+
+
 def test_downgraded_trainer_keeps_trajectory(sharded):
     """The fallback rebuilds tables + step but restores the
     pre-dispatch state: the downgraded run's losses stay finite and

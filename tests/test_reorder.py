@@ -1,15 +1,11 @@
-"""Locality-aware reorder + slab-gather layout (round 9).
+"""Locality-aware reorder layout (round 9).
 
 Covers the layout contract end to end: the reorder permutation
 round-trips against the base layout and is validated on load, old
 (pre-reorder) artifacts still load, training/eval semantics are
 layout-invariant (losses within float-accumulation noise, eval
-bit-parity), the slab-gather streaming path is numerically identical
-to the plain clipped-take path (including adversarial all-scattered
-streams, where no plan must be emitted), the fallback ladder's new
-slab-off rung fires before any impl downgrade, the tuner signature
-keys on the layout, and the bench/report plumbing surfaces
-gather_contiguity with a pinned --json shape.
+bit-parity), the tuner signature keys on the layout, and the
+bench/report plumbing surfaces the layout with a pinned --json shape.
 """
 
 import dataclasses
@@ -53,29 +49,6 @@ def _mesh_graph(n=20, n_feat=12, n_class=4, seed=0):
             "train_mask": ar < N // 2,
             "val_mask": (ar >= N // 2) & (ar < 3 * N // 4),
             "test_mask": ar >= 3 * N // 4,
-        })
-
-
-def _window_graph(n=256, deg=12, n_feat=12, n_class=4, seed=0):
-    """Every node aggregates a contiguous id window below it — the
-    slab-friendly stream shape (runs >= SLAB_RUN survive the bucket
-    table build), again with contiguous mask segments."""
-    src, dst = [], []
-    for i in range(n):
-        for j in range(max(0, i - deg), i):
-            src.append(j)
-            dst.append(i)
-    rng = np.random.default_rng(seed)
-    ar = np.arange(n)
-    return Graph(
-        num_nodes=n,
-        src=np.asarray(src, np.int64), dst=np.asarray(dst, np.int64),
-        ndata={
-            "feat": rng.normal(size=(n, n_feat)).astype(np.float32),
-            "label": rng.integers(0, n_class, size=n).astype(np.int64),
-            "train_mask": ar < n // 2,
-            "val_mask": (ar >= n // 2) & (ar < 3 * n // 4),
-            "test_mask": ar >= 3 * n // 4,
         })
 
 
@@ -298,115 +271,6 @@ def test_two_process_mesh_reorder(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# slab-gather plans: build-time detection + numerical parity
-
-
-def test_slab_plan_adversarial_all_scattered():
-    """A stream with NO +1-consecutive runs must produce no plan at
-    all — the residue path alone is the whole gather."""
-    from pipegcn_tpu.ops.bucket_spmm import build_slab_plan
-
-    sentinel = 4096
-    # strided indices: flat stream 0, 2, 4, ... — never consecutive
-    tbl = (2 * np.arange(16 * 8)).reshape(1, 16, 8).astype(np.int32)
-    assert build_slab_plan(tbl, sentinel) is None
-    # all-sentinel (fully padded bucket): no plan either
-    pad = np.full((1, 16, 8), sentinel, np.int32)
-    assert build_slab_plan(pad, sentinel) is None
-
-
-def test_slab_gather_sum_matches_plain_take():
-    """Device-side parity on a mixed stream: long contiguous runs
-    (slab-covered), short runs and scattered residue, and sentinel
-    padding — the streaming path must reproduce the plain clipped-take
-    row sums up to f32 reduction-order noise."""
-    import jax.numpy as jnp
-
-    from pipegcn_tpu.ops.bucket_spmm import (
-        SLAB_RUN,
-        _slab_gather_sum,
-        build_slab_plan,
-    )
-
-    rng = np.random.default_rng(7)
-    n_src, w, cap, f = 512, 8, 24, 6
-    sentinel = n_src
-    tbl = np.full((1, cap, w), sentinel, np.int32)
-    flat = tbl.reshape(1, -1)
-    # rows 0..11: one long contiguous stream (covered by slabs)
-    flat[0, : 12 * w] = np.arange(12 * w) + 40
-    # rows 12..17: scattered residue, runs shorter than SLAB_RUN
-    flat[0, 12 * w: 18 * w] = rng.choice(
-        np.arange(0, n_src, 3), size=6 * w, replace=False)
-    # rows 18..: left as sentinel padding
-    plan = build_slab_plan(tbl, sentinel)
-    assert plan is not None
-    assert plan["cnt"][0] >= (12 * w) // SLAB_RUN - 1
-    # slab-covered residue entries were replaced by the sentinel
-    assert int((plan["res"] == sentinel).sum()) > int(
-        (tbl == sentinel).sum())
-
-    fbuf_pad = np.concatenate(
-        [rng.normal(size=(n_src, f)).astype(np.float32),
-         np.zeros((1, f), np.float32)])
-    want = fbuf_pad[tbl[0]].sum(axis=1)
-    got = np.asarray(_slab_gather_sum(
-        jnp.asarray(fbuf_pad),
-        {k: jnp.asarray(v[0]) for k, v in plan.items()},
-        cap, w, f))
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-    # and a pure-numpy emulation of the streaming writes agrees exactly
-    buf = fbuf_pad[np.minimum(plan["res"][0].reshape(-1), sentinel)]
-    buf = np.concatenate([buf, np.zeros((SLAB_RUN, f), np.float32)])
-    for i in range(plan["src"].shape[1]):
-        s0, p0 = int(plan["src"][0][i]), int(plan["pos"][0][i])
-        buf[p0:p0 + SLAB_RUN] = fbuf_pad[s0:s0 + SLAB_RUN]
-    np.testing.assert_array_equal(
-        buf[:cap * w].reshape(cap, w, f).sum(axis=1), want)
-
-
-def test_slab_trainer_parity_and_fallback():
-    """End-to-end on the slab-friendly window graph: tables carry slab
-    plans, slab=on training/eval is numerically identical to slab=off,
-    and an injected kernel crash takes the slab-off rung FIRST (same
-    impl) before any impl downgrade."""
-    from pipegcn_tpu.ops.bucket_spmm import (
-        build_sharded_bucket_tables,
-        gather_contiguity,
-    )
-
-    g = _window_graph()
-    sg = ShardedGraph.build(g, np.zeros(g.num_nodes, np.int32),
-                            n_parts=1)
-    tabs = build_sharded_bucket_tables(sg, slab=True)
-    assert any("res_" in k for k in tabs)  # plans were emitted
-    stats = gather_contiguity(tabs, sg.n_max + sg.halo_size)
-    assert stats["mean_run_len"] > 2.0
-    assert 0.0 < stats["slab_frac"] <= 1.0
-
-    t_on = _trainer(sg, g, spmm_impl="bucket", slab="on")
-    assert t_on._slab_active()
-    t_off = _trainer(sg, g, spmm_impl="bucket", slab="off")
-    assert not t_off._slab_active()
-    l_on = [t_on.train_epoch(e) for e in range(3)]
-    l_off = [t_off.train_epoch(e) for e in range(3)]
-    np.testing.assert_allclose(l_on, l_off, rtol=1e-6)
-    assert t_on.evaluate(g, "val_mask") == t_off.evaluate(g, "val_mask")
-
-    # fallback ladder: slab-off rung first, impl rung only after
-    t = _trainer(sg, g, spmm_impl="bucket", slab="on")
-    t._inject_kernel_crash = True
-    t.train_epoch(0)
-    assert t.fallbacks[0]["reason"].startswith("slab-off:")
-    assert t.fallbacks[0]["from_impl"] == "bucket"
-    assert t.fallbacks[0]["to_impl"] == "bucket"
-    assert t.cfg.slab == "off" and not t._slab_active()
-    t._inject_kernel_crash = True
-    t.train_epoch(1)  # second crash: now the impl ladder moves
-    assert t.fallbacks[-1]["to_impl"] != "bucket"
-
-
-# ---------------------------------------------------------------------
 # tuner signature + artifact resolution
 
 
@@ -435,18 +299,6 @@ def test_tuner_signature_keys_on_layout(tmp_path):
     assert rec is not None and why is None
 
 
-def test_slab_candidates_in_grid():
-    from pipegcn_tpu.ops.tuner import candidate_grid
-
-    names = [c["name"] for c in candidate_grid(slab="auto")]
-    slabbed = [n for n in names if "slab" in n]
-    assert slabbed  # the tuner measures the slab twins...
-    assert len(set(names)) == len(names)
-    # ...and slab=off removes them (explicit pin wins)
-    assert not any("slab" in c["name"]
-                   for c in candidate_grid(slab="off"))
-
-
 def test_resolve_reorder_prefers_existing_artifacts(tmp_path):
     from pipegcn_tpu.partition.bench_artifact import (
         artifact_path,
@@ -468,10 +320,10 @@ def test_resolve_reorder_prefers_existing_artifacts(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# report plumbing: contiguity next to the anatomy floor, pinned --json
+# report plumbing: the layout that produced the number, pinned --json
 
 
-def test_report_surfaces_contiguity(tmp_path, capsys):
+def test_report_surfaces_reorder(tmp_path, capsys):
     from pipegcn_tpu.cli.report import main as report_main
     from pipegcn_tpu.cli.report import summarize_run
     from pipegcn_tpu.obs import MetricsLogger, read_metrics
@@ -481,23 +333,11 @@ def test_report_surfaces_contiguity(tmp_path, capsys):
         ml.run_header(config={}, device={}, mesh={})
         ml.event("bench", metric="small_epoch_time", value=1.25,
                  unit="s/epoch", vs_baseline=1.0,
-                 reorder="degree-bfs",
-                 gather_contiguity={"mean_run_len": 7.5,
-                                    "slab_frac": 0.61},
-                 reorder_delta_s=0.12, slab_delta_s=-0.03)
+                 reorder="degree-bfs")
     s = summarize_run(read_metrics(p))
     # the pinned --json shape the bench trajectory consumes
     assert s["reorder"] == "degree-bfs"
-    assert s["gather_mean_run_len"] == pytest.approx(7.5)
-    assert s["gather_slab_frac"] == pytest.approx(0.61)
-    assert s["reorder_delta_s"] == pytest.approx(0.12)
-    assert s["slab_delta_s"] == pytest.approx(-0.03)
     assert report_main([str(p), "--json"]) == 0
-    js = json.loads(capsys.readouterr().out)
-    for k in ("reorder", "gather_mean_run_len", "gather_slab_frac",
-              "reorder_delta_s", "slab_delta_s"):
-        assert k in js, k
+    assert json.loads(capsys.readouterr().out)["reorder"] == "degree-bfs"
     assert report_main([str(p)]) == 0
-    human = capsys.readouterr().out
-    assert "gather contiguity" in human
-    assert "reorder delta" in human
+    assert "degree-bfs" in capsys.readouterr().out

@@ -76,6 +76,17 @@ def test_candidate_grid_full_and_pinned():
                if c["impl"] == "block")
 
 
+def test_candidate_grid_is_the_thirteen_in_order():
+    """pick_winner breaks near-ties by the grid's order and the
+    benchmark logs the winner's name: both are part of the contract."""
+    grid = tuner.candidate_grid()
+    assert [c["name"] for c in grid] == [
+        "xla", "bucket", "bucket-bf16", "bucket-f8", "bucket-f8amax",
+        "block", "block-u4", "block-bf16", "block-u4-bf16", "block-f8",
+        "block-u4-f8", "block-f8amax", "block-u4-f8amax"]
+    assert not any("slab" in c for c in grid)
+
+
 def test_sample_slice_preserves_degree_distribution():
     sg = _sharded(num_nodes=2000, avg_degree=10, seed=7)
     sample, info = tuner.sample_slice(sg, edge_budget=3000,
@@ -327,7 +338,7 @@ def test_timed_program_keeps_forward_and_backward(name):
 
 def _cost(name, impl, s, spread, **kw):
     return dict({"name": name, "impl": impl, "rem_dtype": None,
-                 "rem_amax": False, "block_group": 1, "slab": False,
+                 "rem_amax": False, "block_group": 1,
                  "spmm_fwdbwd_s": s, "spread_s": spread,
                  "est_epoch_spmm_s": s, "error": None}, **kw)
 
@@ -340,7 +351,7 @@ def _cost(name, impl, s, spread, **kw):
       _cost("bucket", "bucket", 1.04e-2, 0.0),
       _cost("block-u4", "block", 1.00e-2, 5e-4)], "bucket"),
     # a slower candidate's own noise does not make it a tie
-    ([_cost("bucket-slab", "bucket", 1.5e-2, 9e-3),
+    ([_cost("bucket-bf16", "bucket", 1.5e-2, 9e-3),
       _cost("block-u4", "block", 1.0e-2, 1e-4)], "block-u4"),
     # outside it the measurement decides, whatever the family
     ([_cost("bucket", "bucket", 3.4e-2, 5e-4),
@@ -388,7 +399,7 @@ def test_raw_edge_kernel_is_asked_at_the_shards_size(monkeypatch):
 
     monkeypatch.setattr(tuner, "shard_size_refusal", refuse)
     rec = tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16",
-                     slab="off", block_group=4,
+                     block_group=4,
                      edge_budget=int(sg.edge_count[0]) // 2)
     assert asked == [(0, 8, None)]
     xla = next(c for c in rec["costs"] if c["name"] == "xla")
@@ -396,7 +407,7 @@ def test_raw_edge_kernel_is_asked_at_the_shards_size(monkeypatch):
     assert xla["out_of_domain"].startswith("RESOURCE_EXHAUSTED")
     assert rec["winner"]["impl"] != "xla"
     tuner.clear_memo()
-    tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16", slab="off",
+    tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16",
                block_group=4, edge_budget=10 ** 9)
     assert len(asked) == 1
 
@@ -660,14 +671,15 @@ def test_tuner_times_what_the_step_runs(tmp_path):
     assert np.isfinite(t.train_epoch(0))
 
 
-@pytest.mark.parametrize("old_format", [1, 2, 3])
+@pytest.mark.parametrize("old_format", [1, 2, 3, 4])
 def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
     """(e) A tuning.json timed on the row-wise sample (tuner format 1),
-    on the destination-major bucket kernels (format 2) or on fp8 rows
-    gathered element by element (format 3) is stale whatever its
-    checksum and signature say: refused with the reason, re-tuned once,
-    replaced on disk."""
-    assert tuner.TUNER_FORMAT == 4
+    on the destination-major bucket kernels (format 2), on fp8 rows
+    gathered element by element (format 3) or over the grid with the
+    streaming-slab twins (format 4) is stale whatever its checksum and
+    signature say: refused with the reason, re-tuned once, replaced on
+    disk."""
+    assert tuner.TUNER_FORMAT == 5
     sg = _sharded(seed=51)
     path = str(tmp_path / "art")
     sg.save(path)
@@ -680,12 +692,12 @@ def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
     rec["tuner_format"] = old_format
     tuner.save_tuning(path, rec)
     got, reason = tuner.load_tuning(path)
-    assert got is None and reason == f"format {old_format} != 4"
+    assert got is None and reason == f"format {old_format} != 5"
     t = Trainer(sgl, cfg, TrainConfig(seed=0))
     assert t.tuning["source"] == "live"
     assert f"format {old_format}" in t.tuning["stale_reason"]
     healed, why = tuner.load_tuning(path)
-    assert why is None and healed["tuner_format"] == 4
+    assert why is None and healed["tuner_format"] == 5
     assert healed["winner"] == t.tuning["winner"]
     assert healed["sample_dense_coverage"] is not None
 
