@@ -145,7 +145,7 @@ def main():
                          "micro-bench tuner; fall back to the "
                          "deterministic default when no persisted "
                          "tuning table is trusted")
-    ap.add_argument("--tuner-samples", type=int, default=1_000_000,
+    ap.add_argument("--tuner-samples", type=int, default=4_000_000,
                     help="edge budget for the tuner's sample of whole "
                          "destination tile-rows")
     ap.add_argument("--rem-dtype", default="none",

@@ -315,6 +315,7 @@ def train_leg(args, log=print, require_memory_stats: bool = False) -> dict:
         "tuning_source": tuning["source"],
         "artifact_source": trainer.sg.source,
         "tables_source": trainer.tables_source,
+        "tables_pad": trainer.tables_pad,
         # [length, seconds] of every dispatch, compiles included
         "dispatches_s": [[n, round(n * t, 3)]
                          for n, t in facts["blocks"]],
@@ -348,11 +349,13 @@ def train_leg(args, log=print, require_memory_stats: bool = False) -> dict:
         f"(impl={facts['kernel']}); the tuner's sample: "
         f"{tuning.get('sample_tile_rows')} tile-rows, dense coverage "
         f"{tuning.get('sample_dense_coverage')} (shard "
-        f"{tuning.get('shard_dense_coverage')}), empty call "
-        f"{tuning.get('call_overhead_s')} s; cost table:")
+        f"{tuning.get('shard_dense_coverage')}), timed edges "
+        f"{tuning.get('timed_edges')} for a shard of "
+        f"{tuning.get('shard_edges')}; cost table:")
     for c in tuning["costs"]:
         log(f"    {c['name']:<18}"
-            f"{c['spmm_fwdbwd_s'] * 1e3:>10.2f} ms   est epoch SpMM "
+            f"{c['spmm_fwdbwd_s'] * 1e3:>10.2f} ms sampled   a call at "
+            f"the shard's size {c['est_call_s']:.4f} s   est epoch SpMM "
             f"{c['est_epoch_spmm_s']:.3f} s   error={c['error']}")
     log(f"  loss {leg['loss_first']} -> {leg['loss_last']} over "
         f"{len(facts['losses'])} epochs; 0 fallback, 0 fault records")

@@ -102,6 +102,16 @@ def _local_parts(args):
     return list(range(lo, hi))
 
 
+def _pad_text(tables_pad) -> str:
+    """Trainer.tables_pad for the set-up line: per direction the row
+    buckets' widths and gather slots an edge (bucket_spmm.pad_stats;
+    under the block kernel, the remainder's)."""
+    return "".join(
+        f" | {d}: widths {t['widths']}, pad_ratio {t['pad_ratio']} "
+        f"({t['slots']} slots / {t['edges']} edges)"
+        for d, t in (tables_pad or {}).items())
+
+
 def prepare(args):
     """Load, partition (or reuse artifact), and return
     (sharded_graph, eval_graphs or None)."""
@@ -437,7 +447,8 @@ def run(args) -> dict:
     print("setup seconds: " + ", ".join(
         f"{k}={v}" for k, v in setup_s.items())
         + f" | partition artifact: {sg.source}"
-        + f" | kernel tables: {trainer.tables_source or 'none'}")
+        + f" | kernel tables: {trainer.tables_source or 'none'}"
+        + _pad_text(trainer.tables_pad))
 
     patcher = None
     journal = None
@@ -525,6 +536,7 @@ def run(args) -> dict:
             device=device_info(),
             mesh={"n_parts": args.n_partitions,
                   **mesh_info(trainer.mesh)},
+            setup_s=setup_s, tables_pad=trainer.tables_pad,
         )
         if replay_stats is not None:
             # the resume replay ran before the sink existed; its audit

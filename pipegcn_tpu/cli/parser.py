@@ -140,11 +140,12 @@ def create_parser() -> argparse.ArgumentParser:
                              "(1 = per-tile block lists)")
     parser.add_argument("--bucket-merge", "--bucket_merge", type=int,
                         default=0,
-                        help="merge bucket-ladder rungs below this width "
-                             "into one bucket (fewer kernel launches / "
-                             "transients per epoch at bounded padding "
-                             "cost; 0 = full ladder). Tuner-signature "
-                             "relevant: changing it re-tunes")
+                        help="no row bucket narrower than this width: "
+                             "lower-degree rows share one bucket (fewer "
+                             "kernel launches / transients per epoch at "
+                             "bounded padding cost; 0 = the widths "
+                             "fitted to the degree histogram). Tuner-"
+                             "signature relevant: changing it re-tunes")
     parser.add_argument("--tune", action="store_true", dest="tune",
                         default=True,
                         help="allow a live tuner micro-bench when "
@@ -157,7 +158,7 @@ def create_parser() -> argparse.ArgumentParser:
                              "deterministic default kernel with a loud "
                              "record")
     parser.add_argument("--tuner-samples", "--tuner_samples", type=int,
-                        default=1_000_000,
+                        default=4_000_000,
                         help="edge budget of the tuner's sample: whole "
                              "blocks of destination tile-rows (1024 rows "
                              "at the default tile), each row with all "

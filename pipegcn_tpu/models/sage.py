@@ -76,10 +76,10 @@ class ModelConfig:
     # (block_spmm._group_union; measured F-tile dedupe headroom in
     # docs/PERF_NOTES.md). 1 = per-tile K-class layout
     block_group: int = 1
-    # bucket-merge lever (ops/bucket_spmm._bucket_widths min_width):
-    # buckets narrower than this merge into the first surviving ladder
-    # rung, trading bounded padding for fewer per-bucket gather
-    # launches/transients. 0 = full ladder.
+    # bucket-merge lever (ops/bucket_spmm.fit_widths min_width): no
+    # bucket narrower than this, so every lower-degree row joins the
+    # first one, trading bounded padding for fewer per-bucket gather
+    # launches/transients. 0 = the widths the histogram asks for.
     bucket_merge: int = 0
     # spmm_impl='auto' resolution (ops/tuner.py): True lets a cache
     # miss run the live micro-benchmark campaign; False restricts auto
@@ -88,7 +88,7 @@ class ModelConfig:
     tune: bool = True
     # edge budget of the tuner's sample of whole destination tile-rows
     # (ops/tuner.py DEFAULT_EDGE_BUDGET)
-    tuner_samples: int = 1_000_000
+    tuner_samples: int = 4_000_000
     # gather-transport dtype for the bucket kernel / block remainder /
     # GAT attention kernel's wide value+cotangent gathers
     # (bucket_spmm.transport_dtypes): None = activation dtype;

@@ -353,14 +353,17 @@ class MetricsLogger:
                costs, sample_dense_coverage: Optional[float] = None,
                shard_dense_coverage: Optional[float] = None,
                sample_tile_rows: Optional[int] = None,
-               call_overhead_s: Optional[float] = None,
+               timed_edges: Optional[List[int]] = None,
+               shard_edges: Optional[int] = None,
                **extra) -> Dict[str, Any]:
         """The SpMM auto-tuner's dispatch decision (ops/tuner.py +
         Trainer._resolve_auto): the winning kernel config, where the
         decision came from (artifact | live | default), the full
-        measured per-candidate cost table, and what the timed sample
-        carried (null where nothing was timed) — the record that says
-        WHY this kernel dispatches."""
+        measured per-candidate cost table (sampled seconds and, from
+        the two nested samples, fixed_s / per_edge_s / est_call_s: what
+        was ranked), and what the timed samples carried (null where
+        nothing was timed) — the record that says WHY this kernel
+        dispatches."""
         extra.setdefault("time_unix", time.time())
         return self.write({
             "event": "tuning",
@@ -370,7 +373,8 @@ class MetricsLogger:
             "sample_dense_coverage": sample_dense_coverage,
             "shard_dense_coverage": shard_dense_coverage,
             "sample_tile_rows": sample_tile_rows,
-            "call_overhead_s": call_overhead_s,
+            "timed_edges": timed_edges,
+            "shard_edges": shard_edges,
             **extra,
         })
 

@@ -17,7 +17,15 @@ from __future__ import annotations
 
 from typing import Dict, Mapping
 
-SCHEMA_VERSION = 16  # v16: the tuning record says what its sample
+SCHEMA_VERSION = 17  # v17: the tuning record says what was RANKED:
+#                      every entry of `costs` carries fixed_s /
+#                      per_edge_s / est_call_s from two nested samples
+#                      (ops/tuner.py shard_estimate; TUNING_COST_FIELDS
+#                      types an entry), the record the samples' and the
+#                      shard's edges (timed_edges, shard_edges);
+#                      call_overhead_s went with the subtraction that
+#                      used it
+#                 v16: the tuning record says what its sample
 #                      carried (sample_dense_coverage beside
 #                      shard_dense_coverage, sample_tile_rows,
 #                      call_overhead_s — ops/tuner.py sample_slice)
@@ -200,18 +208,36 @@ FALLBACK_FIELDS: Dict[str, str] = {
 # nothing). The sample is whole blocks of destination tile-rows; its
 # dense coverage (block_spmm._part_block_stats at the block candidates'
 # tile, threshold and budget) beside the whole shard's says whether the
-# block candidates met the shard's dense tiles. Extras: est_epoch_spmm_s
-# (the winner's estimate, call_overhead_s taken off, to hold against a
-# traced spmm_s).
+# block candidates met the shard's dense tiles.
+# v17: candidates are ranked by est_call_s, a call at the shard's size
+# (shard_edges), the line through its best reps on the nested samples
+# of timed_edges edges (one entry where the shard was timed whole: the
+# estimate is then the time, fixed_s and per_edge_s null). Extras:
+# est_epoch_spmm_s (the winner's est_call_s x the step's aggregations,
+# to hold against a traced spmm_s).
 TUNING_FIELDS: Dict[str, str] = {
     "event": "string",             # "tuning"
     "winner": "object",            # the dispatched kernel config
     "source": "string",            # artifact | live | default
-    "costs": "array",              # measured per-candidate cost table
+    "costs": "array",              # per-candidate table (below)
     "sample_dense_coverage": "number?",  # of the sampled tile-rows
     "shard_dense_coverage": "number?",   # of the heaviest shard
     "sample_tile_rows": "integer?",      # destination tile-rows timed
-    "call_overhead_s": "number?",        # an empty timed call
+    "timed_edges": "array?",             # edges of each timed sample
+    "shard_edges": "integer?",           # edges the estimates are for
+}
+
+# one entry of a tuning record's `costs` (validate_record holds every
+# entry to it): a candidate that failed carries its `error` and nulls
+TUNING_COST_FIELDS: Dict[str, str] = {
+    "name": "string",              # candidate_grid's name
+    "spmm_fwdbwd_s": "number?",    # best rep on the larger sample
+    "spread_s": "number?",         # its median rep over the best
+    "fixed_s": "number?",          # of a call, whatever its edges
+    "per_edge_s": "number?",       # slope between the two samples
+    "est_call_s": "number?",       # fixed_s + per_edge_s * shard_edges
+    "est_epoch_spmm_s": "number?",  # est_call_s x aggregations a step
+    "error": "string?",            # why the candidate did not run
 }
 
 # one record per serving report window (serve/loadgen.run_serving_loop,
@@ -561,6 +587,16 @@ def validate_record(rec: Mapping) -> None:
         if not isinstance(ev, str) or not ev:
             raise ValueError(f"record without a string 'event': {rec!r}")
         return
+    _check_fields(ev, rec, fields)
+    if ev == "tuning":
+        for c in rec["costs"]:
+            if not isinstance(c, dict):
+                raise ValueError(f"tuning record: cost entry {c!r} is "
+                                 f"no object")
+            _check_fields("tuning cost", c, TUNING_COST_FIELDS)
+
+
+def _check_fields(ev: str, rec: Mapping, fields: Mapping) -> None:
     for name, tag in fields.items():
         nullable = tag.endswith("?")
         if nullable:

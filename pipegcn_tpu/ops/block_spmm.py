@@ -49,8 +49,11 @@ from .bucket_spmm import (
     _bucket_widths,
     bucket_aggregate,
     build_tables_for_edges,
+    degree_hist,
+    fit_widths,
     ladder_prefix,
     stack_to_caps,
+    validate_bucket_tables,
 )
 
 # HBM budget for the per-device dense-A tensor (see
@@ -442,23 +445,40 @@ class BlockPlan:
                               pad_b=n_dst_tiles)
 
         # ---- sparse remainder (bucket tables both directions) ----
-        r_src, r_dst = src_o[~in_dense_o], dst_o[~in_dense_o]
+        # widths fitted to the remainder's own degree histograms
+        # (bucket_spmm.fit_widths) unless given; the histograms and the
+        # edges are kept so the sharded builder can fit ONE ladder over
+        # every device's remainder and rebuild these tables alone
+        self._rem_edges = (src_o[~in_dense_o], dst_o[~in_dense_o])
+        r_src, r_dst = self._rem_edges
         self.rem_count = int(r_src.shape[0])
-        max_in = int(np.bincount(r_dst, minlength=n_out).max(initial=1))
-        max_out = int(np.bincount(r_src, minlength=n_src_rows).max(
-            initial=1))
-        self.rem_fwd_widths = list(
+        self.rem_deg_in = np.bincount(r_dst)
+        self.rem_deg_out = np.bincount(r_src)
+        self.rem_fwd_widths = self.rem_bwd_widths = None
+        self.set_remainder_widths(
             fwd_widths if fwd_widths is not None
-            else _bucket_widths(max(max_in, 1)))
-        self.rem_bwd_widths = list(
+            else fit_widths(degree_hist([self.rem_deg_in])),
             bwd_widths if bwd_widths is not None
-            else _bucket_widths(max(max_out, 1)))
-        self.rem_fwd_mats, self.rem_fwd_inv, _ = \
-            build_tables_for_edges(r_src, r_dst, n_out, n_src_rows,
-                                   self.rem_fwd_widths)
-        self.rem_bwd_mats, self.rem_bwd_inv, _ = \
-            build_tables_for_edges(r_dst, r_src, n_src_rows, n_out,
-                                   self.rem_bwd_widths)
+            else fit_widths(degree_hist([self.rem_deg_out])))
+
+    def set_remainder_widths(self, fwd_widths: Sequence[int],
+                             bwd_widths: Sequence[int]) -> None:
+        """(Re)build the remainder's bucket tables at the given widths;
+        a direction already at them is left alone. The dense half is
+        not touched: which edges are remainder is fixed by the block
+        selection."""
+        r_src, r_dst = self._rem_edges
+        if list(fwd_widths) != self.rem_fwd_widths:
+            self.rem_fwd_widths = list(fwd_widths)
+            self.rem_fwd_mats, self.rem_fwd_inv, _ = \
+                build_tables_for_edges(r_src, r_dst, self.n_out,
+                                       self.n_src_rows,
+                                       self.rem_fwd_widths)
+        if list(bwd_widths) != self.rem_bwd_widths:
+            self.rem_bwd_widths = list(bwd_widths)
+            self.rem_bwd_mats, self.rem_bwd_inv, _ = \
+                build_tables_for_edges(r_dst, r_src, self.n_src_rows,
+                                       self.n_out, self.rem_bwd_widths)
 
 
 # bound on one dense-apply chunk's materialized A elements (unpacked,
@@ -807,9 +827,9 @@ def build_sharded_block_tables(sg, tile: int = 256,
 
     def build_plans(cap, fw=None, bw=None, fk=None, bk=None):
         # fresh ladders unless given: a different block cap changes
-        # which edges land in the remainder, and reusing a ladder built
-        # for a different remainder can under-size its top bucket —
-        # build_tables_for_edges would then SILENTLY drop edges
+        # which edges land in the remainder, and a ladder built for a
+        # different remainder can under-size its top bucket
+        # (build_tables_for_edges raises on one)
         return [
             BlockPlan(sg.edge_src[r], sg.edge_dst[r], sg.n_max,
                       n_src_rows, n_feat_hint, tile=tile,
@@ -846,23 +866,26 @@ def build_sharded_block_tables(sg, tile: int = 256,
             break
         bits = emit_bits
 
-    # unify ladders (length = max over devices): remainder bucket widths
-    # AND dense K-class widths. The re-build keeps the SAME cap, so the
-    # dense selection — and thus every remainder degree and per-tile
-    # block count — is unchanged and the unified ladders (covering the
-    # global max) are safe for every device
-    fw_len = max(len(p.rem_fwd_widths) for p in plans)
-    bw_len = max(len(p.rem_bwd_widths) for p in plans)
+    # unify ladders over the devices. The dense K classes keep the x1.5
+    # ladder at the longest device's length. The remainder's widths
+    # are fitted ONCE to the histograms of all the plans (known
+    # only now, after the dense selection). A re-build keeps the SAME
+    # cap, so the dense selection, and thus every remainder degree and
+    # per-tile block count, is unchanged and the unified ladders
+    # (covering the global max) are safe for every device; where only
+    # the remainder's widths differ from a plan's own fit (P > 1),
+    # only its remainder tables are rebuilt
     fk_len = max(len(p.fwd_k_widths) for p in plans)
     bk_len = max(len(p.bwd_k_widths) for p in plans)
-    fw = ladder_prefix(fw_len)
-    bw = ladder_prefix(bw_len)
     fk = ladder_prefix(fk_len)
     bk = ladder_prefix(bk_len)
-    if any(p.rem_fwd_widths != fw or p.rem_bwd_widths != bw
-           or p.fwd_k_widths != fk or p.bwd_k_widths != bk
-           for p in plans):
+    fw = fit_widths(degree_hist(p.rem_deg_in for p in plans))
+    bw = fit_widths(degree_hist(p.rem_deg_out for p in plans))
+    if any(p.fwd_k_widths != fk or p.bwd_k_widths != bk for p in plans):
         plans = build_plans(cap_for(bits), fw=fw, bw=bw, fk=fk, bk=bk)
+    else:
+        for p in plans:
+            p.set_remainder_widths(fw, bw)
 
     B_max = max(p.a_blocks.shape[0] for p in plans)
 
@@ -956,6 +979,10 @@ def build_sharded_block_tables(sg, tile: int = 256,
     stacked.update(stack_to_caps(
         [(p.rem_bwd_mats, p.rem_bwd_inv) for p in plans], sg.n_max,
         "blkrem_bwd"))
+    # every remainder edge sits in exactly one slot, both directions
+    validate_bucket_tables(stacked, sg.n_max, n_src_rows,
+                           n_edges=[p.rem_count for p in plans],
+                           stem="blkrem")
     return stacked, tile
 
 

@@ -6,7 +6,8 @@ does NOT fit VMEM. XLA lowers
 `segment_sum` to scatter-add, which serializes badly on TPU; this
 formulation removes every scatter from both the forward AND the backward:
 
-  1. Host: bucket destination rows by ~x1.5-ladder local degree. Each
+  1. Host: bucket destination rows by local degree, into widths
+     fitted to the degree histogram (fit_widths). Each
      bucket b holds a padded neighbor-index table idx_b laid out
      SLOT-MAJOR, [D_b, n_b] (D_b = bucket width, n_b = its rows
      rounded up to ROW_TILE): row j of the table is neighbor slot j of
@@ -42,10 +43,20 @@ itself an SpMM with edge roles swapped — so the host also builds
 transpose tables (bucket by *source* out-degree) and the custom VJP runs
 the same scatter-free kernel in the other direction, accumulating in f32.
 
-Padding overhead is bounded by 1.5x (the _ladder_rungs width steps)
-and is ~1.2x on real degree distributions. All shapes are static; per-device tables
-are padded to shared row caps so one traced program serves every device in
-shard_map.
+Padding: the gather is request-bound, so what a table costs is its
+SLOTS (width x rows, pad entries included), and the widths are chosen
+to issue as few of them as the degree histogram allows (fit_widths: an
+optimal partition of the histogram into at most max(8, the x1.5
+ladder's non-empty rungs) buckets). The x1.5 ladder this replaced
+(_ladder_rungs, still the dense K classes' in block_spmm) bounds the
+padding at 1.5x and read 1.16 to 1.25 slots an edge on the benchmark's
+graphs; the fitted widths read 1.03 to 1.04 (PERF.md section 6, PR 34;
+pad_stats reports it for any table). No edge is ever dropped: a width
+list that does not cover a row's degree raises in the builder, and the
+validation counts every direction's entries against the edges it was
+built from. All shapes are static; per-device
+tables are padded to shared row caps so one traced program serves every
+device in shard_map.
 """
 
 from __future__ import annotations
@@ -98,13 +109,14 @@ SLAB_BYTES = 256
 
 
 def _ladder_rungs():
-    """The single source of the bucket-width progression: ~x1.5 steps
-    [1, 2, 3, 4, 6, 9, 13, ...]. This bounds bucket padding at 1.5x
-    worst-case (~1.2x on real degree distributions) vs 2x/1.33x for
-    power-of-2 steps — measured at Reddit scale the pow-2 tables
-    carried 1.34x remainder and 1.43x dense-K padding, ~0.35 s/epoch
-    of pure pad work. A longer ladder only adds a few extra (cheap)
-    bucket launches."""
+    """The x1.5 width progression [1, 2, 3, 4, 6, 9, 13, ...]: the
+    ladder of the block kernel's dense K classes, and the yardstick of
+    fit_widths (which never issues more slots, nor more than max(8,
+    this ladder's non-empty rungs) buckets). It bounds padding at 1.5x
+    worst-case vs 2x for power-of-2 steps; on the row buckets it read
+    1.16 (Yelp) to 1.25 (Reddit's remainder) slots an edge, which is
+    why they are fitted to the histogram now (PERF.md section 6, PR
+    34)."""
     w = 1
     while True:
         yield w
@@ -132,6 +144,142 @@ def ladder_prefix(n: int) -> List[int]:
     """First n rungs (the sharded builders regenerate shared ladders
     of a given length from the same generator)."""
     return list(itertools.islice(_ladder_rungs(), n))
+
+
+# fewest buckets a fitted ladder may use where the x1.5 ladder fills
+# fewer. A bucket is a gather program and a reduce program in every
+# layer, direction and scan: about 0.7 MB of compiled step each, which
+# a warm run pays for in loading it. On Reddit's remainder the x1.5
+# ladder fills seven rungs, five of them nearly empty, at 1.25 slots an
+# edge; by the number of fitted widths, on the same histogram (CPU
+# count, PERF.md section 6, PR 34): 5 widths 1.076, 6 1.061, 7 1.050, 8
+# 1.043, 10 1.032. So the fit gets the ladder's own bucket count and,
+# where that is small, eight: most of what the histogram offers for one
+# more bucket than the ladder filled there
+FIT_MIN_BUCKETS = 8
+
+# candidate widths the dynamic programme looks at: every degree present
+# up to this many, else a thinned set (_fit_candidates), so a 100k-degree
+# hub costs milliseconds on the host
+_FIT_CANDIDATES = 512
+
+
+def degree_hist(degs) -> np.ndarray:
+    """[P, D] with hist[r, d] = rows of degree d on shard r, from the
+    per-row degree arrays, one a shard. fit_widths fits ONE ladder to
+    all of them, so one traced program serves every device."""
+    hists = [np.bincount(np.asarray(d, dtype=np.int64), minlength=1)
+             for d in degs]
+    out = np.zeros((len(hists), max(h.shape[0] for h in hists)), np.int64)
+    for r, h in enumerate(hists):
+        out[r, :h.shape[0]] = h
+    return out
+
+
+def _row_caps(n: np.ndarray) -> np.ndarray:
+    """Per-shard row counts [P, ...] -> the shared cap: the largest,
+    rounded up to ROW_TILE (what stack_to_caps pads every shard to)."""
+    return -(-n.max(axis=0) // ROW_TILE) * ROW_TILE
+
+
+def _ladder_slots(hist: np.ndarray, widths: Sequence[int]):
+    """(slots, non-empty buckets) of the per-shard histograms `hist`
+    ([P, D], or [D] for one shard) under ascending `widths`: slots =
+    sum over buckets of width x P x the shared row cap, which is what
+    the stacked tables make the devices gather."""
+    hist = np.atleast_2d(hist)
+    cum = np.concatenate([np.zeros((hist.shape[0], 1), np.int64),
+                          np.cumsum(hist[:, 1:], axis=1)], axis=1)
+    top = np.minimum(np.asarray(widths, np.int64), hist.shape[1] - 1)
+    rows = np.diff(cum[:, top], prepend=0, axis=1)
+    rows[:, -1] += cum[:, -1] - cum[:, top[-1]]  # a short ladder's top
+    slots = (np.asarray(widths, np.int64) * _row_caps(rows)).sum()
+    return int(slots) * hist.shape[0], int(rows.any(axis=0).sum())
+
+
+def _fit_candidates(degs: np.ndarray, rows: np.ndarray,
+                    old: Sequence[int]) -> np.ndarray:
+    """Indices into the ascending present degrees that the programme may
+    end a bucket at: all of them up to _FIT_CANDIDATES, else the bucket
+    tops of the x1.5 ladder `old` (so its answer stays feasible), the
+    largest degree, and even quantiles of the rows and of the edges."""
+    m = degs.shape[0]
+    if m <= _FIT_CANDIDATES:
+        return np.arange(m)
+    q = np.linspace(0.0, 1.0, _FIT_CANDIDATES // 2)
+    cr = np.cumsum(rows)
+    ce = np.cumsum(rows * degs)
+    tops = np.searchsorted(degs, np.asarray(old), side="right") - 1
+    picks = [tops[tops >= 0], [m - 1], np.searchsorted(cr, q * cr[-1]),
+             np.searchsorted(ce, q * ce[-1])]
+    return np.unique(np.minimum(np.concatenate(picks), m - 1))
+
+
+def fit_widths(hist: np.ndarray, max_buckets: Optional[int] = None,
+               min_width: int = 0) -> List[int]:
+    """Bucket widths fitted to the degree histograms of the shards
+    (hist[r, d] = rows of degree d on shard r, degree_hist; a 1-D
+    histogram is one shard; degree 0 has no bucket): ascending, the
+    last at least the largest degree, none below `min_width`
+    (--bucket-merge, as in _bucket_widths), minimising the gathered
+    SLOTS of the stacked tables
+
+        sum_b  w_b * P * row_cap(max_r rows of shard r with
+                                 w_(b-1) < degree <= w_b)
+
+    over every choice of at most `max_buckets` widths: the optimal
+    partition of a histogram into intervals, a dynamic programme over
+    the degrees present. `max_buckets` defaults to max(FIT_MIN_BUCKETS,
+    the NON-EMPTY buckets _bucket_widths gives these histograms), so
+    the x1.5 ladder's filled rungs are a feasible answer: the fit never
+    issues more slots than that ladder and never asks for more buckets
+    than max(8, its). Equal slots take the fewer buckets. Every
+    returned width is a degree present (or `min_width`), so no bucket
+    of the fitted histograms is empty on every shard."""
+    hist = np.atleast_2d(np.asarray(hist, dtype=np.int64))
+    total = hist.sum(axis=0)
+    degs = np.nonzero(total[1:])[0] + 1
+    floor = max(1, int(min_width))
+    if degs.shape[0] == 0:
+        return [floor]
+    old = _bucket_widths(int(degs[-1]), min_width)
+    if max_buckets is None:
+        max_buckets = max(FIT_MIN_BUCKETS, _ladder_slots(hist, old)[1])
+    # a bucket's top: the largest degree present in it, never below the
+    # floor (degrees under the floor share the first surviving width)
+    cand = _fit_candidates(degs, total[degs], old)
+    below = np.searchsorted(degs[cand], floor, side="left")
+    if below and (below == cand.shape[0] or degs[cand[below]] != floor):
+        below -= 1              # the last degree under the floor ends
+    cand = cand[below:]         # the first bucket, at the floor's width
+    width = np.maximum(degs[cand], floor)
+    m = cand.shape[0]
+    # cum[r, j]: rows of shard r up to candidate j - 1; cost[i, j]: one
+    # bucket over candidates i .. j (i <= j) at the shards' shared cap
+    cum = np.concatenate([np.zeros((hist.shape[0], 1), np.int64),
+                          np.cumsum(hist[:, degs], axis=1)[:, cand]],
+                         axis=1)
+    cost = width[None, :] * hist.shape[0] * _row_caps(
+        cum[:, None, 1:] - cum[:, :m, None])
+    inf = np.iinfo(np.int64).max // 4
+    cost = np.where(np.arange(m)[:, None] <= np.arange(m)[None, :],
+                    cost, inf)
+    best = cost[0].copy()       # best[j]: candidates 0 .. j in k buckets
+    back = [np.zeros(m, np.int64)]
+    answer = (int(best[-1]), 0)
+    for k in range(1, min(int(max_buckets), m)):
+        # the last bucket starts at candidate i + 1: cost row i + 1
+        tot = np.minimum(best[:-1, None] + cost[1:], inf)
+        arg = tot.argmin(axis=0)
+        best = tot[arg, np.arange(m)]
+        back.append(arg + 1)
+        if best[-1] < answer[0]:
+            answer = (int(best[-1]), k)
+    out, j = [], m - 1
+    for k in range(answer[1], -1, -1):
+        out.append(int(width[j]))
+        j = int(back[k][j]) - 1
+    return out[::-1]
 
 
 def build_tables_for_edges(
@@ -166,9 +314,15 @@ def build_tables_for_edges(
     deg = (row_ptr[1:] - row_ptr[:-1]).astype(np.int64)
 
     widths_arr = np.asarray(widths, dtype=np.int64)
+    if int(deg.max(initial=0)) > int(widths_arr[-1]):
+        # a table only has `width` slots a row: the tail of a longer
+        # row would be lost without a word
+        raise ValueError(
+            f"bucket widths end at {int(widths_arr[-1])} but a row has "
+            f"{int(deg.max())} edges: the widths were fitted to another "
+            f"degree histogram than these edges'")
     # bucket id = first width >= deg (deg 0 handled separately)
     bid = np.searchsorted(widths_arr, np.maximum(deg, 1))
-    bid = np.minimum(bid, len(widths) - 1)
 
     idx_mats: List[np.ndarray] = []
     counts: List[int] = []
@@ -299,6 +453,28 @@ def _gather_sum(table, mat, scope="", dt=None):
         return msgs.astype(jnp.float32).sum(axis=0)
 
 
+def chunk_rows(w: int, n_b: int, f: int, chunk_elems: int):
+    """(rows a chunk, chunks) bucket_aggregate cuts a [w, n_b] table
+    into at a feature (slab) width of f. A gather stays under
+    `chunk_elems` elements, chunks are equal and ROW_TILE-aligned, and
+    the last one ends at the table's end, so rows * chunks - n_b rows
+    are gathered twice. Cutting at the budget's own row count
+    re-gathered up to a whole chunk a bucket (2% of Yelp's requests on
+    the x1.5 ladder, more on any finer one); spreading n_b evenly over
+    the fewest chunks and rounding up to ROW_TILE re-gathers up to
+    ROW_TILE rows a CHUNK, which is more than that on a bucket of 47
+    chunks. So: the row count between half the budget's and all of it
+    that re-gathers least (the larger on a tie), never more than the
+    even spread does: under ROW_TILE * chunks rows."""
+    max_rows = max(ROW_TILE, chunk_elems // (w * f) // ROW_TILE * ROW_TILE)
+    if n_b <= max_rows:
+        return row_cap(n_b), 1
+    rows = np.arange(max_rows, max_rows // 2, -ROW_TILE)
+    gathered = -(-n_b // rows) * rows
+    r = int(rows[np.argmin(gathered)])
+    return r, -(-n_b // r)
+
+
 def bucket_aggregate(
     fbuf: jax.Array,
     idx_mats: Sequence[jax.Array],
@@ -317,10 +493,12 @@ def bucket_aggregate(
     `chunk_edges` (the --spmm-chunk edge budget) overrides the default
     element budget: each gather materializes at most ~chunk_edges
     messages. A bucket over the budget runs as a lax.scan over slices
-    of its table along the rows, ROW_TILE-aligned, each writing its
-    rows of the bucket's [rows, F] result; the last slice is moved back
-    to end at the table's end, so no padded copy of the int32 table
-    and no stacked copy of the result exist in the step.
+    of its table along the rows, ROW_TILE-aligned and BALANCED
+    (chunk_rows: the fewest chunks the budget allows, of equal size),
+    each writing its rows of the bucket's [rows, F] result; the last
+    slice is moved back to end at the table's end, so no padded copy
+    of the int32 table and no stacked copy of the result exist in the
+    step, and under ROW_TILE rows a chunk are gathered twice.
 
     Rows wider than SLAB_BYTES are processed per feature slab (see
     SLAB_BYTES note above); `slab` overrides the element width (0
@@ -375,16 +553,15 @@ def bucket_aggregate(
         if n_b == 0:
             outs.append(jnp.zeros((0, f), jnp.float32))
             continue
-        rows_per_chunk = max(
-            ROW_TILE, chunk_elems // (w * f) // ROW_TILE * ROW_TILE)
-        if n_b <= rows_per_chunk:
+        rows_per_chunk, n_chunks = chunk_rows(w, n_b, f, chunk_elems)
+        if n_chunks == 1:
             outs.append(_gather_sum(table, mat, scope, dt))
             continue
-        n_chunks = -(-n_b // rows_per_chunk)
 
         def body(out, i):
-            # the ragged last chunk starts early enough to be whole: it
-            # writes some rows of the chunk before it again, the same
+            # the last chunk starts early enough to be whole: it writes
+            # under ROW_TILE * n_chunks rows of the chunk before it
+            # again, the same values
             start = jnp.minimum(i * rows_per_chunk, n_b - rows_per_chunk)
             m = jax.lax.dynamic_slice_in_dim(mat, start, rows_per_chunk,
                                              axis=1)
@@ -450,12 +627,10 @@ class BucketPlan:
         deg_out = np.bincount(edge_src[real], minlength=n_src_rows)
         self.fwd_widths = list(
             fwd_widths if fwd_widths is not None
-            else _bucket_widths(int(deg_in.max(initial=1)))
-        )
+            else fit_widths(degree_hist([deg_in])))
         self.bwd_widths = list(
             bwd_widths if bwd_widths is not None
-            else _bucket_widths(int(deg_out.max(initial=1)))
-        )
+            else fit_widths(degree_hist([deg_out])))
         self.n_out = n_out
         self.n_src_rows = n_src_rows
         self.fwd_mats, self.fwd_inv, self.fwd_counts = \
@@ -614,20 +789,28 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
                                 ) -> Dict[str, np.ndarray]:
     """Stacked per-device tables for shard_map (leading device axis),
     padded to shared bucket widths and per-bucket row caps so the traced
-    program is identical on every device.
+    program is identical on every device. The widths are fitted to the
+    degree histograms of ALL the shards (fit_widths), one ladder a
+    direction; a cap is the largest row count any shard has in a bucket.
 
-    `min_width` merges every bucket narrower than it into the first
-    surviving ladder rung (see _bucket_widths) — the bucket-merge
+    `min_width` is the narrowest width allowed (see fit_widths): every
+    lower-degree row joins the first bucket, the bucket-merge
     launch-overhead lever, surfaced as --bucket-merge.
 
     `plan_cache` (a mutable dict, updated in place) with `dirty` (shard
     ids whose edges changed) is the streaming-delta fast path: per-
-    shard degree maxima and BucketPlans are recomputed only for dirty
-    shards, clean shards reuse the cached ones — the O(E_r) per-shard
-    plan builds are the dominant cost, and a delta batch touches few
-    shards. Cached plans are only valid at the SAME width ladder: if
-    the global max degree moves the ladder, every plan rebuilds (the
-    resulting tables are identical to a cache-free build either way).
+    shard degrees and BucketPlans are recomputed only for
+    dirty shards, clean shards reuse the cached ones: the O(E_r) per-
+    shard plan builds are the dominant cost, and a delta batch touches
+    few shards. Cached plans are only valid at the SAME widths, and a
+    fitted ladder would move with every histogram (every table's shape
+    with it: a recompile). So a dirty rebuild KEEPS the cached ladder
+    for as long as its top width covers the new largest degree, and
+    refits (every plan rebuilt) only when it no longer does or on a
+    build from nothing. After deltas the tables can therefore differ
+    from a cache-free build of the same graph in their WIDTHS and
+    shapes, never in what they sum: every edge sits in exactly one
+    slot either way.
 
     Returns {'bkt_fwd_<b>': [P, w_b, cap_b], 'bkt_fwd_inv': [P, n_max],
              'bkt_bwd_<b>': ..., 'bkt_bwd_inv': [P, R]}: slot-major
@@ -642,27 +825,25 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
         cache.clear()
         stale = set(range(P))
 
-    # shared width ladders from global max degrees (per-shard maxima
-    # cached; only dirty shards rescan their edges)
+    # per-shard degrees (cached; only dirty shards rescan their edges)
     degs = cache.get("degs", [None] * P)
     degs += [None] * (P - len(degs))
     for r in range(P):
-        if degs[r] is not None and r not in stale:
-            continue
-        real = sg.edge_dst[r] < sg.n_max
-        mi, mo = 1, 1
-        if real.any():
-            di = np.bincount(sg.edge_dst[r][real], minlength=sg.n_max)
-            do = np.bincount(sg.edge_src[r][real], minlength=n_src_rows)
-            mi = max(1, int(di.max(initial=1)))
-            mo = max(1, int(do.max(initial=1)))
-        degs[r] = (mi, mo)
-    max_in = max(d[0] for d in degs)
-    max_out = max(d[1] for d in degs)
-    fw = _bucket_widths(max_in, min_width)
-    bw = _bucket_widths(max_out, min_width)
-    if cache.get("widths") != (tuple(fw), tuple(bw)):
-        stale = set(range(P))  # ladder moved: every plan is invalid
+        if degs[r] is None or r in stale:
+            real = sg.edge_dst[r] < sg.n_max
+            degs[r] = (np.bincount(sg.edge_dst[r][real]),
+                       np.bincount(sg.edge_src[r][real]))
+    hist_in = degree_hist(d[0] for d in degs)
+    hist_out = degree_hist(d[1] for d in degs)
+    fw, bw = cache.get("widths", ((), ()))
+    covers = (fw and bw and fw[-1] >= hist_in.shape[1] - 1
+              and bw[-1] >= hist_out.shape[1] - 1)
+    if dirty is None or not covers:
+        kept = (fw, bw)
+        fw = tuple(fit_widths(hist_in, min_width=min_width))
+        bw = tuple(fit_widths(hist_out, min_width=min_width))
+        if (fw, bw) != kept:
+            stale = set(range(P))  # ladder moved: every plan is invalid
 
     old_plans = cache.get("plans", [None] * P)
     old_plans += [None] * (P - len(old_plans))
@@ -675,41 +856,103 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
     if plan_cache is not None:
         plan_cache.update(
             shape=(sg.n_max, n_src_rows), min_width=min_width,
-            widths=(tuple(fw), tuple(bw)), degs=degs, plans=plans)
+            widths=(fw, bw), degs=degs, plans=plans)
     tables = {
         **stack_to_caps([(p.fwd_mats, p.fwd_inv) for p in plans],
                         n_src_rows, "bkt_fwd"),
         **stack_to_caps([(p.bwd_mats, p.bwd_inv) for p in plans],
                         sg.n_max, "bkt_bwd"),
     }
-    validate_bucket_tables(tables, sg.n_max, n_src_rows)
+    validate_bucket_tables(tables, sg.n_max, n_src_rows,
+                           n_edges=[int(d[0].sum()) for d in degs])
     return tables
 
 
+def _bucket_keys(tables, stem: str) -> List[str]:
+    """Keys of one direction's bucket tables ('<stem>_<b>'), in width
+    order, the inverse permutation left out."""
+    return sorted(k for k in tables
+                  if k.startswith(stem + "_") and not k.endswith("inv"))
+
+
+def table_edges(tables: Dict[str, np.ndarray], stem: str,
+                sentinel: int) -> np.ndarray:
+    """[P] edges each device's stacked tables of one direction hold:
+    their entries that are no sentinel."""
+    keys = _bucket_keys(tables, stem)
+    n_dev = tables[stem + "_inv"].shape[0]
+    return sum((np.count_nonzero(np.asarray(tables[k]) != sentinel,
+                                 axis=(1, 2)) for k in keys),
+               np.zeros(n_dev, np.int64))
+
+
+def pad_stats(tables: Dict[str, np.ndarray], stem: str, sentinel: int,
+              chunk_elems: int = DEFAULT_CHUNK_ELEMS,
+              f: int = SLAB_BYTES) -> dict:
+    """What one direction's stacked tables ('<stem>_<b>' [P, w, cap])
+    make the gathers issue: `widths` (the buckets that hold a row),
+    `slots` (sum over devices and buckets of w x the rows the kernel
+    gathers, chunk tails as bucket_aggregate cuts them at a slab of `f`
+    elements: the one-byte transports' 256, the most chunks), `edges`
+    (entries that are no sentinel) and their ratio `pad_ratio`: gather
+    requests an edge, the number the widths are fitted to lower."""
+    widths, slots = [], 0
+    for k in _bucket_keys(tables, stem):
+        n_dev, w, cap = tables[k].shape
+        rows, n_chunks = chunk_rows(w, cap, f, chunk_elems)
+        widths.append(int(w))
+        slots += n_dev * w * rows * n_chunks
+    edges = int(table_edges(tables, stem, sentinel).sum())
+    return {"widths": widths, "slots": int(slots), "edges": edges,
+            "pad_ratio": round(slots / max(edges, 1), 4)}
+
+
+def bucket_pad_stats(tables: Dict[str, np.ndarray], n_max: int,
+                     n_src_rows: int, stem: str = "bkt") -> dict:
+    """pad_stats of both directions of build_sharded_bucket_tables'
+    tables (or, stem 'blkrem', of the block kernel's remainder)."""
+    return {"fwd": pad_stats(tables, stem + "_fwd", n_src_rows),
+            "bwd": pad_stats(tables, stem + "_bwd", n_max)}
+
+
 def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
-                           n_src_rows: int) -> None:
-    """Host-side bounds check of sharded bucket tables ([P, ...] device
+                           n_src_rows: int,
+                           n_edges: Optional[Sequence[int]] = None,
+                           stem: str = "bkt") -> None:
+    """Host-side check of sharded bucket tables ([P, ...] device
     axis leading; tables slot-major [P, w, cap], so a bucket's
-    rows are its LAST axis): every index must lie in [0, bound] where bound is
+    rows are its LAST axis; stem 'blkrem' for the block kernel's
+    remainder).
+
+    Bounds: every index must lie in [0, bound] where bound is
     the consuming gather's zero-sentinel row. The device kernel gathers
     with mode='clip' ON THE STRENGTH OF THIS CHECK — an out-of-bounds
     index from a build bug or a rotted cache must surface HERE as a
     named ValueError at build/load time, never as a silently-clamped
     wrong row (or, under the previous fill-mode gathers, a NaN minted
-    mid-epoch). O(tables) numpy min/max — noise next to the O(E)
-    build."""
-    fwd_rows = sum(int(t.shape[-1]) for k, t in tables.items()
-                   if k.startswith("bkt_fwd_") and not k.endswith("inv"))
-    bwd_rows = sum(int(t.shape[-1]) for k, t in tables.items()
-                   if k.startswith("bkt_bwd_") and not k.endswith("inv"))
+    mid-epoch).
+
+    Edge conservation: with `n_edges` (the real edges of each device,
+    what the tables were built from) every direction must hold exactly
+    that many entries that are no sentinel, device by device. A row's
+    tail lost to a width too short, or an entry overwritten by the
+    sentinel, changes a mean by less than the transports' own noise,
+    so no comparison of outputs sees it (PERF.md section 2): it is
+    counted here. Without `n_edges` the two directions are still held
+    to each other.
+
+    O(tables) numpy passes — noise next to the O(E) build."""
+    fwd, bwd = stem + "_fwd", stem + "_bwd"
+    rows = {d: sum(int(tables[k].shape[-1]) for k in _bucket_keys(tables, d))
+            for d in (fwd, bwd)}
     for k, t in tables.items():
-        if k == "bkt_fwd_inv":
-            hi = fwd_rows          # + the appended zero sentinel row
-        elif k == "bkt_bwd_inv":
-            hi = bwd_rows
-        elif k.startswith("bkt_fwd_"):
+        if k == fwd + "_inv":
+            hi = rows[fwd]         # + the appended zero sentinel row
+        elif k == bwd + "_inv":
+            hi = rows[bwd]
+        elif k.startswith(fwd + "_"):
             hi = n_src_rows        # fbuf_pad's zero sentinel row
-        elif k.startswith("bkt_bwd_"):
+        elif k.startswith(bwd + "_"):
             hi = n_max
         else:
             continue
@@ -722,6 +965,19 @@ def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
                 f"[{lo_v}, {hi_v}] (valid range [0, {hi}]): corrupt "
                 f"table cache or a table-build bug — rebuild the "
                 f"partition artifact's cached tables")
+    held = {fwd: table_edges(tables, fwd, n_src_rows),
+            bwd: table_edges(tables, bwd, n_max)}
+    want = held[fwd] if n_edges is None else np.asarray(n_edges, np.int64)
+    for d, got in held.items():
+        if not np.array_equal(got, want):
+            raise ValueError(
+                f"bucket tables {d!r} hold {got.tolist()} edges a device "
+                f"where {want.tolist()} were "
+                + ("given" if n_edges is not None else
+                   f"put into {fwd!r}")
+                + ": an edge was dropped or overwritten (a table-build "
+                  "bug or a corrupt table cache) — rebuild the partition "
+                  "artifact's cached tables")
 
 
 def make_device_bucket_spmm_fn(d: Dict[str, jax.Array], in_deg: jax.Array,
@@ -733,10 +989,8 @@ def make_device_bucket_spmm_fn(d: Dict[str, jax.Array], in_deg: jax.Array,
     """Bind the per-device blocks of build_sharded_bucket_tables (call
     inside shard_map, after stripping the leading device axis) into the
     differentiable closure."""
-    fwd_mats = [d[k] for k in sorted(d) if k.startswith("bkt_fwd_")
-                and not k.endswith("inv")]
-    bwd_mats = [d[k] for k in sorted(d) if k.startswith("bkt_bwd_")
-                and not k.endswith("inv")]
+    fwd_mats = [d[k] for k in _bucket_keys(d, "bkt_fwd")]
+    bwd_mats = [d[k] for k in _bucket_keys(d, "bkt_bwd")]
     return make_bucket_spmm_fn(
         fwd_mats, d["bkt_fwd_inv"], bwd_mats, d["bkt_bwd_inv"],
         in_deg, n_src_rows, chunk_elems, chunk_edges, rem_dtype,

@@ -47,7 +47,8 @@ from .bucket_spmm import (
     DEFAULT_CHUNK_ELEMS,
     SLAB_BYTES,
     BucketPlan,
-    _bucket_widths,
+    degree_hist,
+    fit_widths,
 )
 
 
@@ -89,16 +90,13 @@ def build_sharded_gat_tables(sg) -> Dict[str, np.ndarray]:
     P = sg.num_parts
     n_src_rows = sg.n_max + sg.halo_size
 
-    max_in, max_out = 1, 1
-    for r in range(P):
-        real = sg.edge_dst[r] < sg.n_max
-        if real.any():
-            di = np.bincount(sg.edge_dst[r][real], minlength=sg.n_max)
-            do = np.bincount(sg.edge_src[r][real], minlength=n_src_rows)
-            max_in = max(max_in, int(di.max(initial=1)))
-            max_out = max(max_out, int(do.max(initial=1)))
-    fw = _bucket_widths(max_in)
-    bw = _bucket_widths(max_out)
+    # one ladder a direction, fitted to the degree histograms of all
+    # the shards (bucket_spmm.fit_widths)
+    real = [sg.edge_dst[r] < sg.n_max for r in range(P)]
+    fw = fit_widths(degree_hist(
+        np.bincount(sg.edge_dst[r][real[r]]) for r in range(P)))
+    bw = fit_widths(degree_hist(
+        np.bincount(sg.edge_src[r][real[r]]) for r in range(P)))
 
     plans = [
         BucketPlan(sg.edge_src[r], sg.edge_dst[r], sg.n_max, n_src_rows,

@@ -21,11 +21,25 @@ replaces drew ~400 single rows uniformly and renumbered them, which
 left no tile dense enough for the MXU path, and its 200k edges were
 4 ms of device work, the size of one host round trip — on the chip it
 ranked the 3.4x faster block kernel 1.6x slower (PERF.md, PR 21).
-The per-SpMM cost is scaled back by shard_edges / sample_edges for
-reporting; the ranking is taken on the measured numbers directly, and
-a near-tie (candidates the spread of the fastest one's reps cannot
-tell from it) falls to the fixed preference order, ``DEFAULT_IMPL``'s
-family first.
+
+What is ranked (``shard_estimate``): what a call would cost AT THE
+SHARD'S SIZE, never the milliseconds of a sampled call. A sample keeps
+a few thousand destination rows and the WHOLE source space, so most of
+a sampled call is work that follows the source rows (packing the table
+into words, the backward's visit of every source row, the block
+kernel's tile padding) and does not grow with the edges: on Reddit the
+1M-edge sample put the bucket kernel with fp8 transport 2 ms behind the
+block kernel, a near-tie, where at the shard's 114.85M edges it costs
+twice the epoch (PERF.md section 6, PR 34). So every candidate is timed
+on TWO nested samples of the same source space (the budget's blocks and
+a quarter of them), the line through the two best reps gives
+``per_edge_s`` and ``fixed_s``, and the ranking is on ``est_call_s =
+fixed_s + per_edge_s * shard_edges``. A near-tie (candidates the
+argmin's own spread, carried through the same arithmetic, cannot tell
+from it) falls to the fixed preference order, ``DEFAULT_IMPL``'s family
+first. A shard under the budget is timed whole, once: its estimate is
+its time. A sample of a single block has no smaller one: its time is
+read in proportion to the edges.
 
 Timing follows the microbench idiom (scripts/spmm_microbench.py):
 tables ride as jit ARGUMENTS, never closure constants (closed-over
@@ -35,10 +49,7 @@ stops after the device does. That scalar is fed by the forward's
 output AND by the gradient under a real cotangent, over the widest
 operand an aggregation inside the step sees: the aggregation is
 linear, so a program that returns the gradient alone has its forward
-removed as dead code (what format 1 timed). The fixed cost of a call
-is measured once per campaign with an empty program
-(``call_overhead_s`` of the record) and taken off the per-epoch
-estimates.
+removed as dead code (what format 1 timed).
 
 Staleness: a persisted table is trusted only when its tuner format,
 source-graph edge checksum AND config signature (backend, feature
@@ -53,11 +64,17 @@ import json
 import os
 import time
 from types import SimpleNamespace
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
-# 5: the grid lost its three streaming-slab twins with the path they
+# 6: candidates are ranked by est_call_s, the estimate of a call at the
+# shard's size from two nested samples (shard_estimate), and the row
+# buckets' widths are fitted to the degree histogram, their chunks
+# balanced (bucket_spmm fit_widths, chunk_rows; PR 34): a table of
+# format 5 ranks sampled milliseconds, of costs the candidates no
+# longer have. 5: the grid lost its three streaming-slab twins with the path they
 # timed (PR 32): a table of format 4 was ranked over another grid and
 # may name a candidate that no longer exists. 4: one-byte transports
 # are gathered as 16-bit words (bucket_spmm _pack_words): every fp8
@@ -69,29 +86,39 @@ import numpy as np
 # they no longer have. 2: the sample keeps whole destination tile-rows
 # (sample_slice); a table timed on the row-wise sample of format 1 is
 # stale
-TUNER_FORMAT = 5
+TUNER_FORMAT = 6
 TUNING_FILE = "tuning.json"
 
-# the sample takes whole blocks of destination rows until this many
-# edges are covered; the CLI surfaces it as --tuner-samples. Sized
-# from both ends on a v5e (PERF.md section 6, PR 27): an empty call
-# costs 1.3 to 4.1 ms, and at 1M edges the fastest candidate's call is
-# about five times that and the slowest thirty; the compile of a
-# candidate's program grows with the sample's shapes (16 of them took
-# 227 to 342 s at 2M edges), so no larger than the clock needs
-DEFAULT_EDGE_BUDGET = 1_000_000
+# the larger sample takes whole blocks of destination rows until this
+# many edges are covered; the CLI surfaces it as --tuner-samples. Sized
+# on a v5e by where a candidate's time becomes a line in its edges
+# (PERF.md section 6, PR 34: nested samples of 1 to 16 blocks of the
+# Reddit shape, 0.5M edges a block): from 1M edges up every candidate's
+# slope reads within a quarter of its cost an edge at the shard's size
+# (`block-f8` 1.55 ns between 1M and 4M for 1.53 traced, `bucket-f8`
+# 5.5 for 5.2), while at ONE block the block candidates with fp8
+# transport read 2 ms under their line (4.5 ms for 6.4), which made
+# the 1M / 0.5M pair rank them 3.4 times too dear. So 4M, whose nested
+# quarter is the 1M point; a candidate costs 4 to 8 s to build, compile
+# and time there, and 2 to 3 s at the quarter
+DEFAULT_EDGE_BUDGET = 4_000_000
 
 # what a tuning record says about its sample beside the cost table
 # (the contracted `tuning` record of the metrics stream carries them):
 # the dense coverage the block candidates met on the sample against the
-# whole shard's, the destination tile-rows sampled, the fixed cost of a
-# timed call, and the winner's estimate, to hold against a traced
-# spmm_s (it reads high by what a call costs whatever its edges: the
-# backward visits every source row of the shard, and shard / sample
-# edges multiplies that too)
+# whole shard's, the destination tile-rows sampled, the edges of the
+# timed samples (two, nested; one where the shard was timed whole) and
+# of the shard the estimates are made for, and the winner's estimate,
+# to hold against a traced spmm_s
 SAMPLE_FIELDS = ("sample_dense_coverage", "shard_dense_coverage",
-                 "sample_tile_rows", "call_overhead_s",
+                 "sample_tile_rows", "timed_edges", "shard_edges",
                  "est_epoch_spmm_s")
+
+# the second, nested sample: this share of the first one's blocks
+# (never under one). A quarter keeps the two points of the line far
+# apart (the slope's error is the clock's over their distance) at a
+# fifth more timed work
+NESTED_FRACTION = 0.25
 
 # tiles per sampled block when the grid is not at hand (choose_reorder,
 # tests): candidate_grid's largest default group
@@ -126,7 +153,8 @@ def clear_memo() -> None:
 
 
 def sample_slice(sg, edge_budget: int = DEFAULT_EDGE_BUDGET,
-                 seed: int = 0, block_rows: int = 256 * _SAMPLE_GROUP):
+                 seed: int = 0, block_rows: int = 256 * _SAMPLE_GROUP,
+                 blocks: Optional[Sequence[int]] = None):
     """A 1-part ShardedGraph-shaped view of the heaviest shard's edges
     that keeps the shard's tile structure.
 
@@ -140,14 +168,16 @@ def sample_slice(sg, edge_budget: int = DEFAULT_EDGE_BUDGET,
     are: a halo source stays behind the shard's n_max, and every
     (destination tile, source tile) pair of a sampled tile-row holds
     the edges it holds on the shard. A shard under the budget is taken
-    whole, ids in place.
+    whole, ids in place. `blocks` names the blocks to keep instead of
+    drawing them (nested_blocks: the second, smaller sample of a
+    campaign, out of the first one's blocks).
 
     The result quacks like a ShardedGraph for the sharded table
     builders: num_parts=1, n_max = the sampled rows, halo_size = what
     is left of the shard's source id space behind them.
 
     Returns (sample, info); info carries sample_edges / shard_edges /
-    full_edges / scale and the sampled blocks' first rows.
+    full_edges and the sampled blocks' first rows.
     """
     r = int(np.argmax(np.asarray(sg.edge_count)))
     ec = int(sg.edge_count[r])
@@ -163,7 +193,9 @@ def sample_slice(sg, edge_budget: int = DEFAULT_EDGE_BUDGET,
     n_blocks = -(-int(sg.n_max) // block_rows)
     blk = ed // block_rows
     occupied = np.flatnonzero(np.bincount(blk, minlength=n_blocks))
-    if shard_edges > edge_budget and occupied.size > 1:
+    if blocks is not None:
+        chosen = np.asarray(blocks, dtype=np.int64)
+    elif shard_edges > edge_budget and occupied.size > 1:
         k = -(-int(edge_budget) * occupied.size // shard_edges)
         k = min(max(k, 1), int(occupied.size))
         rng = np.random.default_rng(seed)
@@ -208,15 +240,29 @@ def sample_slice(sg, edge_budget: int = DEFAULT_EDGE_BUDGET,
         "sample_edges": int(new_src.size),
         "sample_rows": n_dst,
         "shard_edges": shard_edges,
-        "full_edges": int(np.sum(np.asarray(sg.edge_count))),
         # a device runs its own shard: the heaviest one's edges, not
-        # the graph's, are what a per-epoch estimate scales to
-        "scale": shard_edges / max(1, int(new_src.size)),
+        # the graph's, are what an estimate is made for
+        "full_edges": int(np.sum(np.asarray(sg.edge_count))),
         "sampled_rank": r,
         "block_rows": block_rows,
         "block_starts": [int(x) for x in starts],
     }
     return sample, info
+
+
+def nested_blocks(info: Dict[str, Any],
+                  fraction: float = NESTED_FRACTION) -> List[int]:
+    """The blocks of the second, smaller sample of a campaign: every
+    n-th block of the sample `info` describes, `fraction` of them and
+    never under one, so both samples spread over the same row range and
+    share the source space. All of them where no smaller sample exists
+    (one block, or the shard taken whole)."""
+    blocks = [s // info["block_rows"] for s in info["block_starts"]]
+    if info["sample_edges"] >= info["shard_edges"]:
+        return blocks
+    k = max(1, int(round(len(blocks) * fraction)))
+    picks = np.linspace(0, len(blocks) - 1, k).round().astype(int)
+    return [blocks[i] for i in picks]
 
 
 # ---------------------------------------------------------------------
@@ -283,16 +329,6 @@ def _timed_reps(program, args, reps: int) -> List[float]:
         float(program(*args))
         ts.append(time.perf_counter() - t0)
     return ts
-
-
-def call_overhead(fbuf, reps: int = 5) -> float:
-    """The fixed cost of one timed call: a program that only reads the
-    operand, dispatched and read back the way _time_candidate does."""
-    import jax
-    import jax.numpy as jnp
-
-    empty = jax.jit(lambda f: f.astype(jnp.float32).sum())
-    return min(_timed_reps(empty, (fbuf,), reps))
 
 
 def _time_candidate(sample, cand: Dict[str, Any], width: int, *,
@@ -433,11 +469,50 @@ def shard_size_refusal(sg, r: int, width: int,
     return None
 
 
+def shard_estimate(best: Sequence[float], spread: Sequence[float],
+                   edges: Sequence[int],
+                   shard_edges: int) -> Dict[str, Optional[float]]:
+    """One candidate's call at the shard's size, from its timed samples
+    (largest first): `best` rep and `spread` (a typical rep over it) on
+    each, of `edges` edges. Returns fixed_s, per_edge_s, est_call_s and
+    est_spread_s.
+
+    Two samples: the line through the two best reps, read at the
+    shard's edges. What follows the source rows of a call (both samples
+    keep the whole source space) lands in `fixed_s` and is not
+    multiplied; what follows the edges in `per_edge_s`. A line that
+    would cross zero before the smaller sample (the clock's noise on a
+    short call) is taken through the origin instead: the proportional
+    estimate, which no fixed cost can exceed. The estimate is
+    t1 * (1 + k) - t2 * k in the two timed calls, so that is what their
+    spreads move it by.
+
+    One sample, no line: the shard whole (its time is the estimate) or
+    a single block, read in proportion to the edges."""
+    t1, e1 = best[0], edges[0]
+    if len(best) == 1:
+        k = shard_edges / e1
+        return {"fixed_s": None, "per_edge_s": None,
+                "est_call_s": t1 * k, "est_spread_s": spread[0] * k}
+    t2, e2 = best[1], edges[1]
+    per_edge = max(t1 - t2, 0.0) / (e1 - e2)
+    fixed = t1 - per_edge * e1
+    if fixed < 0.0:
+        fixed, per_edge = 0.0, t1 / e1
+    k = (shard_edges - e1) / (e1 - e2)
+    return {"fixed_s": fixed, "per_edge_s": per_edge,
+            "est_call_s": fixed + per_edge * shard_edges,
+            "est_spread_s": spread[0] * (1 + k) + spread[1] * k}
+
+
 def pick_winner(costs: List[Dict[str, Any]]) -> Dict[str, Any]:
-    """The measured argmin, unless it is a near-tie: a candidate whose
-    best rep is no slower than the argmin's slowest (the ranges of
-    their reps overlap) cannot be told from it by this clock, and the
-    choice among those falls to the fixed preference order —
+    """The argmin of `est_call_s`, the estimate of a call at the
+    shard's size, unless it is a near-tie: a candidate whose estimate
+    is no higher than the argmin's plus the argmin's spread (how far
+    a typical rep of its timed calls reads over the best one, carried
+    through the estimate's arithmetic: `est_spread_s`) cannot be told
+    from it by this clock,
+    and the choice among those falls to the fixed preference order —
     DEFAULT_IMPL's family first, then the order of the grid (plain
     transport before the narrower ones). A candidate's own noise never
     makes it a tie: only the argmin's spread widens the set. A
@@ -449,9 +524,9 @@ def pick_winner(costs: List[Dict[str, Any]]) -> Dict[str, Any]:
     if not ok:
         return {"name": DEFAULT_IMPL, "impl": DEFAULT_IMPL,
                 "rem_dtype": None, "rem_amax": False, "block_group": 1}
-    best = min(ok, key=lambda c: c["spmm_fwdbwd_s"])
-    slowest = best["spmm_fwdbwd_s"] + best.get("spread_s", 0.0)
-    tied = [c for c in ok if c["spmm_fwdbwd_s"] <= slowest]
+    best = min(ok, key=lambda c: c["est_call_s"])
+    highest = best["est_call_s"] + best.get("est_spread_s", 0.0)
+    tied = [c for c in ok if c["est_call_s"] <= highest]
     return min(tied, key=lambda c: c["impl"] != DEFAULT_IMPL)
 
 
@@ -505,7 +580,7 @@ def tune(sg, width: int, *, block_tile: int = 256,
          chunk_edges: Optional[int] = None, bucket_merge: int = 0,
          rng_impl: str = "threefry", halo_dtype: str = "none",
          epoch_block: int = 0,
-         edge_budget: int = DEFAULT_EDGE_BUDGET, reps: int = 2,
+         edge_budget: int = DEFAULT_EDGE_BUDGET, reps: int = 5,
          seed: int = 0, step_width: Optional[int] = None,
          spmm_per_epoch: int = _SPMM_PER_EPOCH,
          log: Optional[Callable[[str], None]] = None) -> Dict[str, Any]:
@@ -518,8 +593,10 @@ def tune(sg, width: int, *, block_tile: int = 256,
     are built for it. The candidates are timed over `step_width`
     columns, the widest operand an aggregation INSIDE the step sees
     (under use_pp the first layer's, the widest on a wide-feature
-    graph, is precomputed once), and the per-epoch estimates count
-    `spmm_per_epoch` such aggregations."""
+    graph, is precomputed once), on two nested samples where the shard
+    is over `edge_budget` (module docstring): each is ranked by
+    `est_call_s`, its call at the shard's size, and the per-epoch
+    estimates count `spmm_per_epoch` such calls."""
     step_width = int(step_width or width)
     sig = signature_for(width=width, step_width=step_width,
                         block_tile=block_tile,
@@ -546,11 +623,26 @@ def tune(sg, width: int, *, block_tile: int = 256,
     group = max(c["block_group"] for c in cands)
     sample, info = sample_slice(sg, edge_budget=edge_budget, seed=seed,
                                 block_rows=block_tile * group)
-    # the block builder's dense-A budget is the shard's: the sample
+    shard_edges = info["shard_edges"]
+    # the timed samples, largest first: the budget's blocks and, where
+    # the shard is larger than they are, a nested share of them
+    # (shard_estimate needs two sizes; a shard timed whole needs none,
+    # and a sample of one block has no smaller one)
+    samples = [(sample, info["sample_edges"])]
+    fewer = nested_blocks(info)
+    if len(fewer) < len(info["block_starts"]):
+        small, small_info = sample_slice(
+            sg, block_rows=block_tile * group, blocks=fewer)
+        samples.append((small, small_info["sample_edges"]))
+    timed_edges = [e for _, e in samples]
+
+    # the block builder's dense-A budget is the shard's: a sample
     # gets the share its rows are of the shard's, so a budget that
     # spills tiles on the shard spills them on the sample too
-    byte_budget = max(1, int(DENSE_A_BYTE_BUDGET * sample.n_max
-                             / max(1, sg.n_max)))
+    def byte_budget(smp) -> int:
+        return max(1, int(DENSE_A_BYTE_BUDGET * smp.n_max
+                          / max(1, sg.n_max)))
+
     # did the sample carry the tiles? Dense coverage of the sampled
     # rows against the whole shard's, at the tile, threshold and
     # budget the block candidates are built with
@@ -564,64 +656,75 @@ def tune(sg, width: int, *, block_tile: int = 256,
                                     bits))[0]
     sample_cov = _part_block_stats(
         sample, 0, block_tile, n_src_tiles, thr,
-        max_blocks=budget_block_cap(byte_budget, block_tile, bits))[0]
+        max_blocks=budget_block_cap(byte_budget(sample), block_tile,
+                                    bits))[0]
 
+    # every sample keeps the shard's source id space: one operand
     fbuf = _operand(sample, step_width)
-    overhead = call_overhead(fbuf)
     if log:
-        log(f"# tuner: sample {info['sample_edges']} edges in "
-            f"{len(info['block_starts'])} blocks of "
-            f"{info['block_rows']} rows; dense coverage "
-            f"{sample_cov:.3f} (shard {shard_cov:.3f}); empty call "
-            f"{overhead * 1e3:.2f} ms")
+        log(f"# tuner: samples of {timed_edges} edges "
+            f"({len(info['block_starts'])} blocks of "
+            f"{info['block_rows']} rows the largest) for a shard of "
+            f"{shard_edges}; dense coverage {sample_cov:.3f} "
+            f"(shard {shard_cov:.3f})")
 
-    def est_epoch(s: float) -> float:
-        return round(max(s - overhead, 0.0) * info["scale"]
-                     * spmm_per_epoch, 6)
-
-    tables: Dict[Tuple, Any] = {}
+    tables: List[Dict[Tuple, Any]] = [{} for _ in samples]
     costs: List[Dict[str, Any]] = []
     for cand in cands:
         entry = dict(cand)
         try:
-            ts = _time_candidate(
-                sample, cand, step_width, block_tile=block_tile,
-                block_nnz=block_nnz, chunk_edges=chunk_edges,
-                bucket_merge=bucket_merge, reps=reps, fbuf=fbuf,
-                table_width=width, byte_budget=byte_budget,
-                tables=tables)
-            s = min(ts)
-            entry["spmm_fwdbwd_s"] = s
-            entry["spread_s"] = max(ts) - s
-            entry["est_epoch_spmm_s"] = est_epoch(s)
+            reps_best, reps_spread = [], []
+            for (smp, _), tabs in zip(samples, tables):
+                ts = _time_candidate(
+                    smp, cand, step_width, block_tile=block_tile,
+                    block_nnz=block_nnz, chunk_edges=chunk_edges,
+                    bucket_merge=bucket_merge, reps=reps, fbuf=fbuf,
+                    table_width=width, byte_budget=byte_budget(smp),
+                    tables=tabs)
+                reps_best.append(min(ts))
+                # a typical rep over the best one. Not the slowest: one
+                # rep in a hundred is a host stall of many times the
+                # call (117 ms for 24.5, PERF.md section 6, PR 34), and
+                # the estimate multiplies a spread as it does a time
+                reps_spread.append(float(np.median(ts)) - min(ts))
+            entry["spmm_fwdbwd_s"] = reps_best[0]
+            entry["spread_s"] = reps_spread[0]
+            entry.update(shard_estimate(reps_best, reps_spread,
+                                        timed_edges, shard_edges))
+            est, per_edge = entry["est_call_s"], entry["per_edge_s"]
+            entry["est_epoch_spmm_s"] = round(est * spmm_per_epoch, 6)
             entry["error"] = None
-            if cand["impl"] == "xla" and info["scale"] > 1:
+            if cand["impl"] == "xla" and timed_edges[0] < shard_edges:
                 entry["out_of_domain"] = shard_size_refusal(
                     sg, info["sampled_rank"], step_width, chunk_edges)
             if log:
-                log(f"# tuner: {cand['name']:16s} {s * 1e3:8.2f} ms "
-                    f"(est epoch SpMM "
-                    f"{entry['est_epoch_spmm_s']:.3f} s)"
+                log(f"# tuner: {cand['name']:16s} "
+                    f"{reps_best[0] * 1e3:8.2f} ms sampled"
+                    + (f", {per_edge * 1e9:7.3f} ns an edge + "
+                       f"{entry['fixed_s'] * 1e3:7.2f} ms"
+                       if per_edge is not None else "")
+                    + f": a call at the shard's size {est:.4f} s "
+                      f"(+- {entry['est_spread_s']:.4f})"
                     + (f" OUT OF DOMAIN at the shard's size: "
                        f"{entry['out_of_domain']}"
                        if entry.get("out_of_domain") else ""))
         except Exception as exc:  # noqa: BLE001 — a crashing candidate
             # is a RESULT (out-of-domain config), not a tuner failure
-            entry["spmm_fwdbwd_s"] = None
-            entry["spread_s"] = None
-            entry["est_epoch_spmm_s"] = None
+            entry.update(dict.fromkeys(
+                ("spmm_fwdbwd_s", "spread_s", "fixed_s", "per_edge_s",
+                 "est_call_s", "est_spread_s", "est_epoch_spmm_s")))
             entry["error"] = repr(exc)[:200]
             if log:
                 log(f"# tuner: {cand['name']:16s} FAILED: "
                     f"{entry['error']}")
         costs.append(entry)
 
-    best = pick_winner(costs)
+    winner = pick_winner(costs)
     record = {
         "tuner_format": TUNER_FORMAT,
         "source_edge_checksum": checksum,
         "signature": sig,
-        "winner": {k: best.get(k, False) for k in
+        "winner": {k: winner.get(k, False) for k in
                    ("name", "impl", "rem_dtype", "rem_amax",
                     "block_group")},
         "costs": costs,
@@ -630,8 +733,8 @@ def tune(sg, width: int, *, block_tile: int = 256,
         "sample_dense_coverage": round(sample_cov, 6),
         "shard_dense_coverage": round(shard_cov, 6),
         "sample_tile_rows": -(-info["sample_rows"] // block_tile),
-        "call_overhead_s": overhead,
-        "est_epoch_spmm_s": best.get("est_epoch_spmm_s"),
+        "timed_edges": timed_edges,
+        "est_epoch_spmm_s": winner.get("est_epoch_spmm_s"),
         "time_unix": time.time(),
         **info,
     }

@@ -54,21 +54,18 @@ def _ladder_caps(edge_src_by_rank, edge_dst_by_rank, P, n_max,
     """Shared bucket ladders + per-bucket row caps WITHOUT building any
     tables: one cheap degree-histogram pass per rank (the streamed
     analogue of build_sharded_bucket_tables's cap scan)."""
-    from ..ops.bucket_spmm import _bucket_widths, row_cap
+    from ..ops.bucket_spmm import degree_hist, fit_widths, row_cap
 
-    max_in = max_out = 1
     hists = []
     for r in range(P):
         src = np.asarray(edge_src_by_rank(r))
         dst = np.asarray(edge_dst_by_rank(r))
         real = dst < n_max
-        di = np.bincount(dst[real], minlength=n_max)
-        do = np.bincount(src[real], minlength=n_src_rows)
-        max_in = max(max_in, int(di.max(initial=1)))
-        max_out = max(max_out, int(do.max(initial=1)))
-        hists.append((di, do))
-    fw = _bucket_widths(max_in)
-    bw = _bucket_widths(max_out)
+        hists.append((np.bincount(dst[real], minlength=n_max),
+                      np.bincount(src[real], minlength=n_src_rows)))
+    # one ladder a direction, fitted to the histograms of every rank
+    fw = fit_widths(degree_hist(di for di, _ in hists))
+    bw = fit_widths(degree_hist(do for _, do in hists))
 
     def counts(deg, widths):
         w = np.asarray(widths, np.int64)

@@ -291,7 +291,9 @@ class Trainer:
     # bump when any kernel-table layout changes: stale caches must miss
     # v7: bucket and block-remainder tables slot-major [P, w, cap], cap a
     # multiple of 32 (ops/bucket_spmm.py)
-    _TABLES_FORMAT = 7
+    # v8: row-bucket widths fitted to the degree histogram
+    # (bucket_spmm.fit_widths): tables of the x1.5 ladder must miss
+    _TABLES_FORMAT = 8
 
     def _cached_tables(self, kind: str, build_fn):
         """Disk-cache derived kernel tables next to the partition
@@ -391,6 +393,9 @@ class Trainer:
         self._gat_tables = None
         self.tuning = None
         self.tables_source = None  # set by _cached_tables
+        # gather slots an edge and the widths chosen, per direction, of
+        # the tables in use (bucket_spmm.pad_stats), built or loaded
+        self.tables_pad = None
         if impl not in ("xla", "auto", "bucket", "block"):
             raise ValueError(f"unknown spmm_impl: {impl}")
         if self.cfg.model == "gat":
@@ -426,7 +431,8 @@ class Trainer:
             self._use_block()
 
     def _use_bucket(self, dirty=None) -> None:
-        from ..ops.bucket_spmm import (build_sharded_bucket_tables,
+        from ..ops.bucket_spmm import (bucket_pad_stats,
+                                       build_sharded_bucket_tables,
                                        validate_bucket_tables)
 
         merge = int(getattr(self.cfg, "bucket_merge", 0))
@@ -440,12 +446,20 @@ class Trainer:
                 dirty=dirty))
         # the kernel's clip-mode gathers are sound only for
         # in-bounds tables; a rotted cache must fail HERE, loudly,
-        # not clamp to wrong rows mid-epoch
-        validate_bucket_tables(self._bucket_tables, self.sg.n_max,
-                               self.sg.n_max + self.sg.halo_size)
+        # not clamp to wrong rows mid-epoch, and a table that lost an
+        # edge must not train at all
+        n_src_rows = self.sg.n_max + self.sg.halo_size
+        validate_bucket_tables(
+            self._bucket_tables, self.sg.n_max, n_src_rows,
+            n_edges=[int(np.count_nonzero(d < self.sg.n_max))
+                     for d in self.sg.edge_dst])
+        self.tables_pad = bucket_pad_stats(
+            self._bucket_tables, self.sg.n_max, n_src_rows)
 
     def _use_block(self) -> None:
         from ..ops.block_spmm import build_sharded_block_tables
+        from ..ops.bucket_spmm import (bucket_pad_stats,
+                                       validate_bucket_tables)
 
         w_hint = max(self.cfg.layer_sizes[:self.cfg.n_graph_layers])
         tile = self.cfg.block_tile
@@ -459,6 +473,14 @@ class Trainer:
                 self.sg, tile=tile, n_feat_hint=w_hint,
                 nnz_threshold=nnz, group=grp)[0])
         self._block_tile = tile
+        # the remainder's tables, built or loaded: in bounds, and both
+        # directions hold the same edges (the builder counted them
+        # against the edges the dense blocks left)
+        n_src_rows = self.sg.n_max + self.sg.halo_size
+        validate_bucket_tables(self._block_tables, self.sg.n_max,
+                               n_src_rows, stem="blkrem")
+        self.tables_pad = bucket_pad_stats(
+            self._block_tables, self.sg.n_max, n_src_rows, stem="blkrem")
 
     def _resolve_auto(self) -> str:
         """Pick the concrete kernel for spmm_impl='auto' from measured
@@ -714,6 +736,15 @@ class Trainer:
         # per-shard BucketPlan cache for dirty-shard-only rebuilds
         # (_use_bucket passes it through to build_sharded_bucket_tables)
         self._bucket_plan_cache: dict = {}
+        if self._bucket_tables is not None:
+            # the first delta keeps the widths the step was compiled
+            # for, as every later one does (a dirty rebuild refits only
+            # a ladder that a row has outgrown)
+            self._bucket_plan_cache.update(
+                shape=(self.sg.n_max, self.sg.n_max + self.sg.halo_size),
+                min_width=int(getattr(self.cfg, "bucket_merge", 0)),
+                widths=tuple(tuple(self.tables_pad[d]["widths"])
+                             for d in ("fwd", "bwd")))
         # topology generation: bumped once per applied DeltaBatch, and
         # stamped into checkpoints (the journal watermark) so every
         # resume path knows which graph the params trained against
@@ -771,7 +802,13 @@ class Trainer:
                 self._gat_tables = self._cached_tables(
                     "gat", lambda: build_sharded_gat_tables(self.sg))
                 rebuilt += self.P
+            tables_before = set(self.data)
             self.data = self._put_data(skip_edges=self._edges_trimmed)
+            if set(self.data) != tables_before:
+                # a kernel's widths were refitted (a row outgrew the
+                # ladder's top width) or a bucket emptied or filled:
+                # the step's in_specs name the tables one by one
+                self._step = self._build_step()
             self._flush_comm_rows(report)
         if self.cfg.compute_dtype != jnp.float32:
             self.data["feat"] = self.data["feat"].astype(
@@ -1854,7 +1891,8 @@ class Trainer:
             metrics.run_header(
                 config={"model": dataclasses.asdict(self.cfg),
                         "train": dataclasses.asdict(self.tcfg)},
-                device=device_info(), mesh=mesh_info(self.mesh))
+                device=device_info(), mesh=mesh_info(self.mesh),
+                tables_pad=self.tables_pad)
         # ---- tuner decision (set at _setup_spmm for spmm_impl='auto'):
         # surface WHY this kernel dispatches, once per run ----
         if getattr(self, "tuning", None) is not None and \

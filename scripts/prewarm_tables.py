@@ -39,7 +39,7 @@ def main():
     ap.add_argument("--group", type=int, default=1)
     ap.add_argument("--block-nnz", type=int, default=0)
     ap.add_argument("--bucket-merge", type=int, default=0)
-    ap.add_argument("--tuner-samples", type=int, default=1_000_000)
+    ap.add_argument("--tuner-samples", type=int, default=4_000_000)
     ap.add_argument("--retune", action="store_true",
                     help="with --impl auto: delete any persisted "
                          "tuning.json first and force a fresh "

@@ -548,3 +548,123 @@ def test_remainder_scopes_carry_the_rem_prefix(edges):
         debug_info=True)
     names = set(re.findall(r"rem_(?:gather|reduce|unpermute)(?=/)", txt))
     assert names == {"rem_gather", "rem_reduce", "rem_unpermute"}
+
+
+# ---------------- the remainder's widths are fitted; the K classes' are not --
+
+def _clustered_shards(n_parts, seed=3):
+    """Shards with a dense diagonal of (dst-tile, src-tile) blocks and
+    a remainder whose degrees differ by shard."""
+    from types import SimpleNamespace
+
+    rng = np.random.default_rng(seed)
+    n_max, halo, tile = 128, 32, 16
+    srcs, dsts = [], []
+    for r in range(n_parts):
+        s, d = [], []
+        for t in range(n_max // tile):       # 60 edges a diagonal block
+            d.append(rng.integers(0, tile, 60) + t * tile)
+            s.append(rng.integers(0, tile, 60) + t * tile)
+        degs = rng.poisson(3 + 4 * r, n_max)
+        d.append(np.repeat(np.arange(n_max), degs))
+        s.append(rng.integers(0, n_max + halo, degs.sum()))
+        srcs.append(np.concatenate(s))
+        dsts.append(np.concatenate(d))
+    e_max = max(a.size for a in srcs)
+    pad = [e_max - a.size for a in srcs]
+    return SimpleNamespace(
+        num_parts=n_parts, n_max=n_max, halo_size=halo,
+        edge_count=np.asarray([a.size for a in srcs]),
+        edge_src=np.stack([np.concatenate([a, np.zeros(p, np.int64)])
+                           for a, p in zip(srcs, pad)]).astype(np.int32),
+        edge_dst=np.stack([np.concatenate([a, np.full(p, n_max)])
+                           for a, p in zip(dsts, pad)]).astype(np.int32))
+
+
+@pytest.mark.parametrize("group", [1, 2])
+@pytest.mark.parametrize("n_parts", [1, 4])
+def test_sharded_block_tables_fit_the_remainder_only(n_parts, group):
+    """build_sharded_block_tables fits ONE remainder ladder a direction
+    to the remainder histograms of all the shards (known after the
+    dense selection); the dense K classes keep the x1.5 ladder; every
+    device's kernel equals the dense mean, forward and VJP; and
+    bucket_pad_stats reads the remainder's padding off the tables."""
+    from pipegcn_tpu.ops.block_spmm import (
+        build_sharded_block_tables,
+        make_device_block_spmm_fn,
+    )
+    from pipegcn_tpu.ops.bucket_spmm import (bucket_pad_stats, degree_hist,
+                                             fit_widths, ladder_prefix)
+
+    sg = _clustered_shards(n_parts)
+    n_src = sg.n_max + sg.halo_size
+    f, tile = 8, 16
+    tabs, _ = build_sharded_block_tables(sg, tile=tile, n_feat_hint=f,
+                                         nnz_threshold=20, group=group)
+    plans = [BlockPlan(sg.edge_src[r], sg.edge_dst[r], sg.n_max, n_src, f,
+                       tile=tile, nnz_threshold=20, group=group)
+             for r in range(n_parts)]
+    assert all(p.a_blocks.shape[0] and p.rem_count for p in plans)
+    pad = bucket_pad_stats(tabs, sg.n_max, n_src, stem="blkrem")
+    for d, degs in (("fwd", [p.rem_deg_in for p in plans]),
+                    ("bwd", [p.rem_deg_out for p in plans])):
+        want = fit_widths(degree_hist(degs))
+        keys = sorted(k for k in tabs if k.startswith(f"blkrem_{d}_")
+                      and not k.endswith("inv"))
+        assert [tabs[k].shape[1] for k in keys] == want
+        assert pad[d]["widths"] == want
+        assert pad[d]["edges"] == sum(p.rem_count for p in plans)
+        assert pad[d]["slots"] == sum(tabs[k].size for k in keys)
+        # the dense classes: rungs of the x1.5 ladder, as before
+        stem, end = (f"blk_{d}u_g", "t") if group > 1 else \
+            (f"blk_{d}_g", "b")
+        k_widths = [tabs[k].shape[-1] for k in sorted(tabs)
+                    if k.startswith(stem) and k.endswith(end)]
+        assert k_widths and set(k_widths) <= set(ladder_prefix(12))
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((sg.n_max, f)), jnp.float32)
+    for r in range(n_parts):
+        real = sg.edge_dst[r] < sg.n_max
+        src, dst = sg.edge_src[r][real], sg.edge_dst[r][real]
+        deg = np.maximum(np.bincount(dst, minlength=sg.n_max), 1)
+        fn = make_device_block_spmm_fn(
+            {k: jnp.asarray(v[r]) for k, v in tabs.items()},
+            jnp.asarray(deg, jnp.float32), sg.n_max, n_src, tile)
+        out, vjp = jax.vjp(fn, x)
+        a = np.zeros((sg.n_max, n_src))
+        np.add.at(a, (dst, src), 1.0)
+        a /= deg[:, None]
+        np.testing.assert_allclose(np.asarray(out), a @ np.asarray(x),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(vjp(c)[0]),
+                                   a.T @ np.asarray(c),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_set_remainder_widths_rebuilds_the_remainder_alone(edges):
+    """A plan moved to another remainder ladder rebuilds those tables
+    and nothing of the dense half; at its own widths it rebuilds
+    nothing."""
+    src, dst, n_out, n_src = edges
+    plan = BlockPlan(src, dst, n_out, n_src, n_feat=8, tile=16,
+                     nnz_threshold=4)
+    a_blocks, groups = plan.a_blocks, plan.fwd_groups
+    fwd, bwd = plan.rem_fwd_mats, plan.rem_bwd_mats
+    plan.set_remainder_widths(plan.rem_fwd_widths, plan.rem_bwd_widths)
+    assert plan.rem_fwd_mats is fwd and plan.rem_bwd_mats is bwd
+    wider = plan.rem_fwd_widths[:-1] + [plan.rem_fwd_widths[-1] + 3]
+    plan.set_remainder_widths(wider, plan.rem_bwd_widths)
+    assert plan.rem_fwd_mats is not fwd and plan.rem_bwd_mats is bwd
+    assert [m.shape[0] for m in plan.rem_fwd_mats] == wider
+    assert plan.a_blocks is a_blocks and plan.fwd_groups is groups
+    deg = jnp.asarray(np.maximum(np.bincount(dst, minlength=n_out), 1)
+                      .astype(np.float32))
+    arrs = {k: jnp.asarray(v) for k, v in plan_to_arrays(plan).items()}
+    fbuf = np.random.default_rng(0).standard_normal(
+        (n_src, 8)).astype(np.float32)
+    out = make_block_spmm_fn(arrs, deg, n_out, n_src, 16)(
+        jnp.asarray(fbuf))
+    np.testing.assert_allclose(
+        np.asarray(out), _ref_mean(src, dst, n_out, fbuf, deg),
+        rtol=1e-5, atol=1e-5)

@@ -266,12 +266,13 @@ def test_transport_cast_saturates_not_nan():
 
 # ---------------- named scopes inside the kernel ---------------------------
 
-def _window_graph(n=256, deg=12, n_feat=12, n_class=4, seed=0):
-    """Every node aggregates a contiguous id window below it."""
+def _window_graph(n=1024, deg=12, n_feat=12, n_class=4, seed=0):
+    """Every node aggregates a contiguous id window below it, of 1 to
+    `deg` ids in turn: several buckets under the fitted widths too."""
     from pipegcn_tpu.graph.csr import Graph
 
-    src = [j for i in range(n) for j in range(max(0, i - deg), i)]
-    dst = [i for i in range(n) for j in range(max(0, i - deg), i)]
+    src = [j for i in range(n) for j in range(max(0, i - 1 - i % deg), i)]
+    dst = [i for i in range(n) for j in range(max(0, i - 1 - i % deg), i)]
     rng = np.random.default_rng(seed)
     ar = np.arange(n)
     return Graph(
@@ -390,15 +391,18 @@ def _assert_slot_major(mats, width_of=None):
 @pytest.mark.parametrize("transport", sorted(TRANSPORTS))
 @pytest.mark.parametrize("width", LADDER)
 def test_slot_major_forward_and_vjp_match_dense(width, transport):
-    """Every ladder width up to 211, every transport dtype: the forward
-    over the slot-major tables and its VJP (the same kernel over the
-    transpose tables) are the dense float32 products of the operand AS
-    TRANSPORTED, unchunked and over three chunks with a ragged last
-    one; rows without an edge read zero."""
+    """Every x1.5 rung up to 211 as a bucket's width (the forward
+    tables on that ladder, the transpose tables on widths fitted to
+    their histogram), every transport dtype: the forward over the
+    slot-major tables and its VJP (the same kernel over the transpose
+    tables) are the dense float32 products of the operand AS
+    TRANSPORTED, unchunked and over three balanced chunks, the last one
+    moved back to the table's end; rows without an edge read zero."""
     dt = TRANSPORTS[transport]
     f = 8
     src, dst, n_out, n_src = _bucket_edges(width, seed=width)
-    plan = BucketPlan(src, dst, n_out, n_src)
+    plan = BucketPlan(src, dst, n_out, n_src,
+                      fwd_widths=_bucket_widths(int(np.bincount(dst).max())))
     _assert_slot_major(plan.fwd_mats, plan.fwd_widths)
     _assert_slot_major(plan.bwd_mats, plan.bwd_widths)
     b = plan.fwd_widths.index(width)
@@ -544,7 +548,7 @@ def test_reduce_operand_is_the_transported_stream(transport, chunked):
     dt = TRANSPORTS[transport]
     f = 8
     src, dst, n_out, n_src = _bucket_edges(63, seed=2)
-    plan = BucketPlan(src, dst, n_out, n_src)
+    plan = BucketPlan(src, dst, n_out, n_src, fwd_widths=_bucket_widths(63))
     mats = [jnp.asarray(m) for m in plan.fwd_mats]
     chunk = 64 * 63 * f if chunked else 1 << 30
     jaxpr = jax.make_jaxpr(
@@ -671,3 +675,506 @@ def test_other_operands_are_gathered_as_they_are(case):
     want = _dense(src, dst, n_out, n_src) @ np.asarray(
         x.astype(jnp.float32), np.float64)
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-6, atol=1e-5)
+
+
+# ---------------- widths fitted to the degree histogram -----------------------
+
+def _slots(hist, widths):
+    from pipegcn_tpu.ops.bucket_spmm import _ladder_slots
+
+    return _ladder_slots(np.asarray(hist, np.int64), widths)
+
+
+def _hist(kind):
+    rng = np.random.default_rng(7)
+    if kind == "poisson":
+        deg = rng.poisson(10, 200_000) + 1
+    elif kind == "normal":      # between the rungs 94 and 141
+        deg = np.clip(rng.normal(98, 14, 60_000).round(), 60, 140)
+    elif kind == "power-law":
+        deg = np.minimum(rng.zipf(1.7, 300_000), 5000)
+    elif kind == "single-degree":
+        deg = np.full(5000, 37)
+    elif kind == "one-hub":
+        deg = np.concatenate([rng.integers(1, 8, 4000), [100_000]])
+    else:
+        deg = np.zeros(0, np.int64)
+    return np.bincount(deg.astype(np.int64), minlength=1)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_fit_widths_is_the_optimum_on_small_histograms(seed):
+    """Against every choice of at most K widths out of the degrees
+    present, on small random histograms with gaps, of one shard or of
+    two or three (slots then count every shard at the largest one's rows):
+    the programme's slots are the least, within K, ascending, the last
+    the largest degree, none below min_width."""
+    import itertools
+
+    from pipegcn_tpu.ops.bucket_spmm import fit_widths
+
+    rng = np.random.default_rng(seed)
+    for _ in range(40):
+        n = int(rng.integers(1, 11))
+        # one shard, or three whose rows pad to the largest's cap
+        hist = np.zeros((int(rng.choice([1, 2, 3])), n + 1), np.int64)
+        hist[:, 1:] = rng.integers(0, 200, hist[:, 1:].shape) * (
+            rng.random(hist[:, 1:].shape) < 0.7)
+        hist[0, int(rng.integers(1, n + 1))] += 5
+        k, floor = int(rng.integers(1, 5)), int(rng.integers(0, 6))
+        got = fit_widths(hist if seed % 2 else hist.squeeze(), k, floor)
+        degs = np.nonzero(hist.sum(axis=0)[1:])[0] + 1
+        tops = sorted({max(int(d), floor, 1) for d in degs})
+        best = min(
+            _slots(hist, list(combo) + [tops[-1]])[0]
+            for j in range(min(k, len(tops)))
+            for combo in itertools.combinations(tops[:-1], j))
+        assert _slots(hist, got)[0] == best, (hist, k, floor, got)
+        assert len(got) <= k and got == sorted(set(got))
+        assert got[-1] >= degs[-1] and got[0] >= floor
+
+
+@pytest.mark.parametrize("min_width", [0, 5])
+@pytest.mark.parametrize("kind", ["poisson", "normal", "power-law",
+                                  "single-degree", "one-hub", "empty"])
+def test_fit_widths_never_worse_than_the_ladder(kind, min_width):
+    """On a histogram of each family the fitted widths issue no more
+    slots than the x1.5 ladder, in no more buckets than max(8, the
+    ladder's non-empty rungs); the top width covers the largest degree
+    and none is below min_width. Between two rungs (normal) and on
+    Poisson degrees the fit is several points under the ladder."""
+    from pipegcn_tpu.ops.bucket_spmm import FIT_MIN_BUCKETS, fit_widths
+
+    hist = _hist(kind)
+    got = fit_widths(hist, min_width=min_width)
+    assert got == sorted(set(got)) and got[0] >= max(1, min_width)
+    if kind == "empty":
+        assert got == [max(1, min_width)]
+        return
+    top = hist.shape[0] - 1
+    old = _bucket_widths(top, min_width)
+    old_slots, old_filled = _slots(hist, old)
+    slots, filled = _slots(hist, got)
+    assert got[-1] >= top
+    assert slots <= old_slots
+    assert filled == len(got) <= max(FIT_MIN_BUCKETS, old_filled)
+    edges = int((np.arange(top + 1) * hist).sum())
+    if kind in ("poisson", "normal") and not min_width:
+        assert old_slots / edges > 1.15 and slots / edges < 1.06
+    if kind == "single-degree":
+        assert got == [37]
+
+
+def test_fit_widths_thins_a_hub_histogram_fast():
+    """Thousands of distinct degrees up to a 100k hub: the candidates
+    are thinned, the answer stays within the rule, and the host pays
+    well under a second."""
+    import time
+
+    from pipegcn_tpu.ops.bucket_spmm import (_FIT_CANDIDATES,
+                                             FIT_MIN_BUCKETS, fit_widths)
+
+    rng = np.random.default_rng(3)
+    deg = np.minimum(rng.zipf(1.5, 2_000_000), 100_000)
+    deg[0] = 100_000
+    hist = np.bincount(deg)
+    assert np.count_nonzero(hist) > 4 * _FIT_CANDIDATES
+    t0 = time.perf_counter()
+    got = fit_widths(hist)
+    assert time.perf_counter() - t0 < 1.0
+    old_slots, old_filled = _slots(hist, _bucket_widths(100_000))
+    assert got[-1] == 100_000
+    assert _slots(hist, got)[0] <= old_slots
+    assert len(got) <= max(FIT_MIN_BUCKETS, old_filled)
+
+
+@pytest.mark.parametrize("transport", ["float32", "e4m3"])
+def test_fitted_plan_between_two_rungs_matches_dense(transport):
+    """Degrees concentrated between the old rungs 94 and 141 (Reddit's
+    remainder): BucketPlan's own widths split them into several
+    buckets of far fewer slots, and forward and VJP are the dense
+    products."""
+    dt = TRANSPORTS[transport]
+    rng = np.random.default_rng(9)
+    n_src, f = 400, 8
+    degs = np.clip(rng.normal(106, 10, 700).round(), 95, 140).astype(int)
+    degs = np.concatenate([degs, [0, 0, 1, 3]])
+    dst = np.repeat(np.arange(degs.size), degs)
+    src = np.concatenate([rng.choice(n_src, d, replace=False)
+                          for d in degs])
+    plan = BucketPlan(src, dst, degs.size, n_src)
+    hist = np.bincount(degs)
+    assert 3 <= len(plan.fwd_widths) <= 8
+    assert plan.fwd_widths[-1] == degs.max()
+    assert _slots(hist, plan.fwd_widths)[0] < 0.85 * _slots(
+        hist, _bucket_widths(int(degs.max())))[0]
+    _assert_slot_major(plan.fwd_mats, plan.fwd_widths)
+    _assert_slot_major(plan.bwd_mats, plan.bwd_widths)
+    x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32).astype(dt)
+    g = jnp.asarray(rng.standard_normal((degs.size, f)),
+                    jnp.float32).astype(dt)
+    a = _dense(src, dst, degs.size, n_src)
+    for chunk_elems in (1 << 30, 96 * 100 * f):
+        out = bucket_aggregate(
+            x, [jnp.asarray(m) for m in plan.fwd_mats],
+            jnp.asarray(plan.fwd_inv), chunk_elems=chunk_elems)
+        np.testing.assert_allclose(
+            np.asarray(out),
+            a @ np.asarray(x.astype(jnp.float32), np.float64),
+            rtol=2e-6, atol=1e-5)
+        back = bucket_aggregate(
+            g, [jnp.asarray(m) for m in plan.bwd_mats],
+            jnp.asarray(plan.bwd_inv), chunk_elems=chunk_elems)
+        np.testing.assert_allclose(
+            np.asarray(back),
+            a.T @ np.asarray(g.astype(jnp.float32), np.float64),
+            rtol=2e-6, atol=1e-5)
+
+
+# ---------------- balanced chunks --------------------------------------------
+
+def test_chunk_rows_regathers_under_a_tile_a_chunk():
+    """Over random widths, row counts and budgets: chunks are
+    ROW_TILE-aligned, fit the budget, cover the table, and the rows
+    gathered twice are under ROW_TILE * chunks (never more than an even
+    spread over the fewest chunks re-gathers)."""
+    from pipegcn_tpu.ops.bucket_spmm import ROW_TILE, chunk_rows, row_cap
+
+    rng = np.random.default_rng(11)
+    for _ in range(2000):
+        w = int(rng.integers(1, 600))
+        f = int(rng.choice([8, 64, 128, 256]))
+        n_b = ROW_TILE * int(rng.integers(1, 20_000))
+        budget = int(rng.choice([1 << 16, 1 << 20, 32 << 20]))
+        rows, n_chunks = chunk_rows(w, n_b, f, budget)
+        max_rows = max(ROW_TILE, budget // (w * f) // ROW_TILE * ROW_TILE)
+        assert rows % ROW_TILE == 0 and 0 < rows <= max_rows
+        assert rows <= n_b and rows * n_chunks >= n_b
+        assert (n_chunks == 1) == (n_b <= max_rows)
+        fewest = -(-n_b // max_rows)
+        assert n_chunks < 2 * fewest + 1
+        even = row_cap(-(-n_b // fewest)) * fewest
+        assert rows * n_chunks <= even
+        assert rows * n_chunks - n_b < ROW_TILE * n_chunks
+    # Reddit's width-141 bucket as PR 32 cut it, and a fitted one
+    assert chunk_rows(141, 135_488, 256, 32 << 20) == (928, 146)
+    assert chunk_rows(100, 54_400, 256, 32 << 20) == (1088, 50)
+
+
+@pytest.mark.parametrize("n_rows", [130, 200, 330])
+def test_chunked_rows_are_written_once_or_with_equal_values(n_rows):
+    """A bucket cut into several chunks, the last one moved back over
+    its neighbour: every row of the result equals the unchunked one
+    bit for bit (a row written twice is written the same)."""
+    from pipegcn_tpu.ops.bucket_spmm import ROW_TILE, chunk_rows
+
+    width, f = 28, 8
+    src, dst, n_out, n_src = _bucket_edges(width, seed=n_rows,
+                                           n_rows=n_rows, n_low=0)
+    mats, inv, counts = build_tables_for_edges(src, dst, n_out, n_src,
+                                               [width])
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((n_src, f)),
+                    jnp.float32)
+    whole = bucket_aggregate(x, [jnp.asarray(mats[0])], jnp.asarray(inv))
+    budget = 96 * width * f
+    rows, n_chunks = chunk_rows(width, mats[0].shape[1], f, budget)
+    assert n_chunks > 1
+    assert rows * n_chunks - mats[0].shape[1] < ROW_TILE * n_chunks
+    cut = bucket_aggregate(x, [jnp.asarray(mats[0])], jnp.asarray(inv),
+                           chunk_elems=budget)
+    assert np.array_equal(np.asarray(whole), np.asarray(cut))
+
+
+# ---------------- one ladder over the shards, sticky under deltas ------------
+
+def _four_shards(seed=0, hub=0):
+    """Four shards of unlike degrees (2, 6, 12 and 20 a row on average)
+    as the sharded builders see them."""
+    from types import SimpleNamespace
+
+    rng = np.random.default_rng(seed)
+    n_max, halo, e_max = 192, 32, 6000
+    srcs, dsts = [], []
+    for r, mean in enumerate((2, 6, 12, 20)):
+        degs = np.minimum(rng.poisson(mean, n_max - 8), n_max)
+        if hub and r == 1:
+            degs[0] = hub
+        dst = np.repeat(np.arange(degs.size), degs)
+        src = np.concatenate([rng.choice(n_max + halo, d, replace=False)
+                              for d in degs] + [np.zeros(0, np.int64)])
+        pad = e_max - dst.size
+        srcs.append(np.concatenate([src, np.zeros(pad, np.int64)]))
+        dsts.append(np.concatenate([dst, np.full(pad, n_max)]))
+    return SimpleNamespace(
+        num_parts=4, n_max=n_max, halo_size=halo,
+        edge_src=np.stack(srcs).astype(np.int32),
+        edge_dst=np.stack(dsts).astype(np.int32))
+
+
+def _table_neighbours(tables, stem, sentinel):
+    """{(device, row): sorted neighbours} read back from stacked
+    slot-major tables: what they sum, whatever their widths."""
+    keys = sorted(k for k in tables if k.startswith(stem + "_")
+                  and not k.endswith("inv"))
+    out = {}
+    for dev in range(tables[stem + "_inv"].shape[0]):
+        cols = [tables[k][dev][:, c] for k in keys
+                for c in range(tables[k].shape[2])]
+        for row, pos in enumerate(tables[stem + "_inv"][dev]):
+            if pos < len(cols):
+                nb = cols[pos][cols[pos] != sentinel]
+                out[dev, row] = sorted(nb.tolist())
+    return out
+
+
+def _shard_neighbours(sg, transpose=False):
+    out = {}
+    for r in range(sg.num_parts):
+        real = sg.edge_dst[r] < sg.n_max
+        a, b = sg.edge_src[r][real], sg.edge_dst[r][real]
+        if transpose:
+            a, b = b, a
+        for s, d in zip(a.tolist(), b.tolist()):
+            out.setdefault((r, d), []).append(s)
+    return {k: sorted(v) for k, v in out.items()}
+
+
+def test_one_fitted_ladder_serves_every_shard():
+    """Four shards of unlike degree histograms: the builder fits ONE
+    ladder a direction to all of them, every device's tables have those
+    widths (one traced program), caps are the largest shard's, the
+    stacked tables gather exactly the slots the fit minimised (no more
+    than on the x1.5 ladder), and each device's tables hold exactly its
+    own edges."""
+    from pipegcn_tpu.ops.bucket_spmm import (bucket_pad_stats,
+                                             build_sharded_bucket_tables,
+                                             degree_hist, fit_widths)
+
+    sg = _four_shards()
+    n_src_rows = sg.n_max + sg.halo_size
+    tabs = build_sharded_bucket_tables(sg)
+    real = [sg.edge_dst[r] < sg.n_max for r in range(4)]
+    for stem, arr, sentinel in (("bkt_fwd", sg.edge_dst, n_src_rows),
+                                ("bkt_bwd", sg.edge_src, sg.n_max)):
+        hist = degree_hist(np.bincount(arr[r][real[r]]) for r in range(4))
+        want = fit_widths(hist)
+        keys = sorted(k for k in tabs if k.startswith(stem + "_")
+                      and not k.endswith("inv"))
+        assert [tabs[k].shape[1] for k in keys] == want
+        assert sum(tabs[k].size for k in keys) == _slots(hist, want)[0] \
+            <= _slots(hist, _bucket_widths(hist.shape[1] - 1))[0]
+        assert all(tabs[k].shape[0] == 4 and tabs[k].shape[2] % 32 == 0
+                   for k in keys)
+        assert _table_neighbours(tabs, stem, sentinel) == \
+            _shard_neighbours(sg, transpose=stem == "bkt_bwd")
+    pad = bucket_pad_stats(tabs, sg.n_max, n_src_rows)
+    n_edges = int(sum(r.sum() for r in real))
+    assert pad["fwd"]["edges"] == pad["bwd"]["edges"] == n_edges
+    assert pad["fwd"]["slots"] == sum(
+        4 * t.shape[1] * t.shape[2] for k, t in tabs.items()
+        if k.startswith("bkt_fwd_") and not k.endswith("inv"))
+
+
+def test_dirty_rebuild_keeps_the_ladder_until_it_is_outgrown():
+    """With a plan cache and dirty shards the cached widths stay while
+    their top covers the largest degree (clean shards' plans are
+    reused; the tables hold the new edges at the OLD widths, where a
+    cache-free build may choose others); a degree past the top refits
+    and rebuilds every plan."""
+    from pipegcn_tpu.ops.bucket_spmm import build_sharded_bucket_tables
+
+    sg = _four_shards()
+    n_src_rows = sg.n_max + sg.halo_size
+    cache = {}
+    build_sharded_bucket_tables(sg, plan_cache=cache)
+    widths, plans = cache["widths"], list(cache["plans"])
+    # shard 2 changes: its rows of degree 1 to 3 lose their edges
+    deg = np.bincount(sg.edge_dst[2][sg.edge_dst[2] < sg.n_max],
+                      minlength=sg.n_max)
+    sg.edge_dst[2][np.isin(sg.edge_dst[2], np.nonzero(deg <= 3)[0])] = \
+        sg.n_max
+    tabs = build_sharded_bucket_tables(sg, plan_cache=cache, dirty=[2])
+    assert cache["widths"] == widths
+    assert [p is q for p, q in zip(cache["plans"], plans)] == \
+        [True, True, False, True]
+    assert _table_neighbours(tabs, "bkt_fwd", n_src_rows) == \
+        _shard_neighbours(sg)
+    assert _table_neighbours(tabs, "bkt_bwd", sg.n_max) == \
+        _shard_neighbours(sg, transpose=True)
+    # a row of shard 1 (and no other shard) outgrows the forward
+    # ladder's top width: its pad edges become edges of row 0
+    top = widths[0][-1]
+    grown = sg
+    pad = np.nonzero(grown.edge_dst[1] == sg.n_max)[0][:top + 40]
+    grown.edge_dst[1][pad] = 0
+    grown.edge_src[1][pad] = np.arange(pad.size) % n_src_rows
+    hub = int(np.count_nonzero(grown.edge_dst[1] == 0))
+    assert hub > top
+    tabs = build_sharded_bucket_tables(grown, plan_cache=cache, dirty=[1])
+    assert cache["widths"][0][-1] == hub
+    assert not any(p is q for p, q in zip(cache["plans"], plans))
+    assert _table_neighbours(tabs, "bkt_fwd", n_src_rows) == \
+        _shard_neighbours(grown)
+
+
+def test_fit_widths_takes_the_stacked_objective_at_two_shards():
+    """P = 2, one shard of low degrees and one of high: under shard_map
+    every shard's bucket is padded to the larger one's rows, and the fit
+    minimises THAT (never more than the ladder gives the stacked
+    tables), which the sum of the two histograms does not describe."""
+    from pipegcn_tpu.ops.bucket_spmm import fit_widths
+
+    rng = np.random.default_rng(5)
+    degs = [rng.poisson(6, 5000) + 1, rng.poisson(40, 5000) + 1]
+    size = max(int(d.max()) for d in degs) + 1
+    hist = np.stack([np.bincount(d, minlength=size) for d in degs])
+    got = fit_widths(hist)
+    ladder = _bucket_widths(size - 1)
+    assert got[-1] == size - 1
+    assert _slots(hist, got)[0] <= _slots(hist, ladder)[0]
+    # the widths fitted to the summed histogram cost the stacked tables
+    # at least as much as the ones fitted to them
+    summed = fit_widths(hist.sum(axis=0), len(got))
+    assert _slots(hist, got)[0] <= _slots(hist, summed)[0]
+
+
+# ---------------- no edge is ever dropped silently ------------------------------
+
+def test_widths_that_do_not_cover_a_degree_raise():
+    """A width list whose top is under a row's degree would lose that
+    row's tail: the builder refuses it, by name, in both directions of a
+    plan; at the degree itself it builds."""
+    src, dst, n_out, n_src = _bucket_edges(28, seed=1)
+    top = int(np.bincount(dst).max())
+    with pytest.raises(ValueError, match=f"end at {top - 1} but a row "
+                                         f"has {top} edges"):
+        build_tables_for_edges(src, dst, n_out, n_src, [4, top - 1])
+    with pytest.raises(ValueError, match="but a row has"):
+        BucketPlan(src, dst, n_out, n_src, bwd_widths=[1])
+    mats, _, counts = build_tables_for_edges(src, dst, n_out, n_src,
+                                             [4, top])
+    assert sum(int((m != n_src).sum()) for m in mats) == src.size
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("kernel", ["bucket", "block remainder"])
+def test_an_entry_overwritten_by_the_sentinel_is_counted(kernel, direction):
+    """One edge of one device replaced by the sentinel (in bounds, and a
+    change of one mean under any transport's noise): the validation
+    counts each direction's entries against the edges the tables were
+    built from and against each other, and names the direction."""
+    from pipegcn_tpu.ops.block_spmm import build_sharded_block_tables
+    from pipegcn_tpu.ops.bucket_spmm import (build_sharded_bucket_tables,
+                                             table_edges,
+                                             validate_bucket_tables)
+
+    sg = _four_shards()
+    n_src_rows = sg.n_max + sg.halo_size
+    if kernel == "bucket":
+        stem, tabs = "bkt", build_sharded_bucket_tables(sg)
+        n_edges = [int((d < sg.n_max).sum()) for d in sg.edge_dst]
+    else:
+        stem = "blkrem"
+        tabs, _ = build_sharded_block_tables(sg, tile=16, n_feat_hint=8,
+                                             nnz_threshold=6)
+        n_edges = table_edges(tabs, "blkrem_fwd", n_src_rows).tolist()
+        assert 0 < sum(n_edges) < int((sg.edge_dst < sg.n_max).sum())
+    validate_bucket_tables(tabs, sg.n_max, n_src_rows, n_edges, stem)
+    sentinel = n_src_rows if direction == "fwd" else sg.n_max
+    key = sorted(k for k in tabs if k.startswith(f"{stem}_{direction}_")
+                 and not k.endswith("inv"))[1]
+    bad = {k: np.array(v) for k, v in tabs.items()}
+    dev, slot, col = np.argwhere(bad[key] != sentinel)[-1]
+    bad[key][dev, slot, col] = sentinel
+    for count in (n_edges, None):
+        with pytest.raises(ValueError, match="dropped or overwritten"):
+            validate_bucket_tables(bad, sg.n_max, n_src_rows, count, stem)
+
+
+# ---------------- fitted tables sum what the ladder's tables sum ----------------
+
+def _ladder_fit(hist, max_buckets=None, min_width=0):
+    """fit_widths' signature, the x1.5 ladder's answer."""
+    return _bucket_widths(np.atleast_2d(hist).shape[1] - 1, min_width)
+
+
+def _sums_of(kernel, sg):
+    """Forward output and every VJP of `kernel` over sg's tables, as
+    float32 arrays (sequential: two epochs' losses, which carry both)."""
+    n_src = sg.n_max + sg.halo_size
+    rng = np.random.default_rng(0)
+    if kernel == "sequential":
+        from pipegcn_tpu.parallel import SequentialRunner
+
+        cfg = ModelConfig(layer_sizes=(sg.n_feat, 16, 16, sg.n_class),
+                          train_size=sg.n_train_global, dropout=0.0,
+                          norm="layer", spmm_impl="bucket")
+        run = SequentialRunner(sg, cfg, TrainConfig(
+            lr=0.01, n_epochs=2, enable_pipeline=True, eval=False, seed=2))
+        return [np.asarray([run.run_epoch(e) for e in range(2)])]
+    out = []
+    for r in range(sg.num_parts):
+        deg = jnp.asarray(sg.in_deg[r], jnp.float32)
+        x = jnp.asarray(rng.standard_normal((n_src, 8)), jnp.float32)
+        c = jnp.asarray(rng.standard_normal((sg.n_max, 8)), jnp.float32)
+        if kernel == "attention":
+            from pipegcn_tpu.ops.gat_bucket import (build_sharded_gat_tables,
+                                                    make_device_gat_fn)
+
+            d = {k: jnp.asarray(v[r])
+                 for k, v in build_sharded_gat_tables(sg).items()}
+            fn = make_device_gat_fn(d, sg.n_max, n_src, 2, 0.2)
+            args = (x.reshape(n_src, 2, 4),
+                    jnp.asarray(rng.standard_normal((n_src, 2)),
+                                jnp.float32),
+                    jnp.asarray(rng.standard_normal((sg.n_max, 2)),
+                                jnp.float32))
+            c = c.reshape(sg.n_max, 2, 4)
+        elif kernel == "block remainder":
+            from pipegcn_tpu.ops.block_spmm import (
+                build_sharded_block_tables, make_device_block_spmm_fn)
+
+            tabs, tile = build_sharded_block_tables(
+                sg, tile=16, n_feat_hint=8, nnz_threshold=6)
+            fn = make_device_block_spmm_fn(
+                {k: jnp.asarray(v[r]) for k, v in tabs.items()}, deg,
+                sg.n_max, n_src, tile)
+            args = (x,)
+        else:
+            from pipegcn_tpu.ops.bucket_spmm import (
+                build_sharded_bucket_tables, make_device_bucket_spmm_fn)
+
+            tabs = build_sharded_bucket_tables(sg)
+            fn = make_device_bucket_spmm_fn(
+                {k: jnp.asarray(v[r]) for k, v in tabs.items()}, deg, n_src)
+            args = (x,)
+        y, vjp = jax.vjp(fn, *args)
+        out += [np.asarray(y)] + [np.asarray(g) for g in vjp(c)]
+    return out
+
+
+@pytest.mark.parametrize("kernel", ["bucket", "block remainder",
+                                    "sequential", "attention"])
+def test_fitted_tables_sum_what_the_ladder_tables_sum(kernel, monkeypatch):
+    """Every builder of row buckets (the bucket kernel, the block
+    kernel's remainder, the sequential runner, the attention tables),
+    two shards: at the fitted widths the kernel's float32 forward and
+    backward are what they are at the x1.5 ladder's widths (the same
+    edges in other slots, so a sum in another order), and the widths
+    do differ."""
+    from pipegcn_tpu.ops import block_spmm, bucket_spmm, gat_bucket
+
+    g = synthetic_graph(num_nodes=500, avg_degree=14, n_feat=12,
+                        n_class=4, seed=3)
+    sg = ShardedGraph.build(g, partition_graph(g, 2, seed=0), n_parts=2)
+    fitted = _sums_of(kernel, sg)
+    widths = bucket_spmm.build_sharded_bucket_tables(sg)
+    for mod in (bucket_spmm, block_spmm, gat_bucket):
+        monkeypatch.setattr(mod, "fit_widths", _ladder_fit)
+    ladder = _sums_of(kernel, sg)
+    assert {k: v.shape for k, v in widths.items()} != {
+        k: v.shape
+        for k, v in bucket_spmm.build_sharded_bucket_tables(sg).items()}
+    assert len(fitted) == len(ladder)
+    for a, b in zip(fitted, ladder):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)

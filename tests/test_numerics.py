@@ -417,8 +417,20 @@ def test_bucket_table_validation_catches_oob(sharded, side, where):
     bad[key].reshape(-1)[at] = -3
     with pytest.raises(ValueError, match="out-of-bounds"):
         validate_bucket_tables(bad, sg.n_max, n_src)
-    bad[key].reshape(-1)[at] = bound          # the sentinel is in bounds
-    validate_bucket_tables(bad, sg.n_max, n_src)
+    # the sentinel is in bounds: over a padding slot it changes
+    # nothing, over an edge it drops that edge, which the count of each
+    # direction's entries against the other's (or the graph's) names
+    real = int(tables[key].reshape(-1)[at]) != bound
+    assert real == (where == "first")
+    bad[key].reshape(-1)[at] = bound
+    n_edges = [int(np.count_nonzero(d < sg.n_max)) for d in sg.edge_dst]
+    for count in (None, n_edges):
+        if real:
+            with pytest.raises(ValueError, match="dropped or overwritten"):
+                validate_bucket_tables(bad, sg.n_max, n_src, n_edges=count)
+        else:
+            validate_bucket_tables(bad, sg.n_max, n_src, n_edges=count)
+    bad[key].reshape(-1)[at] = tables[key].reshape(-1)[at]
     rows = sum(tables[k].shape[-1] for k in plain)
     bad[f"bkt_{side}_inv"].reshape(-1)[at] = rows + 1
     with pytest.raises(ValueError, match="out-of-bounds"):

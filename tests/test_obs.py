@@ -263,6 +263,38 @@ def test_schema_v16_drift_guard():
         assert obs_schema.SCHEMA_VERSION > 16
 
 
+# FROZEN copy of the v17 additions (v16 + what the tuner RANKS: a call
+# at the shard's size from two nested samples, ops/tuner.py
+# shard_estimate; call_overhead_s went with the subtraction that used
+# it). Same contract as the earlier guards.
+_V17_TUNING_FIELDS = {
+    "event": "string", "winner": "object", "source": "string",
+    "costs": "array", "sample_dense_coverage": "number?",
+    "shard_dense_coverage": "number?", "sample_tile_rows": "integer?",
+    "timed_edges": "array?", "shard_edges": "integer?",
+}
+_V17_TUNING_COST_FIELDS = {
+    "name": "string", "spmm_fwdbwd_s": "number?", "spread_s": "number?",
+    "fixed_s": "number?", "per_edge_s": "number?",
+    "est_call_s": "number?", "est_epoch_spmm_s": "number?",
+    "error": "string?",
+}
+
+
+def test_schema_v17_drift_guard():
+    if obs_schema.SCHEMA_VERSION == 17:
+        for frozen, live, what in (
+                (_V17_TUNING_FIELDS, obs_schema.TUNING_FIELDS, "tuning"),
+                (_V17_TUNING_COST_FIELDS, obs_schema.TUNING_COST_FIELDS,
+                 "tuning cost")):
+            for name, tag in frozen.items():
+                assert live.get(name) == tag, (
+                    f"schema field {what}.{name} removed or retyped "
+                    f"without bumping SCHEMA_VERSION")
+    else:
+        assert obs_schema.SCHEMA_VERSION > 17
+
+
 def test_validate_record():
     validate_record({"event": "epoch", "epoch": 0, "step_time_s": 0.1,
                      "loss": 1.0, "grad_norm": 0.5, "halo_bytes": 128,
@@ -287,7 +319,8 @@ def test_validate_record():
 def test_validate_tuning_record():
     sample = {"sample_dense_coverage": 0.79,
               "shard_dense_coverage": 0.8, "sample_tile_rows": 16,
-              "call_overhead_s": 1e-3}
+              "timed_edges": [1_000_000, 250_000],
+              "shard_edges": 114_000_000}
     validate_record({"event": "tuning",
                      "winner": {"name": "block-u4-bf16",
                                 "impl": "block"},
@@ -306,6 +339,18 @@ def test_validate_tuning_record():
     with pytest.raises(ValueError, match="sample_dense_coverage"):
         validate_record({"event": "tuning", "winner": {},
                          "source": "live", "costs": []})
+    # every entry of the cost table says what was ranked (v17)
+    cost = {"name": "block-f8", "spmm_fwdbwd_s": 7.2e-3,
+            "spread_s": 1e-4, "fixed_s": 5e-3, "per_edge_s": 2.2e-9,
+            "est_call_s": 0.26, "est_epoch_spmm_s": 0.78, "error": None}
+    validate_record({"event": "tuning", "winner": {}, "source": "live",
+                     "costs": [cost, dict.fromkeys(cost) | {
+                         "name": "xla", "error": "boom"}], **sample})
+    with pytest.raises(ValueError, match="tuning cost.*est_call_s"):
+        validate_record({"event": "tuning", "winner": {},
+                         "source": "live", "costs": [
+                             {k: v for k, v in cost.items()
+                              if k != "est_call_s"}], **sample})
 
 
 def test_validate_serving_record():

@@ -60,9 +60,13 @@ def _fresh_rebuild(patcher, sg, cfg, tcfg):
     return Trainer(sg2, cfg, tcfg), sg2
 
 
-def _assert_data_bit_identical(t, t2):
-    d1 = jax.device_get(t.data)
-    d2 = jax.device_get(t2.data)
+def _assert_data_bit_identical(t, t2, but=()):
+    """Every device table equal bit for bit, but those whose key starts
+    with one of `but`."""
+    d1 = {k: v for k, v in jax.device_get(t.data).items()
+          if not k.startswith(tuple(but))}
+    d2 = {k: v for k, v in jax.device_get(t2.data).items()
+          if not k.startswith(tuple(but))}
     assert set(d1) == set(d2)
     for k in sorted(d1):
         a, b = np.asarray(d1[k]), np.asarray(d2[k])
@@ -78,9 +82,12 @@ def _assert_data_bit_identical(t, t2):
 @pytest.mark.parametrize("spmm", ["xla", "bucket"])
 def test_patched_tables_bit_identical_to_rebuild(spmm):
     """Every device table (CSR slabs, send-lists, halo routing, feats,
-    masks, kernel tables) after two delta batches == a from-scratch
-    build of the post-delta graph — on the raw-gather AND the
-    dirty-shard incremental bucket-table path."""
+    masks) after two delta batches == a from-scratch build of the
+    post-delta graph, on the raw-gather AND the dirty-shard incremental
+    bucket-table path. The bucket kernel's tables keep the ladder they
+    had (a fitted ladder moves with the histogram, and with it every
+    shape: bucket_spmm.build_sharded_bucket_tables), so they are held
+    to what they SUM: every row's neighbours, both directions."""
     g, parts, sg, cfg, tcfg, t, patcher = _stack(spmm=spmm)
     n0 = g.num_nodes
     for b in synthetic_delta_schedule(g, n_batches=2, edges_per_batch=5,
@@ -93,7 +100,9 @@ def test_patched_tables_bit_identical_to_rebuild(spmm):
     assert patcher.g.num_nodes == n0 + 4
     assert patcher.sg is t.sg
     t2, _ = _fresh_rebuild(patcher, sg, cfg, tcfg)
-    _assert_data_bit_identical(t, t2)
+    _assert_data_bit_identical(t, t2, but=("bkt_",))
+    if spmm == "bucket":
+        _assert_bucket_tables_sum_the_same(t, t2)
     # eval parity on the patched graph: identical params through both
     # stacks must score identically (the forward pass IS the tables)
     t2.state = dict(t2.state)
@@ -104,6 +113,65 @@ def test_patched_tables_bit_identical_to_rebuild(spmm):
     assert a1 == a2
     # ...and training continues finite on the patched tables
     assert np.isfinite(t.train_epoch(0))
+
+
+def _assert_bucket_tables_sum_the_same(t, t2):
+    from test_bucket_spmm import _table_neighbours
+
+    n_src_rows = t.sg.n_max + t.sg.halo_size
+    for stem, sentinel in (("bkt_fwd", n_src_rows), ("bkt_bwd", t.sg.n_max)):
+        assert _table_neighbours(t._bucket_tables, stem, sentinel) == \
+            _table_neighbours(t2._bucket_tables, stem, sentinel)
+
+
+def _bucket_widths_of(t):
+    return {k: v.shape[1] for k, v in t._bucket_tables.items()
+            if not k.endswith("inv")}
+
+
+def test_bucket_delta_keeps_the_ladder_and_a_grown_row_refits():
+    """Under the bucket kernel a within-slack delta is a DIRTY rebuild:
+    the fitted widths it was compiled for stay (same jitted step, same
+    table widths, from the first delta on), and the tables sum what
+    a from-scratch build of the patched graph sums. A delta that grows
+    a row past the ladder's top width refits, and still sums the same."""
+    g, parts, sg, cfg, tcfg, t, patcher = _stack(slack=0.30,
+                                                 spmm="bucket")
+    assert np.isfinite(t.train_epoch(0))
+    step_before, widths = t._step, _bucket_widths_of(t)
+    ladder = t._bucket_plan_cache["widths"]
+    for d, fitted in zip(("bkt_fwd", "bkt_bwd"), ladder):
+        assert [w for k, w in sorted(widths.items())
+                if k.startswith(d)] == list(fitted)
+    b = synthetic_delta_schedule(g, n_batches=1, edges_per_batch=6,
+                                 dels_per_batch=2, nodes_per_batch=1,
+                                 seed=3)[0]
+    rep = t.apply_graph_deltas(b)
+    assert not rep.repadded and rep.touched_parts
+    assert t._step is step_before
+    assert t._bucket_plan_cache["widths"] == ladder
+    assert _bucket_widths_of(t) == widths
+    t2, _ = _fresh_rebuild(patcher, sg, cfg, tcfg)
+    _assert_bucket_tables_sum_the_same(t, t2)
+    assert np.isfinite(t.train_epoch(1))
+    # the node of the most in-edges gets more than the top width holds,
+    # from nodes of its own partition that are no neighbours yet
+    deg = np.bincount(patcher.g.dst, minlength=patcher.g.num_nodes)
+    v = int(np.argmax(deg))
+    nbrs = set(patcher.g.src[patcher.g.dst == v].tolist())
+    mates = [u for u in np.nonzero(patcher.parts == patcher.parts[v])[0]
+             if u not in nbrs]
+    need = ladder[0][-1] - int(deg[v]) + 2
+    assert 0 < need <= len(mates)
+    rep = t.apply_graph_deltas(DeltaBatch.make(
+        seq=1, add_edges=[(u, v) for u in mates[:need]]))
+    assert not rep.repadded
+    refit = t._bucket_plan_cache["widths"]
+    assert refit != ladder and refit[0][-1] >= ladder[0][-1] + 2
+    t3, _ = _fresh_rebuild(patcher, sg, cfg, tcfg)
+    _assert_bucket_tables_sum_the_same(t, t3)
+    assert _bucket_widths_of(t) == _bucket_widths_of(t3)
+    assert np.isfinite(t.train_epoch(2))
 
 
 # ---------------- slack exhaustion -----------------------------------

@@ -12,8 +12,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from pipegcn_tpu.ops.bucket_spmm import (ROW_TILE, _rides_as_words,
-                                         bucket_aggregate)
+from pipegcn_tpu.ops.bucket_spmm import (DEFAULT_CHUNK_ELEMS, ROW_TILE,
+                                         _rides_as_words, bucket_aggregate,
+                                         chunk_rows)
 
 
 @pytest.fixture(scope="module")
@@ -73,18 +74,19 @@ def _arrays(shape: str):
     return out
 
 
-# Reddit's remainder at width 256 and Yelp's buckets at width 512, rows
-# as the chunking leaves them: (table shapes, source rows, F, dtype)
+# Reddit's remainder at width 256 and Yelp's buckets at width 512, at
+# widths fitted to their degree histograms (fit_widths; the narrowest,
+# a middle and the widest bucket of each, rows as the benchmark's
+# graphs fill them, PERF.md section 6, PR 34), cut into chunks as
+# chunk_rows cuts them: (table shapes, source rows, F, dtype)
+_REDDIT = [(90, 55_552), (104, 59_008), (538, 416)]
 CASES = {
-    "reddit-e4m3": ([(13, 2048), (141, 20000 // 32 * 32), (316, 4000)],
-                    233_000, 256, jnp.float8_e4m3fn),
-    "reddit-e5m2": ([(13, 2048), (141, 20000 // 32 * 32), (316, 4000)],
-                    233_000, 256, jnp.float8_e5m2),
-    "reddit-bf16": ([(13, 2048), (141, 20000 // 32 * 32), (316, 4000)],
-                    233_000, 256, jnp.bfloat16),
-    "yelp-e4m3": ([(9, 150_016), (13, 200_000 // 32 * 32), (19, 90_016)],
+    "reddit-e4m3": (_REDDIT, 233_000, 256, jnp.float8_e4m3fn),
+    "reddit-e5m2": (_REDDIT, 233_000, 256, jnp.float8_e5m2),
+    "reddit-bf16": (_REDDIT, 233_000, 256, jnp.bfloat16),
+    "yelp-e4m3": ([(7, 92_960), (11, 89_696), (32, 2528)],
                   717_000, 512, jnp.float8_e4m3fn),
-    "f32": ([(13, 2048), (141, 20000 // 32 * 32)], 233_000, 64,
+    "f32": ([(90, 2048), (104, 20000 // 32 * 32)], 233_000, 64,
             jnp.float32),
 }
 
@@ -116,8 +118,10 @@ def test_reduce_reads_the_transport_dtype_on_the_chip(one_chip, case):
     slab = min(f, 256 // item)
     words = _rides_as_words(dt, slab)
     assert words == (item == 1)
-    chunks = [(w, min(r, 32 * 1024 * 1024 // (w * slab) // 32 * 32))
+    chunks = [(w, chunk_rows(w, r, slab, DEFAULT_CHUNK_ELEMS)[0])
               for w, r in shapes]
+    assert all(c % ROW_TILE == 0 and c <= r for (_, c), (_, r)
+               in zip(chunks, shapes))
     messages = {w * r * slab for w, r in chunks}
     # per element a message is `item` bytes; as words, half as many
     # elements of two bytes. Anything wider of either count is a copy

@@ -97,8 +97,8 @@ def test_sample_slice_preserves_degree_distribution():
     assert sample.n_max + sample.halo_size == sg.n_max + sg.halo_size
     assert 0 < sample.n_max < sg.n_max
     assert info["sample_edges"] == int(sample.edge_count[0])
-    assert info["full_edges"] >= info["sample_edges"]
-    assert info["scale"] >= 1.0
+    assert info["full_edges"] >= info["shard_edges"] \
+        >= info["sample_edges"]
     # each sampled destination keeps its FULL in-edge list, so every
     # sampled in-degree exists in the source shard's distribution
     ec = int(sg.edge_count[0])
@@ -259,7 +259,8 @@ def test_shard_under_budget_is_taken_whole():
     assert sample.n_max == sg.n_max and sample.halo_size == sg.halo_size
     assert np.array_equal(sample.edge_src[0], sg.edge_src[0])
     assert np.array_equal(sample.edge_dst[0], sg.edge_dst[0])
-    assert info["scale"] == 1.0
+    assert info["sample_edges"] == info["shard_edges"]
+    assert tuner.nested_blocks(info) == list(range(-(-sg.n_max // _BLOCK)))
     assert _stats(sample) == _stats(sg)
 
 
@@ -278,7 +279,8 @@ def test_multipart_shard_keeps_halo_sources_behind_n_max():
                                       block_rows=128)
     r = info["sampled_rank"]
     assert r == int(np.argmax(sg.edge_count))
-    assert info["scale"] == int(sg.edge_count[r]) / info["sample_edges"]
+    assert info["shard_edges"] == int(sg.edge_count[r]) \
+        > info["sample_edges"]
     assert sample.n_max < sg.n_max
     assert sample.n_max + sample.halo_size == sg.n_max + sg.halo_size
     ec = int(sg.edge_count[r])
@@ -336,17 +338,24 @@ def test_timed_program_keeps_forward_and_backward(name):
                                    rtol=5e-3, atol=0.5)
 
 
-def _cost(name, impl, s, spread, **kw):
+def _cost(name, impl, est, est_spread, **kw):
+    """A cost entry whose estimate at the shard's size is `est` s (the
+    reps allow `est_spread` more); on the SAMPLE every candidate read
+    the same 5 ms, so nothing but est_call_s can rank them."""
+    timed = None if est is None else 5e-3
     return dict({"name": name, "impl": impl, "rem_dtype": None,
                  "rem_amax": False, "block_group": 1,
-                 "spmm_fwdbwd_s": s, "spread_s": spread,
-                 "est_epoch_spmm_s": s, "error": None}, **kw)
+                 "spmm_fwdbwd_s": timed, "spread_s": timed and 0.0,
+                 "fixed_s": timed and 0.0,
+                 "per_edge_s": est and est / 1e6,
+                 "est_call_s": est, "est_spread_s": est_spread,
+                 "est_epoch_spmm_s": est, "error": None}, **kw)
 
 
 @pytest.mark.parametrize("costs,want", [
-    # (f) where the ranges of their reps overlap the clock cannot tell
-    # them apart: the fixed preference order decides, DEFAULT_IMPL's
-    # family first
+    # (f) where the argmin's estimate, with the range its reps allow,
+    # reaches another's, the clock cannot tell them apart: the fixed
+    # preference order decides, DEFAULT_IMPL's family first
     ([_cost("xla", "xla", 1.01e-2, 1e-4),
       _cost("bucket", "bucket", 1.04e-2, 0.0),
       _cost("block-u4", "block", 1.00e-2, 5e-4)], "bucket"),
@@ -378,6 +387,9 @@ def _cost(name, impl, s, spread, **kw):
      tuner.DEFAULT_IMPL),
 ])
 def test_near_tie_falls_to_the_preference_order(costs, want):
+    """pick_winner ranks est_call_s (every entry's sampled time is the
+    same 5 ms) and keeps its shape: argmin, near-ties by the argmin's
+    spread, then the preference order."""
     win = tuner.pick_winner(costs)
     assert win["name"] == want
     assert win["impl"] in ("xla", "bucket", "block")
@@ -410,6 +422,128 @@ def test_raw_edge_kernel_is_asked_at_the_shards_size(monkeypatch):
     tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16",
                block_group=4, edge_budget=10 ** 9)
     assert len(asked) == 1
+
+
+def _planted_clock(monkeypatch, line):
+    """Replace the clock: a candidate `name` reads fixed + per_edge *
+    (the timed sample's edges) seconds on every rep, `line[name]` =
+    (fixed, per_edge) or a function of the edges. Returns the list of
+    (name, edges) timed."""
+    timed = []
+
+    def clock(sample, cand, width, *, reps, **kw):
+        e = int(sample.edge_count[0])
+        timed.append((cand["name"], e))
+        fixed, per_edge = line.get(cand["name"], (1.0, 1e-6))
+        return [fixed + per_edge * e] * reps
+
+    monkeypatch.setattr(tuner, "_time_candidate", clock)
+    return timed
+
+
+@pytest.mark.parametrize("case", ["per-edge cost decides",
+                                  "near-tie on the estimate"])
+def test_candidates_are_ranked_at_the_shards_size(monkeypatch, case):
+    """Two candidates that read the SAME seconds on the larger sample,
+    one by a fixed cost and a cheap edge, the other by an edge 2.5
+    times dearer: the sampled time cannot rank them and the preference
+    order would hand the tie to the bucket family; est_call_s ranks the
+    cheap edge first, by the factor the shard's edges make of it. Where
+    the estimates themselves are a near-tie, the preference order still
+    decides."""
+    sg = _planted_shard()
+    shard = int(sg.edge_count[0])
+    _, info = tuner.sample_slice(sg, edge_budget=shard // 4,
+                                 block_rows=_BLOCK)
+    e1 = info["sample_edges"]
+    _, small = tuner.sample_slice(sg, block_rows=_BLOCK,
+                                  blocks=tuner.nested_blocks(info))
+    e2 = small["sample_edges"]
+    assert e2 < e1 < shard
+    assert set(small["block_starts"]) < set(info["block_starts"])
+    edge = 2e-9
+    dear = 2.5 * edge if case == "per-edge cost decides" \
+        else edge * (1 + 1e-4)
+    line = {"block-u4-bf16": (dear * e1 - edge * e1, edge),
+            "bucket-bf16": (0.0, dear)}
+    timed = _planted_clock(monkeypatch, line)
+    rec = tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16",
+                     block_group=4, edge_budget=shard // 4, reps=2)
+    assert rec["timed_edges"] == [e1, e2] and rec["shard_edges"] == shard
+    assert timed.count(("bucket-bf16", e1)) == 1 \
+        and timed.count(("bucket-bf16", e2)) == 1
+    cost = {c["name"]: c for c in rec["costs"]}
+    a, b = cost["block-u4-bf16"], cost["bucket-bf16"]
+    assert a["spmm_fwdbwd_s"] == pytest.approx(b["spmm_fwdbwd_s"])
+    assert a["per_edge_s"] == pytest.approx(edge)
+    assert b["per_edge_s"] == pytest.approx(dear)
+    assert b["fixed_s"] == pytest.approx(0.0, abs=1e-12)
+    for c in (a, b):
+        assert c["est_call_s"] == pytest.approx(
+            c["fixed_s"] + c["per_edge_s"] * shard)
+        assert c["est_epoch_spmm_s"] == pytest.approx(
+            c["est_call_s"] * rec["spmm_per_epoch"], abs=1e-6)
+    if case == "per-edge cost decides":
+        assert b["est_call_s"] > 1.5 * a["est_call_s"]
+        assert rec["winner"]["name"] == "block-u4-bf16"
+        return
+    # a planted clock has no spread: the tie needs the argmin's
+    assert rec["winner"]["name"] == "block-u4-bf16"
+    a["est_spread_s"] = b["est_call_s"] - a["est_call_s"]
+    assert tuner.pick_winner(rec["costs"])["name"] == "bucket-bf16"
+
+
+def test_shard_estimate_is_the_line_through_two_calls():
+    """fixed + per_edge * edges through both timed calls, the spreads
+    carried through the same arithmetic; a line that
+    would cross zero before the smaller sample goes through the origin
+    (the proportional estimate), and a smaller sample that reads no
+    faster leaves the whole time fixed."""
+    edges, shard = [1_000_000, 250_000], 100_000_000
+    got = tuner.shard_estimate([7e-3, 4e-3], [1e-4, 2e-4], edges, shard)
+    assert got["per_edge_s"] == pytest.approx(4e-9)
+    assert got["fixed_s"] == pytest.approx(3e-3)
+    assert got["est_call_s"] == pytest.approx(3e-3 + 0.4)
+    # the estimate is t1 * (1 + k) - t2 * k, k = 99M / 0.75M: so are
+    # the spreads of the two calls
+    assert got["est_spread_s"] == pytest.approx(1e-4 * 133 + 2e-4 * 132)
+    got = tuner.shard_estimate([8e-3, 1e-3], [0.0, 0.0], edges, shard)
+    assert got["fixed_s"] == 0.0 and got["per_edge_s"] == pytest.approx(8e-9)
+    assert got["est_call_s"] == pytest.approx(0.8)
+    got = tuner.shard_estimate([5e-3, 6e-3], [0.0, 0.0], edges, shard)
+    assert (got["fixed_s"], got["per_edge_s"], got["est_call_s"]) \
+        == (5e-3, 0.0, 5e-3)
+    # one sample: in proportion to the edges, no line
+    assert tuner.shard_estimate([5e-3], [1e-4], [1_000_000], shard) == {
+        "fixed_s": None, "per_edge_s": None,
+        "est_call_s": pytest.approx(0.5), "est_spread_s": pytest.approx(1e-2)}
+
+
+def test_shard_under_the_budget_is_timed_once(monkeypatch):
+    """A shard the budget covers is its own sample: every candidate is
+    timed on it alone, once, and ranked by that time."""
+    sg = _planted_shard()
+    shard = int(sg.edge_count[0])
+    timed = _planted_clock(monkeypatch, {"bucket-bf16": (3e-3, 0.0),
+                                         "block-u4-bf16": (2e-3, 0.0)})
+    rec = tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16",
+                     block_group=4, edge_budget=10 ** 9)
+    assert rec["timed_edges"] == [shard]
+    assert sorted(timed) == sorted(
+        (c["name"], shard) for c in rec["costs"])
+    assert all(c["est_call_s"] == c["spmm_fwdbwd_s"]
+               and c["fixed_s"] is None for c in rec["costs"])
+    assert rec["winner"]["name"] == "block-u4-bf16"
+    # a budget of one block leaves no smaller sample either: timed
+    # once, read in proportion to the edges
+    tuner.clear_memo()
+    del timed[:]
+    rec = tuner.tune(sg, 8, block_tile=_TILE, rem_dtype="bfloat16",
+                     block_group=4, edge_budget=1)
+    (e1,) = rec["timed_edges"]
+    assert e1 < shard and len(timed) == len(rec["costs"])
+    assert all(c["est_call_s"] == pytest.approx(
+        c["spmm_fwdbwd_s"] * shard / e1) for c in rec["costs"])
 
 
 def test_edge_budget_defaults_agree():
@@ -445,12 +579,12 @@ def test_cost_table_roundtrip_artifact(tmp_path, mmap):
         (c["spmm_fwdbwd_s"] is None) == (c["error"] is not None)
         for c in costs)
     ok = [c for c in costs if c["error"] is None]
-    best = min(ok, key=lambda c: c["spmm_fwdbwd_s"])  # measured argmin
+    best = min(ok, key=lambda c: c["est_call_s"])  # measured argmin
     # the winner is the argmin or a near-tie of it (pick_winner)
     assert win["name"] == tuner.pick_winner(costs)["name"]
     chosen = next(c for c in ok if c["name"] == win["name"])
-    assert chosen["spmm_fwdbwd_s"] - best["spmm_fwdbwd_s"] \
-        <= best["spread_s"]
+    assert chosen["est_call_s"] - best["est_call_s"] \
+        <= best["est_spread_s"]
     assert os.path.exists(tuner.tuning_path(path))
     assert np.isfinite(t1.train_epoch(0))
 
@@ -478,8 +612,10 @@ def _plant_table(path, sg, cfg, winner):
             int(sg.source_edge_checksum) & ((1 << 64) - 1),
         "signature": sig,
         "winner": winner,
-        "costs": [dict(winner, spmm_fwdbwd_s=1e-4,
-                       est_epoch_spmm_s=1e-3, error=None)],
+        "costs": [dict(winner, spmm_fwdbwd_s=1e-4, spread_s=0.0,
+                       fixed_s=None, per_edge_s=None, est_call_s=1e-4,
+                       est_spread_s=0.0, est_epoch_spmm_s=3e-4,
+                       error=None)],
     }
     tuner.save_tuning(path, rec)
     return rec
@@ -613,8 +749,10 @@ def test_tuning_record_schema_contract(tune):
     """The trainer-emitted tuning dict must satisfy the contracted
     obs record kind (tests/test_obs.py pins the field list): beside
     winner / source / costs, what the timed sample carried — numbers
-    from a live campaign, nulls from the no-measurement default."""
-    from pipegcn_tpu.obs.schema import TUNING_FIELDS, validate_record
+    from a live campaign, nulls from the no-measurement default — and
+    in every entry of costs what was ranked."""
+    from pipegcn_tpu.obs.schema import (TUNING_COST_FIELDS, TUNING_FIELDS,
+                                        validate_record)
 
     sg = _sharded(seed=41)
     t = Trainer(sg, _cfg(sg, tune=tune), TrainConfig(seed=0))
@@ -632,7 +770,13 @@ def test_tuning_record_schema_contract(tune):
     assert rec["sample_dense_coverage"] == rec["shard_dense_coverage"]
     assert 0.0 <= rec["shard_dense_coverage"] <= 1.0
     assert rec["sample_tile_rows"] == -(-sg.n_max // t.cfg.block_tile)
-    assert rec["call_overhead_s"] > 0
+    # ... and is timed once: its estimate is its time, no line
+    assert rec["timed_edges"] == [rec["shard_edges"]] \
+        == [int(sg.edge_count[0])]
+    assert all(set(TUNING_COST_FIELDS) <= set(c) for c in rec["costs"])
+    assert all(c["fixed_s"] is None and c["per_edge_s"] is None
+               and c["est_call_s"] == c["spmm_fwdbwd_s"]
+               for c in rec["costs"] if c["error"] is None)
     win = next(c for c in rec["costs"]
                if c["name"] == rec["winner"]["name"])
     assert rec["est_epoch_spmm_s"] == win["est_epoch_spmm_s"] >= 0
@@ -661,9 +805,8 @@ def test_tuner_times_what_the_step_runs(tmp_path):
     assert rec["signature"]["step_width"] == 16
     assert rec["spmm_per_epoch"] == 2            # layers 1 and 2
     for c in rec["costs"]:
-        want = max(c["spmm_fwdbwd_s"] - rec["call_overhead_s"], 0.0) \
-            * rec["scale"] * 2
-        assert c["est_epoch_spmm_s"] == pytest.approx(want, abs=1e-6)
+        assert c["est_epoch_spmm_s"] == pytest.approx(
+            c["est_call_s"] * 2, abs=1e-6)
     # a table timed over another operand is another table
     assert tuner.signature_for(
         width=48, block_tile=cfg.block_tile, bucket_merge=0,
@@ -671,15 +814,15 @@ def test_tuner_times_what_the_step_runs(tmp_path):
     assert np.isfinite(t.train_epoch(0))
 
 
-@pytest.mark.parametrize("old_format", [1, 2, 3, 4])
+@pytest.mark.parametrize("old_format", [1, 2, 3, 4, 5])
 def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
     """(e) A tuning.json timed on the row-wise sample (tuner format 1),
     on the destination-major bucket kernels (format 2), on fp8 rows
-    gathered element by element (format 3) or over the grid with the
-    streaming-slab twins (format 4) is stale whatever its checksum and
-    signature say: refused with the reason, re-tuned once, replaced on
-    disk."""
-    assert tuner.TUNER_FORMAT == 5
+    gathered element by element (format 3), over the grid with the
+    streaming-slab twins (format 4) or on row buckets of the x1.5
+    ladder (format 5) is stale whatever its checksum and signature
+    say: refused with the reason, re-tuned once, replaced on disk."""
+    assert tuner.TUNER_FORMAT == 6
     sg = _sharded(seed=51)
     path = str(tmp_path / "art")
     sg.save(path)
@@ -692,27 +835,30 @@ def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
     rec["tuner_format"] = old_format
     tuner.save_tuning(path, rec)
     got, reason = tuner.load_tuning(path)
-    assert got is None and reason == f"format {old_format} != 5"
+    assert got is None and reason == f"format {old_format} != 6"
     t = Trainer(sgl, cfg, TrainConfig(seed=0))
     assert t.tuning["source"] == "live"
     assert f"format {old_format}" in t.tuning["stale_reason"]
     healed, why = tuner.load_tuning(path)
-    assert why is None and healed["tuner_format"] == 5
+    assert why is None and healed["tuner_format"] == 6
     assert healed["winner"] == t.tuning["winner"]
     assert healed["sample_dense_coverage"] is not None
 
 
+@pytest.mark.parametrize("old_format", [6, 7])
 @pytest.mark.parametrize("impl", ["bucket", "block"])
-def test_format_6_tables_are_refused_and_rebuilt(tmp_path, impl):
+def test_older_format_tables_are_refused_and_rebuilt(tmp_path, impl,
+                                                     old_format):
     """A `*_tables.npz` stamped with table format 6 (bucket and
-    remainder tables destination-major, [P, cap, w]) is refused by
-    name, rebuilt slot-major and replaced on disk; the next trainer
-    loads the rebuilt file."""
+    remainder tables destination-major, [P, cap, w]) or 7 (slot-major,
+    on the x1.5 ladder's widths) is refused by name, rebuilt slot-major
+    at the fitted widths and replaced on disk; the next trainer loads
+    the rebuilt file and reports the same padding."""
     sg = _sharded(seed=52)
     path = str(tmp_path / "art")
     sg.save(path)
     sgl = ShardedGraph.load(path)
-    assert Trainer._TABLES_FORMAT == 7
+    assert Trainer._TABLES_FORMAT == 8
     t0 = Trainer(sgl, _cfg(sgl, spmm_impl=impl), TrainConfig(seed=0))
     assert t0.tables_source == "built in this run"
     fname, = [os.path.join(path, f) for f in os.listdir(path)
@@ -722,20 +868,30 @@ def test_format_6_tables_are_refused_and_rebuilt(tmp_path, impl):
     plain = [k for k in z if k.startswith(stem) and not k.endswith("inv")]
     assert plain and all(z[k].shape[-1] % 32 == 0 for k in plain)
     slot_major = {k: z[k].shape for k in plain}
-    # what PR 28's code left there: format 6, destination-major
-    z["__stamp__"] = np.asarray([6, z["__stamp__"][1]], np.uint64)
-    for k in plain:
-        z[k] = np.ascontiguousarray(z[k].transpose(0, 2, 1))
+    # what older code left there: PR 28's format 6, destination-major,
+    # or PR 30's format 7
+    z["__stamp__"] = np.asarray([old_format, z["__stamp__"][1]], np.uint64)
+    if old_format == 6:
+        for k in plain:
+            z[k] = np.ascontiguousarray(z[k].transpose(0, 2, 1))
     with open(fname, "wb") as f:
         np.savez(f, **z)
     t1 = Trainer(ShardedGraph.load(path), _cfg(sgl, spmm_impl=impl),
                  TrainConfig(seed=0))
     assert t1.tables_source == (
-        f"built in this run (refused {fname}: table format 6 != 7)")
+        f"built in this run (refused {fname}: table format "
+        f"{old_format} != 8)")
     healed = np.load(fname)
-    assert int(healed["__stamp__"][0]) == 7
+    assert int(healed["__stamp__"][0]) == 8
     assert {k: healed[k].shape for k in plain} == slot_major
     assert np.isfinite(t1.train_epoch(0))
     t2 = Trainer(ShardedGraph.load(path), _cfg(sgl, spmm_impl=impl),
                  TrainConfig(seed=0))
     assert t2.tables_source == f"loaded from {fname}"
+    # the padding report is read off the tables, built or loaded
+    assert t2.tables_pad == t0.tables_pad
+    for d in ("fwd", "bwd"):
+        pad = t2.tables_pad[d]
+        assert pad["slots"] >= pad["edges"] > 0
+        assert pad["widths"] == sorted(pad["widths"])
+        assert pad["pad_ratio"] == round(pad["slots"] / pad["edges"], 4)
