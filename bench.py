@@ -501,11 +501,10 @@ def _measure(args, backend, device_kind, n_parts, sg,
             # CLI convention: 0 means "use the break-even default"
             extras["dense_coverage"] = round(estimate_block_coverage(
                 sg, args.block_tile, w_hint,
-                nnz_threshold=args.block_nnz or None
-            ), 3)
-            extras["dense_blocks"] = int(
-                next(v for k, v in trainer._block_tables.items()
-                     if k in ("blk_a", "blk_a_bits")).shape[1])
+                nnz_threshold=args.block_nnz or None,
+                group=cfg.block_group), 3)
+            extras["dense_blocks"] = trainer.tables_pad["fwd"][
+                "dense_blocks"]
 
         # ---- overlap evidence: pipelined vs vanilla -------------------
         if not args.no_compare:
@@ -577,10 +576,10 @@ def _measure(args, backend, device_kind, n_parts, sg,
                     extras["dense_coverage"] = round(
                         estimate_block_coverage(
                             sg, args.block_tile, w_hint,
-                            nnz_threshold=args.block_nnz or None), 3)
-                    extras["dense_blocks"] = int(
-                        next(v for k, v in tr_win._block_tables.items()
-                             if k in ("blk_a", "blk_a_bits")).shape[1])
+                            nnz_threshold=args.block_nnz or None,
+                            group=tr_win.cfg.block_group), 3)
+                    extras["dense_blocks"] = tr_win.tables_pad["fwd"][
+                        "dense_blocks"]
                 # the vanilla-vs-pipelined comparison (if it ran) was
                 # measured on the DEFAULT config — relabel so no one
                 # divides default vanilla time by the candidate headline

@@ -265,9 +265,8 @@ def check_reference(winner: dict, nodes: int = 8_000, degree: int = 200,
         jax.jit(jax.grad(lambda f: (reference(f) ** 2).sum()))(fbuf))
     out = {"kernel": winner["name"], "nodes": nodes,
            "edges": int(sg.edge_count[0]),
-           "dense_tiles": int(next(
-               (v.shape[1] for k, v in tr.data.items()
-                if k in ("blk_a", "blk_a_bits")), 0)),
+           "dense_tiles": ((tr.tables_pad or {}).get("fwd") or {}).get(
+               "dense_blocks", 0),
            "fwd_median_rel_err": round(fwd, 5),
            "bwd_median_rel_err": round(bwd, 5)}
     check(np.isfinite(fwd) and fwd < REF_FWD_MEDIAN_REL,

@@ -105,10 +105,14 @@ def _local_parts(args):
 def _pad_text(tables_pad) -> str:
     """Trainer.tables_pad for the set-up line: per direction the row
     buckets' widths and gather slots an edge (bucket_spmm.pad_stats;
-    under the block kernel, the remainder's)."""
+    under the block kernel, the remainder's, and what the dense half
+    stores: block_spmm.dense_pad_stats)."""
     return "".join(
         f" | {d}: widths {t['widths']}, pad_ratio {t['pad_ratio']} "
         f"({t['slots']} slots / {t['edges']} edges)"
+        + (f", dense_pad {t['dense_pad']} ({t['dense_slots']} slots / "
+           f"{t['dense_blocks']} blocks, a_bytes {t['a_bytes']})"
+           if "dense_pad" in t else "")
         for d, t in (tables_pad or {}).items())
 
 

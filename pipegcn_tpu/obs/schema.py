@@ -17,7 +17,12 @@ from __future__ import annotations
 
 from typing import Dict, Mapping
 
-SCHEMA_VERSION = 17  # v17: the tuning record says what was RANKED:
+SCHEMA_VERSION = 18  # v18: the run record's `tables_pad` is typed
+#                      (TABLES_PAD_FIELDS a direction) and, under the
+#                      block kernel, says what the dense half STORES:
+#                      dense_blocks, dense_slots, dense_pad, a_bytes
+#                      (ops/block_spmm.py dense_pad_stats)
+#                 v17: the tuning record says what was RANKED:
 #                      every entry of `costs` carries fixed_s /
 #                      per_edge_s / est_call_s from two nested samples
 #                      (ops/tuner.py shard_estimate; TUNING_COST_FIELDS
@@ -58,6 +63,23 @@ RUN_FIELDS: Dict[str, str] = {
     "config": "object",          # model/train/CLI config snapshot
     "device": "object",          # platform / device_kind / counts
     "mesh": "object",            # n_parts, axis names/shape
+}
+
+# one direction (`fwd`, `bwd`) of a run record's `tables_pad`, where it
+# carries one (validate_record holds both to it): the row-bucket tables
+# in use (bucket_spmm.pad_stats) and, under the block kernel, what the
+# dense half stores (block_spmm.dense_pad_stats)
+TABLES_PAD_FIELDS: Dict[str, str] = {
+    "widths": "array",           # the row buckets that hold a row
+    "slots": "integer",          # gather requests: width x rows
+    "edges": "integer",          # entries that are no sentinel
+    "pad_ratio": "number",       # slots / edges
+}
+TABLES_PAD_DENSE_FIELDS: Dict[str, str] = {
+    "dense_blocks": "integer",   # [tile, tile] slots that hold an edge
+    "dense_slots": "integer",    # slots stored, pads and tails included
+    "dense_pad": "number",       # dense_slots / dense_blocks
+    "a_bytes": "integer",        # bytes of A one device stores
 }
 
 # one record per training epoch
@@ -588,6 +610,12 @@ def validate_record(rec: Mapping) -> None:
             raise ValueError(f"record without a string 'event': {rec!r}")
         return
     _check_fields(ev, rec, fields)
+    if ev == "run":
+        for d, pad in (rec.get("tables_pad") or {}).items():
+            _check_fields(f"run tables_pad.{d}", pad, TABLES_PAD_FIELDS)
+            if any(k in pad for k in TABLES_PAD_DENSE_FIELDS):
+                _check_fields(f"run tables_pad.{d}", pad,
+                              TABLES_PAD_DENSE_FIELDS)
     if ev == "tuning":
         for c in rec["costs"]:
             if not isinstance(c, dict):

@@ -26,9 +26,21 @@ Mechanics:
     holding per-edge 1.0 (duplicate edges accumulate).
   - Forward: per destination tile, sum_k A[blk_k] @ fbuf_tile[src_k]
     via one batched einsum inside a lax.scan over destination tiles.
-  - Backward: the same A blocks, transposed roles — per SOURCE tile,
-    sum_k A[blk_k]^T @ g_tile[dst_k] — so no scatter anywhere; the
-    remainder's backward is the bucket kernel's transpose tables.
+  - Backward: per SOURCE tile, sum_k A[blk_k]^T @ g_tile[dst_k] — so
+    no scatter anywhere; the remainder's backward is the bucket
+    kernel's transpose tables.
+  - A is STORED in the order the step reads it, once a direction: the
+    builder writes, for every class of output rows, the blocks of its
+    (row, slot) pairs side by side as ONE operand [rows, K, S(/8), G*T]
+    (the backward's copy holds the transposes: [rows, K, T(/8), G*S]),
+    zero blocks in the pad slots, rows padded to the chunk multiple.
+    Both directions run the one contraction "rksm,rksf->rmf" on a
+    slice of it: the step gathers no A block, lays none out again and
+    pads none. The output rows sit on the minor axis and the
+    contraction is packed along the second-minor one because that is
+    the layout the chip's compiler reads the einsum's left operand in
+    (tests/test_tpu_compile.py): stored otherwise it is copied there
+    every call.
   - Mean normalization (in_deg division) is applied once at the end,
     after dense + remainder parts are summed.
 
@@ -51,21 +63,32 @@ from .bucket_spmm import (
     build_tables_for_edges,
     degree_hist,
     fit_widths,
-    ladder_prefix,
     stack_to_caps,
     validate_bucket_tables,
 )
 
-# HBM budget for the per-device dense-A tensor (see
-# build_sharded_block_tables) — shared with estimate_block_coverage and
-# the multichip projection so every consumer predicts the same spill.
+
+# HBM budget for a device's dense-A tensors AS STORED: both directions'
+# arrangements, class and chunk pads and the stacked devices' shared
+# row caps included (budget_block_cap) — shared with
+# estimate_block_coverage and the multichip projection so every
+# consumer predicts the same spill.
 DENSE_A_BYTE_BUDGET = 2 << 30
 
+# bound on one dense-apply chunk's materialized A elements (unpacked,
+# compute dtype): 32M elems = 64 MB bf16
+_DENSE_CHUNK_ELEMS = 32 * 1024 * 1024
 
-def budget_block_cap(byte_budget: int, tile: int, bits: int = 1) -> int:
-    """Max dense A-blocks that fit `byte_budget` at `bits` per entry
-    (1 = the optimistic bit-packed encoding for 0/1 graphs)."""
-    return max(1, (int(byte_budget) * 8) // (tile * tile * bits))
+
+def _class_shape(rows: int, width: int, group: int,
+                 tile: int) -> Tuple[int, ...]:
+    """Leading axes a dense class of `rows` output rows is STORED
+    under: (rows,) where its unpacked A ([rows, width, tile,
+    group*tile]) is within _DENSE_CHUNK_ELEMS, else (n_chunks,
+    rows_per_chunk): the `xs` of the scan the kernel runs over it, the
+    tail filled with zero slots here so the device pads nothing."""
+    rpc = max(1, _DENSE_CHUNK_ELEMS // (group * width * tile * tile))
+    return (rows,) if rows <= rpc else (-(-rows // rpc), rpc)
 
 
 def _pad_rows(mat: np.ndarray, rows: int, fill) -> np.ndarray:
@@ -75,82 +98,30 @@ def _pad_rows(mat: np.ndarray, rows: int, fill) -> np.ndarray:
                   ((0, 0),) * (mat.ndim - 1), constant_values=fill)
 
 
-def pack_a_blocks(a_blocks: np.ndarray) -> np.ndarray:
-    """Bit-pack 0/1-valued dense blocks [B, T, S] -> uint8 [B, T, S//8].
+def pack_a_blocks(a_blocks: np.ndarray, axis: int = -1) -> np.ndarray:
+    """Bit-pack 0/1-valued dense blocks [B, T, S] along `axis` ->
+    uint8, that axis 8 times shorter.
 
     On simple graphs (edge multiplicity <= 1 — the common case after
     self-loop normalization) every A entry is 0 or 1, so one bit per
     entry suffices: 8x less HBM than int8, which buys 8x more dense
     blocks under the same byte budget. Little-endian bit order matches
-    the device-side unpack in _dense_apply."""
-    assert a_blocks.shape[-1] % 8 == 0, a_blocks.shape
+    the device-side _unpack_bits."""
+    assert a_blocks.shape[axis] % 8 == 0, a_blocks.shape
     assert a_blocks.max(initial=0.0) <= 1.0, "bit-packing needs 0/1 A"
-    return np.packbits(a_blocks.astype(bool), axis=-1, bitorder="little")
+    return np.packbits(a_blocks.astype(bool), axis=axis,
+                       bitorder="little")
 
 
-def _unpack_bits(blks: jax.Array, s: int, compute_dtype) -> jax.Array:
-    """Device-side inverse of pack_a_blocks on gathered [..., T, S//8]
-    uint8 blocks -> [..., T, S] in the compute dtype."""
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (blks[..., None] >> shifts) & jnp.uint8(1)
-    return bits.reshape(blks.shape[:-1] + (s,)).astype(compute_dtype)
-
-
-def _max_group_count(keys: np.ndarray, n_groups: int) -> int:
-    return max(int(np.bincount(keys, minlength=n_groups).max(initial=0)),
-               1)
-
-
-def _group_by_key(keys, vals_a, vals_b, n_groups, widths, pad_a, pad_b):
-    """Bucket the (vals_a[i], vals_b[i]) pairs of each key into
-    power-of-2 width classes by the key's pair count — the tile-level
-    analogue of bucket_spmm's degree bucketing. A flat [n_groups, K_max]
-    layout wastes (K_max - K_mean)/K_max of the dense path (measured 60%
-    at Reddit scale: K_max 90 vs K_mean 36); per-width classes bound the
-    padding at 2x and concentrate it in the cheap small-K classes.
-
-    Returns (mats, inv, counts): mats[w] = (a_mat, b_mat), each
-    [n_w, widths[w]] int32 padded with pad_a/pad_b; inv [n_groups] int32
-    mapping each key to its row in the width-class concatenation (keys
-    with no pairs -> sum(counts), the caller's zero sentinel row);
-    counts[w] = real rows in class w."""
-    order = np.argsort(keys, kind="stable")
-    va, vb = vals_a[order], vals_b[order]
-    cnt = np.bincount(keys, minlength=n_groups)
-    # The fill mask truncates at each key's class width, so a ladder
-    # whose top rung is below the max per-key count would silently drop
-    # (A-block, tile) pairs. Fail loudly instead of aggregating wrong.
-    max_cnt = int(cnt.max(initial=0))
-    if max_cnt > widths[-1]:
-        raise ValueError(
-            f"width ladder {tuple(widths)} tops out below the max "
-            f"per-key pair count {max_cnt}; pairs would be dropped")
-    ptr = np.zeros(n_groups + 1, np.int64)
-    np.cumsum(cnt, out=ptr[1:])
-    widths_arr = np.asarray(widths, dtype=np.int64)
-    wid = np.minimum(np.searchsorted(widths_arr, np.maximum(cnt, 1)),
-                     len(widths) - 1)
-    mats, counts = [], []
-    inv = np.full(n_groups, -1, np.int64)
-    offset = 0
-    for w_i, w in enumerate(widths):
-        rows = np.nonzero((wid == w_i) & (cnt > 0))[0]
-        n_w = rows.shape[0]
-        a_mat = np.full((n_w, w), pad_a, np.int32)
-        b_mat = np.full((n_w, w), pad_b, np.int32)
-        if n_w:
-            j = np.arange(w)[None, :]
-            mask = j < cnt[rows][:, None]
-            pos = (ptr[rows][:, None] + j)[mask]
-            r, c = np.nonzero(mask)
-            a_mat[r, c] = va[pos]
-            b_mat[r, c] = vb[pos]
-            inv[rows] = offset + np.arange(n_w)
-        mats.append((a_mat, b_mat))
-        counts.append(n_w)
-        offset += n_w
-    inv[inv < 0] = offset
-    return mats, inv.astype(np.int32), counts
+def _unpack_bits(a: jax.Array, compute_dtype) -> jax.Array:
+    """Device-side inverse of pack_a_blocks along the SECOND-MINOR
+    axis, the contraction's: uint8 [..., C/8, M] -> [..., C, M] in the
+    compute dtype. A byte row becomes eight rows under it and the minor
+    axis is not touched."""
+    shifts = jnp.arange(8, dtype=jnp.uint8)[:, None]
+    bits = (a[..., None, :] >> shifts) & jnp.uint8(1)
+    return bits.reshape(a.shape[:-2] + (a.shape[-2] * 8, a.shape[-1])
+                        ).astype(compute_dtype)
 
 
 def _group_union(keys: np.ndarray, others: np.ndarray, n_key_tiles: int,
@@ -250,10 +221,120 @@ def _group_union(keys: np.ndarray, others: np.ndarray, n_key_tiles: int,
     return classes, inv.astype(np.int32), counts, widths
 
 
+def occupied_blocks(sg, r: int, tile: int,
+                    n_src_tiles: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(ids, counts) of the (dst-tile, src-tile) blocks that hold a
+    real edge of device r, ids ascending: id = dst_tile * n_src_tiles
+    + src_tile. Goes through np.unique on the occupied ids (O(E)
+    memory) — a dense bincount over the n_dst_tiles x n_src_tiles id
+    space would be tens of GB at 10M-node-shard scale."""
+    dst = np.asarray(sg.edge_dst[r]).astype(np.int64)
+    real = dst < sg.n_max
+    src = np.asarray(sg.edge_src[r]).astype(np.int64)[real]
+    return np.unique((dst[real] // tile) * n_src_tiles + src // tile,
+                     return_counts=True)
+
+
+def _select_dense(counts: np.ndarray, thr: int,
+                  max_blocks: Optional[int]) -> np.ndarray:
+    """Mask of the occupied blocks that go the MXU path: `thr` edges
+    or more and, under a cap, the `max_blocks` densest of those (best
+    edges-replaced-per-byte; ties at the cutoff lose their lowest
+    ids); the rest spill to the sparse remainder. The ONE definition of
+    the split: BlockPlan, the coverage estimate and the budget share
+    it."""
+    sel = counts >= thr
+    if max_blocks is not None and int(sel.sum()) > max_blocks:
+        cutoff = np.sort(counts[sel])[-max_blocks]
+        sel &= counts >= cutoff
+        over = int(sel.sum()) - max_blocks
+        if over > 0:  # ties at the cutoff
+            sel[np.nonzero(sel & (counts == cutoff))[0][:over]] = False
+    return sel
+
+
+def _union_sizes(keys: np.ndarray, others: np.ndarray,
+                 group: int) -> np.ndarray:
+    """Distinct other-tiles of every group of `group` consecutive key
+    tiles that holds a dense block: the width _group_union's classes
+    are cut by, from the blocks' tile ids alone."""
+    if not keys.shape[0]:
+        return np.zeros(0, np.int64)
+    span = int(others.max()) + 1
+    pairs = np.unique((keys // group) * span + others)
+    return np.unique(pairs // span, return_counts=True)[1]
+
+
+def _class_shapes(sizes: Sequence[np.ndarray], group: int, tile: int):
+    """(widths, shapes) of one direction's dense classes over the
+    stacked devices: the x1.5 ladder up to the largest union any device
+    holds, and per rung the leading axes it is stored under
+    (_class_shape of the most rows any device files under it; None for
+    a rung no device fills)."""
+    top = max((int(u.max(initial=1)) for u in sizes), default=1)
+    widths = _bucket_widths(top)
+    w_arr = np.asarray(widths, dtype=np.int64)
+    caps = np.zeros(len(widths), np.int64)
+    for u in sizes:
+        caps = np.maximum(caps, np.bincount(
+            np.searchsorted(w_arr, u), minlength=len(widths)))
+    return widths, [_class_shape(int(c), w, group, tile) if c else None
+                    for w, c in zip(widths, caps)]
+
+
+def dense_a_bytes(blocks: Sequence[Tuple[np.ndarray, np.ndarray]],
+                  tile: int, bits: int, group: int = 1) -> int:
+    """Bytes of A one device STORES for the stacked devices' dense
+    blocks (`blocks`: per device the (dst-tile, src-tile) ids of its
+    blocks): both directions, every class at the shared row cap and
+    chunk multiple, the pad slots' zero blocks counted as the blocks
+    they are."""
+    slots = 0
+    for key, other in ((0, 1), (1, 0)):
+        widths, shapes = _class_shapes(
+            [_union_sizes(b[key], b[other], group) for b in blocks],
+            group, tile)
+        slots += sum(int(np.prod(shape)) * group * w
+                     for w, shape in zip(widths, shapes) if shape)
+    return slots * tile * tile * bits // 8
+
+
+def budget_block_cap(byte_budget: int, tile: int, bits: int,
+                     occupied: Sequence[Tuple[np.ndarray, np.ndarray]],
+                     thr: int, n_src_tiles: int,
+                     group: int = 1) -> Optional[int]:
+    """Most dense A-blocks a device may keep so that what the builder
+    STORES of them (dense_a_bytes at `bits` per entry, 1 = the
+    bit-packed encoding of 0/1 graphs) fits `byte_budget`; None where
+    every block of `thr` edges or more fits. `occupied`: per stacked
+    device its occupied_blocks. One cap for all devices, found by
+    bisection on the count of densest blocks kept (what is stored
+    grows with it but for a chunk's tail, and only a count that was
+    seen to fit is returned; at least 1)."""
+    def stored(cap):
+        kept = [ids[_select_dense(counts, thr, cap)]
+                for ids, counts in occupied]
+        return dense_a_bytes([(b // n_src_tiles, b % n_src_tiles)
+                              for b in kept], tile, bits, group)
+
+    hi = max((int(np.count_nonzero(c >= thr)) for _, c in occupied),
+             default=0)
+    if hi == 0 or stored(hi) <= byte_budget:
+        return None
+    lo = 1
+    while hi - lo > 1:
+        mid = (lo + hi) // 2
+        if stored(mid) <= byte_budget:
+            lo = mid
+        else:
+            hi = mid
+    return lo
+
+
 def estimate_block_coverage(sg, tile: int, n_feat_hint: int,
                             nnz_threshold: Optional[int] = None,
                             byte_budget: Optional[int] = DENSE_A_BYTE_BUDGET,
-                            ) -> float:
+                            group: int = 1) -> float:
     """Fraction of real edges lying in (dst-tile, src-tile) blocks dense
     enough for the MXU path (>= `nnz_threshold`, defaulting to
     BlockPlan's read-cost break-even).
@@ -262,22 +343,22 @@ def estimate_block_coverage(sg, tile: int, n_feat_hint: int,
     hybrid block kernel and the pure bucket kernel without paying for a
     full plan build. High coverage means the layout (usually
     cluster-renumbered, partition/halo.py `cluster`) concentrates
-    community edges into dense tiles. Counting goes through np.unique
-    on the occupied block ids (O(E) memory) — a dense bincount over the
-    n_dst_tiles x n_src_tiles id space would be tens of GB at
-    10M-node-shard scale.
+    community edges into dense tiles.
 
-    `byte_budget` mirrors build_sharded_block_tables' HBM cap: without
-    it the estimate counts dense blocks the real plan would spill, and
-    `auto` could pick the block kernel at a realized coverage far below
-    the threshold. The cap tracks the builder's A encoding: 1-bit
-    packing when the graph is simple (no duplicate edges) and
-    tile % 8 == 0, else the int8 cap (8x fewer blocks) — the bf16/f32
-    ratchets (multiplicity > 127) are rare enough to leave optimistic."""
+    `byte_budget` mirrors build_sharded_block_tables' HBM cap
+    (budget_block_cap, the builder's own): without it the estimate
+    counts dense blocks the real plan would spill, and `auto` could
+    pick the block kernel at a realized coverage far below the
+    threshold. The cap tracks the builder's A encoding: 1-bit packing
+    when the graph is simple (no duplicate edges) and tile % 8 == 0,
+    else the int8 cap — the bf16/f32 ratchets (multiplicity > 127)
+    are rare enough to leave optimistic."""
     thr = nnz_threshold if nnz_threshold is not None else max(
         1, (tile * tile) // max(n_feat_hint, 1))
     n_src_rows = sg.n_max + sg.halo_size
     n_src_tiles = -(-n_src_rows // tile)
+    occupied = [occupied_blocks(sg, r, tile, n_src_tiles)
+                for r in range(sg.num_parts)]
     cap = None
     if byte_budget is not None:
         bits = 1 if tile % 8 == 0 else 8
@@ -289,54 +370,44 @@ def estimate_block_coverage(sg, tile: int, n_feat_hint: int,
                 if np.unique(key).shape[0] < key.shape[0]:
                     bits = 8  # duplicate edges -> builder can't bit-pack
                     break
-        cap = budget_block_cap(byte_budget, tile, bits)
+        cap = budget_block_cap(byte_budget, tile, bits, occupied, thr,
+                               n_src_tiles, group)
     dense = tot = 0
     for r in range(sg.num_parts):
-        cov, _, d, t = _part_block_stats(sg, r, tile, n_src_tiles, thr,
-                                         max_blocks=cap)
+        _, _, d, t = _part_block_stats(sg, r, tile, n_src_tiles, thr,
+                                       max_blocks=cap,
+                                       occupied=occupied[r])
         dense += d
         tot += t
     return dense / max(tot, 1)
 
 
 def _part_block_stats(sg, r: int, tile: int, n_src_tiles: int, thr: int,
-                      max_blocks: Optional[int] = None):
+                      max_blocks: Optional[int] = None, occupied=None):
     """(coverage, dense_block_count, dense_edges, real_edges) of one
-    device's shard at the given tile/threshold — the single definition
-    of the dense/remainder split shared by estimate_block_coverage and
-    the multichip projection tool. `max_blocks` keeps only the densest
-    blocks, matching BlockPlan's budget cutoff."""
-    e = int(sg.edge_count[r])
-    src = sg.edge_src[r][:e].astype(np.int64)
-    dst = sg.edge_dst[r][:e].astype(np.int64)
-    real = dst < sg.n_max
-    src, dst = src[real], dst[real]
-    _, counts = np.unique((dst // tile) * n_src_tiles + (src // tile),
-                          return_counts=True)
-    sel = counts >= thr
-    if max_blocks is not None and int(sel.sum()) > max_blocks:
-        kept = np.sort(counts[sel])[-max_blocks:]
-        dense, n_dense = int(kept.sum()), int(kept.shape[0])
-    else:
-        dense, n_dense = int(counts[sel].sum()), int(sel.sum())
-    tot = int(src.shape[0])
-    return dense / max(tot, 1), n_dense, dense, tot
+    device's shard at the given tile/threshold — the dense/remainder
+    split (_select_dense) as estimate_block_coverage, the tuner and the
+    multichip projection tool read it. `max_blocks` keeps only the
+    densest blocks, matching BlockPlan's budget cutoff; `occupied`
+    spares the O(E) pass where the caller holds occupied_blocks."""
+    _, counts = occupied if occupied is not None else occupied_blocks(
+        sg, r, tile, n_src_tiles)
+    kept = counts[_select_dense(counts, thr, max_blocks)]
+    dense, tot = int(kept.sum()), int(counts.sum())
+    return dense / max(tot, 1), int(kept.shape[0]), dense, tot
 
 
 class BlockPlan:
     """Host-side hybrid plan for one device's edge list.
 
     Attributes (all numpy, static shapes):
-      a_blocks:    [B, T, S] f32 — dense block values (1.0 per edge);
-                   block B-1 is NOT special; a zero block is appended
-                   on device as index B.
-      fwd_groups/fwd_ginv/fwd_gcounts: destination tiles' (A-block,
-                   source-tile) pair lists, K-bucketed into power-of-2
-                   width classes (_group_by_key) so per-tile padding
-                   never exceeds 2x; fwd_ginv restores tile order from
-                   the class concatenation.
-      bwd_groups/bwd_ginv/bwd_gcounts: the transpose — per source tile,
-                   the A-block and destination-tile pairs.
+      a_blocks:    [B, T, S] f32 — dense block values (1.0 per edge),
+                   in block-id order (dst tile major).
+      block_dst/block_src: [B] the blocks' destination / source tile.
+      dense_classes(direction): the blocks filed by output row and
+                   slot (_group_union): per width class the index
+                   matrices the builders arrange A and the tile lists
+                   by (_dense_tables).
       rem_*:       remainder edges' bucket tables (fwd + transpose).
     """
 
@@ -346,8 +417,6 @@ class BlockPlan:
                  nnz_threshold: Optional[int] = None,
                  fwd_widths: Optional[Sequence[int]] = None,
                  bwd_widths: Optional[Sequence[int]] = None,
-                 fwd_k_widths: Optional[Sequence[int]] = None,
-                 bwd_k_widths: Optional[Sequence[int]] = None,
                  max_blocks: Optional[int] = None,
                  group: int = 1):
         T = S = tile
@@ -372,18 +441,9 @@ class BlockPlan:
 
         order = stable_argsort(bid)
         src_o, dst_o, bid_o = src[order], dst[order], bid[order]
-        uniq, starts, counts = np.unique(bid_o, return_index=True,
-                                         return_counts=True)
-        dense_sel = counts >= nnz_threshold
-        if max_blocks is not None and int(dense_sel.sum()) > max_blocks:
-            # HBM budget: keep only the densest blocks (best edges-
-            # replaced-per-byte); the rest spill to the sparse remainder
-            cutoff = np.sort(counts[dense_sel])[-max_blocks]
-            dense_sel &= counts >= cutoff
-            if int(dense_sel.sum()) > max_blocks:  # ties at the cutoff
-                over = int(dense_sel.sum()) - max_blocks
-                tie_idx = np.nonzero(dense_sel & (counts == cutoff))[0]
-                dense_sel[tie_idx[:over]] = False
+        uniq, counts = np.unique(bid_o, return_counts=True)
+        # HBM budget: under a cap only the densest blocks stay dense
+        dense_sel = _select_dense(counts, nnz_threshold, max_blocks)
 
         # ---- dense blocks ----
         dense_ids = uniq[dense_sel]
@@ -413,36 +473,9 @@ class BlockPlan:
             self.a_blocks[k0:k0 + n_blk] += np.bincount(
                 flat, minlength=n_blk * T * S
             ).astype(np.float32).reshape(n_blk, T, S)
-        bd = (dense_ids // n_src_tiles).astype(np.int64)
-        bs = (dense_ids % n_src_tiles).astype(np.int64)
-
-        blk_idx = np.arange(B, dtype=np.int64)
-        if self.group > 1:
-            # union-gather layout: `group` consecutive key tiles share
-            # one gathered union of other-tiles (see _group_union)
-            (self.fwd_u_classes, self.fwd_u_inv, self.fwd_u_counts,
-             self.fwd_k_widths) = _group_union(
-                bd, bs, n_dst_tiles, n_src_tiles, self.group, B,
-                widths=fwd_k_widths)
-            (self.bwd_u_classes, self.bwd_u_inv, self.bwd_u_counts,
-             self.bwd_k_widths) = _group_union(
-                bs, bd, n_src_tiles, n_dst_tiles, self.group, B,
-                widths=bwd_k_widths)
-        else:
-            self.fwd_k_widths = list(
-                fwd_k_widths if fwd_k_widths is not None
-                else _bucket_widths(_max_group_count(bd, n_dst_tiles)))
-            self.bwd_k_widths = list(
-                bwd_k_widths if bwd_k_widths is not None
-                else _bucket_widths(_max_group_count(bs, n_src_tiles)))
-            self.fwd_groups, self.fwd_ginv, self.fwd_gcounts = \
-                _group_by_key(bd, blk_idx, bs, n_dst_tiles,
-                              self.fwd_k_widths, pad_a=B,
-                              pad_b=n_src_tiles)
-            self.bwd_groups, self.bwd_ginv, self.bwd_gcounts = \
-                _group_by_key(bs, blk_idx, bd, n_src_tiles,
-                              self.bwd_k_widths, pad_a=B,
-                              pad_b=n_dst_tiles)
+        self.block_dst = (dense_ids // n_src_tiles).astype(np.int64)
+        self.block_src = (dense_ids % n_src_tiles).astype(np.int64)
+        self.dense_count = int(in_dense_o.sum())
 
         # ---- sparse remainder (bucket tables both directions) ----
         # widths fitted to the remainder's own degree histograms
@@ -480,141 +513,101 @@ class BlockPlan:
                 build_tables_for_edges(r_dst, r_src, self.n_src_rows,
                                        self.n_out, self.rem_bwd_widths)
 
+    def dense_classes(self, direction: str,
+                      widths: Optional[Sequence[int]] = None):
+        """_group_union of one direction's (output tile, other tile)
+        pairs: the forward files blocks by destination tile, the
+        backward by source tile; `group` consecutive output tiles share
+        a row. `widths`: the stacked devices' shared ladder (the plan's
+        own x1.5 ladder when None)."""
+        B = self.a_blocks.shape[0]
+        if direction == "fwd":
+            return _group_union(self.block_dst, self.block_src,
+                                self.n_dst_tiles, self.n_src_tiles,
+                                self.group, B, widths=widths)
+        return _group_union(self.block_src, self.block_dst,
+                            self.n_src_tiles, self.n_dst_tiles,
+                            self.group, B, widths=widths)
 
-# bound on one dense-apply chunk's materialized A elements (unpacked,
-# compute dtype): 32M elems = 64 MB bf16
-_DENSE_CHUNK_ELEMS = 32 * 1024 * 1024
 
+def _dense_apply(classes, inv, tiles, T, out_rows, n_feat, compute_dtype):
+    """For every output tile i: sum_k A[i, k] @ tiles[tile(i, k)], one
+    batched contraction a class of output rows ([R, G*T, K*S] @
+    [R, K*S, F] — MXU-shaped as stored, _dense_tables), forward and
+    backward alike: the backward's A is the transposes' arrangement.
 
-def _apply_classes(classes, compute, per_row_elems, pads, inv, out_tile,
-                   n_feat, out_rows):
-    """Shared scaffold of the dense applies (per-tile and grouped): run
-    `compute` over each class's index mats — chunked via a lax.scan
-    over padded row blocks whenever the per-chunk transient would
-    exceed _DENSE_CHUNK_ELEMS — then concatenate every class's output
-    tiles (plus one zero sentinel row), restore output-tile order with
-    `inv`, and flatten tiles to rows.
+    classes: [(a, t_mat)] per width class. `a` is the class's A as the
+    builder stored it, [R, K, S, G*T] in its STORED dtype (bf16 / f32 /
+    int8) or bit-packed uint8 [R, K, S/8, G*T]: `group` consecutive
+    output tiles share a row and the gathered union of their K other
+    tiles, t_mat [R, K] (pad -> the zero tile; pad slots of `a` are
+    zero blocks). The cast/unpack to the compute dtype happens per
+    chunk, so the full A is never materialized in a wider dtype. A
+    class over the chunk bound comes as [n_chunks, R, ...] (and t_mat
+    as [n_chunks, R, K]): the xs of a lax.scan, so the unpacked A
+    transient stays bounded and nothing is padded or gathered here.
+    tiles: [n_tiles+1, S, F] (last = zeros). `inv` restores output-tile
+    order from the class-concatenated [sum R * G] tile axis (plus one
+    zero sentinel row). Returns [n_out_tiles*T, F] f32."""
+    if not classes:  # no dense block at all: the remainder holds all
+        return jnp.zeros((out_rows, n_feat), jnp.float32)
+    def compute(a, ti):  # [R, K, S(/8), G*T], [R, K] -> [R, G*T, F] f32
+        with jax.named_scope("unpack"):
+            blks = _unpack_bits(a, compute_dtype) \
+                if a.dtype == jnp.uint8 else a.astype(compute_dtype)
+        with jax.named_scope("tile"):
+            tls = jnp.take(tiles, ti, axis=0,
+                           mode="clip")           # [R, K, S, F]
+            # k and s stay two contracted axes: merged into one the
+            # chip's compiler windows the contraction 5 to 13% slower
+            # (PERF.md section 6, PR 37)
+            return jnp.einsum("rksm,rksf->rmf", blks, tls,
+                              preferred_element_type=jnp.float32)
 
-    classes: list of index-mat tuples (leading axis = class rows);
-    compute(*mats) -> [rows, ..., out_tile, n_feat] f32 (extra middle
-    axes are flattened into the tile axis); per_row_elems(mats) ->
-    transient elements per row (the chunk divisor); pads: per-mat pad
-    constants for the scan's padded tail (must point at zero
-    blocks/tiles so pad rows compute zeros that get sliced away)."""
-    outs = []
-    for mats in classes:
-        n_w = mats[0].shape[0]
-        if n_w == 0:
-            continue
-        rpc = max(1, _DENSE_CHUNK_ELEMS // max(1, per_row_elems(mats)))
-        if n_w <= rpc:
-            out = compute(*mats)
+    # every class writes its rows (G output tiles each, side by side as
+    # the einsum leaves them) into ONE buffer, the class concatenation
+    # plus a zero sentinel row, in place: a scanned class carries it
+    # through its loop, and the einsum's fusion writes a chunk where it
+    # belongs. (With a result of its own a class, the chip's compiler
+    # keeps that in its fast memory for the whole loop, 111 MB of it
+    # for the widest Reddit class, and the operand's take then loses
+    # its place there: twice the time. Cut into output tiles before it
+    # is written, a chunk of ONE row becomes a plain matmul whose
+    # unpacked A is written out and laid out again. PERF.md section 6,
+    # PR 37.)
+    m = classes[0][0].shape[-1]
+    n_rows = sum(int(np.prod(a.shape[:-3])) for a, _ in classes)
+    res = jnp.zeros((n_rows + 1, m, n_feat), jnp.float32)
+    # a scan's carry keeps its type: under shard_map the result varies
+    # over the mesh axes its inputs vary over, from the first iteration
+    vma = jax.typeof(tiles).vma.union(
+        *(jax.typeof(a).vma for a, _ in classes))
+    if vma:
+        res = jax.lax.pcast(res, tuple(vma), to="varying")
+    off = 0
+    for a, ti in classes:
+        if a.ndim == 5:
+            def body(res, xs, off=off, step=a.shape[1]):
+                i, a_c, t_c = xs
+                out = compute(a_c, t_c)
+                with jax.named_scope("tile"):
+                    return jax.lax.dynamic_update_slice(
+                        res, out, (off + i * step, 0, 0)), None
+
+            res, _ = jax.lax.scan(
+                body, res, (jnp.arange(a.shape[0]), a, ti))
+            off += a.shape[0] * a.shape[1]
         else:
-            n_chunks = -(-n_w // rpc)
-            pad_rows = n_chunks * rpc - n_w
-            padded = tuple(
-                jnp.pad(m, ((0, pad_rows),) + ((0, 0),) * (m.ndim - 1),
-                        constant_values=p)
-                for m, p in zip(mats, pads))
-
-            def body(_, idx):
-                return None, compute(*idx)
-
-            _, chunks = jax.lax.scan(
-                body, None,
-                tuple(m.reshape((n_chunks, rpc) + m.shape[1:])
-                      for m in padded))
-            out = chunks.reshape((n_chunks * rpc,)
-                                 + chunks.shape[2:])[:n_w]
-        outs.append(out.reshape(-1, out_tile, n_feat))
-    # mode='clip': indices in-bounds by construction (appended zero
-    # rows are the sentinels) — fill-mode gathers are the one path
-    # that can mint NaN from valid data (bucket_spmm rationale)
+            out = compute(a, ti)
+            with jax.named_scope("tile"):
+                res = jax.lax.dynamic_update_slice(res, out, (off, 0, 0))
+            off += a.shape[0]
+    # mode='clip': indices in-bounds by construction (the last row's
+    # tiles are the sentinel) — fill-mode gathers are the one path that
+    # can mint NaN from valid data (bucket_spmm rationale)
     with jax.named_scope("unpermute"):
-        outs.append(jnp.zeros((1, out_tile, n_feat), jnp.float32))
-        res = jnp.take(jnp.concatenate(outs, axis=0), inv, axis=0,
-                       mode="clip")
-        return res.reshape(-1, n_feat)[:out_rows]
-
-
-def _dense_apply(a_pad, groups, ginv, tiles, T, out_rows, n_feat,
-                 compute_dtype, transpose=False, packed=False):
-    """For every output tile i: sum_k A[blk(i,k)] (@ or transposed-@)
-    tiles[tile(i,k)], where the (blk, tile) pair lists are K-bucketed
-    into power-of-2 width classes (`groups`: [(blk_mat, tile_mat)] per
-    class, `ginv` restoring tile order — see _group_by_key).
-
-    a_pad: [B+1, T, S] in its STORED dtype (possibly int8; last block =
-    zeros) — or, with packed=True, bit-packed [B+1, T, S//8] uint8 —
-    the cast/unpack to the compute dtype happens per chunk on the
-    gathered [R, K, T, S] slice, so the full A tensor is never
-    materialized in a wider dtype; likewise the backward's A^T lives in
-    the einsum spec, never as a transposed copy. tiles: [n_tiles+1, S,
-    F] (last = zeros). Returns [n_out_tiles*T, F] f32.
-
-    Each class runs as one batched contraction ([R, T, K*S] @
-    [R, K*S, F] after XLA canonicalization — MXU-shaped), chunked over
-    rows so the unpacked A transient stays bounded (_apply_classes)."""
-    spec = "rkts,rktf->rsf" if transpose else "rkts,rksf->rtf"
-    s = a_pad.shape[-1] * 8 if packed else a_pad.shape[-1]
-
-    def compute(bi, ti):  # [R, K] x2 -> [R, T, F] f32
-        with jax.named_scope("unpack"):
-            blks = jnp.take(a_pad, bi, axis=0, mode="clip")
-            blks = _unpack_bits(blks, s, compute_dtype) if packed \
-                else blks.astype(compute_dtype)
-        with jax.named_scope("tile"):
-            tls = jnp.take(tiles, ti, axis=0,
-                           mode="clip")           # [R, K, S|T, F]
-            return jnp.einsum(spec, blks, tls,
-                              preferred_element_type=jnp.float32)
-
-    # transients: unpacked A [R, K, T, S] + gathered tiles [R, K, S, F]
-    return _apply_classes(
-        groups, compute,
-        lambda mats: mats[0].shape[1] * s * max(T, n_feat),
-        (a_pad.shape[0] - 1, tiles.shape[0] - 1),
-        ginv, T, n_feat, out_rows)
-
-
-def _dense_apply_grouped(a_pad, classes, inv, tiles, T, out_rows,
-                         n_feat, compute_dtype, transpose=False,
-                         packed=False):
-    """Union-gather dense apply: for every group of `group` consecutive
-    output tiles, gather the union of the group's source tiles ONCE
-    ([R, U, S, F]) and consume it directly in one batched contraction
-    against the group's gathered A blocks ([R, group, U, T, S]) — the
-    per-tile F-traffic dedupe _group_union documents.
-
-    classes: [(a_idx [R, group, U_w], t_mat [R, U_w])] per U-width
-    class; inv restores output-tile order from the class-concatenated
-    [sum R_w * group] flat tile axis. Forward contracts (u, s) -> out
-    [R, group, T, F]; transpose contracts (u, t) -> [R, group, S, F]
-    (the backward's per-source-tile sum of A^T @ g)."""
-    spec = "rduts,rutf->rdsf" if transpose else "rduts,rusf->rdtf"
-    s = a_pad.shape[-1] * 8 if packed else a_pad.shape[-1]
-
-    def compute(ai, ti):  # [R, group, U] + [R, U] -> [R, group, T|S, F]
-        with jax.named_scope("unpack"):
-            blks = jnp.take(a_pad, ai, axis=0,
-                            mode="clip")          # [R, G, U, T, S(/8)]
-            blks = _unpack_bits(blks, s, compute_dtype) if packed \
-                else blks.astype(compute_dtype)
-        with jax.named_scope("tile"):
-            tls = jnp.take(tiles, ti, axis=0,
-                           mode="clip")           # [R, U, S|T, F]
-            return jnp.einsum(spec, blks, tls,
-                              preferred_element_type=jnp.float32)
-
-    # transients: unpacked A [R, G, U, T, S] + gathered union tiles
-    # [R, U, S, F] (F can exceed G*T on wide input layers); square
-    # tiles, so the output's in-tile dim is T in both directions
-    return _apply_classes(
-        classes, compute,
-        lambda mats: max(mats[0].shape[1] * mats[0].shape[2] * T * s,
-                         mats[0].shape[2] * s * n_feat),
-        (a_pad.shape[0] - 1, tiles.shape[0] - 1),
-        inv, T, n_feat, out_rows)
+        return jnp.take(res.reshape(-1, T, n_feat), inv, axis=0,
+                        mode="clip").reshape(-1, n_feat)[:out_rows]
 
 
 def make_block_spmm_fn(
@@ -628,16 +621,18 @@ def make_block_spmm_fn(
     rem_amax: bool = False,
 ):
     """Differentiable hybrid mean-aggregation closure f(fbuf [R, F]) ->
-    f32 [n_out, F]. `plan_arrays` holds the BlockPlan tensors (see
-    sharded_block_tables for keys), already stripped to per-device blocks
-    when used inside shard_map. `rem_dtype` narrows the REMAINDER's
-    gather transport only (bucket_spmm.transport_dtypes) — the dense
-    MXU path keeps the activation dtype. `rem_amax` swaps the static
+    f32 [n_out, F]. `plan_arrays` holds one device's tables (_dense_tables
+    and the remainder's, see build_sharded_block_tables for keys), the
+    leading device axis stripped when used inside shard_map. `rem_dtype`
+    narrows the REMAINDER's gather transport only
+    (bucket_spmm.transport_dtypes) — the dense MXU path keeps the
+    activation dtype. `rem_amax` swaps the static
     saturating fp8 cast for the amax-clamped one (the de-scale applies
     to the remainder alone, before it joins the dense partial).
 
     Named for the profiler (obs/profiler.py SCOPE_NAMES): `unpack` (the
-    A-block take, the bit unpack or cast to the compute dtype), `tile`
+    slice of a chunk's A, its bit unpack or cast to the compute dtype;
+    the compiler fuses the latter into the einsum), `tile`
     (the tiling of the operand, the tile take and the einsum),
     `unpermute` (output-tile order restored), `rem_gather` /
     `rem_reduce` / `rem_unpermute` (bucket_aggregate over the
@@ -668,44 +663,19 @@ def make_block_spmm_fn(
         return [d[k] for k in sorted(d)
                 if k.startswith(prefix) and not k.endswith("inv")]
 
-    def dense_groups(direction):  # [(blk_mat, tile_mat)] in width order
-        bs_ = sorted(k[:-1] for k in d
-                     if k.startswith(f"blk_{direction}_g")
-                     and k.endswith("b"))
-        return [(d[k + "b"], d[k + "t"]) for k in bs_]
-
-    def union_classes(direction):  # [(a_idx, t_mat)] in U-width order
-        bs_ = sorted(k[:-1] for k in d
-                     if k.startswith(f"blk_{direction}u_g")
-                     and k.endswith("a"))
-        return [(d[k + "a"], d[k + "t"]) for k in bs_]
-
-    grouped = "blk_fwdu_inv" in d
-    packed = "blk_a_bits" in d
-
-    def a_padded():
-        # append the zero block IN the stored dtype (bit-packed uint8 /
-        # int8/bf16/f32); the per-step unpack/cast to the compute dtype
-        # lives in _dense_apply
-        a = d["blk_a_bits"] if packed else d["blk_a"]
-        with jax.named_scope("unpack"):
-            return jnp.concatenate(
-                [a, jnp.zeros((1,) + a.shape[1:], a.dtype)], axis=0)
+    def dense_classes(direction):  # [(a, t_mat)] in width order
+        stems = sorted(k[:-1] for k in d
+                       if k.startswith(f"blk_{direction}_g")
+                       and k.endswith("a"))
+        return [(d[k + "a"], d[k + "t"]) for k in stems]
 
     @jax.custom_vjp
     def f(fbuf):
         n_s_tiles = -(-n_src_rows // T)
         tiles = tiles_of(fbuf, n_s_tiles, T)
-        if grouped:
-            dense = _dense_apply_grouped(
-                a_padded(), union_classes("fwd"), d["blk_fwdu_inv"],
-                tiles, T, n_out, fbuf.shape[-1], fbuf.dtype,
-                packed=packed)
-        else:
-            dense = _dense_apply(a_padded(), dense_groups("fwd"),
-                                 d["blk_fwd_ginv"], tiles, T, n_out,
-                                 fbuf.shape[-1], fbuf.dtype,
-                                 packed=packed)
+        dense = _dense_apply(dense_classes("fwd"), d["blk_fwd_inv"],
+                             tiles, T, n_out, fbuf.shape[-1],
+                             fbuf.dtype)
         rem_in, rem_inv = _rem_cast(fbuf, rem_fwd_dt)
         rem = bucket_aggregate(
             rem_in, rem_mats("blkrem_fwd_"), d["blkrem_fwd_inv"],
@@ -727,19 +697,13 @@ def make_block_spmm_fn(
             gd32 = g.astype(jnp.float32) / deg_col
         with jax.named_scope("cast"):
             gd = gd32.astype(proto.dtype)
-        # transpose dense: per source tile, sum A^T @ g_tile
+        # transpose dense: per source tile, sum A^T @ g_tile, the
+        # forward's contraction over the transposes' own arrangement
         n_d_tiles = -(-n_out // T)
         g_tiles = tiles_of(gd, n_d_tiles, T)
-        if grouped:
-            dense = _dense_apply_grouped(
-                a_padded(), union_classes("bwd"), d["blk_bwdu_inv"],
-                g_tiles, T, n_src_rows, g.shape[-1], gd.dtype,
-                transpose=True, packed=packed)
-        else:
-            dense = _dense_apply(a_padded(), dense_groups("bwd"),
-                                 d["blk_bwd_ginv"], g_tiles, T,
-                                 n_src_rows, g.shape[-1], gd.dtype,
-                                 transpose=True, packed=packed)
+        dense = _dense_apply(dense_classes("bwd"), d["blk_bwd_inv"],
+                             g_tiles, T, n_src_rows, g.shape[-1],
+                             gd.dtype)
         # the remainder's transport cast comes straight from the f32
         # cotangent — not through the proto.dtype rounding above
         # (matching bucket_spmm's single-rounding path)
@@ -759,31 +723,175 @@ def make_block_spmm_fn(
     return f
 
 
-def plan_to_arrays(p: BlockPlan) -> Dict[str, np.ndarray]:
-    """Flatten a BlockPlan into the array dict make_block_spmm_fn uses."""
-    arrs = {
-        "blk_a": p.a_blocks,
-        "blkrem_fwd_inv": p.rem_fwd_inv,
-        "blkrem_bwd_inv": p.rem_bwd_inv,
-    }
-    if p.group > 1:
-        arrs["blk_fwdu_inv"] = p.fwd_u_inv
-        arrs["blk_bwdu_inv"] = p.bwd_u_inv
-        for direction, classes in (("fwd", p.fwd_u_classes),
-                                   ("bwd", p.bwd_u_classes)):
+def _required_bits(plans: Sequence[BlockPlan], tile: int):
+    """(bits, dtype) of the narrowest exact encoding for the plans' A
+    counts: 1-bit packing (counts <= 1) buys 8x the dense coverage of
+    int8 (<= 127) per HBM byte, which in turn halves bf16 and quarters
+    f32 (the device unpacks/casts A to the activation dtype at use)."""
+    import ml_dtypes
+
+    a_max = max((float(p.a_blocks.max(initial=0.0)) for p in plans),
+                default=0.0)
+    if a_max <= 1 and tile % 8 == 0:  # pack_a_blocks needs 8 | tile
+        return 1, None  # bit-packed uint8 (pack_a_blocks)
+    if a_max <= 127:
+        return 8, np.int8
+    if a_max <= 256:
+        return 16, ml_dtypes.bfloat16
+    return 32, np.float32
+
+
+def _reoffset_inv(inv, counts, rows, group):
+    """A plan's inverse permutation (r * group + d over its own class
+    rows) moved to the rows each class is stored under (the shared
+    caps, chunk tails included); its sentinel to theirs."""
+    r_old = inv.astype(np.int64) // group
+    out = np.full_like(r_old, sum(rows) * group)
+    off_old = off_new = 0
+    for n_b, n_st in zip(counts, rows):
+        sel = (r_old >= off_old) & (r_old < off_old + n_b)
+        out[sel] = (r_old[sel] - off_old + off_new) * group \
+            + inv[sel] % group
+        off_old += n_b
+        off_new += n_st
+    return out.astype(np.int32)
+
+
+def _dense_tables(plans: Sequence[BlockPlan], bits: int,
+                  a_dtype) -> Dict[str, np.ndarray]:
+    """The dense half's tables of the stacked plans, a leading device
+    axis on each, per direction d in (fwd, bwd) and width class w:
+
+      blk_<d>_g<w>a  A in the order the kernel reads it (_dense_apply):
+                     [P, rows, K, tile(/8), G*tile], a class over the
+                     chunk bound [P, n_chunks, rows, ...]
+                     (_class_shape). Row r holds, side by side, the
+                     blocks of its G output tiles against the union of
+                     their K other tiles, each block with its
+                     CONTRACTED axis first (the forward's [S, T], the
+                     backward's [T, S]) and, at bits == 1, bit-packed
+                     along it; a slot with no block, a row past a
+                     device's own and a chunk's tail are zero blocks.
+      blk_<d>_g<w>t  [P, rows, K] int32 other-tile ids (pad -> the
+                     zero tile), under the same leading axes.
+      blk_<d>_inv    [P, n_key_tiles] output tile -> r * G + g in the
+                     class concatenation (no dense block -> the
+                     sentinel row behind it).
+
+    Rows are padded to the largest device's count a class: one traced
+    program serves every device."""
+    tile, group = plans[0].tile, plans[0].group
+
+    def encode(a, axis):  # [B, T, S] -> [B+1, contracted(/8), other]
+        enc = pack_a_blocks(a, axis=axis) if bits == 1 \
+            else a.astype(a_dtype)
+        if axis == 2:
+            enc = np.ascontiguousarray(enc.transpose(0, 2, 1))
+        return np.concatenate(
+            [enc, np.zeros((1,) + enc.shape[1:], enc.dtype)])
+
+    per_dev = [{} for _ in plans]
+    for d, axis in (("fwd", 2), ("bwd", 1)):
+        sizes = [_union_sizes(*((p.block_dst, p.block_src) if d == "fwd"
+                                else (p.block_src, p.block_dst)), group)
+                 for p in plans]
+        widths, shapes = _class_shapes(sizes, group, tile)
+        rows = [int(np.prod(shape)) if shape else 0 for shape in shapes]
+        for p, arrs in zip(plans, per_dev):
+            classes, inv, counts, _ = p.dense_classes(d, widths)
+            arrs[f"blk_{d}_inv"] = _reoffset_inv(inv, counts, rows, group)
+            enc = encode(p.a_blocks, axis)
+            zero_tile = p.n_src_tiles if d == "fwd" else p.n_dst_tiles
             for w_i, (a_idx, t_mat) in enumerate(classes):
-                if a_idx.shape[0]:
-                    arrs[f"blk_{direction}u_g{w_i:02d}a"] = a_idx
-                    arrs[f"blk_{direction}u_g{w_i:02d}t"] = t_mat
-    else:
-        arrs["blk_fwd_ginv"] = p.fwd_ginv
-        arrs["blk_bwd_ginv"] = p.bwd_ginv
-        for direction, groups in (("fwd", p.fwd_groups),
-                                  ("bwd", p.bwd_groups)):
-            for w_i, (a_mat, b_mat) in enumerate(groups):
-                if a_mat.shape[0]:
-                    arrs[f"blk_{direction}_g{w_i:02d}b"] = a_mat
-                    arrs[f"blk_{direction}_g{w_i:02d}t"] = b_mat
+                if not rows[w_i]:
+                    continue
+                # [n, G, K, C, M] -> [n, K, C, G*M]
+                n, c_in, m_in = a_idx.shape[0], enc.shape[1], enc.shape[2]
+                a = np.zeros((rows[w_i], widths[w_i], c_in,
+                              group * m_in), enc.dtype)
+                a[:n] = enc[a_idx].transpose(0, 2, 3, 1, 4).reshape(
+                    (n,) + a.shape[1:])
+                arrs[f"blk_{d}_g{w_i:02d}a"] = a.reshape(
+                    shapes[w_i] + a.shape[1:])
+                arrs[f"blk_{d}_g{w_i:02d}t"] = _pad_rows(
+                    t_mat, rows[w_i], zero_tile).astype(np.int32).reshape(
+                        shapes[w_i] + t_mat.shape[1:])
+    return {k: np.stack([arrs[k] for arrs in per_dev])
+            for k in per_dev[0]}
+
+
+def _dense_keys(tables, direction: str) -> List[str]:
+    """Stems 'blk_<direction>_g<w>' of one direction's dense classes,
+    in width order ('a' the stored A, 't' its tile ids)."""
+    return sorted(k[:-1] for k in tables
+                  if k.startswith(f"blk_{direction}_g")
+                  and k.endswith("a"))
+
+
+def dense_pad_stats(tables: Dict[str, np.ndarray], tile: int) -> dict:
+    """What the stacked tables' dense half stores, per direction, read
+    off the A arrays themselves (built or loaded): `dense_blocks` (the
+    slots that hold an edge, summed over the devices), `dense_slots`
+    (every [tile, tile] slot stored: pad slots, pad rows and chunk
+    tails with them, each an MXU pass a call), their ratio `dense_pad`,
+    `a_bytes` (the arrays' bytes on ONE device) and `dense_edges` ([P]
+    edges each device's A counts: a bit set under packing, the entries'
+    sum otherwise)."""
+    out = {}
+    for d in ("fwd", "bwd"):
+        blocks = slots = a_bytes = 0
+        n_dev = tables[f"blk_{d}_inv"].shape[0]
+        edges = np.zeros(n_dev, np.int64)
+        for stem in _dense_keys(tables, d):
+            a = np.asarray(tables[stem + "a"])
+            k, c, m = a.shape[-3:]
+            # [P, rows, K, C, G, tile]: one (k, g) pair a slot
+            a = a.reshape(n_dev, -1, k, c, m // tile, tile)
+            if a.dtype == np.uint8:
+                # the bits set, eight bytes of the minor axis at a time
+                cnt = np.bitwise_count(a.view(np.uint64))
+            else:
+                cnt = a.astype(np.float32)
+            per_slot = cnt.sum(axis=(3, 5), dtype=np.int64 if
+                               a.dtype == np.uint8 else np.float64)
+            blocks += int(np.count_nonzero(per_slot))
+            slots += per_slot.size
+            a_bytes += a.nbytes // n_dev
+            edges += np.rint(per_slot.sum(axis=(1, 2, 3))).astype(np.int64)
+        out[d] = {"dense_blocks": blocks, "dense_slots": int(slots),
+                  "dense_pad": round(slots / max(blocks, 1), 4),
+                  "a_bytes": int(a_bytes), "dense_edges": edges}
+    return out
+
+
+def validate_dense_tables(tables: Dict[str, np.ndarray], tile: int,
+                          n_edges: Optional[Sequence[int]] = None) -> dict:
+    """Edge conservation for the dense half, as validate_bucket_tables
+    has it for the remainder: each direction's A counts every dense
+    edge once — the two arrangements hold the same edges on every
+    device and, where given, `n_edges` of them ([P]: the edges the
+    remainder does not hold). Run at build and on a cache load: a
+    table that lost a block must not train. Returns dense_pad_stats
+    without the per-device counts."""
+    stats = dense_pad_stats(tables, tile)
+    fwd, bwd = (stats[d].pop("dense_edges") for d in ("fwd", "bwd"))
+    want = fwd if n_edges is None else np.asarray(n_edges, np.int64)
+    if not (np.array_equal(fwd, want) and np.array_equal(bwd, want)):
+        raise ValueError(
+            f"dense A tables hold {fwd.tolist()} (fwd) and "
+            f"{bwd.tolist()} (bwd) edges a device, expected "
+            f"{want.tolist()}: a block was lost or counted twice")
+    return stats
+
+
+def plan_to_arrays(p: BlockPlan) -> Dict[str, np.ndarray]:
+    """One plan's tables as make_block_spmm_fn takes them: the stacked
+    builder's (_dense_tables, A in the plan's own narrowest encoding)
+    with the device axis stripped, and the remainder's."""
+    arrs = {k: v[0] for k, v in _dense_tables(
+        [p], *_required_bits([p], p.tile)).items()}
+    arrs["blkrem_fwd_inv"] = p.rem_fwd_inv
+    arrs["blkrem_bwd_inv"] = p.rem_bwd_inv
     # remainder tables are slot-major [w, rows] (bucket_spmm): a
     # bucket with no row has no column
     for b, m in enumerate(p.rem_fwd_mats):
@@ -802,176 +910,65 @@ def build_sharded_block_tables(sg, tile: int = 256,
                                group: int = 1,
                                ) -> Tuple[Dict[str, np.ndarray], int]:
     """Stacked per-device hybrid plans (leading device axis), padded to
-    shared shapes: same B (dense block count), same K (per-tile block
-    list width), same remainder bucket ladders/caps. Returns
+    shared shapes: the dense classes' ladders and row caps
+    (_dense_tables), the remainder's bucket ladders and caps. Returns
     (tables, tile)."""
     P = sg.num_parts
     n_src_rows = sg.n_max + sg.halo_size
-    # HBM budget for the per-device dense-A tensor: keep the densest
-    # blocks under byte_budget, spill the rest to the sparse remainder.
-    # Past this size the A reads stop paying for the gathers they
-    # replace and, at Reddit scale, the table alone would crowd a v5e's
-    # 16 GB HBM (an unbudgeted clustered Reddit shard produced 6.5 GB).
-    # First pass assumes bit-packed A (1 bit per entry — the common
-    # case: simple graphs have 0/1 edge multiplicities); if the counts
-    # force a wider dtype, plans rebuild under the correspondingly
-    # smaller cap.
-    def cap_for(bits: int) -> int:
-        return budget_block_cap(byte_budget, tile, bits)
+    n_src_tiles = -(-n_src_rows // tile)
+    thr = nnz_threshold if nnz_threshold is not None else max(
+        1, (tile * tile) // max(n_feat_hint, 1))
+    # HBM budget for the per-device dense-A tensors: keep the densest
+    # blocks whose STORED arrangements fit byte_budget, spill the rest
+    # to the sparse remainder. Past this size the A reads stop paying
+    # for the gathers they replace and, at Reddit scale, the tables
+    # alone would crowd a v5e's 16 GB HBM (an unbudgeted clustered
+    # Reddit shard produced 6.5 GB). First pass assumes bit-packed A (1
+    # bit per entry — the common case: simple graphs have 0/1 edge
+    # multiplicities); if the counts force a wider dtype, plans rebuild
+    # under the correspondingly smaller cap.
+    occupied = [occupied_blocks(sg, r, tile, n_src_tiles)
+                for r in range(P)]
 
-    # narrowest exact encoding for the A counts: 1-bit packing (counts
-    # <= 1) buys 8x the dense coverage of int8 (<= 127) per HBM byte,
-    # which in turn halves bf16 and quarters f32 (the device
-    # unpacks/casts A to the activation dtype at use)
-    import ml_dtypes
-
-    def build_plans(cap, fw=None, bw=None, fk=None, bk=None):
-        # fresh ladders unless given: a different block cap changes
-        # which edges land in the remainder, and a ladder built for a
-        # different remainder can under-size its top bucket
-        # (build_tables_for_edges raises on one)
+    def build_plans(bits):
+        cap = budget_block_cap(byte_budget, tile, bits, occupied, thr,
+                               n_src_tiles, group)
         return [
             BlockPlan(sg.edge_src[r], sg.edge_dst[r], sg.n_max,
                       n_src_rows, n_feat_hint, tile=tile,
-                      nnz_threshold=nnz_threshold,
-                      fwd_widths=fw, bwd_widths=bw,
-                      fwd_k_widths=fk, bwd_k_widths=bk, max_blocks=cap,
-                      group=group)
+                      nnz_threshold=thr, max_blocks=cap, group=group)
             for r in range(P)
         ]
 
-    def required_bits(plans):
-        a_max = max((float(p.a_blocks.max(initial=0.0)) for p in plans),
-                    default=0.0)
-        if a_max <= 1 and tile % 8 == 0:  # pack_a_blocks needs S % 8
-            return 1, None  # bit-packed uint8 (pack_a_blocks)
-        if a_max <= 127:
-            return 8, np.int8
-        if a_max <= 256:
-            return 16, ml_dtypes.bfloat16
-        return 32, np.float32
-
-    # fixpoint on the A encoding: cap = budget / (bits per entry), but
-    # the counts (and thus the bits required for exactness) depend on
-    # which blocks the cap keeps. bits only ratchets up, so this
+    # fixpoint on the A encoding: the cap follows the bits per entry,
+    # but the counts (and thus the bits required for exactness) depend
+    # on which blocks the cap keeps. bits only ratchets up, so this
     # terminates in <= 4 builds. The SHIPPED encoding (emit_bits /
     # a_dtype) is re-read off the final plans: it may be narrower than
     # the cap assumed (e.g. the smaller cap dropped every multi-edge
     # block) — exact, merely under-using the budget.
     bits = 1
     while True:
-        plans = build_plans(cap_for(bits))
-        emit_bits, a_dtype = required_bits(plans)
+        plans = build_plans(bits)
+        emit_bits, a_dtype = _required_bits(plans, tile)
         if emit_bits <= bits:
             break
         bits = emit_bits
 
-    # unify ladders over the devices. The dense K classes keep the x1.5
-    # ladder at the longest device's length. The remainder's widths
-    # are fitted ONCE to the histograms of all the plans (known
-    # only now, after the dense selection). A re-build keeps the SAME
-    # cap, so the dense selection, and thus every remainder degree and
-    # per-tile block count, is unchanged and the unified ladders
-    # (covering the global max) are safe for every device; where only
-    # the remainder's widths differ from a plan's own fit (P > 1),
-    # only its remainder tables are rebuilt
-    fk_len = max(len(p.fwd_k_widths) for p in plans)
-    bk_len = max(len(p.bwd_k_widths) for p in plans)
-    fk = ladder_prefix(fk_len)
-    bk = ladder_prefix(bk_len)
+    # the remainder's widths are fitted ONCE to the histograms of all
+    # the plans (known only now, after the dense selection); where they
+    # differ from a plan's own fit (P > 1), its remainder tables alone
+    # are rebuilt. The dense classes keep the x1.5 ladder, at the
+    # longest device's length (_dense_tables)
     fw = fit_widths(degree_hist(p.rem_deg_in for p in plans))
     bw = fit_widths(degree_hist(p.rem_deg_out for p in plans))
-    if any(p.fwd_k_widths != fk or p.bwd_k_widths != bk for p in plans):
-        plans = build_plans(cap_for(bits), fw=fw, bw=bw, fk=fk, bk=bk)
-    else:
-        for p in plans:
-            p.set_remainder_widths(fw, bw)
-
-    B_max = max(p.a_blocks.shape[0] for p in plans)
-
-    def dense_counts(p, direction):
-        if group > 1:
-            return (p.fwd_u_counts if direction == "fwd"
-                    else p.bwd_u_counts)
-        return p.fwd_gcounts if direction == "fwd" else p.bwd_gcounts
-
-    fk_caps = [max(dense_counts(p, "fwd")[w] for p in plans)
-               for w in range(fk_len)]
-    bk_caps = [max(dense_counts(p, "bwd")[w] for p in plans)
-               for w in range(bk_len)]
-
-    def reoffset_inv(inv, counts, caps):
-        inv = inv.astype(np.int64)
-        out = np.full_like(inv, sum(caps))
-        off_old = off_new = 0
-        for n_b, cap in zip(counts, caps):
-            sel = (inv >= off_old) & (inv < off_old + n_b)
-            out[sel] = inv[sel] - off_old + off_new
-            off_old += n_b
-            off_new += cap
-        return out.astype(np.int32)
-
-    tables: Dict[str, List[np.ndarray]] = {}
     for p in plans:
-        B = p.a_blocks.shape[0]
-        a_pad = _pad_rows(p.a_blocks, B_max, 0.0)
-        arrs = {
-            # pad dense blocks to B_max with zero blocks; pad indices
-            # point at the appended zero block (index B_max on device)
-            ("blk_a_bits" if emit_bits == 1 else "blk_a"):
-                pack_a_blocks(a_pad) if emit_bits == 1
-                else a_pad.astype(a_dtype),
-        }
-        if group > 1:
-            # inv entries encode r * group + d; reoffset the row part
-            # to the shared per-class caps (sentinel sum(counts)*G ->
-            # sum(caps)*G falls out of reoffset_inv's default)
-            arrs["blk_fwdu_inv"] = (
-                reoffset_inv(p.fwd_u_inv // group, p.fwd_u_counts,
-                             fk_caps).astype(np.int64) * group
-                + p.fwd_u_inv % group).astype(np.int32)
-            arrs["blk_bwdu_inv"] = (
-                reoffset_inv(p.bwd_u_inv // group, p.bwd_u_counts,
-                             bk_caps).astype(np.int64) * group
-                + p.bwd_u_inv % group).astype(np.int32)
-            for direction, classes, caps in (
-                    ("fwd", p.fwd_u_classes, fk_caps),
-                    ("bwd", p.bwd_u_classes, bk_caps)):
-                for w_i, (a_idx, t_mat) in enumerate(classes):
-                    if not caps[w_i]:
-                        continue
-                    a_idx = np.where(a_idx == B, B_max, a_idx)
-                    arrs[f"blk_{direction}u_g{w_i:02d}a"] = _pad_rows(
-                        a_idx, caps[w_i], B_max).astype(np.int32)
-                    arrs[f"blk_{direction}u_g{w_i:02d}t"] = _pad_rows(
-                        t_mat, caps[w_i],
-                        p.n_src_tiles if direction == "fwd"
-                        else p.n_dst_tiles).astype(np.int32)
-        else:
-            arrs["blk_fwd_ginv"] = reoffset_inv(p.fwd_ginv,
-                                                p.fwd_gcounts, fk_caps)
-            arrs["blk_bwd_ginv"] = reoffset_inv(p.bwd_ginv,
-                                                p.bwd_gcounts, bk_caps)
-            for direction, groups, caps in (
-                    ("fwd", p.fwd_groups, fk_caps),
-                    ("bwd", p.bwd_groups, bk_caps)):
-                for w_i, (a_mat, b_mat) in enumerate(groups):
-                    if not caps[w_i]:
-                        continue
-                    # remap this device's pad-block id B to the shared
-                    # zero block B_max; pad rows point at it entirely
-                    # (the matching tile pad is the zero tile, already
-                    # shared)
-                    a_mat = np.where(a_mat == B, B_max, a_mat)
-                    arrs[f"blk_{direction}_g{w_i:02d}b"] = _pad_rows(
-                        a_mat, caps[w_i], B_max).astype(np.int32)
-                    arrs[f"blk_{direction}_g{w_i:02d}t"] = _pad_rows(
-                        b_mat, caps[w_i],
-                        p.n_src_tiles if direction == "fwd"
-                        else p.n_dst_tiles).astype(np.int32)
-        for k, v in arrs.items():
-            tables.setdefault(k, []).append(v)
-    stacked = {k: np.stack(v) for k, v in tables.items()}
+        p.set_remainder_widths(fw, bw)
+
+    stacked = _dense_tables(plans, emit_bits, a_dtype)
+    # every dense edge sits in each direction's A once
+    validate_dense_tables(stacked, tile,
+                          n_edges=[p.dense_count for p in plans])
     # the remainder's bucket tables, slot-major at shared row caps
     stacked.update(stack_to_caps(
         [(p.rem_fwd_mats, p.rem_fwd_inv) for p in plans], n_src_rows,

@@ -616,7 +616,7 @@ def tune(sg, width: int, *, block_tile: int = 256,
         return hit
 
     from .block_spmm import (DENSE_A_BYTE_BUDGET, _part_block_stats,
-                             budget_block_cap)
+                             budget_block_cap, occupied_blocks)
 
     cands = candidate_grid(block_group=block_group, rem_dtype=rem_dtype,
                            rem_amax=rem_amax)
@@ -650,14 +650,17 @@ def tune(sg, width: int, *, block_tile: int = 256,
         1, block_tile * block_tile // max(width, 1))
     n_src_tiles = -(-(sg.n_max + sg.halo_size) // block_tile)
     bits = 1 if block_tile % 8 == 0 else 8
-    shard_cov = _part_block_stats(
-        sg, info["sampled_rank"], block_tile, n_src_tiles, thr,
-        max_blocks=budget_block_cap(DENSE_A_BYTE_BUDGET, block_tile,
-                                    bits))[0]
-    sample_cov = _part_block_stats(
-        sample, 0, block_tile, n_src_tiles, thr,
-        max_blocks=budget_block_cap(byte_budget(sample), block_tile,
-                                    bits))[0]
+
+    def coverage(g, r, budget):
+        # under the cap the builder would keep this device's blocks to
+        occ = occupied_blocks(g, r, block_tile, n_src_tiles)
+        return _part_block_stats(
+            g, r, block_tile, n_src_tiles, thr, occupied=occ,
+            max_blocks=budget_block_cap(budget, block_tile, bits, [occ],
+                                        thr, n_src_tiles))[0]
+
+    shard_cov = coverage(sg, info["sampled_rank"], DENSE_A_BYTE_BUDGET)
+    sample_cov = coverage(sample, 0, byte_budget(sample))
 
     # every sample keeps the shard's source id space: one operand
     fbuf = _operand(sample, step_width)

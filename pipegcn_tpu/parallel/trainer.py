@@ -293,7 +293,10 @@ class Trainer:
     # multiple of 32 (ops/bucket_spmm.py)
     # v8: row-bucket widths fitted to the degree histogram
     # (bucket_spmm.fit_widths): tables of the x1.5 ladder must miss
-    _TABLES_FORMAT = 8
+    # v9: the block kernel's A stored per direction and class in the
+    # order the step reads it (block_spmm._dense_tables): one
+    # block-id-ordered table with its index matrices must miss
+    _TABLES_FORMAT = 9
 
     def _cached_tables(self, kind: str, build_fn):
         """Disk-cache derived kernel tables next to the partition
@@ -394,7 +397,9 @@ class Trainer:
         self.tuning = None
         self.tables_source = None  # set by _cached_tables
         # gather slots an edge and the widths chosen, per direction, of
-        # the tables in use (bucket_spmm.pad_stats), built or loaded
+        # the tables in use (bucket_spmm.pad_stats) and, under the
+        # block kernel, what its dense half stores
+        # (block_spmm.dense_pad_stats), built or loaded
         self.tables_pad = None
         if impl not in ("xla", "auto", "bucket", "block"):
             raise ValueError(f"unknown spmm_impl: {impl}")
@@ -457,8 +462,9 @@ class Trainer:
             self._bucket_tables, self.sg.n_max, n_src_rows)
 
     def _use_block(self) -> None:
-        from ..ops.block_spmm import build_sharded_block_tables
-        from ..ops.bucket_spmm import (bucket_pad_stats,
+        from ..ops.block_spmm import (build_sharded_block_tables,
+                                      validate_dense_tables)
+        from ..ops.bucket_spmm import (bucket_pad_stats, table_edges,
                                        validate_bucket_tables)
 
         w_hint = max(self.cfg.layer_sizes[:self.cfg.n_graph_layers])
@@ -473,14 +479,23 @@ class Trainer:
                 self.sg, tile=tile, n_feat_hint=w_hint,
                 nnz_threshold=nnz, group=grp)[0])
         self._block_tile = tile
-        # the remainder's tables, built or loaded: in bounds, and both
-        # directions hold the same edges (the builder counted them
-        # against the edges the dense blocks left)
+        # the tables, built or loaded: the remainder's in bounds, both
+        # its directions holding the same edges, and each direction's
+        # A counting every edge the remainder does not hold, once (the
+        # builder counted both against the edges it was given)
         n_src_rows = self.sg.n_max + self.sg.halo_size
         validate_bucket_tables(self._block_tables, self.sg.n_max,
                                n_src_rows, stem="blkrem")
         self.tables_pad = bucket_pad_stats(
             self._block_tables, self.sg.n_max, n_src_rows, stem="blkrem")
+        with self._timed("tables"):
+            rem = table_edges(self._block_tables, "blkrem_fwd", n_src_rows)
+            dense = validate_dense_tables(
+                self._block_tables, tile,
+                n_edges=[int(np.count_nonzero(d < self.sg.n_max)) - int(e)
+                         for d, e in zip(self.sg.edge_dst, rem)])
+        for d, stats in dense.items():
+            self.tables_pad[d].update(stats)
 
     def _resolve_auto(self) -> str:
         """Pick the concrete kernel for spmm_impl='auto' from measured
@@ -980,8 +995,7 @@ class Trainer:
         sg = sg if sg is not None else self.sg
         data = data if data is not None else self.data
         n_max = sg.n_max
-        use_tables = ("bkt_fwd_inv" in data) or ("blk_a" in data) \
-            or ("blk_a_bits" in data)
+        use_tables = ("bkt_fwd_inv" in data) or ("blk_fwd_inv" in data)
 
         def pp(d):
             d = {k: v[0] for k, v in d.items()}
@@ -1057,7 +1071,7 @@ class Trainer:
                 rem_dtype=rem_dtype,
                 rem_amax=cfg.rem_amax and transport,
             )
-        if "blk_a" in d or "blk_a_bits" in d:
+        if "blk_fwd_inv" in d:
             from ..ops.block_spmm import make_device_block_spmm_fn
 
             return make_device_block_spmm_fn(

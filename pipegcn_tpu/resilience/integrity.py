@@ -579,8 +579,7 @@ class IntegrityPlane:
         n_max, P = sg.n_max, trainer.P
         n_src = n_max + sg.halo_size
         data = trainer.data
-        use_tables = ("bkt_fwd_inv" in data) or ("blk_a" in data) \
-            or ("blk_a_bits" in data)
+        use_tables = ("bkt_fwd_inv" in data) or ("blk_fwd_inv" in data)
         keys = ["feat", "in_deg", "send_idx", "send_mask"]
         if use_tables:
             keys += [k for k in data

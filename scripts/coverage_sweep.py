@@ -87,14 +87,14 @@ def main():
     from pipegcn_tpu.graph import load_data
     from pipegcn_tpu.ops.block_spmm import (DENSE_A_BYTE_BUDGET,
                                             _part_block_stats,
-                                            budget_block_cap)
+                                            budget_block_cap,
+                                            occupied_blocks)
     from pipegcn_tpu.partition import ShardedGraph, locality_clusters
     from pipegcn_tpu.partition.partitioner import partition_graph
 
     g = load_data(args.dataset)
     parts = partition_graph(g, 1, seed=0)
     tile = args.tile
-    cap = budget_block_cap(DENSE_A_BYTE_BUDGET, tile)
 
     rows = []
     for tsize in args.cluster_sizes:
@@ -102,6 +102,7 @@ def main():
         cluster = locality_clusters(g, target_size=tsize, seed=0)
         sg = ShardedGraph.build(g, parts, n_parts=1, cluster=cluster)
         n_src_tiles = -(-(sg.n_max + sg.halo_size) // tile)
+        occupied = occupied_blocks(sg, 0, tile, n_src_tiles)
         build_s = time.time() - t0
         seen_thr = set()
         for thr0 in args.nnz:
@@ -109,8 +110,11 @@ def main():
             if thr in seen_thr:  # 0 resolves to the break-even, which
                 continue         # may duplicate an explicit entry
             seen_thr.add(thr)
+            cap = budget_block_cap(DENSE_A_BYTE_BUDGET, tile, 1,
+                                   [occupied], thr, n_src_tiles)
             cov, n_dense, dense_e, tot_e = _part_block_stats(
-                sg, 0, tile, n_src_tiles, thr, max_blocks=cap)
+                sg, 0, tile, n_src_tiles, thr, max_blocks=cap,
+                occupied=occupied)
             rem_e = tot_e - dense_e
             t_ep, t_d, t_r = model_epoch(
                 dense_e, rem_e, n_dense, tile,

@@ -58,7 +58,8 @@ def main():
     from pipegcn_tpu.graph import load_data
     from pipegcn_tpu.ops.block_spmm import (DENSE_A_BYTE_BUDGET,
                                             _part_block_stats,
-                                            budget_block_cap)
+                                            budget_block_cap,
+                                            occupied_blocks)
     from pipegcn_tpu.partition import (ShardedGraph, locality_clusters,
                                        partition_graph)
 
@@ -112,9 +113,12 @@ def main():
     # cap at the HBM byte budget exactly as the real plan builder does —
     # uncapped counts would project dense capacity the budgeted plan
     # spills to the remainder
-    cap = budget_block_cap(DENSE_A_BYTE_BUDGET, tile)
+    occupied = [occupied_blocks(sg, r, tile, n_src_tiles)
+                for r in range(P)]
+    cap = budget_block_cap(DENSE_A_BYTE_BUDGET, tile, 1, occupied, thr,
+                           n_src_tiles)
     stats = [_part_block_stats(sg, r, tile, n_src_tiles, thr,
-                               max_blocks=cap)
+                               max_blocks=cap, occupied=occupied[r])
              for r in range(P)]
     cov = np.array([st[0] for st in stats])
     dense_blocks = np.array([st[1] for st in stats])

@@ -130,8 +130,7 @@ def main():
     dense = variant("dense-only", dense_keep)
     rem = variant("rem-only",
                   lambda k: aux(k) or is_rem(k)
-                  or (is_dense(k) and (inv_only(k) or k in
-                                       ("blk_a", "blk_a_bits"))))
+                  or (is_dense(k) and inv_only(k)))
     print(f"# per-SpMM (fwd+bwd avg ~ epoch has 3 fwd + 3 bwd):")
     print(f"full fwd {full[0]*1e3:.1f} ms, fwd+bwd {full[1]*1e3:.1f} ms; "
           f"dense fwd {dense[0]*1e3:.1f}, rem fwd {rem[0]*1e3:.1f}")
@@ -139,21 +138,24 @@ def main():
     print(f"# est SpMM-only epoch: {est_epoch:.3f}s")
 
     if args.probe_traffic:
-        # Attribute the dense-only time between F-tile reads, A reads
-        # and the MXU term by TABLE SURGERY: identical program shapes,
-        # but every group entry points at tile/block 0, collapsing that
-        # operand's distinct HBM traffic to one tile. (Numerics are
-        # wrong on purpose; only time matters.) The F-tile delta decides
-        # whether the union-gather reuse design (docs/PERF_NOTES.md
-        # "F-tile reuse headroom") is worth building.
-        prefixes = ("blk_fwd_g", "blk_bwd_g",
-                    "blk_fwdu_g", "blk_bwdu_g")  # per-tile + grouped
+        # Attribute the dense-only time between F-tile reads and the
+        # rest (A's slices, the MXU term) by TABLE SURGERY: identical
+        # program shapes, but every tile index points at tile 0,
+        # collapsing the operand's distinct HBM traffic to one tile.
+        # (Numerics are wrong on purpose; only time matters.) The F-tile
+        # delta decides whether the union-gather reuse design
+        # (docs/PERF_NOTES.md "F-tile reuse headroom") is worth
+        # building. A itself is stored in reading order and sliced, not
+        # gathered (block_spmm._dense_tables): there is no A index to
+        # collapse, so the A-collapsed and pre-unpacked-A probes of the
+        # block-id-ordered table went with it.
+        prefixes = ("blk_fwd_g", "blk_bwd_g")
 
         def surgery(name, zero_suffix):
             saved = {}
             for k in list(d.keys()):
                 if k.startswith(prefixes):
-                    if k.endswith(zero_suffix) and not k.endswith("ginv"):
+                    if k.endswith(zero_suffix):
                         saved[k] = d[k]
                         d[k] = jnp.zeros_like(d[k])
             try:
@@ -162,46 +164,10 @@ def main():
                 d.update(saved)
 
         tile0 = surgery("tile0-dense", "t")   # all F-tile reads -> tile 0
-        # A-index matrices end with "b" in the per-tile layout, "a" in
-        # the grouped one
-        a0 = surgery("a0-dense", "a" if args.group > 1 else "b")
         print("# dense decomposition (fwd): "
               f"baseline {dense[0]*1e3:.1f} ms, "
               f"F-tile-collapsed {tile0[0]*1e3:.1f} ms "
-              f"(F-read share {(dense[0]-tile0[0])*1e3:.1f} ms), "
-              f"A-collapsed {a0[0]*1e3:.1f} ms "
-              f"(A-read share {(dense[0]-a0[0])*1e3:.1f} ms)")
-
-        # Unpack-transient probe: the same plan with A PRE-UNPACKED to
-        # bf16 on the host — no device-side bit unpack, so the
-        # [rows, K, T, S] elementwise transient (which XLA materializes
-        # between HBM round-trips; it cannot fuse producers into a dot)
-        # disappears, at the price of 16x the A-read bytes. (The
-        # fused unpack+matmul kernel this probe once motivated lost
-        # on-chip twice and was deleted — docs/PERF_NOTES.md "fused
-        # block kernel: negative result".) Note the a0 surgery above
-        # does NOT isolate this: collapsing indices to block 0 still
-        # unpacks every slot.
-        if "blk_a_bits" in d:
-            packed_bits = d.pop("blk_a_bits")
-            # np.unpackbits is the exact inverse of pack_a_blocks
-            # (bitorder='little'); upload the narrow uint8 and cast to
-            # bf16 eagerly on device (16x less host->device traffic
-            # than a host-widened array)
-            d["blk_a"] = jnp.asarray(np.unpackbits(
-                np.asarray(packed_bits), axis=-1, bitorder="little"
-            )).astype(jnp.bfloat16)
-            try:
-                unp = variant("wide-A-dense", dense_keep)
-            finally:
-                del d["blk_a"]
-                d["blk_a_bits"] = packed_bits
-            print("# unpack probe (fwd): packed "
-                  f"{dense[0]*1e3:.1f} ms vs pre-unpacked bf16 "
-                  f"{unp[0]*1e3:.1f} ms (transient-minus-read delta "
-                  f"{(dense[0]-unp[0])*1e3:.1f} ms)")
-        else:
-            unp = None
+              f"(F-read share {(dense[0]-tile0[0])*1e3:.1f} ms)")
 
         # machine-readable record so the cost-model recalibration
         # (scripts/coverage_sweep.py --gather-rps/--fixed-s) can
@@ -216,11 +182,8 @@ def main():
             "dense_fwd_s": dense[0], "dense_fwdbwd_s": dense[1],
             "rem_fwd_s": rem[0], "rem_fwdbwd_s": rem[1],
             "ftile_collapsed_fwd_s": tile0[0],
-            "a_collapsed_fwd_s": a0[0],
             "est_spmm_epoch_s": est_epoch,
         }
-        if unp is not None:
-            rec["wide_a_fwd_s"] = unp[0]
         # keyed by backend/config so a CPU smoke run or a different
         # group/fused probe never clobbers the real TPU calibration
         # record

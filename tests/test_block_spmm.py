@@ -41,6 +41,12 @@ def _ref_mean(src, dst, n_out, fbuf, deg):
     return out / np.asarray(deg)[:, None]
 
 
+def _dense_a(tables, direction="fwd"):
+    """The stored A arrays of one direction's dense classes."""
+    return [v for k, v in sorted(tables.items())
+            if k.startswith(f"blk_{direction}_g") and k.endswith("a")]
+
+
 def _make_fn(src, dst, n_out, n_src, deg, tile, nnz_threshold):
     plan = BlockPlan(src, dst, n_out, n_src, n_feat=8, tile=tile,
                      nnz_threshold=nnz_threshold)
@@ -140,6 +146,8 @@ def test_block_budget_spill_and_wide_counts_stay_exact():
     path): every edge must still be aggregated exactly once — spilled
     blocks' high-degree rows must not overflow a stale remainder
     ladder."""
+    import ml_dtypes
+
     from pipegcn_tpu.graph import synthetic_graph
     from pipegcn_tpu.graph.csr import Graph
     from pipegcn_tpu.ops.block_spmm import (
@@ -165,7 +173,9 @@ def test_block_budget_spill_and_wide_counts_stay_exact():
     # then halve the cap during the dtype rebuild
     tables, tile = build_sharded_block_tables(
         sg, tile=16, n_feat_hint=6, byte_budget=16 * 16 * 2)
-    assert tables["blk_a"].dtype != np.int8  # the wide-dtype path ran
+    # the wide-dtype path ran, in both directions' arrangements
+    assert {a.dtype for d in ("fwd", "bwd") for a in _dense_a(tables, d)} \
+        == {np.dtype(ml_dtypes.bfloat16)}
 
     fbuf_rows = sg.n_max + sg.halo_size
     fbuf = rng.standard_normal((fbuf_rows, 6)).astype(np.float32)
@@ -203,9 +213,7 @@ def test_trainer_block_clustered_matches_xla():
         losses[impl] = [t.train_epoch(e) for e in range(6)]
         if impl == "block":
             # the clustered layout must actually produce dense blocks
-            tb = t._block_tables
-            a_key = "blk_a_bits" if "blk_a_bits" in tb else "blk_a"
-            assert tb[a_key].shape[1] > 0
+            assert _dense_a(t._block_tables)
     np.testing.assert_allclose(losses["xla"], losses["block"], rtol=2e-4)
 
 
@@ -226,9 +234,10 @@ def test_trainer_block_bf16_fused():
 
 def test_bitpacked_a_parity_and_selection():
     """Simple graphs (0/1 edge multiplicity) ship A bit-packed: the
-    sharded builder must emit blk_a_bits (uint8, S//8 wide), the cap
-    must reflect the 8x cheaper encoding, and the device unpack must be
-    numerically identical to the unpacked plan."""
+    sharded builder must emit both directions' A as uint8 with the
+    contracted axis packed 8 to a byte, the cap must reflect the 8x
+    cheaper encoding, and the device unpack must be numerically
+    identical to the unpacked plan."""
     from pipegcn_tpu.ops.block_spmm import (
         build_sharded_block_tables,
         make_device_block_spmm_fn,
@@ -260,9 +269,13 @@ def test_bitpacked_a_parity_and_selection():
 
     tables, tile = build_sharded_block_tables(
         sg, tile=16, n_feat_hint=8, byte_budget=1 << 16)
-    assert "blk_a_bits" in tables and "blk_a" not in tables
-    a_bits = tables["blk_a_bits"]
-    assert a_bits.dtype == np.uint8 and a_bits.shape[-1] == tile // 8
+    for d in ("fwd", "bwd"):
+        assert _dense_a(tables, d)
+        for k in (k for k in tables if k.startswith(f"blk_{d}_g")
+                  and k.endswith("a")):
+            a_bits, width = tables[k], tables[k[:-1] + "t"].shape[-1]
+            assert a_bits.dtype == np.uint8
+            assert a_bits.shape[-3:] == (width, tile // 8, tile)
 
     fbuf_rows = sg.n_max + sg.halo_size
     fbuf = rng.standard_normal((fbuf_rows, 8)).astype(np.float32)
@@ -276,14 +289,14 @@ def test_bitpacked_a_parity_and_selection():
                     fbuf, sg.in_deg[0])
     np.testing.assert_allclose(out[:sg.n_max], ref, rtol=1e-5, atol=1e-5)
 
-    # pack/unpack round-trip on a raw block tensor
+    # pack/unpack round-trip on a raw block tensor: the device unpacks
+    # along the second-minor axis, the one the kernel contracts
     a = (rng.random((3, 16, 16)) < 0.3).astype(np.float32)
-    packed = pack_a_blocks(a)
-    import jax.numpy as jnp2
+    packed = pack_a_blocks(a, axis=1)
+    assert packed.shape == (3, 2, 16)
     from pipegcn_tpu.ops.block_spmm import _unpack_bits
 
-    unpacked = np.asarray(_unpack_bits(jnp2.asarray(packed), 16,
-                                       jnp2.float32))
+    unpacked = np.asarray(_unpack_bits(jnp.asarray(packed), jnp.float32))
     np.testing.assert_array_equal(unpacked, a)
 
 
@@ -323,7 +336,8 @@ def test_block_grouped_union_matches_dense(edges, group):
                      nnz_threshold=4, group=group)
     assert plan.a_blocks.shape[0] > 0
     arrs = {k: jnp.asarray(v) for k, v in plan_to_arrays(plan).items()}
-    assert "blk_fwdu_inv" in arrs  # grouped layout actually emitted
+    # grouped layout actually emitted: `group` output tiles a row
+    assert {a.shape[-1] for a in _dense_a(arrs)} == {group * 16}
     fn = make_block_spmm_fn(arrs, deg, n_out, n_src, 16)
     out = fn(fbuf)
     np.testing.assert_allclose(
@@ -357,15 +371,16 @@ def test_trainer_block_grouped_matches_xla():
         t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
         losses[impl] = [t.train_epoch(e) for e in range(6)]
         if impl == "block":
-            assert any(k.startswith("blk_fwdu_g") for k in t._block_tables)
+            assert {a.shape[-1] for a in _dense_a(t._block_tables)} \
+                == {4 * 32}
     np.testing.assert_allclose(losses["xla"], losses["block"], rtol=2e-4)
 
 
 @pytest.mark.parametrize("group", [1, 4])
 def test_chunked_scan_path_matches(edges, group, monkeypatch):
-    """Force _apply_classes' lax.scan chunking (tiny element budget) —
-    the padded-tail/reshape/slice logic must not change results in
-    either dense layout."""
+    """Force the builder's chunking (tiny element budget): a class
+    stored as the xs of a lax.scan, its tail zero slots, must not
+    change results in either dense layout."""
     import pipegcn_tpu.ops.block_spmm as bsp
 
     src, dst, n_out, n_src = edges
@@ -378,13 +393,14 @@ def test_chunked_scan_path_matches(edges, group, monkeypatch):
                      nnz_threshold=4, group=group)
     arrs = {k: jnp.asarray(v) for k, v in plan_to_arrays(plan).items()}
     fn = make_block_spmm_fn(arrs, deg, n_out, n_src, 16)
-    # reference values (fwd AND grad) must trace BEFORE the patch:
-    # fn is unjitted, so a later jax.grad(fn) would re-trace through
-    # the patched chunk budget and compare the scan path to itself
+    assert all(a.ndim == 4 for a in _dense_a(arrs))
     ref = np.asarray(fn(fbuf))
     g_ref = jax.grad(lambda f: (fn(f) ** 2).sum())(fbuf)
+    # the chunking is the BUILDER's: the kernel scans what is stored
     monkeypatch.setattr(bsp, "_DENSE_CHUNK_ELEMS", 2048)
-    fn_c = make_block_spmm_fn(arrs, deg, n_out, n_src, 16)
+    arrs_c = {k: jnp.asarray(v) for k, v in plan_to_arrays(plan).items()}
+    assert any(a.ndim == 5 for a in _dense_a(arrs_c))
+    fn_c = make_block_spmm_fn(arrs_c, deg, n_out, n_src, 16)
     np.testing.assert_allclose(np.asarray(fn_c(fbuf)), ref,
                                rtol=1e-6, atol=1e-6)
     g_c = jax.grad(lambda f: (fn_c(f) ** 2).sum())(fbuf)
@@ -411,9 +427,7 @@ def test_trainer_headline_stack_fused():
                                      feat_corr=True, grad_corr=True))
     # the grouped union-gather tables must actually be in play — zero
     # dense tiles would silently reduce this to a remainder-only run
-    assert any(k.startswith("blk_fwdu_g") for k in t._block_tables)
-    a_key = "blk_a_bits" if "blk_a_bits" in t._block_tables else "blk_a"
-    assert t._block_tables[a_key].shape[1] > 0
+    assert {a.shape[-1] for a in _dense_a(t._block_tables)} == {4 * 32}
     losses = list(t.train_epochs(0, 4)) + list(t.train_epochs(4, 16))
     assert np.isfinite(losses).all()
     assert np.mean(losses[-4:]) < np.mean(losses[:4])
@@ -444,8 +458,8 @@ def test_scan_names_the_kernels_work(kernel):
                       spmm_impl="block", block_tile=32, dtype="bfloat16",
                       use_pp=True, **kernel)
     t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
-    grouped = any(k.startswith("blk_fwdu_g") for k in t._block_tables)
-    assert grouped == ("block_group" in kernel)
+    assert {a.shape[-1] for a in _dense_a(t._block_tables)} == {
+        32 * kernel.get("block_group", 1)}
     txt = t.step_compiled_text(2)
     cov = scope_coverage(txt)
     for direction in ("fwd", "bwd"):
@@ -616,10 +630,8 @@ def test_sharded_block_tables_fit_the_remainder_only(n_parts, group):
         assert pad[d]["edges"] == sum(p.rem_count for p in plans)
         assert pad[d]["slots"] == sum(tabs[k].size for k in keys)
         # the dense classes: rungs of the x1.5 ladder, as before
-        stem, end = (f"blk_{d}u_g", "t") if group > 1 else \
-            (f"blk_{d}_g", "b")
         k_widths = [tabs[k].shape[-1] for k in sorted(tabs)
-                    if k.startswith(stem) and k.endswith(end)]
+                    if k.startswith(f"blk_{d}_g") and k.endswith("t")]
         assert k_widths and set(k_widths) <= set(ladder_prefix(12))
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32)
@@ -649,7 +661,7 @@ def test_set_remainder_widths_rebuilds_the_remainder_alone(edges):
     src, dst, n_out, n_src = edges
     plan = BlockPlan(src, dst, n_out, n_src, n_feat=8, tile=16,
                      nnz_threshold=4)
-    a_blocks, groups = plan.a_blocks, plan.fwd_groups
+    a_blocks, block_dst = plan.a_blocks, plan.block_dst
     fwd, bwd = plan.rem_fwd_mats, plan.rem_bwd_mats
     plan.set_remainder_widths(plan.rem_fwd_widths, plan.rem_bwd_widths)
     assert plan.rem_fwd_mats is fwd and plan.rem_bwd_mats is bwd
@@ -657,7 +669,7 @@ def test_set_remainder_widths_rebuilds_the_remainder_alone(edges):
     plan.set_remainder_widths(wider, plan.rem_bwd_widths)
     assert plan.rem_fwd_mats is not fwd and plan.rem_bwd_mats is bwd
     assert [m.shape[0] for m in plan.rem_fwd_mats] == wider
-    assert plan.a_blocks is a_blocks and plan.fwd_groups is groups
+    assert plan.a_blocks is a_blocks and plan.block_dst is block_dst
     deg = jnp.asarray(np.maximum(np.bincount(dst, minlength=n_out), 1)
                       .astype(np.float32))
     arrs = {k: jnp.asarray(v) for k, v in plan_to_arrays(plan).items()}
@@ -668,3 +680,250 @@ def test_set_remainder_widths_rebuilds_the_remainder_alone(edges):
     np.testing.assert_allclose(
         np.asarray(out), _ref_mean(src, dst, n_out, fbuf, deg),
         rtol=1e-5, atol=1e-5)
+
+
+# ---------------- A is stored in the order the step reads it ----------------
+
+def _tiled_shards(n_parts, encoding, seed=11):
+    """Shards whose destination tiles hold 1 to 6 dense blocks each (so
+    several K classes fill) over a sparse remainder. `encoding` decides
+    the edge multiplicities and with them how A is stored: "bits" (a
+    simple graph), "int8" (an edge three times) or "bf16" (one 200
+    times)."""
+    from types import SimpleNamespace
+
+    rng = np.random.default_rng(seed)
+    n_max, halo, tile = 160, 32, 16
+    n_src, n_t = n_max + halo, n_max // tile
+    srcs, dsts = [], []
+    for r in range(n_parts):
+        s, d = [], []
+        for t in range(n_t):
+            for o in rng.choice(n_src // tile, 1 + (t + r) % 6,
+                                replace=False):
+                cells = rng.choice(tile * tile, 40, replace=False)
+                d.append(cells // tile + t * tile)
+                s.append(cells % tile + o * tile)
+        d.append(rng.integers(0, n_max, 300))
+        s.append(rng.integers(0, n_src, 300))
+        key = np.unique(np.concatenate(d) * n_src + np.concatenate(s))
+        d, s = key // n_src, key % n_src
+        reps = {"bits": 0, "int8": 2, "bf16": 199}[encoding]
+        # the repeated edge sits in a dense block
+        srcs.append(np.concatenate([s, np.full(reps, s[0])]))
+        dsts.append(np.concatenate([d, np.full(reps, d[0])]))
+    e_max = max(a.size for a in srcs)
+    return SimpleNamespace(
+        num_parts=n_parts, n_max=n_max, halo_size=halo,
+        edge_count=np.asarray([a.size for a in srcs]),
+        edge_src=np.stack([np.pad(a, (0, e_max - a.size))
+                           for a in srcs]).astype(np.int32),
+        edge_dst=np.stack([np.pad(a, (0, e_max - a.size),
+                                  constant_values=n_max)
+                           for a in dsts]).astype(np.int32))
+
+
+def _gather_formulation(plan, direction, tiles, as_gathered=False):
+    """The dense half as the kernel ran it before A was stored in
+    reading order: ONE table in block-id order with a zero block
+    appended, a class's blocks gathered by BlockPlan's index matrices
+    ([R, G, U, T, S]), laid out again as the contraction's left operand
+    ([R, U, S, G*T]; the backward contracts the other axis of the SAME
+    blocks: [R, U, T, G*S]) and contracted: the gather and the relayout
+    the chip ran every call. `as_gathered` contracts the gathered
+    blocks through the einsum spec the kernel wrote instead (no
+    relayout of ours; XLA's own, so the float sums may take another
+    order). Kept as the reference of what is summed."""
+    T, f = plan.tile, tiles.shape[-1]
+    a_pad = jnp.concatenate([jnp.asarray(plan.a_blocks),
+                             jnp.zeros((1, T, T), jnp.float32)])
+    classes, inv, _, _ = plan.dense_classes(direction)
+    spec = "rduts,rusf->rdtf" if direction == "fwd" else "rduts,rutf->rdsf"
+    axes = (0, 2, 4, 1, 3) if direction == "fwd" else (0, 2, 3, 1, 4)
+    outs = []
+    for a_idx, t_mat in classes:
+        r, grp, u = a_idx.shape
+        if not r:
+            continue
+        blks = jnp.take(a_pad, jnp.asarray(a_idx), axis=0)
+        tls = jnp.take(tiles, jnp.asarray(t_mat), axis=0)
+        if as_gathered:
+            out = jnp.einsum(spec, blks, tls,
+                             preferred_element_type=jnp.float32)
+        else:
+            out = jnp.einsum(
+                "rksm,rksf->rmf",
+                blks.transpose(axes).reshape(r, u, T, grp * T), tls,
+                preferred_element_type=jnp.float32)
+        outs.append(out.reshape(-1, T, f))
+    outs.append(jnp.zeros((1, T, f), jnp.float32))
+    return jnp.take(jnp.concatenate(outs), jnp.asarray(inv),
+                    axis=0).reshape(-1, f)
+
+
+@pytest.mark.parametrize("n_parts", [1, 2])
+@pytest.mark.parametrize("chunked", [False, True],
+                         ids=["whole", "scanned"])
+@pytest.mark.parametrize("encoding", ["bits", "int8", "bf16"])
+@pytest.mark.parametrize("group", [1, 4])
+def test_stored_a_sums_what_the_gathers_summed(group, encoding, chunked,
+                                               n_parts, monkeypatch):
+    """build_sharded_block_tables stores A per direction and class in
+    the order the kernel reads it; forward and backward, on every
+    stacked device, the dense half gives BIT FOR BIT what gathering
+    block-id-ordered A by the plan's index matrices gave (the backward
+    from the transposes' own copy what the transposed contraction
+    gave), whatever the encoding and whether or not a class is stored
+    as a scan's chunks."""
+    import ml_dtypes
+
+    import pipegcn_tpu.ops.block_spmm as bsp
+
+    tile, f = 16, 8
+    if chunked:
+        # 4 [tile, tile] slots a chunk: every class of more rows scans
+        monkeypatch.setattr(bsp, "_DENSE_CHUNK_ELEMS", 4 * tile * tile)
+    sg = _tiled_shards(n_parts, encoding)
+    n_src = sg.n_max + sg.halo_size
+    tabs, _ = bsp.build_sharded_block_tables(
+        sg, tile=tile, n_feat_hint=f, nnz_threshold=20, group=group)
+    want_dt = {"bits": np.uint8, "int8": np.int8,
+               "bf16": ml_dtypes.bfloat16}[encoding]
+    a_all = _dense_a(tabs, "fwd") + _dense_a(tabs, "bwd")
+    assert a_all and {a.dtype for a in a_all} == {np.dtype(want_dt)}
+    assert any(a.ndim == 6 for a in a_all) == chunked  # [P, n, rows, ...]
+    assert len({a.shape[-3] for a in a_all}) > 1       # several K classes
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32)
+    g = jnp.asarray(rng.standard_normal((sg.n_max, f)), jnp.float32)
+    for r in range(n_parts):
+        plan = BlockPlan(sg.edge_src[r], sg.edge_dst[r], sg.n_max, n_src,
+                         f, tile=tile, nnz_threshold=20, group=group)
+        assert plan.a_blocks.shape[0] > 20
+        d = {k: jnp.asarray(v[r]) for k, v in tabs.items()}
+        for direction, operand, n_key in (("fwd", x, plan.n_src_tiles),
+                                          ("bwd", g, plan.n_dst_tiles)):
+            tiles = jnp.pad(operand, ((0, (n_key + 1) * tile
+                                       - operand.shape[0]), (0, 0))
+                            ).reshape(n_key + 1, tile, f)
+            stems = sorted(k[:-1] for k in d
+                           if k.startswith(f"blk_{direction}_g")
+                           and k.endswith("a"))
+            n_rows = n_src if direction == "bwd" else sg.n_max
+            got = bsp._dense_apply(
+                [(d[k + "a"], d[k + "t"]) for k in stems],
+                d[f"blk_{direction}_inv"], tiles, tile, n_rows, f,
+                jnp.float32)
+            ref = _gather_formulation(plan, direction, tiles)[:n_rows]
+            assert np.abs(np.asarray(ref)).max() > 1.0
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(_gather_formulation(
+                    plan, direction, tiles, as_gathered=True))[:n_rows],
+                rtol=1e-5, atol=1e-5)
+
+
+def test_dense_edges_are_counted_once_a_direction(tmp_path):
+    """Edge conservation for the dense half: each direction's A counts
+    every edge the remainder does not hold, once, device by device —
+    checked where the tables are built and again where a trainer loads
+    them from its cache; a table that lost a block does not train."""
+    from pipegcn_tpu.ops.block_spmm import (build_sharded_block_tables,
+                                            validate_dense_tables)
+    from pipegcn_tpu.ops.bucket_spmm import table_edges
+    from pipegcn_tpu.partition import locality_clusters
+
+    sg = _tiled_shards(2, "int8")
+    n_src = sg.n_max + sg.halo_size
+    tabs, tile = build_sharded_block_tables(sg, tile=16, n_feat_hint=8,
+                                            nnz_threshold=20, group=2)
+    real = [int(np.count_nonzero(d < sg.n_max)) for d in sg.edge_dst]
+    dense = [n - e for n, e in zip(
+        real, table_edges(tabs, "blkrem_fwd", n_src))]
+    assert min(dense) > 1000
+    stats = validate_dense_tables(tabs, tile, n_edges=dense)
+    for d in ("fwd", "bwd"):
+        assert stats[d]["dense_slots"] >= stats[d]["dense_blocks"] > 40
+        assert stats[d]["a_bytes"] == sum(a[0].nbytes
+                                          for a in _dense_a(tabs, d))
+    assert stats["fwd"]["dense_blocks"] == stats["bwd"]["dense_blocks"]
+    lost = dict(tabs)
+    k = next(k for k in tabs if k.startswith("blk_bwd_g")
+             and k.endswith("a") and tabs[k][1, 0].any())
+    lost[k] = tabs[k].copy()
+    lost[k][1, 0] = 0                      # device 1 loses a row's blocks
+    with pytest.raises(ValueError, match="a block was lost"):
+        validate_dense_tables(lost, tile, n_edges=dense)
+    with pytest.raises(ValueError, match="a block was lost"):
+        validate_dense_tables(lost, tile)  # the directions disagree
+
+    # the same check guards a cache load
+    g = synthetic_graph(num_nodes=600, avg_degree=10, n_feat=12,
+                        n_class=4, homophily=0.9, seed=25)
+    parts = partition_graph(g, 2, seed=0)
+    ShardedGraph.build(
+        g, parts, n_parts=2,
+        cluster=locality_clusters(g, target_size=64, seed=0)
+    ).save(str(tmp_path / "art"))
+    cfg = None
+    for corrupt in (False, True):
+        sgl = ShardedGraph.load(str(tmp_path / "art"))
+        cfg = cfg or ModelConfig(
+            layer_sizes=(12, 16, 4), norm="layer", dropout=0.0,
+            train_size=sgl.n_train_global, spmm_impl="block",
+            block_tile=32)
+        if not corrupt:
+            t = Trainer(sgl, cfg, TrainConfig(seed=0))
+            assert t.tables_source == "built in this run"
+            pad = t.tables_pad
+            fname, = tmp_path.glob("art/*_tables.npz")
+            z = dict(np.load(fname))
+            k = next(k for k in z if k.startswith("blk_fwd_g")
+                     and k.endswith("a"))
+            assert z[k].any()
+            z[k] = np.zeros_like(z[k])
+            t2 = Trainer(ShardedGraph.load(str(tmp_path / "art")), cfg,
+                         TrainConfig(seed=0))
+            assert t2.tables_source == f"loaded from {fname}"
+            assert t2.tables_pad == pad and pad["fwd"]["dense_blocks"] > 0
+            with open(fname, "wb") as fh:
+                np.savez(fh, **z)
+        else:
+            with pytest.raises(ValueError, match="a block was lost"):
+                Trainer(sgl, cfg, TrainConfig(seed=0))
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_budget_bounds_what_is_stored(group):
+    """DENSE_A_BYTE_BUDGET's rule on a small scale: under a budget that
+    cannot hold every dense block, both directions' A AS STORED (pad
+    slots, shared row caps and chunk tails included) fit it, the
+    densest blocks are the ones kept, and estimate_block_coverage
+    predicts the very split the builder makes."""
+    from pipegcn_tpu.ops.block_spmm import (build_sharded_block_tables,
+                                            dense_pad_stats,
+                                            estimate_block_coverage)
+    from pipegcn_tpu.ops.bucket_spmm import table_edges
+
+    sg = _tiled_shards(2, "bits")
+    n_src = sg.n_max + sg.halo_size
+    real = sum(int(np.count_nonzero(d < sg.n_max)) for d in sg.edge_dst)
+    stored, covered = {}, {}
+    budgets = [1 << 30]
+    while budgets:
+        budget = budgets.pop(0)
+        tabs, tile = build_sharded_block_tables(
+            sg, tile=16, n_feat_hint=8, nnz_threshold=20, group=group,
+            byte_budget=budget)
+        stats = dense_pad_stats(tabs, tile)
+        stored[budget] = stats["fwd"]["a_bytes"] + stats["bwd"]["a_bytes"]
+        covered[budget] = 1 - int(table_edges(
+            tabs, "blkrem_fwd", n_src).sum()) / real
+        assert stored[budget] <= budget
+        assert covered[budget] == pytest.approx(estimate_block_coverage(
+            sg, 16, 8, nnz_threshold=20, byte_budget=budget, group=group))
+        if budget == 1 << 30:  # then three fifths and a quarter of it all
+            budgets = [stored[budget] * 3 // 5, stored[budget] // 4]
+    full, most, some = sorted(stored, reverse=True)
+    assert stored[full] > most >= stored[most] > stored[some] > 0
+    assert covered[full] > covered[most] > covered[some] > 0

@@ -295,6 +295,66 @@ def test_schema_v17_drift_guard():
         assert obs_schema.SCHEMA_VERSION > 17
 
 
+# FROZEN copy of the v18 additions (v17 + the run record's `tables_pad`
+# typed a direction, and what the block kernel's dense half stores:
+# ops/block_spmm.py dense_pad_stats). Same contract as the earlier
+# guards.
+_V18_TABLES_PAD_FIELDS = {
+    "widths": "array", "slots": "integer", "edges": "integer",
+    "pad_ratio": "number",
+}
+_V18_TABLES_PAD_DENSE_FIELDS = {
+    "dense_blocks": "integer", "dense_slots": "integer",
+    "dense_pad": "number", "a_bytes": "integer",
+}
+
+
+def test_schema_v18_drift_guard():
+    if obs_schema.SCHEMA_VERSION == 18:
+        for frozen, live, what in (
+                (_V17_TUNING_FIELDS, obs_schema.TUNING_FIELDS, "tuning"),
+                (_V17_TUNING_COST_FIELDS, obs_schema.TUNING_COST_FIELDS,
+                 "tuning cost"),
+                (_V18_TABLES_PAD_FIELDS, obs_schema.TABLES_PAD_FIELDS,
+                 "run tables_pad"),
+                (_V18_TABLES_PAD_DENSE_FIELDS,
+                 obs_schema.TABLES_PAD_DENSE_FIELDS, "run tables_pad")):
+            for name, tag in frozen.items():
+                assert live.get(name) == tag, (
+                    f"schema field {what}.{name} removed or retyped "
+                    f"without bumping SCHEMA_VERSION")
+    else:
+        assert obs_schema.SCHEMA_VERSION > 18
+
+
+def test_validate_run_record_tables_pad():
+    """A run record's `tables_pad` is held to TABLES_PAD_FIELDS a
+    direction; where a direction says what the block kernel's dense
+    half stores it says all of it (v18); null under `xla`."""
+    run = {"event": "run", "schema_version": obs_schema.SCHEMA_VERSION,
+           "time_unix": 0.0, "config": {}, "device": {}, "mesh": {}}
+    rows = {"widths": [7, 32], "slots": 1036, "edges": 1000,
+            "pad_ratio": 1.036}
+    dense = {"dense_blocks": 38744, "dense_slots": 46135,
+             "dense_pad": 1.1908, "a_bytes": 377_937_920}
+    validate_record(run)
+    validate_record({**run, "tables_pad": None})
+    validate_record({**run, "tables_pad": {"fwd": rows, "bwd": rows}})
+    validate_record({**run, "tables_pad": {"fwd": {**rows, **dense},
+                                           "bwd": {**rows, **dense}}})
+    with pytest.raises(ValueError, match="tables_pad.bwd.*pad_ratio"):
+        validate_record({**run, "tables_pad": {
+            "fwd": rows, "bwd": {k: v for k, v in rows.items()
+                                 if k != "pad_ratio"}}})
+    with pytest.raises(ValueError, match="tables_pad.fwd.*a_bytes"):
+        validate_record({**run, "tables_pad": {"fwd": {
+            **rows, **{k: v for k, v in dense.items()
+                       if k != "a_bytes"}}}})
+    with pytest.raises(ValueError, match="expected integer"):
+        validate_record({**run, "tables_pad": {"fwd": {
+            **rows, **dense, "dense_slots": 1.5}}})
+
+
 def test_validate_record():
     validate_record({"event": "epoch", "epoch": 0, "step_time_s": 0.1,
                      "loss": 1.0, "grad_norm": 0.5, "halo_bytes": 128,

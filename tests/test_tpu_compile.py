@@ -160,3 +160,123 @@ def test_reduce_reads_the_transport_dtype_on_the_chip(one_chip, case):
                  and any(got == "u16" and n == (n_src + 1) * slab // 2
                          for got, _, n in _arrays(o[1]))]
         assert len(packs) == 1, packs
+
+
+# The dense classes of the Reddit cells (PERF.md section 5, PR 37: the
+# traced class shapes), as the builder stores them: (leading axes, K).
+# Group 1 (`block-f8`): four scanned classes and a whole one; groups
+# of 4 (`reddit_p1_block`): five scanned and a whole one. The
+# backward's classes have the forward's shapes (the graph is symmetric
+# in its statistics): the SAME contraction over the transposes' copy.
+_DENSE = {
+    1: [((36, 8), 63), ((38, 12), 42), ((10, 5), 94), ((7, 18), 28),
+        ((47,), 3)],
+    4: [((106, 1), 94), ((38, 2), 63), ((21, 1), 141), ((6, 1), 211),
+        ((5, 4), 28), ((3,), 42)],
+}
+
+
+def _operands(hlo: str):
+    """{instruction: its operands' names} over the whole module."""
+    out = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (?:\(.*?\)|\S+) "
+                     r"[\w\-]+\((.*?)\)(?:,|$)", line)
+        if m:
+            out[m.group(1)] = re.findall(r"%([\w.\-]+)", m.group(2))
+    return out
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_stored_a_is_read_where_it_lies(one_chip, group):
+    """The block kernel's dense half at the Reddit cells' class shapes,
+    forward and backward in one program: under `unpack` the device
+    schedules no gather, copy, pad or concatenate (a chunk's A is a
+    slice of the scan's xs, already in the layout the einsum's fusion
+    reads: the contraction packed along the second-minor axis, the
+    output rows on the lanes), and no instruction writes anything of a
+    class's whole A: the table is touched by the loop that carries it
+    and the slice that reads it, nothing else. The einsum's fusion
+    unpacks the slice itself and writes its rows into the direction's
+    one result buffer."""
+    from pipegcn_tpu.ops.block_spmm import make_block_spmm_fn
+
+    tile, f, n_rows = 256, 256, 232_965
+    n_tiles = -(-n_rows // tile)
+    sds = jax.ShapeDtypeStruct
+    tabs = {}
+    for d in ("fwd", "bwd"):
+        tabs[f"blk_{d}_inv"] = sds((n_tiles,), jnp.int32, sharding=one_chip)
+        tabs[f"blkrem_{d}_inv"] = sds((n_rows,), jnp.int32,
+                                      sharding=one_chip)
+        tabs[f"blkrem_{d}_00"] = sds((8, 64), jnp.int32, sharding=one_chip)
+        for w, (lead, k) in enumerate(_DENSE[group]):
+            tabs[f"blk_{d}_g{w:02d}a"] = sds(
+                lead + (k, tile // 8, group * tile), jnp.uint8,
+                sharding=one_chip)
+            tabs[f"blk_{d}_g{w:02d}t"] = sds(lead + (k,), jnp.int32,
+                                             sharding=one_chip)
+
+    def both(tabs, deg, x, g):
+        fn = make_block_spmm_fn(tabs, deg, n_rows, n_rows, tile,
+                                rem_dtype="float8")
+        out, vjp = jax.vjp(fn, x)
+        return out, vjp(g)[0]
+
+    hlo = jax.jit(both).lower(
+        tabs, sds((n_rows,), jnp.float32, sharding=one_chip),
+        sds((n_rows, f), jnp.bfloat16, sharding=one_chip),
+        sds((n_rows, f), jnp.float32, sharding=one_chip)).compile().as_text()
+    ops = _scheduled(hlo)
+    unpack = [o for o in ops if re.search(r"/unpack/", o[3])]
+    assert {"bwd" in o[3] for o in unpack} == {False, True}
+    moved = [o for o in unpack
+             if o[2] in ("gather", "copy", "pad", "concatenate")
+             or re.search(r"gather|copy|pad|concatenate", o[0])]
+    assert not moved, moved
+    # a scanned class's whole A: written by nothing, read by the loop
+    # that carries it (and the tuple plumbing around it) and by the
+    # fusion that slices a chunk off it. (A class stored whole is the
+    # einsum's operand as it lies; where the compiler prefetches a
+    # small one to its fast memory, that is its placement, not a pass
+    # the program asked for.)
+    scanned = [(lead, k) for lead, k in _DENSE[group] if len(lead) > 1]
+    whole = {lead[0] * lead[1] * k * tile // 8 * group * tile
+             for lead, k in scanned}
+    plumbing = ("parameter", "while", "tuple", "get-tuple-element",
+                "bitcast")
+    shapes = {o[0]: o[1] for o in ops}
+    writes = [o for o in ops if o[2] not in plumbing
+              and any(dt == "u8" and n in whole
+                      for dt, _, n in _arrays(o[1]))]
+    assert not writes, writes
+    operands = _operands(hlo)
+    reads = [o for o in ops if o[2] not in plumbing
+             and any(dt == "u8" and n in whole
+                     for src in operands.get(o[0], ())
+                     for dt, _, n in _arrays(shapes.get(src, "")))]
+    chunk = {lead[1] * k * tile // 8 * group * tile for lead, k in scanned}
+    assert len(reads) == 2 * len(scanned), reads
+    for o in reads:
+        (dt, _, n), = _arrays(o[1])
+        assert o[2] == "fusion" and dt == "u8" and n in chunk, o
+    # and the einsum unpacks the packed chunk inside its own fusion:
+    # what reads a slice writes the class's f32 result, nothing else
+    slices = {o[0] for o in reads}
+    users = [o for o in ops
+             if slices & set(operands.get(o[0], ())) and o[2] != "bitcast"]
+    assert len(users) == len(slices), users
+    assert all(o[2] == "fusion" and _arrays(o[1])[0][0] == "f32"
+               and "rksm,rksf->rmf" in o[3] for o in users), users
+    # ... and writes it in place into the ONE result buffer of the
+    # direction (every class's rows and a sentinel): no class has a
+    # result of its own for the compiler to keep in fast memory, where
+    # it crowded out the operand's take (PERF.md section 6, PR 37)
+    n_rows = sum(lead[0] * (lead[1] if len(lead) > 1 else 1)
+                 for lead, _ in _DENSE[group])
+    for o in users:
+        assert _arrays(o[1]) == [("f32", 4,
+                                  (n_rows + 1) * group * tile * f)], o
+    own = {lead[0] * lead[1] * group * tile * f for lead, _ in scanned}
+    assert not [o for o in ops if o[2] != "parameter"
+                for dt, _, n in _arrays(o[1]) if dt == "f32" and n in own]

@@ -845,21 +845,26 @@ def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
     assert healed["sample_dense_coverage"] is not None
 
 
-@pytest.mark.parametrize("old_format", [6, 7])
+@pytest.mark.parametrize("old_format", [6, 7, 8])
 @pytest.mark.parametrize("impl", ["bucket", "block"])
 def test_older_format_tables_are_refused_and_rebuilt(tmp_path, impl,
                                                      old_format):
     """A `*_tables.npz` stamped with table format 6 (bucket and
-    remainder tables destination-major, [P, cap, w]) or 7 (slot-major,
-    on the x1.5 ladder's widths) is refused by name, rebuilt slot-major
-    at the fitted widths and replaced on disk; the next trainer loads
-    the rebuilt file and reports the same padding."""
+    remainder tables destination-major, [P, cap, w]), 7 (slot-major,
+    on the x1.5 ladder's widths) or 8 (the block kernel's A one table
+    in block-id order beside its index matrices) is refused by name,
+    rebuilt slot-major at the fitted widths, A stored in reading order,
+    and replaced on disk; the next trainer loads the rebuilt file and
+    reports the same padding."""
     sg = _sharded(seed=52)
     path = str(tmp_path / "art")
     sg.save(path)
     sgl = ShardedGraph.load(path)
-    assert Trainer._TABLES_FORMAT == 8
-    t0 = Trainer(sgl, _cfg(sgl, spmm_impl=impl), TrainConfig(seed=0))
+    assert Trainer._TABLES_FORMAT == 9
+    # tiles small enough for this graph to fill some
+    kw = dict(spmm_impl=impl, **(dict(block_tile=16, block_nnz=4)
+                                 if impl == "block" else {}))
+    t0 = Trainer(sgl, _cfg(sgl, **kw), TrainConfig(seed=0))
     assert t0.tables_source == "built in this run"
     fname, = [os.path.join(path, f) for f in os.listdir(path)
               if f.endswith("_tables.npz")]
@@ -869,23 +874,33 @@ def test_older_format_tables_are_refused_and_rebuilt(tmp_path, impl,
     assert plain and all(z[k].shape[-1] % 32 == 0 for k in plain)
     slot_major = {k: z[k].shape for k in plain}
     # what older code left there: PR 28's format 6, destination-major,
-    # or PR 30's format 7
+    # PR 30's format 7 or PR 34's format 8
     z["__stamp__"] = np.asarray([old_format, z["__stamp__"][1]], np.uint64)
     if old_format == 6:
         for k in plain:
             z[k] = np.ascontiguousarray(z[k].transpose(0, 2, 1))
+    dense = [k for k in z if k.startswith("blk_") and k.endswith("a")]
+    assert bool(dense) == (impl == "block")
+    if old_format == 8 and impl == "block":
+        # format 8 kept ONE table, block-id ordered, and index matrices
+        z["blk_a_bits"] = np.zeros((1, 4, 32, 4), np.uint8)
+        for k in dense:
+            z[k[:-1] + "b"] = np.zeros(z[k[:-1] + "t"].shape, np.int32)
+            del z[k]
     with open(fname, "wb") as f:
         np.savez(f, **z)
-    t1 = Trainer(ShardedGraph.load(path), _cfg(sgl, spmm_impl=impl),
+    t1 = Trainer(ShardedGraph.load(path), _cfg(sgl, **kw),
                  TrainConfig(seed=0))
     assert t1.tables_source == (
         f"built in this run (refused {fname}: table format "
-        f"{old_format} != 8)")
+        f"{old_format} != 9)")
     healed = np.load(fname)
-    assert int(healed["__stamp__"][0]) == 8
+    assert int(healed["__stamp__"][0]) == 9
     assert {k: healed[k].shape for k in plain} == slot_major
+    assert "blk_a_bits" not in healed.files
+    assert all(k in healed.files for k in dense)
     assert np.isfinite(t1.train_epoch(0))
-    t2 = Trainer(ShardedGraph.load(path), _cfg(sgl, spmm_impl=impl),
+    t2 = Trainer(ShardedGraph.load(path), _cfg(sgl, **kw),
                  TrainConfig(seed=0))
     assert t2.tables_source == f"loaded from {fname}"
     # the padding report is read off the tables, built or loaded
@@ -895,3 +910,12 @@ def test_older_format_tables_are_refused_and_rebuilt(tmp_path, impl,
         assert pad["slots"] >= pad["edges"] > 0
         assert pad["widths"] == sorted(pad["widths"])
         assert pad["pad_ratio"] == round(pad["slots"] / pad["edges"], 4)
+        # under the block kernel, what the dense half stores as well
+        assert ("dense_pad" in pad) == (impl == "block")
+        if impl == "block":
+            assert pad["dense_slots"] >= pad["dense_blocks"] > 0
+            assert pad["dense_pad"] == round(
+                pad["dense_slots"] / pad["dense_blocks"], 4)
+            assert pad["a_bytes"] == sum(
+                healed[k][0].nbytes for k in dense
+                if k.startswith(f"blk_{d}_"))
