@@ -17,7 +17,12 @@ from __future__ import annotations
 
 from typing import Dict, Mapping
 
-SCHEMA_VERSION = 18  # v18: the run record's `tables_pad` is typed
+SCHEMA_VERSION = 19  # v19: each direction of `tables_pad` says how its
+#                      source rows are cut for the gathers: `parts`
+#                      (tables, bucket_spmm.source_parts) and
+#                      `part_rows` (rows of the tallest part); `widths`
+#                      is a list a part
+#                 v18: the run record's `tables_pad` is typed
 #                      (TABLES_PAD_FIELDS a direction) and, under the
 #                      block kernel, says what the dense half STORES:
 #                      dense_blocks, dense_slots, dense_pad, a_bytes
@@ -70,10 +75,12 @@ RUN_FIELDS: Dict[str, str] = {
 # in use (bucket_spmm.pad_stats) and, under the block kernel, what the
 # dense half stores (block_spmm.dense_pad_stats)
 TABLES_PAD_FIELDS: Dict[str, str] = {
-    "widths": "array",           # the row buckets that hold a row
+    "widths": "array",           # a part's row buckets that hold a row
     "slots": "integer",          # gather requests: width x rows
     "edges": "integer",          # entries that are no sentinel
     "pad_ratio": "number",       # slots / edges
+    "parts": "integer",          # tables the source rows are cut into
+    "part_rows": "integer",      # source rows of the tallest part
 }
 TABLES_PAD_DENSE_FIELDS: Dict[str, str] = {
     "dense_blocks": "integer",   # [tile, tile] slots that hold an edge

@@ -58,12 +58,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from .bucket_spmm import (
+    Direction,
     _bucket_widths,
     bucket_aggregate,
-    build_tables_for_edges,
     degree_hist,
+    direction_tables,
     fit_widths,
-    stack_to_caps,
+    stack_direction,
     validate_bucket_tables,
 )
 
@@ -408,7 +409,10 @@ class BlockPlan:
                    slot (_group_union): per width class the index
                    matrices the builders arrange A and the tile lists
                    by (_dense_tables).
-      rem_*:       remainder edges' bucket tables (fwd + transpose).
+      rem_fwd/rem_bwd: the remainder edges' bucket tables, forward
+                   and transpose (bucket_spmm.Direction: cut by source
+                   rows into parts where the rows would make a table
+                   taller than GATHER_PART_BYTES).
     """
 
     def __init__(self, edge_src: np.ndarray, edge_dst: np.ndarray,
@@ -478,40 +482,25 @@ class BlockPlan:
         self.dense_count = int(in_dense_o.sum())
 
         # ---- sparse remainder (bucket tables both directions) ----
-        # widths fitted to the remainder's own degree histograms
-        # (bucket_spmm.fit_widths) unless given; the histograms and the
-        # edges are kept so the sharded builder can fit ONE ladder over
-        # every device's remainder and rebuild these tables alone
-        self._rem_edges = (src_o[~in_dense_o], dst_o[~in_dense_o])
-        r_src, r_dst = self._rem_edges
+        # widths fitted to the remainder's own degree histograms, a part
+        # each (bucket_spmm.fit_widths) unless given; the directions
+        # keep their edges and per-part degrees so the sharded builder
+        # can fit ONE ladder a part over every device's remainder and
+        # rebuild these tables alone
+        r_src, r_dst = src_o[~in_dense_o], dst_o[~in_dense_o]
         self.rem_count = int(r_src.shape[0])
-        self.rem_deg_in = np.bincount(r_dst)
-        self.rem_deg_out = np.bincount(r_src)
-        self.rem_fwd_widths = self.rem_bwd_widths = None
-        self.set_remainder_widths(
-            fwd_widths if fwd_widths is not None
-            else fit_widths(degree_hist([self.rem_deg_in])),
-            bwd_widths if bwd_widths is not None
-            else fit_widths(degree_hist([self.rem_deg_out])))
+        self.rem_fwd = Direction(r_src, r_dst, n_out, n_src_rows,
+                                 fwd_widths)
+        self.rem_bwd = Direction(r_dst, r_src, n_src_rows, n_out,
+                                 bwd_widths)
 
-    def set_remainder_widths(self, fwd_widths: Sequence[int],
-                             bwd_widths: Sequence[int]) -> None:
-        """(Re)build the remainder's bucket tables at the given widths;
-        a direction already at them is left alone. The dense half is
-        not touched: which edges are remainder is fixed by the block
-        selection."""
-        r_src, r_dst = self._rem_edges
-        if list(fwd_widths) != self.rem_fwd_widths:
-            self.rem_fwd_widths = list(fwd_widths)
-            self.rem_fwd_mats, self.rem_fwd_inv, _ = \
-                build_tables_for_edges(r_src, r_dst, self.n_out,
-                                       self.n_src_rows,
-                                       self.rem_fwd_widths)
-        if list(bwd_widths) != self.rem_bwd_widths:
-            self.rem_bwd_widths = list(bwd_widths)
-            self.rem_bwd_mats, self.rem_bwd_inv, _ = \
-                build_tables_for_edges(r_dst, r_src, self.n_src_rows,
-                                       self.n_out, self.rem_bwd_widths)
+    def set_remainder_widths(self, fwd_widths, bwd_widths) -> None:
+        """(Re)build the remainder's bucket tables at the given widths
+        (one ladder, or one a part); a part already at its widths is
+        left alone. The dense half is not touched: which edges are
+        remainder is fixed by the block selection."""
+        self.rem_fwd.set_widths(fwd_widths)
+        self.rem_bwd.set_widths(bwd_widths)
 
     def dense_classes(self, direction: str,
                       widths: Optional[Sequence[int]] = None):
@@ -659,10 +648,6 @@ def make_block_spmm_fn(
             xp = jnp.pad(x, ((0, rpad + S), (0, 0)))  # + one zero tile
             return xp.reshape(n_tiles + 1, S, x.shape[-1])
 
-    def rem_mats(prefix):
-        return [d[k] for k in sorted(d)
-                if k.startswith(prefix) and not k.endswith("inv")]
-
     def dense_classes(direction):  # [(a, t_mat)] in width order
         stems = sorted(k[:-1] for k in d
                        if k.startswith(f"blk_{direction}_g")
@@ -678,7 +663,7 @@ def make_block_spmm_fn(
                              fbuf.dtype)
         rem_in, rem_inv = _rem_cast(fbuf, rem_fwd_dt)
         rem = bucket_aggregate(
-            rem_in, rem_mats("blkrem_fwd_"), d["blkrem_fwd_inv"],
+            rem_in, *direction_tables(d, "blkrem_fwd"),
             chunk_edges=chunk_edges, scope="rem_")
         with jax.named_scope("scale"):
             if rem_inv is not None:
@@ -712,7 +697,7 @@ def make_block_spmm_fn(
         else:
             rem_in, rem_inv = gd, None
         rem = bucket_aggregate(
-            rem_in, rem_mats("blkrem_bwd_"), d["blkrem_bwd_inv"],
+            rem_in, *direction_tables(d, "blkrem_bwd"),
             chunk_edges=chunk_edges, scope="rem_")
         with jax.named_scope("scale"):
             if rem_inv is not None:
@@ -890,16 +875,10 @@ def plan_to_arrays(p: BlockPlan) -> Dict[str, np.ndarray]:
     with the device axis stripped, and the remainder's."""
     arrs = {k: v[0] for k, v in _dense_tables(
         [p], *_required_bits([p], p.tile)).items()}
-    arrs["blkrem_fwd_inv"] = p.rem_fwd_inv
-    arrs["blkrem_bwd_inv"] = p.rem_bwd_inv
-    # remainder tables are slot-major [w, rows] (bucket_spmm): a
-    # bucket with no row has no column
-    for b, m in enumerate(p.rem_fwd_mats):
-        if m.shape[1]:
-            arrs[f"blkrem_fwd_{b:02d}"] = m
-    for b, m in enumerate(p.rem_bwd_mats):
-        if m.shape[1]:
-            arrs[f"blkrem_bwd_{b:02d}"] = m
+    # the remainder's, slot-major [w, rows] (bucket_spmm), a part at a
+    # time: the stacked builder's keys, a bucket with no row left out
+    for stem, d in (("blkrem_fwd", p.rem_fwd), ("blkrem_bwd", p.rem_bwd)):
+        arrs.update((k, v[0]) for k, v in stack_direction([d], stem).items())
     return arrs
 
 
@@ -955,13 +934,15 @@ def build_sharded_block_tables(sg, tile: int = 256,
             break
         bits = emit_bits
 
-    # the remainder's widths are fitted ONCE to the histograms of all
-    # the plans (known only now, after the dense selection); where they
-    # differ from a plan's own fit (P > 1), its remainder tables alone
-    # are rebuilt. The dense classes keep the x1.5 ladder, at the
-    # longest device's length (_dense_tables)
-    fw = fit_widths(degree_hist(p.rem_deg_in for p in plans))
-    bw = fit_widths(degree_hist(p.rem_deg_out for p in plans))
+    # the remainder's widths are fitted ONCE a part to the histograms
+    # of all the plans (known only now, after the dense selection);
+    # where they differ from a plan's own fit (P > 1), its remainder
+    # tables alone are rebuilt. The dense classes keep the x1.5 ladder,
+    # at the longest device's length (_dense_tables)
+    fw, bw = ([fit_widths(degree_hist(getattr(p, d).degs[i]
+                                      for p in plans))
+               for i in range(getattr(plans[0], d).k)]
+              for d in ("rem_fwd", "rem_bwd"))
     for p in plans:
         p.set_remainder_widths(fw, bw)
 
@@ -970,12 +951,10 @@ def build_sharded_block_tables(sg, tile: int = 256,
     validate_dense_tables(stacked, tile,
                           n_edges=[p.dense_count for p in plans])
     # the remainder's bucket tables, slot-major at shared row caps
-    stacked.update(stack_to_caps(
-        [(p.rem_fwd_mats, p.rem_fwd_inv) for p in plans], n_src_rows,
-        "blkrem_fwd"))
-    stacked.update(stack_to_caps(
-        [(p.rem_bwd_mats, p.rem_bwd_inv) for p in plans], sg.n_max,
-        "blkrem_bwd"))
+    stacked.update(stack_direction([p.rem_fwd for p in plans],
+                                   "blkrem_fwd"))
+    stacked.update(stack_direction([p.rem_bwd for p in plans],
+                                   "blkrem_bwd"))
     # every remainder edge sits in exactly one slot, both directions
     validate_bucket_tables(stacked, sg.n_max, n_src_rows,
                            n_edges=[p.rem_count for p in plans],

@@ -27,6 +27,15 @@ formulation removes every scatter from both the forward AND the backward:
   3. Results concatenate in bucket order; one final gather by a
      precomputed inverse permutation restores destination order.
 
+Where the source rows would make fbuf_pad taller than the chip keeps
+in its fast memory space S(1) (GATHER_PART_BYTES; Yelp's 717k rows,
+not Reddit's 233k), a request costs about 3.6 times as much, so a
+direction is cut by SOURCE rows into equal parts (Direction), each
+with its own slot-major tables, fitted widths, zero sentinel and
+inverse permutation over every destination row; the kernel packs and
+gathers one part after the other and sums the parts' unpermuted
+results in f32. One part is the uncut kernel.
+
 Why slot-major, and why n_b is a multiple of ROW_TILE = 32: the chip's
 gather yields the flat [D_b * n_b, F] stream, and the 3-D view the
 reduction needs is free only if its second-minor axis is a whole
@@ -61,8 +70,9 @@ device in shard_map.
 
 from __future__ import annotations
 
+import functools
 import itertools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -103,9 +113,39 @@ def row_cap(n: int) -> int:
 # twice the price each: the gather gained nothing from it until the
 # row was handed over as 128 sixteen-bit words (_pack_words). A table
 # too large for that memory space (Yelp's 717k rows, 183 MB a slab)
-# is read from HBM at 9 to 12.5 ns a request whatever the type.
+# is read from HBM at 9 to 12.5 ns a request whatever the type, so no
+# gather reads one: see GATHER_PART_BYTES.
 # The slab width stays 256 BYTES for every dtype.
 SLAB_BYTES = 256
+
+# the tallest table a gather reads, in bytes of 256-byte slab rows, its
+# zero sentinel row included: a direction whose source rows would make
+# a taller one is cut by source rows into parts (source_parts), each
+# gathered from its own table. On a v5e the compiler keeps a table of
+# up to 102.5 MiB (the tallest measured) in memory space S(1), where a
+# request costs 1.7 ns; a 175 MiB table stays in HBM at 6.1 ns
+# (scripts/gather_parts_microbench.py; docs/PERF_NOTES.md "The
+# row-gather cliff"). Every part writes a result for nearly every
+# destination row, so fewer parts cost less beside the gathers: Yelp's
+# step read 0.3546 s an epoch in two parts of 87.5 MiB, 0.3791 in three
+# of 58 MiB (PERF.md section 6). A constant of the device, as
+# SLAB_BYTES is
+GATHER_PART_BYTES = 96 * 2**20
+
+
+def source_parts(n_rows: int) -> int:
+    """Parts a direction of `n_rows` source rows is cut into: the fewest
+    whose tables (a part's rows and its sentinel, SLAB_BYTES each) stay
+    within GATHER_PART_BYTES."""
+    per_part = GATHER_PART_BYTES // SLAB_BYTES - 1
+    return max(1, -(-int(n_rows) // per_part))
+
+
+def part_bounds(n_rows: int, k: int) -> List[Tuple[int, int]]:
+    """[lo, hi) source rows of each of k parts: equal within a row, so
+    the builder and the kernel find the same ones from the height and
+    the part count alone."""
+    return [(p * n_rows // k, (p + 1) * n_rows // k) for p in range(k)]
 
 
 def _ladder_rungs():
@@ -490,6 +530,16 @@ def bucket_aggregate(
     (build_tables_for_edges), and index into fbuf with R itself as the
     zero-row sentinel.
 
+    A direction cut by source rows into k parts (Direction) comes
+    as k lists of tables and a list of k inverse permutations: part p
+    indexes rows part_bounds(R, k)[p] of fbuf, counted from the part's
+    first, with the part's row count as its zero sentinel. Each part
+    is packed from its own rows and gathered from its own table (one
+    that fits memory space S(1), GATHER_PART_BYTES), and the parts'
+    sums meet in the unpermute: sum over p of take(res_p, inv_p), all
+    in f32. One part is the uncut program, op for op; which runs
+    follows from the tables alone.
+
     `chunk_edges` (the --spmm-chunk edge budget) overrides the default
     element budget: each gather materializes at most ~chunk_edges
     messages. A bucket over the budget runs as a lax.scan over slices
@@ -536,6 +586,40 @@ def bucket_aggregate(
                                   chunk_edges, slab, scope)
     if chunk_edges:
         chunk_elems = chunk_edges * f
+    if not isinstance(inv_perm, (list, tuple)):
+        idx_mats, inv_perm = [idx_mats], [inv_perm]
+    bounds = part_bounds(fbuf.shape[0], len(inv_perm))
+    results = []
+    for (lo, hi), mats in zip(bounds, idx_mats):
+        rows = fbuf
+        if results:
+            # a part's table is packed once the part before it is
+            # summed, so one part's table is live at a time and each
+            # keeps its place in S(1) (scheduled freely, the chip's
+            # compiler interleaves the parts' chunk loops and leaves a
+            # third of them reading from HBM)
+            with jax.named_scope(scope + "gather"):
+                rows, results[-1] = jax.lax.optimization_barrier(
+                    (fbuf, results[-1]))
+        results.append(_part_sums(rows if len(bounds) == 1
+                                  else rows[lo:hi], mats, chunk_elems,
+                                  scope))
+    # a part contributes to every destination row that has an edge in
+    # it; a row with none reads the part's zero sentinel row. One take
+    # a part; their sum is no pass of its own, the chip's compiler
+    # fuses it into the write of the result (tests/test_tpu_compile.py)
+    with jax.named_scope(scope + "unpermute"):
+        outs = [jnp.take(res, inv, axis=0, mode="clip")
+                for res, inv in zip(results, inv_perm)]
+        return functools.reduce(jnp.add, outs)
+
+
+def _part_sums(fbuf, idx_mats, chunk_elems, scope):
+    """One part's buckets (bucket_aggregate): fbuf [R, f] is the part's
+    source rows, the tables index it with R as the zero sentinel.
+    Returns the f32 concatenation of the buckets' sums and one zero
+    row, the operand of the part's inverse permutation."""
+    f = fbuf.shape[-1]
     with jax.named_scope(scope + "gather"):
         fbuf_pad = jnp.concatenate(
             [fbuf, jnp.zeros((1, f), fbuf.dtype)], axis=0
@@ -580,9 +664,8 @@ def bucket_aggregate(
                 out0 = jax.lax.pcast(out0, tuple(vma), to="varying")
         outs.append(jax.lax.scan(body, out0, jnp.arange(n_chunks))[0])
     with jax.named_scope(scope + "unpermute"):
-        res = jnp.concatenate(outs + [jnp.zeros((1, f), jnp.float32)],
-                              axis=0)
-        return jnp.take(res, inv_perm, axis=0, mode="clip")
+        return jnp.concatenate(outs + [jnp.zeros((1, f), jnp.float32)],
+                               axis=0)
 
 
 def _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems, chunk_edges,
@@ -608,38 +691,134 @@ def _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems, chunk_edges,
         return out[:, :f] if pad_f else out
 
 
+class TablePart(NamedTuple):
+    """One part's tables (build_tables_for_edges) and the widths they
+    were built at."""
+    mats: List[np.ndarray]
+    inv: np.ndarray
+    counts: List[int]
+    widths: List[int]
+
+
+def part_degrees(gsrc: np.ndarray, gdst: np.ndarray, n_out: int,
+                 n_src_rows: int, k: int) -> List[np.ndarray]:
+    """Per part of k (part_bounds over the n_src_rows source rows), the
+    [n_out] degrees of the destination rows over the part's edges: one
+    mask pass a part over the edges (none for one part)."""
+    if k == 1:
+        return [np.bincount(gdst, minlength=n_out)]
+    return [np.bincount(gdst[(gsrc >= lo) & (gsrc < hi)], minlength=n_out)
+            for lo, hi in part_bounds(n_src_rows, k)]
+
+
+class Direction:
+    """One direction's bucket tables, cut by source rows into parts.
+
+    The edges (real ones only) are gathered from `gsrc`, n_src_rows
+    source rows, into `gdst`, n_out destination rows. The source rows
+    are cut into k = source_parts(n_src_rows) ranges (part_bounds);
+    part p holds the edges whose source lies in its range, with indices
+    counted from the range's first row and the range's row count as its
+    zero sentinel, its widths fitted to its own degrees (or given), and
+    an inverse permutation over ALL n_out rows: a row with no edge in
+    the part points at the part's zero output row. One part is the
+    uncut table. `k` overrides the count (the attention kernel and the
+    sequential runner keep one)."""
+
+    def __init__(self, gsrc: np.ndarray, gdst: np.ndarray, n_out: int,
+                 n_src_rows: int, widths=None, k: Optional[int] = None):
+        self.k = source_parts(n_src_rows) if k is None else int(k)
+        self.n_out, self.n_src_rows = n_out, n_src_rows
+        self._edges = (gsrc, gdst)
+        self.parts: List[Optional[TablePart]] = [None] * self.k
+        self.set_widths(widths if widths is not None else
+                        [fit_widths(degree_hist([d])) for d in self.degs])
+
+    @functools.cached_property
+    def degs(self) -> List[np.ndarray]:
+        """Per part, the destination rows' degrees over its edges."""
+        return part_degrees(*self._edges, self.n_out, self.n_src_rows,
+                            self.k)
+
+    def set_widths(self, widths) -> None:
+        """(Re)build the parts at `widths`: one ladder a part, or one
+        ladder for every part. A part already at its widths is left
+        alone."""
+        if len(widths) and np.ndim(widths[0]) == 0:
+            widths = [widths] * self.k
+        if len(widths) != self.k:
+            raise ValueError(f"{len(widths)} width ladders for a "
+                             f"direction cut into {self.k} parts")
+        gsrc, gdst = self._edges
+        bounds = part_bounds(self.n_src_rows, self.k)
+        for p, ((lo, hi), w) in enumerate(zip(bounds, widths)):
+            if self.parts[p] is not None and self.parts[p].widths == list(w):
+                continue
+            if self.k == 1:
+                src, dst = gsrc, gdst
+            else:
+                sel = (gsrc >= lo) & (gsrc < hi)
+                src, dst = gsrc[sel] - lo, gdst[sel]
+            self.parts[p] = TablePart(*build_tables_for_edges(
+                src, dst, self.n_out, hi - lo, w), list(w))
+
+    @property
+    def widths(self) -> Tuple[Tuple[int, ...], ...]:
+        return tuple(tuple(p.widths) for p in self.parts)
+
+    def whole(self) -> TablePart:
+        """The one part of an uncut direction."""
+        if self.k != 1:
+            raise ValueError(f"a direction cut into {self.k} parts by "
+                             f"source rows has no one table: read .parts")
+        return self.parts[0]
+
+
+def stack_direction(dirs: Sequence[Direction], stem: str
+                    ) -> Dict[str, np.ndarray]:
+    """Every device's Direction stacked for shard_map (stack_to_caps a
+    part): part 0 under '<stem>_<b>' / '<stem>_inv', part p > 0 under
+    '<stem>_p<p>_<b>' / '<stem>_p<p>_inv', so an uncut direction keeps
+    the keys it always had. The devices share the source-row count, so
+    they share the cut."""
+    out: Dict[str, np.ndarray] = {}
+    bounds = part_bounds(dirs[0].n_src_rows, dirs[0].k)
+    for p, (lo, hi) in enumerate(bounds):
+        out.update(stack_to_caps([(d.parts[p].mats, d.parts[p].inv)
+                                  for d in dirs], hi - lo,
+                                 _part_stem(stem, p)))
+    return out
+
+
+def _part_stem(stem: str, p: int) -> str:
+    return stem if p == 0 else f"{stem}_p{p}"
+
+
 class BucketPlan:
     """Host-side plan for one device: forward + transpose bucket tables.
 
     fwd aggregates src->dst (the training SpMM over the [R=n_inner+halo]
     source rows into n_out destination rows); bwd aggregates dst->src for
-    the gradient. Tables are numpy, slot-major and ready for
+    the gradient. Each is a Direction, cut by its own source rows (R for
+    fwd, n_out for bwd) into parts where those would make a table taller
+    than GATHER_PART_BYTES. Tables are numpy, slot-major and ready for
     bucket_aggregate as they are; pad_to_caps widens them to row caps
-    shared across devices.
+    shared across devices. Widths: one ladder (for every part) or one a
+    part; `parts` = (fwd, bwd) part counts overrides the heights'.
     """
 
     def __init__(self, edge_src: np.ndarray, edge_dst: np.ndarray,
                  n_out: int, n_src_rows: int,
-                 fwd_widths: Optional[Sequence[int]] = None,
-                 bwd_widths: Optional[Sequence[int]] = None):
+                 fwd_widths=None, bwd_widths=None,
+                 parts: Optional[Tuple[int, int]] = None):
         real = edge_dst < n_out
-        deg_in = np.bincount(edge_dst[real], minlength=n_out)
-        deg_out = np.bincount(edge_src[real], minlength=n_src_rows)
-        self.fwd_widths = list(
-            fwd_widths if fwd_widths is not None
-            else fit_widths(degree_hist([deg_in])))
-        self.bwd_widths = list(
-            bwd_widths if bwd_widths is not None
-            else fit_widths(degree_hist([deg_out])))
+        src, dst = edge_src[real], edge_dst[real]
+        k_fwd, k_bwd = parts if parts is not None else (None, None)
         self.n_out = n_out
         self.n_src_rows = n_src_rows
-        self.fwd_mats, self.fwd_inv, self.fwd_counts = \
-            build_tables_for_edges(edge_src, edge_dst, n_out, n_src_rows,
-                                   self.fwd_widths)
+        self.fwd = Direction(src, dst, n_out, n_src_rows, fwd_widths, k_fwd)
         # transpose: swap roles; "destinations" are the source rows
-        self.bwd_mats, self.bwd_inv, self.bwd_counts = \
-            build_tables_for_edges(edge_dst[real], edge_src[real],
-                                   n_src_rows, n_out, self.bwd_widths)
+        self.bwd = Direction(dst, src, n_src_rows, n_out, bwd_widths, k_bwd)
 
 
 def transport_dtypes(rem_dtype: Optional[str]):
@@ -812,12 +991,20 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
     shapes, never in what they sum: every edge sits in exactly one
     slot either way.
 
+    Each direction is cut by its source rows into parts where those
+    would make a table taller than GATHER_PART_BYTES (Direction); every
+    part has its own ladder, fitted to that part's histograms over all
+    the shards, and its own caps.
+
     Returns {'bkt_fwd_<b>': [P, w_b, cap_b], 'bkt_fwd_inv': [P, n_max],
              'bkt_bwd_<b>': ..., 'bkt_bwd_inv': [P, R]}: slot-major
-    (module docstring), cap_b a multiple of ROW_TILE.
+    (module docstring), cap_b a multiple of ROW_TILE; a direction cut
+    into parts has its part p > 0 under 'bkt_fwd_p<p>_<b>' and
+    'bkt_fwd_p<p>_inv' (stack_direction).
     """
     P = sg.num_parts
     n_src_rows = sg.n_max + sg.halo_size
+    ks = (source_parts(n_src_rows), source_parts(sg.n_max))
     cache = plan_cache if plan_cache is not None else {}
     stale = set(range(P)) if dirty is None or not cache else set(dirty)
     if cache.get("shape") != (sg.n_max, n_src_rows) or \
@@ -825,23 +1012,26 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
         cache.clear()
         stale = set(range(P))
 
-    # per-shard degrees (cached; only dirty shards rescan their edges)
+    # per-shard degrees of each part (cached; only dirty shards rescan
+    # their edges)
     degs = cache.get("degs", [None] * P)
     degs += [None] * (P - len(degs))
     for r in range(P):
         if degs[r] is None or r in stale:
             real = sg.edge_dst[r] < sg.n_max
-            degs[r] = (np.bincount(sg.edge_dst[r][real]),
-                       np.bincount(sg.edge_src[r][real]))
-    hist_in = degree_hist(d[0] for d in degs)
-    hist_out = degree_hist(d[1] for d in degs)
+            src, dst = sg.edge_src[r][real], sg.edge_dst[r][real]
+            degs[r] = (part_degrees(src, dst, sg.n_max, n_src_rows, ks[0]),
+                       part_degrees(dst, src, n_src_rows, sg.n_max, ks[1]))
+    hists = [[degree_hist(d[i][p] for d in degs) for p in range(k)]
+             for i, k in enumerate(ks)]
     fw, bw = cache.get("widths", ((), ()))
-    covers = (fw and bw and fw[-1] >= hist_in.shape[1] - 1
-              and bw[-1] >= hist_out.shape[1] - 1)
+    covers = (fw and bw and all(
+        w[-1] >= h.shape[1] - 1
+        for w, h in zip(fw + bw, hists[0] + hists[1])))
     if dirty is None or not covers:
         kept = (fw, bw)
-        fw = tuple(fit_widths(hist_in, min_width=min_width))
-        bw = tuple(fit_widths(hist_out, min_width=min_width))
+        fw, bw = (tuple(tuple(fit_widths(h, min_width=min_width))
+                        for h in hs) for hs in hists)
         if (fw, bw) != kept:
             stale = set(range(P))  # ladder moved: every plan is invalid
 
@@ -857,54 +1047,99 @@ def build_sharded_bucket_tables(sg, chunk_elems: int = DEFAULT_CHUNK_ELEMS,
         plan_cache.update(
             shape=(sg.n_max, n_src_rows), min_width=min_width,
             widths=(fw, bw), degs=degs, plans=plans)
-    tables = {
-        **stack_to_caps([(p.fwd_mats, p.fwd_inv) for p in plans],
-                        n_src_rows, "bkt_fwd"),
-        **stack_to_caps([(p.bwd_mats, p.bwd_inv) for p in plans],
-                        sg.n_max, "bkt_bwd"),
-    }
-    validate_bucket_tables(tables, sg.n_max, n_src_rows,
-                           n_edges=[int(d[0].sum()) for d in degs])
+    tables = {**stack_direction([p.fwd for p in plans], "bkt_fwd"),
+              **stack_direction([p.bwd for p in plans], "bkt_bwd")}
+    validate_bucket_tables(
+        tables, sg.n_max, n_src_rows,
+        n_edges=[sum(int(x.sum()) for x in d[0]) for d in degs])
     return tables
 
 
 def _bucket_keys(tables, stem: str) -> List[str]:
-    """Keys of one direction's bucket tables ('<stem>_<b>'), in width
+    """Keys of one part's bucket tables ('<stem>_<b>'), in width
     order, the inverse permutation left out."""
-    return sorted(k for k in tables
-                  if k.startswith(stem + "_") and not k.endswith("inv"))
+    return sorted(k for k in tables if k.startswith(stem + "_")
+                  and k[len(stem) + 1:].isdigit())
+
+
+def _part_stems(tables, stem: str) -> List[str]:
+    """The stems of a direction's parts in `tables`, part 0 first
+    (stack_direction's keys)."""
+    out = [stem]
+    while f"{_part_stem(stem, len(out))}_inv" in tables:
+        out.append(_part_stem(stem, len(out)))
+    return out
+
+
+def direction_tables(tables, stem: str):
+    """(idx_mats, inv_perm) of one direction as bucket_aggregate takes
+    them: one part's list of tables and its permutation, or a list of
+    each for a direction cut into parts."""
+    stems = _part_stems(tables, stem)
+    if len(stems) == 1:
+        return [tables[k] for k in _bucket_keys(tables, stem)], \
+            tables[stem + "_inv"]
+    return ([[tables[k] for k in _bucket_keys(tables, s)] for s in stems],
+            [tables[s + "_inv"] for s in stems])
+
+
+def table_widths(tables, stem: str) -> Tuple[Tuple[int, ...], ...]:
+    """Per part of one direction, the widths of its buckets that hold a
+    row (tables [P, w, cap] or one device's [w, cap])."""
+    return tuple(tuple(int(tables[k].shape[-2])
+                       for k in _bucket_keys(tables, s))
+                 for s in _part_stems(tables, stem))
+
+
+def _parts_of(tables, stem: str, n_src_rows: int):
+    """[(part stem, [lo, hi) source rows)] of one direction of
+    `n_src_rows` source rows: the cut the kernel finds from the height
+    and the part count (part_bounds)."""
+    stems = _part_stems(tables, stem)
+    return list(zip(stems, part_bounds(n_src_rows, len(stems))))
 
 
 def table_edges(tables: Dict[str, np.ndarray], stem: str,
-                sentinel: int) -> np.ndarray:
+                n_src_rows: int) -> np.ndarray:
     """[P] edges each device's stacked tables of one direction hold:
-    their entries that are no sentinel."""
-    keys = _bucket_keys(tables, stem)
+    their entries that are no sentinel, over all its parts (a part's
+    sentinel is its row count)."""
     n_dev = tables[stem + "_inv"].shape[0]
-    return sum((np.count_nonzero(np.asarray(tables[k]) != sentinel,
-                                 axis=(1, 2)) for k in keys),
+    return sum((np.count_nonzero(np.asarray(tables[k]) != hi - lo,
+                                 axis=(1, 2))
+                for s, (lo, hi) in _parts_of(tables, stem, n_src_rows)
+                for k in _bucket_keys(tables, s)),
                np.zeros(n_dev, np.int64))
 
 
-def pad_stats(tables: Dict[str, np.ndarray], stem: str, sentinel: int,
+def pad_stats(tables: Dict[str, np.ndarray], stem: str, n_src_rows: int,
               chunk_elems: int = DEFAULT_CHUNK_ELEMS,
               f: int = SLAB_BYTES) -> dict:
-    """What one direction's stacked tables ('<stem>_<b>' [P, w, cap])
-    make the gathers issue: `widths` (the buckets that hold a row),
-    `slots` (sum over devices and buckets of w x the rows the kernel
-    gathers, chunk tails as bucket_aggregate cuts them at a slab of `f`
-    elements: the one-byte transports' 256, the most chunks), `edges`
-    (entries that are no sentinel) and their ratio `pad_ratio`: gather
-    requests an edge, the number the widths are fitted to lower."""
+    """What one direction's stacked tables ('<stem>_<b>' [P, w, cap],
+    a part's under its own stem) make the gathers issue: `widths` (the
+    buckets that hold a row, a list a part),
+    `slots` (sum over devices, parts and buckets of w x the rows the
+    kernel gathers, chunk tails as bucket_aggregate cuts them at a slab
+    of `f` elements: the one-byte transports' 256, the most chunks),
+    `edges` (entries that are no sentinel) and their ratio `pad_ratio`:
+    gather requests an edge, the number the widths are fitted to lower;
+    `parts` (tables the source rows are cut into, GATHER_PART_BYTES)
+    and `part_rows` (source rows of the tallest part)."""
+    parts = _parts_of(tables, stem, n_src_rows)
     widths, slots = [], 0
-    for k in _bucket_keys(tables, stem):
-        n_dev, w, cap = tables[k].shape
-        rows, n_chunks = chunk_rows(w, cap, f, chunk_elems)
-        widths.append(int(w))
-        slots += n_dev * w * rows * n_chunks
-    edges = int(table_edges(tables, stem, sentinel).sum())
-    return {"widths": widths, "slots": int(slots), "edges": edges,
-            "pad_ratio": round(slots / max(edges, 1), 4)}
+    for s, _ in parts:
+        widths.append([])
+        for k in _bucket_keys(tables, s):
+            n_dev, w, cap = tables[k].shape
+            rows, n_chunks = chunk_rows(w, cap, f, chunk_elems)
+            widths[-1].append(int(w))
+            slots += n_dev * w * rows * n_chunks
+    edges = int(table_edges(tables, stem, n_src_rows).sum())
+    return {"widths": widths,
+            "slots": int(slots), "edges": edges,
+            "pad_ratio": round(slots / max(edges, 1), 4),
+            "parts": len(parts),
+            "part_rows": max(hi - lo for _, (lo, hi) in parts)}
 
 
 def bucket_pad_stats(tables: Dict[str, np.ndarray], n_max: int,
@@ -922,10 +1157,12 @@ def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
     """Host-side check of sharded bucket tables ([P, ...] device
     axis leading; tables slot-major [P, w, cap], so a bucket's
     rows are its LAST axis; stem 'blkrem' for the block kernel's
-    remainder).
+    remainder), part by part where a direction is cut by source rows.
 
     Bounds: every index must lie in [0, bound] where bound is
-    the consuming gather's zero-sentinel row. The device kernel gathers
+    the consuming gather's zero-sentinel row: a part's row count for
+    its tables, the rows of its buckets for its inverse permutation.
+    The device kernel gathers
     with mode='clip' ON THE STRENGTH OF THIS CHECK — an out-of-bounds
     index from a build bug or a rotted cache must surface HERE as a
     named ValueError at build/load time, never as a silently-clamped
@@ -934,7 +1171,8 @@ def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
 
     Edge conservation: with `n_edges` (the real edges of each device,
     what the tables were built from) every direction must hold exactly
-    that many entries that are no sentinel, device by device. A row's
+    that many entries that are no sentinel over all its parts, device
+    by device. A row's
     tail lost to a width too short, or an entry overwritten by the
     sentinel, changes a mean by less than the transports' own noise,
     so no comparison of outputs sees it (PERF.md section 2): it is
@@ -943,30 +1181,23 @@ def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,
 
     O(tables) numpy passes — noise next to the O(E) build."""
     fwd, bwd = stem + "_fwd", stem + "_bwd"
-    rows = {d: sum(int(tables[k].shape[-1]) for k in _bucket_keys(tables, d))
-            for d in (fwd, bwd)}
-    for k, t in tables.items():
-        if k == fwd + "_inv":
-            hi = rows[fwd]         # + the appended zero sentinel row
-        elif k == bwd + "_inv":
-            hi = rows[bwd]
-        elif k.startswith(fwd + "_"):
-            hi = n_src_rows        # fbuf_pad's zero sentinel row
-        elif k.startswith(bwd + "_"):
-            hi = n_max
-        else:
-            continue
-        a = np.asarray(t)
-        lo_v = int(a.min(initial=0))
-        hi_v = int(a.max(initial=0))
-        if lo_v < 0 or hi_v > hi:
-            raise ValueError(
-                f"bucket table {k!r} holds out-of-bounds indices "
-                f"[{lo_v}, {hi_v}] (valid range [0, {hi}]): corrupt "
-                f"table cache or a table-build bug — rebuild the "
-                f"partition artifact's cached tables")
-    held = {fwd: table_edges(tables, fwd, n_src_rows),
-            bwd: table_edges(tables, bwd, n_max)}
+    src_rows = {fwd: n_src_rows, bwd: n_max}
+    for d, n in src_rows.items():
+        for s, (lo, hi) in _parts_of(tables, d, n):
+            keys = _bucket_keys(tables, s)
+            bounds = [(k, hi - lo) for k in keys] + [
+                (s + "_inv", sum(int(tables[k].shape[-1]) for k in keys))]
+            for k, top in bounds:
+                a = np.asarray(tables[k])
+                lo_v = int(a.min(initial=0))
+                hi_v = int(a.max(initial=0))
+                if lo_v < 0 or hi_v > top:
+                    raise ValueError(
+                        f"bucket table {k!r} holds out-of-bounds indices "
+                        f"[{lo_v}, {hi_v}] (valid range [0, {top}]): "
+                        f"corrupt table cache or a table-build bug — "
+                        f"rebuild the partition artifact's cached tables")
+    held = {d: table_edges(tables, d, n) for d, n in src_rows.items()}
     want = held[fwd] if n_edges is None else np.asarray(n_edges, np.int64)
     for d, got in held.items():
         if not np.array_equal(got, want):
@@ -989,9 +1220,7 @@ def make_device_bucket_spmm_fn(d: Dict[str, jax.Array], in_deg: jax.Array,
     """Bind the per-device blocks of build_sharded_bucket_tables (call
     inside shard_map, after stripping the leading device axis) into the
     differentiable closure."""
-    fwd_mats = [d[k] for k in _bucket_keys(d, "bkt_fwd")]
-    bwd_mats = [d[k] for k in _bucket_keys(d, "bkt_bwd")]
     return make_bucket_spmm_fn(
-        fwd_mats, d["bkt_fwd_inv"], bwd_mats, d["bkt_bwd_inv"],
+        *direction_tables(d, "bkt_fwd"), *direction_tables(d, "bkt_bwd"),
         in_deg, n_src_rows, chunk_elems, chunk_edges, rem_dtype,
         rem_amax)

@@ -98,17 +98,21 @@ def build_sharded_gat_tables(sg) -> Dict[str, np.ndarray]:
     bw = fit_widths(degree_hist(
         np.bincount(sg.edge_src[r][real[r]]) for r in range(P)))
 
+    # one part a direction: this kernel reads its tables whole, not
+    # through bucket_aggregate
     plans = [
         BucketPlan(sg.edge_src[r], sg.edge_dst[r], sg.n_max, n_src_rows,
-                   fwd_widths=fw, bwd_widths=bw)
+                   fwd_widths=fw, bwd_widths=bw, parts=(1, 1))
         for r in range(P)
     ]
+    fwd = [p.fwd.whole() for p in plans]
+    bwd = [p.bwd.whole() for p in plans]
     # BucketPlan's tables are slot-major [w, rows rounded up to 32]
     # (bucket_spmm, the mean kernel's layout); this kernel still reads
     # them destination-major, so the builder below takes each table's
     # transpose and the plan's own (rounded) row counts as its offsets
-    fwd_n = [[m.shape[1] for m in p.fwd_mats] for p in plans]
-    bwd_n = [[m.shape[1] for m in p.bwd_mats] for p in plans]
+    fwd_n = [[m.shape[1] for m in t.mats] for t in fwd]
+    bwd_n = [[m.shape[1] for m in t.mats] for t in bwd]
     fwd_caps = [max(n[b] for n in fwd_n) for b in range(len(fw))]
     bwd_caps = [max(n[b] for n in bwd_n) for b in range(len(bw))]
 
@@ -139,31 +143,31 @@ def build_sharded_gat_tables(sg) -> Dict[str, np.ndarray]:
         return out.astype(np.int32)
 
     # one O(n) scan per plan/orientation (not per bucket)
-    fwd_rows = [_rows_for_buckets(p.fwd_inv, p.fwd_counts) for p in plans]
-    bwd_rows = [_rows_for_buckets(p.bwd_inv, p.bwd_counts) for p in plans]
+    fwd_rows = [_rows_for_buckets(t.inv, t.counts) for t in fwd]
+    bwd_rows = [_rows_for_buckets(t.inv, t.counts) for t in bwd]
 
     tables: Dict[str, np.ndarray] = {
         "gat_fwd_inv": np.stack([
-            reoffset(p.fwd_inv, n, fwd_caps)
-            for p, n in zip(plans, fwd_n)]),
+            reoffset(t.inv, n, fwd_caps)
+            for t, n in zip(fwd, fwd_n)]),
         "gat_bwd_inv": np.stack([
-            reoffset(p.bwd_inv, n, bwd_caps)
-            for p, n in zip(plans, bwd_n)]),
+            reoffset(t.inv, n, bwd_caps)
+            for t, n in zip(bwd, bwd_n)]),
     }
     for b in range(len(fw)):
         if not fwd_caps[b]:
             continue
         tables[f"gat_fwd_{b:02d}"] = np.stack(
-            [pad_mat(p.fwd_mats[b].T, fwd_caps[b], n_src_rows)
-             for p in plans])
+            [pad_mat(t.mats[b].T, fwd_caps[b], n_src_rows)
+             for t in fwd])
         tables[f"gat_fwd_rows_{b:02d}"] = np.stack(
             [pad_rows(r[b], fwd_caps[b], sg.n_max) for r in fwd_rows])
     for b in range(len(bw)):
         if not bwd_caps[b]:
             continue
         tables[f"gat_bwd_{b:02d}"] = np.stack(
-            [pad_mat(p.bwd_mats[b].T, bwd_caps[b], sg.n_max)
-             for p in plans])
+            [pad_mat(t.mats[b].T, bwd_caps[b], sg.n_max)
+             for t in bwd])
         tables[f"gat_bwd_rows_{b:02d}"] = np.stack(
             [pad_rows(r[b], bwd_caps[b], n_src_rows) for r in bwd_rows])
     return tables

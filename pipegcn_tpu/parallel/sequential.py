@@ -90,12 +90,13 @@ def _rank_bucket_tables(edge_src, edge_dst, n_max, n_src_rows, fw, bw,
     axis, so one traced program serves every rank."""
     from ..ops.bucket_spmm import BucketPlan, pad_to_caps
 
+    # one part a direction: the ladders and caps above are the uncut
+    # tables' (bucket_spmm.Direction)
     p = BucketPlan(edge_src, edge_dst, n_max, n_src_rows,
-                   fwd_widths=fw, bwd_widths=bw)
-    fwd_mats, fwd_inv = pad_to_caps(p.fwd_mats, p.fwd_inv, fwd_caps,
-                                    n_src_rows)
-    bwd_mats, bwd_inv = pad_to_caps(p.bwd_mats, p.bwd_inv, bwd_caps,
-                                    n_max)
+                   fwd_widths=fw, bwd_widths=bw, parts=(1, 1))
+    fwd, bwd = p.fwd.whole(), p.bwd.whole()
+    fwd_mats, fwd_inv = pad_to_caps(fwd.mats, fwd.inv, fwd_caps, n_src_rows)
+    bwd_mats, bwd_inv = pad_to_caps(bwd.mats, bwd.inv, bwd_caps, n_max)
     t = {"bkt_fwd_inv": fwd_inv, "bkt_bwd_inv": bwd_inv}
     t.update((f"bkt_fwd_{b:02d}", m)
              for b, m in enumerate(fwd_mats) if m.shape[1])

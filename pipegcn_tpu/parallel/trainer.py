@@ -296,7 +296,12 @@ class Trainer:
     # v9: the block kernel's A stored per direction and class in the
     # order the step reads it (block_spmm._dense_tables): one
     # block-id-ordered table with its index matrices must miss
-    _TABLES_FORMAT = 9
+    # v10: a bucket direction whose source rows would make a gather
+    # table taller than bucket_spmm.GATHER_PART_BYTES is cut by source
+    # rows into parts, each its own tables under '<stem>_p<p>_...'
+    # (bucket_spmm.stack_direction): uncut tables of a tall graph
+    # must miss
+    _TABLES_FORMAT = 10
 
     def _cached_tables(self, kind: str, build_fn):
         """Disk-cache derived kernel tables next to the partition
@@ -752,13 +757,15 @@ class Trainer:
         # (_use_bucket passes it through to build_sharded_bucket_tables)
         self._bucket_plan_cache: dict = {}
         if self._bucket_tables is not None:
+            from ..ops.bucket_spmm import table_widths
+
             # the first delta keeps the widths the step was compiled
-            # for, as every later one does (a dirty rebuild refits only
-            # a ladder that a row has outgrown)
+            # for, a ladder a part, as every later one does (a dirty
+            # rebuild refits only a ladder that a row has outgrown)
             self._bucket_plan_cache.update(
                 shape=(self.sg.n_max, self.sg.n_max + self.sg.halo_size),
                 min_width=int(getattr(self.cfg, "bucket_merge", 0)),
-                widths=tuple(tuple(self.tables_pad[d]["widths"])
+                widths=tuple(table_widths(self._bucket_tables, f"bkt_{d}")
                              for d in ("fwd", "bwd")))
         # topology generation: bumped once per applied DeltaBatch, and
         # stamped into checkpoints (the journal watermark) so every

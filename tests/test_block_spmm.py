@@ -555,8 +555,9 @@ def test_remainder_scopes_carry_the_rem_prefix(edges):
         np.maximum(np.bincount(dst, minlength=n_out), 1).astype(np.float32))
     plan, fn = _make_fn(src, dst, n_out, n_src, deg, 16, 4)
     arrs = plan_to_arrays(plan)
-    for b, m in enumerate(plan.rem_fwd_mats):
-        assert m.shape[0] == plan.rem_fwd_widths[b] and m.shape[1] % 32 == 0
+    rem = plan.rem_fwd.whole()
+    for b, m in enumerate(rem.mats):
+        assert m.shape[0] == rem.widths[b] and m.shape[1] % 32 == 0
         assert (f"blkrem_fwd_{b:02d}" in arrs) == bool(m.shape[1])
     txt = jax.jit(fn).lower(jnp.ones((n_src, 8), jnp.float32)).as_text(
         debug_info=True)
@@ -620,19 +621,72 @@ def test_sharded_block_tables_fit_the_remainder_only(n_parts, group):
              for r in range(n_parts)]
     assert all(p.a_blocks.shape[0] and p.rem_count for p in plans)
     pad = bucket_pad_stats(tabs, sg.n_max, n_src, stem="blkrem")
-    for d, degs in (("fwd", [p.rem_deg_in for p in plans]),
-                    ("bwd", [p.rem_deg_out for p in plans])):
+    for d, degs in (("fwd", [p.rem_fwd.degs[0] for p in plans]),
+                    ("bwd", [p.rem_bwd.degs[0] for p in plans])):
         want = fit_widths(degree_hist(degs))
         keys = sorted(k for k in tabs if k.startswith(f"blkrem_{d}_")
                       and not k.endswith("inv"))
         assert [tabs[k].shape[1] for k in keys] == want
-        assert pad[d]["widths"] == want
+        assert pad[d]["widths"] == [want]
         assert pad[d]["edges"] == sum(p.rem_count for p in plans)
         assert pad[d]["slots"] == sum(tabs[k].size for k in keys)
         # the dense classes: rungs of the x1.5 ladder, as before
         k_widths = [tabs[k].shape[-1] for k in sorted(tabs)
                     if k.startswith(f"blk_{d}_g") and k.endswith("t")]
         assert k_widths and set(k_widths) <= set(ladder_prefix(12))
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((sg.n_max, f)), jnp.float32)
+    for r in range(n_parts):
+        real = sg.edge_dst[r] < sg.n_max
+        src, dst = sg.edge_src[r][real], sg.edge_dst[r][real]
+        deg = np.maximum(np.bincount(dst, minlength=sg.n_max), 1)
+        fn = make_device_block_spmm_fn(
+            {k: jnp.asarray(v[r]) for k, v in tabs.items()},
+            jnp.asarray(deg, jnp.float32), sg.n_max, n_src, tile)
+        out, vjp = jax.vjp(fn, x)
+        a = np.zeros((sg.n_max, n_src))
+        np.add.at(a, (dst, src), 1.0)
+        a /= deg[:, None]
+        np.testing.assert_allclose(np.asarray(out), a @ np.asarray(x),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(vjp(c)[0]),
+                                   a.T @ np.asarray(c),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n_parts", [1, 4])
+def test_remainder_cut_into_parts_matches_dense(n_parts, monkeypatch):
+    """With the part bound lowered under the shards' heights the
+    remainder's directions are cut by source rows into parts (keys of
+    their own, a ladder a part fitted over every shard, each part's
+    indices under its own row count): every remainder edge is counted
+    once over the parts, `plan_to_arrays` hands the one-device parts
+    over as the builder made them, and every device's kernel is the
+    dense mean, forward and VJP."""
+    from pipegcn_tpu.ops import bucket_spmm
+    from pipegcn_tpu.ops.block_spmm import (build_sharded_block_tables,
+                                            make_device_block_spmm_fn)
+    from pipegcn_tpu.ops.bucket_spmm import (_part_stems, bucket_pad_stats,
+                                             source_parts)
+
+    monkeypatch.setattr(bucket_spmm, "GATHER_PART_BYTES",
+                        61 * bucket_spmm.SLAB_BYTES)
+    sg = _clustered_shards(n_parts)
+    n_src = sg.n_max + sg.halo_size
+    f, tile = 8, 16
+    tabs, _ = build_sharded_block_tables(sg, tile=tile, n_feat_hint=f,
+                                         nnz_threshold=20)
+    pad = bucket_pad_stats(tabs, sg.n_max, n_src, stem="blkrem")
+    for d, rows in (("fwd", n_src), ("bwd", sg.n_max)):
+        k = source_parts(rows)
+        assert k > 1 and pad[d]["parts"] == k
+        assert len(_part_stems(tabs, f"blkrem_{d}")) == k
+        assert len(pad[d]["widths"]) == k
+    plan = BlockPlan(sg.edge_src[0], sg.edge_dst[0], sg.n_max, n_src, f,
+                     tile=tile, nnz_threshold=20)
+    arrs = plan_to_arrays(plan)
+    assert "blkrem_fwd_p1_inv" in arrs and "blkrem_bwd_p1_inv" in arrs
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32)
     c = jnp.asarray(rng.standard_normal((sg.n_max, f)), jnp.float32)
@@ -662,13 +716,16 @@ def test_set_remainder_widths_rebuilds_the_remainder_alone(edges):
     plan = BlockPlan(src, dst, n_out, n_src, n_feat=8, tile=16,
                      nnz_threshold=4)
     a_blocks, block_dst = plan.a_blocks, plan.block_dst
-    fwd, bwd = plan.rem_fwd_mats, plan.rem_bwd_mats
-    plan.set_remainder_widths(plan.rem_fwd_widths, plan.rem_bwd_widths)
-    assert plan.rem_fwd_mats is fwd and plan.rem_bwd_mats is bwd
-    wider = plan.rem_fwd_widths[:-1] + [plan.rem_fwd_widths[-1] + 3]
-    plan.set_remainder_widths(wider, plan.rem_bwd_widths)
-    assert plan.rem_fwd_mats is not fwd and plan.rem_bwd_mats is bwd
-    assert [m.shape[0] for m in plan.rem_fwd_mats] == wider
+    fwd, bwd = plan.rem_fwd.whole().mats, plan.rem_bwd.whole().mats
+    fw, bw = plan.rem_fwd.whole().widths, plan.rem_bwd.whole().widths
+    plan.set_remainder_widths(fw, bw)
+    assert plan.rem_fwd.whole().mats is fwd
+    assert plan.rem_bwd.whole().mats is bwd
+    wider = fw[:-1] + [fw[-1] + 3]
+    plan.set_remainder_widths(wider, bw)
+    assert plan.rem_fwd.whole().mats is not fwd
+    assert plan.rem_bwd.whole().mats is bwd
+    assert [m.shape[0] for m in plan.rem_fwd.whole().mats] == wider
     assert plan.a_blocks is a_blocks and plan.block_dst is block_dst
     deg = jnp.asarray(np.maximum(np.bincount(dst, minlength=n_out), 1)
                       .astype(np.float32))

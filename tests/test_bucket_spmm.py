@@ -108,8 +108,7 @@ def test_bucket_mean_fn_grad_matches_reference(edges):
     )
     plan = BucketPlan(src, dst, n_out, n_src)
     fn = make_bucket_spmm_fn(
-        [jnp.asarray(m) for m in plan.fwd_mats], jnp.asarray(plan.fwd_inv),
-        [jnp.asarray(m) for m in plan.bwd_mats], jnp.asarray(plan.bwd_inv),
+        *_as_operands(plan.fwd), *_as_operands(plan.bwd),
         deg, n_src,
     )
     order = np.argsort(dst, kind="stable")
@@ -209,12 +208,10 @@ def test_float8_transport_tolerance_and_slab_width():
         np.maximum(np.bincount(dst, minlength=n_out), 1).astype(np.float32))
     plan = BucketPlan(src, dst, n_out, n_src)
     f32_fn = make_bucket_spmm_fn(
-        [jnp.asarray(m) for m in plan.fwd_mats], jnp.asarray(plan.fwd_inv),
-        [jnp.asarray(m) for m in plan.bwd_mats], jnp.asarray(plan.bwd_inv),
+        *_as_operands(plan.fwd), *_as_operands(plan.bwd),
         deg, n_src)
     f8_fn = make_bucket_spmm_fn(
-        [jnp.asarray(m) for m in plan.fwd_mats], jnp.asarray(plan.fwd_inv),
-        [jnp.asarray(m) for m in plan.bwd_mats], jnp.asarray(plan.bwd_inv),
+        *_as_operands(plan.fwd), *_as_operands(plan.bwd),
         deg, n_src, rem_dtype="float8")
     fbuf = jnp.asarray(rng.standard_normal((n_src, 256)).astype(np.float32))
     o32 = np.asarray(f32_fn(fbuf))
@@ -324,8 +321,7 @@ def test_slabbed_aggregate_names_its_relayout(edges):
 
     src, dst, n_out, n_src = edges
     plan = BucketPlan(src, dst, n_out, n_src)
-    mats = [jnp.asarray(m) for m in plan.fwd_mats]
-    inv = jnp.asarray(plan.fwd_inv)
+    mats, inv = _as_operands(plan.fwd)
 
     def paths(scope):
         fn = jax.jit(lambda x: bucket_aggregate(x, mats, inv, slab=4,
@@ -403,12 +399,13 @@ def test_slot_major_forward_and_vjp_match_dense(width, transport):
     src, dst, n_out, n_src = _bucket_edges(width, seed=width)
     plan = BucketPlan(src, dst, n_out, n_src,
                       fwd_widths=_bucket_widths(int(np.bincount(dst).max())))
-    _assert_slot_major(plan.fwd_mats, plan.fwd_widths)
-    _assert_slot_major(plan.bwd_mats, plan.bwd_widths)
-    b = plan.fwd_widths.index(width)
+    fwd, bwd = plan.fwd.whole(), plan.bwd.whole()
+    _assert_slot_major(fwd.mats, fwd.widths)
+    _assert_slot_major(bwd.mats, bwd.widths)
+    b = fwd.widths.index(width)
     # widths 1 and 2 also hold the low-degree rows: 150 rows, or a few more
-    n_b = plan.fwd_counts[b]
-    assert 150 <= n_b <= 170 and plan.fwd_mats[b].shape == (
+    n_b = fwd.counts[b]
+    assert 150 <= n_b <= 170 and fwd.mats[b].shape == (
         width, 160 if n_b <= 160 else 192)
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32).astype(dt)
@@ -416,19 +413,19 @@ def test_slot_major_forward_and_vjp_match_dense(width, transport):
     a = _dense(src, dst, n_out, n_src)
     want_f = a @ np.asarray(x.astype(jnp.float32), np.float64)
     want_b = a.T @ np.asarray(g.astype(jnp.float32), np.float64)
-    fm = [jnp.asarray(m) for m in plan.fwd_mats]
-    bm = [jnp.asarray(m) for m in plan.bwd_mats]
+    fm = [jnp.asarray(m) for m in fwd.mats]
+    bm = [jnp.asarray(m) for m in bwd.mats]
     # 64 rows of the bucket a chunk: 160 rows are chunks of 64, 64 and a
     # last one moved back to rows 96..160
     for chunk_elems in (1 << 30, 64 * width * f):
-        out = bucket_aggregate(x, fm, jnp.asarray(plan.fwd_inv),
+        out = bucket_aggregate(x, fm, jnp.asarray(fwd.inv),
                                chunk_elems=chunk_elems)
         assert out.dtype == jnp.float32 and out.shape == (n_out, f)
         np.testing.assert_allclose(np.asarray(out), want_f, rtol=2e-6,
                                    atol=1e-5)
         zero = np.bincount(dst, minlength=n_out) == 0
         assert zero.sum() == 9 and not np.asarray(out)[zero].any()
-        back = bucket_aggregate(g, bm, jnp.asarray(plan.bwd_inv),
+        back = bucket_aggregate(g, bm, jnp.asarray(bwd.inv),
                                 chunk_elems=chunk_elems)
         np.testing.assert_allclose(np.asarray(back), want_b, rtol=2e-6,
                                    atol=1e-5)
@@ -549,14 +546,13 @@ def test_reduce_operand_is_the_transported_stream(transport, chunked):
     f = 8
     src, dst, n_out, n_src = _bucket_edges(63, seed=2)
     plan = BucketPlan(src, dst, n_out, n_src, fwd_widths=_bucket_widths(63))
-    mats = [jnp.asarray(m) for m in plan.fwd_mats]
+    mats, inv = _as_operands(plan.fwd)
     chunk = 64 * 63 * f if chunked else 1 << 30
     jaxpr = jax.make_jaxpr(
-        lambda x: bucket_aggregate(x, mats, jnp.asarray(plan.fwd_inv),
-                                   chunk_elems=chunk)
+        lambda x: bucket_aggregate(x, mats, inv, chunk_elems=chunk)
     )(jnp.zeros((n_src, f), dt)).jaxpr
     seen = assert_reduce_reads_transport(jaxpr, dt, f)
-    live = [m.shape for m in plan.fwd_mats if m.shape[1]]
+    live = [m.shape for m in mats if m.shape[1]]
     want = [(w, 64 if chunked and w == 63 else n) for w, n in live]
     assert sorted(seen) == sorted(want)
 
@@ -593,9 +589,7 @@ def test_word_path_equals_element_path_bit_for_bit(fmt, f, chunked,
     width = 13
     src, dst, n_out, n_src = _bucket_edges(width, seed=31)
     plan = BucketPlan(src, dst, n_out, n_src)
-    fm = [jnp.asarray(m) for m in plan.fwd_mats]
-    bm = [jnp.asarray(m) for m in plan.bwd_mats]
-    finv, binv = jnp.asarray(plan.fwd_inv), jnp.asarray(plan.bwd_inv)
+    (fm, finv), (bm, binv) = _as_operands(plan.fwd), _as_operands(plan.bwd)
     chunk = 64 * width * 256 if chunked else 1 << 30
     rng = np.random.default_rng(f)
     # small and large magnitudes: subnormals of both formats are sent
@@ -665,13 +659,13 @@ def test_other_operands_are_gathered_as_they_are(case):
              "float32": (jnp.float32, 64)}[case]
     src, dst, n_out, n_src = _bucket_edges(6, seed=8)
     plan = BucketPlan(src, dst, n_out, n_src)
-    fm = [jnp.asarray(m) for m in plan.fwd_mats]
+    fm, inv = _as_operands(plan.fwd)
     x = jnp.asarray(np.random.default_rng(0).standard_normal((n_src, f)),
                     jnp.float32).astype(dt)
     ops = _gather_operands(
-        lambda a: bucket_aggregate(a, fm, jnp.asarray(plan.fwd_inv)), x)
+        lambda a: bucket_aggregate(a, fm, inv), x)
     assert ops and all(o == (dt, (n_src + 1, f)) for o in ops), ops
-    out = bucket_aggregate(x, fm, jnp.asarray(plan.fwd_inv))
+    out = bucket_aggregate(x, fm, inv)
     want = _dense(src, dst, n_out, n_src) @ np.asarray(
         x.astype(jnp.float32), np.float64)
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-6, atol=1e-5)
@@ -803,28 +797,29 @@ def test_fitted_plan_between_two_rungs_matches_dense(transport):
     src = np.concatenate([rng.choice(n_src, d, replace=False)
                           for d in degs])
     plan = BucketPlan(src, dst, degs.size, n_src)
+    fwd, bwd = plan.fwd.whole(), plan.bwd.whole()
     hist = np.bincount(degs)
-    assert 3 <= len(plan.fwd_widths) <= 8
-    assert plan.fwd_widths[-1] == degs.max()
-    assert _slots(hist, plan.fwd_widths)[0] < 0.85 * _slots(
+    assert 3 <= len(fwd.widths) <= 8
+    assert fwd.widths[-1] == degs.max()
+    assert _slots(hist, fwd.widths)[0] < 0.85 * _slots(
         hist, _bucket_widths(int(degs.max())))[0]
-    _assert_slot_major(plan.fwd_mats, plan.fwd_widths)
-    _assert_slot_major(plan.bwd_mats, plan.bwd_widths)
+    _assert_slot_major(fwd.mats, fwd.widths)
+    _assert_slot_major(bwd.mats, bwd.widths)
     x = jnp.asarray(rng.standard_normal((n_src, f)), jnp.float32).astype(dt)
     g = jnp.asarray(rng.standard_normal((degs.size, f)),
                     jnp.float32).astype(dt)
     a = _dense(src, dst, degs.size, n_src)
     for chunk_elems in (1 << 30, 96 * 100 * f):
         out = bucket_aggregate(
-            x, [jnp.asarray(m) for m in plan.fwd_mats],
-            jnp.asarray(plan.fwd_inv), chunk_elems=chunk_elems)
+            x, [jnp.asarray(m) for m in fwd.mats],
+            jnp.asarray(fwd.inv), chunk_elems=chunk_elems)
         np.testing.assert_allclose(
             np.asarray(out),
             a @ np.asarray(x.astype(jnp.float32), np.float64),
             rtol=2e-6, atol=1e-5)
         back = bucket_aggregate(
-            g, [jnp.asarray(m) for m in plan.bwd_mats],
-            jnp.asarray(plan.bwd_inv), chunk_elems=chunk_elems)
+            g, [jnp.asarray(m) for m in bwd.mats],
+            jnp.asarray(bwd.inv), chunk_elems=chunk_elems)
         np.testing.assert_allclose(
             np.asarray(back),
             a.T @ np.asarray(g.astype(jnp.float32), np.float64),
@@ -1002,8 +997,9 @@ def test_dirty_rebuild_keeps_the_ladder_until_it_is_outgrown():
     assert _table_neighbours(tabs, "bkt_bwd", sg.n_max) == \
         _shard_neighbours(sg, transpose=True)
     # a row of shard 1 (and no other shard) outgrows the forward
-    # ladder's top width: its pad edges become edges of row 0
-    top = widths[0][-1]
+    # ladder's top width (the cache keeps a ladder a part; this graph's
+    # directions are one part each): its pad edges become edges of row 0
+    top = widths[0][0][-1]
     grown = sg
     pad = np.nonzero(grown.edge_dst[1] == sg.n_max)[0][:top + 40]
     grown.edge_dst[1][pad] = 0
@@ -1011,7 +1007,7 @@ def test_dirty_rebuild_keeps_the_ladder_until_it_is_outgrown():
     hub = int(np.count_nonzero(grown.edge_dst[1] == 0))
     assert hub > top
     tabs = build_sharded_bucket_tables(grown, plan_cache=cache, dirty=[1])
-    assert cache["widths"][0][-1] == hub
+    assert cache["widths"][0][0][-1] == hub
     assert not any(p is q for p, q in zip(cache["plans"], plans))
     assert _table_neighbours(tabs, "bkt_fwd", n_src_rows) == \
         _shard_neighbours(grown)
@@ -1178,3 +1174,255 @@ def test_fitted_tables_sum_what_the_ladder_tables_sum(kernel, monkeypatch):
     assert len(fitted) == len(ladder)
     for a, b in zip(fitted, ladder):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+# ---------------- a direction cut by source rows into parts -----------------
+
+def _as_operands(direction):
+    """A Direction's tables as bucket_aggregate takes them: one part's
+    list and permutation, or a list of each."""
+    parts = [([jnp.asarray(m) for m in p.mats], jnp.asarray(p.inv))
+             for p in direction.parts]
+    if len(parts) == 1:
+        return parts[0]
+    return [m for m, _ in parts], [i for _, i in parts]
+
+
+PART_TRANSPORTS = {"float32": (jnp.float32, None),
+                   "bfloat16": (jnp.bfloat16, "bfloat16"),
+                   "fp8-words": (jnp.float8_e4m3fn, "float8")}
+
+
+@pytest.mark.parametrize("transport", sorted(PART_TRANSPORTS))
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_parts_sum_what_one_table_sums(k, transport):
+    """A direction cut by source rows into k parts (each its own table,
+    zero row and fitted widths), forward and transpose, through feature
+    slabs and a chunked bucket: on integer-valued features every sum is
+    the uncut table's and the dense one's, exactly (the same f32 sums in
+    another order); the closure's VJP is the uncut one's within f32
+    tolerance. fp8 rides the gathers as 16-bit words."""
+    from pipegcn_tpu.ops.bucket_spmm import _rides_as_words, chunk_rows
+
+    src, dst, n_out, n_src = _bucket_edges(19, seed=k, n_rows=260,
+                                           n_src=400)
+    one = BucketPlan(src, dst, n_out, n_src, parts=(1, 1))
+    cut = BucketPlan(src, dst, n_out, n_src, parts=(k, k))
+    assert (cut.fwd.k, cut.bwd.k) == (k, k)
+    dt, rem_dtype = PART_TRANSPORTS[transport]
+    f, slab, chunk = 24, 8, 8 * 32 * 3
+    assert _rides_as_words(dt, slab) == (transport == "fp8-words")
+    assert any(chunk_rows(m.shape[0], m.shape[1], slab, chunk)[1] > 1
+               for p in cut.fwd.parts for m in p.mats if m.shape[1])
+    rng = np.random.default_rng(k)
+    x = rng.integers(-3, 4, (n_src, f)).astype(np.float32)
+    g = rng.integers(-3, 4, (n_out, f)).astype(np.float32)
+    a = _dense(src, dst, n_out, n_src)
+    for plan_dir, feats, want in ((cut.fwd, x, a @ x), (cut.bwd, g, a.T @ g)):
+        got = bucket_aggregate(jnp.asarray(feats, dt),
+                               *_as_operands(plan_dir), chunk_elems=chunk,
+                               slab=slab)
+        assert np.array_equal(np.asarray(got), want)
+    deg = jnp.asarray(np.maximum(np.bincount(dst, minlength=n_out), 1),
+                      jnp.float32)
+    outs = []
+    for plan in (one, cut):
+        fn = make_bucket_spmm_fn(*_as_operands(plan.fwd),
+                                 *_as_operands(plan.bwd), deg, n_src,
+                                 chunk_elems=chunk, rem_dtype=rem_dtype)
+        y, vjp = jax.vjp(fn, jnp.asarray(x))
+        outs.append((np.asarray(y), np.asarray(vjp(jnp.asarray(g))[0])))
+    for u, v in zip(*outs):
+        np.testing.assert_allclose(v, u, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("n_rows,k", [(232_966, 1), (393_215, 1),
+                                      (393_216, 2), (716_848, 2),
+                                      (786_431, 3)])
+def test_part_count_follows_the_height(n_rows, k):
+    """Reddit's 232,966 source rows make one table of 256-byte slab rows
+    under GATHER_PART_BYTES, Yelp's 716,848 two; the parts are equal
+    within a row, cover every row once, and none is taller than the
+    bound holds (its rows and its zero row)."""
+    from pipegcn_tpu.ops.bucket_spmm import (GATHER_PART_BYTES, SLAB_BYTES,
+                                             part_bounds, source_parts)
+
+    assert source_parts(n_rows) == k
+    bounds = part_bounds(n_rows, k)
+    assert bounds[0][0] == 0 and bounds[-1][1] == n_rows
+    assert all(a[1] == b[0] for a, b in zip(bounds, bounds[1:]))
+    rows = [hi - lo for lo, hi in bounds]
+    assert max(rows) - min(rows) <= 1
+    assert (max(rows) + 1) * SLAB_BYTES <= GATHER_PART_BYTES
+    if k > 1:   # and one part fewer would make one too tall
+        assert (-(-n_rows // (k - 1)) + 1) * SLAB_BYTES > GATHER_PART_BYTES
+
+
+def _cut_shards(monkeypatch, rows_a_part=80):
+    """_four_shards with the part bound lowered so that a direction's
+    source rows are cut into parts of at most `rows_a_part` rows."""
+    from pipegcn_tpu.ops import bucket_spmm
+
+    monkeypatch.setattr(bucket_spmm, "GATHER_PART_BYTES",
+                        (rows_a_part + 1) * bucket_spmm.SLAB_BYTES)
+    return _four_shards()
+
+
+def _part_neighbours(tables, stem, n_src_rows):
+    """_table_neighbours over every part of a direction, the part's
+    rows counted from the first source row again."""
+    from pipegcn_tpu.ops.bucket_spmm import (_bucket_keys, _part_stems,
+                                             part_bounds)
+
+    stems = _part_stems(tables, stem)
+    out = {}
+    for s, (lo, hi) in zip(stems, part_bounds(n_src_rows, len(stems))):
+        own = {k: tables[k] for k in _bucket_keys(tables, s) + [s + "_inv"]}
+        for key, nb in _table_neighbours(own, s, hi - lo).items():
+            out.setdefault(key, []).extend(lo + v for v in nb)
+    return {key: sorted(v) for key, v in out.items() if v}
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_sharded_parts_hold_every_edge_once(direction, monkeypatch):
+    """Four shards cut into three parts a direction: each part has its
+    own keys, its own fitted ladder and its own inverse permutation over
+    every destination row; a row with no edge in a part points at that
+    part's zero row; over all parts each device's tables hold exactly
+    its edges (validate_bucket_tables counts them), pad_stats reports
+    the cut, and every device's closure is the dense mean, forward and
+    VJP."""
+    from pipegcn_tpu.ops.bucket_spmm import (
+        _bucket_keys, _part_stems, bucket_pad_stats,
+        build_sharded_bucket_tables, make_device_bucket_spmm_fn,
+        part_bounds)
+
+    sg = _cut_shards(monkeypatch)
+    n_src_rows = sg.n_max + sg.halo_size
+    tabs = build_sharded_bucket_tables(sg)
+    stem = f"bkt_{direction}"
+    src_rows = n_src_rows if direction == "fwd" else sg.n_max
+    stems = _part_stems(tabs, stem)
+    assert stems == [stem, f"{stem}_p1", f"{stem}_p2"]
+    assert _part_neighbours(tabs, stem, src_rows) == \
+        _shard_neighbours(sg, transpose=direction == "bwd")
+    pad = bucket_pad_stats(tabs, sg.n_max, n_src_rows)[direction]
+    bounds = part_bounds(src_rows, 3)
+    assert pad["parts"] == 3 and pad["part_rows"] == max(
+        hi - lo for lo, hi in bounds) <= 80
+    assert pad["widths"] == [[tabs[k].shape[1] for k in _bucket_keys(tabs, s)]
+                             for s in stems]
+    real = [sg.edge_dst[r] < sg.n_max for r in range(4)]
+    assert pad["edges"] == int(sum(m.sum() for m in real))
+    gsrc = sg.edge_src if direction == "fwd" else sg.edge_dst
+    gdst = sg.edge_dst if direction == "fwd" else sg.edge_src
+    for s, (lo, hi) in zip(stems, bounds):
+        zero_row = sum(tabs[k].shape[2] for k in _bucket_keys(tabs, s))
+        for r in range(4):
+            sel = real[r] & (gsrc[r] >= lo) & (gsrc[r] < hi)
+            has = np.zeros(tabs[s + "_inv"].shape[1], bool)
+            has[gdst[r][sel]] = True
+            assert np.all((tabs[s + "_inv"][r] == zero_row) == ~has)
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((n_src_rows, 8)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((sg.n_max, 8)), jnp.float32)
+    for r in range(4):
+        a = np.zeros((sg.n_max, n_src_rows))
+        np.add.at(a, (sg.edge_dst[r][real[r]], sg.edge_src[r][real[r]]), 1)
+        deg = np.maximum(a.sum(axis=1), 1)
+        fn = make_device_bucket_spmm_fn(
+            {k: jnp.asarray(v[r]) for k, v in tabs.items()},
+            jnp.asarray(deg, jnp.float32), n_src_rows)
+        y, vjp = jax.vjp(fn, x)
+        np.testing.assert_allclose(np.asarray(y),
+                                   (a / deg[:, None]) @ np.asarray(x),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(vjp(c)[0]),
+                                   (a / deg[:, None]).T @ np.asarray(c),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("part", [0, 1, 2])
+def test_an_index_past_its_parts_sentinel_raises(part, monkeypatch):
+    """An index that is in bounds for the whole source rows but past its
+    own part's zero row (the part's row count) is refused by name, as
+    is an inverse permutation past the part's own buckets; the part's
+    sentinel itself over an edge is a dropped edge, counted."""
+    from pipegcn_tpu.ops.bucket_spmm import (
+        _bucket_keys, _part_stem, build_sharded_bucket_tables, part_bounds,
+        validate_bucket_tables)
+
+    sg = _cut_shards(monkeypatch)
+    n_src_rows = sg.n_max + sg.halo_size
+    tabs = build_sharded_bucket_tables(sg)
+    lo, hi = part_bounds(n_src_rows, 3)[part]
+    s = _part_stem("bkt_fwd", part)
+    key = _bucket_keys(tabs, s)[0]
+    assert hi - lo < n_src_rows
+    bad = {k: np.array(v) for k, v in tabs.items()}
+    bad[key][0, 0, 0] = hi - lo + 1
+    with pytest.raises(ValueError, match=f"{key!r} holds out-of-bounds"):
+        validate_bucket_tables(bad, sg.n_max, n_src_rows)
+    bad[key][0, 0, 0] = hi - lo
+    with pytest.raises(ValueError, match="dropped or overwritten"):
+        validate_bucket_tables(bad, sg.n_max, n_src_rows)
+    bad[key][0, 0, 0] = tabs[key][0, 0, 0]
+    rows = sum(tabs[k].shape[2] for k in _bucket_keys(tabs, s))
+    bad[s + "_inv"][0, 0] = rows + 1
+    with pytest.raises(ValueError, match="out-of-bounds"):
+        validate_bucket_tables(bad, sg.n_max, n_src_rows)
+
+
+def test_dirty_rebuild_keeps_the_parts_and_the_edges(monkeypatch):
+    """The streaming path over a cut direction: the cache keeps a ladder
+    a part, a dirty rebuild reuses the clean shards' plans with the
+    parts they have, and the tables hold the changed graph's edges."""
+    from pipegcn_tpu.ops.bucket_spmm import (_part_stems,
+                                             build_sharded_bucket_tables)
+
+    sg = _cut_shards(monkeypatch)
+    n_src_rows = sg.n_max + sg.halo_size
+    cache = {}
+    build_sharded_bucket_tables(sg, plan_cache=cache)
+    widths, plans = cache["widths"], list(cache["plans"])
+    assert [len(w) for w in widths] == [3, 3]
+    deg = np.bincount(sg.edge_dst[2][sg.edge_dst[2] < sg.n_max],
+                      minlength=sg.n_max)
+    sg.edge_dst[2][np.isin(sg.edge_dst[2], np.nonzero(deg <= 3)[0])] = \
+        sg.n_max
+    tabs = build_sharded_bucket_tables(sg, plan_cache=cache, dirty=[2])
+    assert cache["widths"] == widths
+    assert [p is q for p, q in zip(cache["plans"], plans)] == \
+        [True, True, False, True]
+    assert all(p.fwd.k == p.bwd.k == 3 for p in cache["plans"])
+    for d, rows in (("fwd", n_src_rows), ("bwd", sg.n_max)):
+        assert len(_part_stems(tabs, f"bkt_{d}")) == 3
+        assert _part_neighbours(tabs, f"bkt_{d}", rows) == \
+            _shard_neighbours(sg, transpose=d == "bwd")
+
+
+@pytest.mark.parametrize("rem_dtype", [None, "float8"])
+def test_trainer_over_parts_matches_xla(rem_dtype, monkeypatch):
+    """The trainer on four devices (halo rows among the forward's source
+    rows) with the part bound lowered so that both directions are cut
+    into parts: `tables_pad` says so, and the losses are the XLA
+    path's, within the transport's rounding."""
+    from pipegcn_tpu.ops import bucket_spmm as bs
+
+    g = synthetic_graph(num_nodes=300, avg_degree=7, n_feat=10, n_class=4,
+                        seed=21)
+    sg = ShardedGraph.build(g, partition_graph(g, 4, seed=0), n_parts=4)
+    monkeypatch.setattr(bs, "GATHER_PART_BYTES", 31 * bs.SLAB_BYTES)
+    losses = {}
+    for impl in ("xla", "bucket"):
+        cfg = ModelConfig(layer_sizes=(10, 16, 4), norm="layer",
+                          dropout=0.0, train_size=sg.n_train_global,
+                          spmm_impl=impl, spmm_chunk=40,
+                          rem_dtype=rem_dtype if impl == "bucket" else None)
+        t = Trainer(sg, cfg, TrainConfig(seed=4, enable_pipeline=True))
+        losses[impl] = [t.train_epoch(e) for e in range(6)]
+    n_src_rows = sg.n_max + sg.halo_size
+    assert t.tables_pad["fwd"]["parts"] == bs.source_parts(n_src_rows) > 1
+    assert t.tables_pad["bwd"]["parts"] == bs.source_parts(sg.n_max) > 1
+    np.testing.assert_allclose(losses["xla"], losses["bucket"],
+                               rtol=5e-2 if rem_dtype else 2e-4)

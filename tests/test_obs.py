@@ -327,14 +327,41 @@ def test_schema_v18_drift_guard():
         assert obs_schema.SCHEMA_VERSION > 18
 
 
+# FROZEN copy of the v19 additions (v18 + how each direction's source
+# rows are cut for the gathers: ops/bucket_spmm.py source_parts). Same
+# contract as the earlier guards.
+_V19_TABLES_PAD_FIELDS = {**_V18_TABLES_PAD_FIELDS, "parts": "integer",
+                          "part_rows": "integer"}
+
+
+def test_schema_v19_drift_guard():
+    if obs_schema.SCHEMA_VERSION == 19:
+        for frozen, live, what in (
+                (_V17_TUNING_FIELDS, obs_schema.TUNING_FIELDS, "tuning"),
+                (_V17_TUNING_COST_FIELDS, obs_schema.TUNING_COST_FIELDS,
+                 "tuning cost"),
+                (_V19_TABLES_PAD_FIELDS, obs_schema.TABLES_PAD_FIELDS,
+                 "run tables_pad"),
+                (_V18_TABLES_PAD_DENSE_FIELDS,
+                 obs_schema.TABLES_PAD_DENSE_FIELDS, "run tables_pad")):
+            for name, tag in frozen.items():
+                assert live.get(name) == tag, (
+                    f"schema field {what}.{name} removed or retyped "
+                    f"without bumping SCHEMA_VERSION")
+    else:
+        assert obs_schema.SCHEMA_VERSION > 19
+
+
 def test_validate_run_record_tables_pad():
     """A run record's `tables_pad` is held to TABLES_PAD_FIELDS a
     direction; where a direction says what the block kernel's dense
-    half stores it says all of it (v18); null under `xla`."""
+    half stores it says all of it (v18); every direction says how its
+    source rows are cut for the gathers, its widths a list a part
+    (v19); null under `xla`."""
     run = {"event": "run", "schema_version": obs_schema.SCHEMA_VERSION,
            "time_unix": 0.0, "config": {}, "device": {}, "mesh": {}}
-    rows = {"widths": [7, 32], "slots": 1036, "edges": 1000,
-            "pad_ratio": 1.036}
+    rows = {"widths": [[7, 32]], "slots": 1036, "edges": 1000,
+            "pad_ratio": 1.036, "parts": 1, "part_rows": 232_966}
     dense = {"dense_blocks": 38744, "dense_slots": 46135,
              "dense_pad": 1.1908, "a_bytes": 377_937_920}
     validate_record(run)
@@ -353,6 +380,12 @@ def test_validate_run_record_tables_pad():
     with pytest.raises(ValueError, match="expected integer"):
         validate_record({**run, "tables_pad": {"fwd": {
             **rows, **dense, "dense_slots": 1.5}}})
+    cut = {**rows, "widths": [[1, 9, 17], [1, 8, 15], [1, 8, 16]],
+           "parts": 3, "part_rows": 238_950}
+    validate_record({**run, "tables_pad": {"fwd": cut, "bwd": cut}})
+    with pytest.raises(ValueError, match="tables_pad.fwd.*part_rows"):
+        validate_record({**run, "tables_pad": {"fwd": {
+            k: v for k, v in cut.items() if k != "part_rows"}}})
 
 
 def test_validate_record():

@@ -140,9 +140,10 @@ def test_bucket_delta_keeps_the_ladder_and_a_grown_row_refits():
     assert np.isfinite(t.train_epoch(0))
     step_before, widths = t._step, _bucket_widths_of(t)
     ladder = t._bucket_plan_cache["widths"]
+    # the cache keeps a ladder a part; these directions are one part
     for d, fitted in zip(("bkt_fwd", "bkt_bwd"), ladder):
         assert [w for k, w in sorted(widths.items())
-                if k.startswith(d)] == list(fitted)
+                if k.startswith(d)] == list(*fitted)
     b = synthetic_delta_schedule(g, n_batches=1, edges_per_batch=6,
                                  dels_per_batch=2, nodes_per_batch=1,
                                  seed=3)[0]
@@ -161,13 +162,13 @@ def test_bucket_delta_keeps_the_ladder_and_a_grown_row_refits():
     nbrs = set(patcher.g.src[patcher.g.dst == v].tolist())
     mates = [u for u in np.nonzero(patcher.parts == patcher.parts[v])[0]
              if u not in nbrs]
-    need = ladder[0][-1] - int(deg[v]) + 2
+    need = ladder[0][0][-1] - int(deg[v]) + 2
     assert 0 < need <= len(mates)
     rep = t.apply_graph_deltas(DeltaBatch.make(
         seq=1, add_edges=[(u, v) for u in mates[:need]]))
     assert not rep.repadded
     refit = t._bucket_plan_cache["widths"]
-    assert refit != ladder and refit[0][-1] >= ladder[0][-1] + 2
+    assert refit != ladder and refit[0][0][-1] >= ladder[0][0][-1] + 2
     t3, _ = _fresh_rebuild(patcher, sg, cfg, tcfg)
     _assert_bucket_tables_sum_the_same(t, t3)
     assert _bucket_widths_of(t) == _bucket_widths_of(t3)

@@ -12,9 +12,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from pipegcn_tpu.ops.bucket_spmm import (DEFAULT_CHUNK_ELEMS, ROW_TILE,
-                                         _rides_as_words, bucket_aggregate,
-                                         chunk_rows)
+from pipegcn_tpu.ops.bucket_spmm import (DEFAULT_CHUNK_ELEMS,
+                                         GATHER_PART_BYTES, ROW_TILE,
+                                         SLAB_BYTES, _rides_as_words,
+                                         bucket_aggregate, chunk_rows,
+                                         part_bounds, source_parts)
 
 
 @pytest.fixture(scope="module")
@@ -78,17 +80,45 @@ def _arrays(shape: str):
 # widths fitted to their degree histograms (fit_widths; the narrowest,
 # a middle and the widest bucket of each, rows as the benchmark's
 # graphs fill them, PERF.md section 6, PR 34), cut into chunks as
-# chunk_rows cuts them: (table shapes, source rows, F, dtype)
+# chunk_rows cuts them: (table shapes, source rows, F, dtype). Yelp's
+# 716,847 source rows are more than one table under GATHER_PART_BYTES
+# holds: its buckets as the builder cuts them (the graph in node
+# order), two parts of about 358,424 rows, each with widths fitted to
+# its own degrees (a list of table shapes a part)
 _REDDIT = [(90, 55_552), (104, 59_008), (538, 416)]
+_YELP_PARTS = [[(3, 137_472), (6, 115_168), (19, 2656)],
+               [(3, 136_704), (7, 89_408), (19, 6784)]]
 CASES = {
     "reddit-e4m3": (_REDDIT, 233_000, 256, jnp.float8_e4m3fn),
     "reddit-e5m2": (_REDDIT, 233_000, 256, jnp.float8_e5m2),
     "reddit-bf16": (_REDDIT, 233_000, 256, jnp.bfloat16),
     "yelp-e4m3": ([(7, 92_960), (11, 89_696), (32, 2528)],
                   717_000, 512, jnp.float8_e4m3fn),
+    "yelp-e4m3-parts": (_YELP_PARTS, 716_847, 512, jnp.float8_e4m3fn),
     "f32": ([(90, 2048), (104, 20000 // 32 * 32)], 233_000, 64,
             jnp.float32),
 }
+
+
+def _compiled(one_chip, case, scope=""):
+    """The compiled text of bucket_aggregate over CASES[case]'s tables:
+    one list of them, or a list a part and a permutation a part."""
+    shapes, n_src, f, dt = CASES[case]
+    sds = jax.ShapeDtypeStruct
+
+    def idx(s):
+        return sds(s, jnp.int32, sharding=one_chip)
+
+    inv = sds((n_src,), jnp.int32, sharding=one_chip)
+    if isinstance(shapes[0], list):
+        mats, invs = [[idx(s) for s in p] for p in shapes], \
+            [inv] * len(shapes)
+    else:
+        mats, invs = [idx(s) for s in shapes], inv
+    fn = jax.jit(lambda x, mats, inv: bucket_aggregate(x, mats, inv,
+                                                       scope=scope))
+    return fn.lower(sds((n_src, f), dt, sharding=one_chip), mats,
+                    invs).compile().as_text()
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -100,16 +130,13 @@ def test_reduce_reads_the_transport_dtype_on_the_chip(one_chip, case):
     reduction. fp8 rows are gathered as 16-bit words of 128 columns
     (half the elements, the same bytes), split and widened a byte plane
     at a time inside that fusion, whose output is two [rows, F/2]
-    halves."""
+    halves. A direction cut into parts holds to all of it part by
+    part, and packs each part's table once a call."""
+    hlo = _compiled(one_chip, case, "rem_")
     shapes, n_src, f, dt = CASES[case]
+    parts = shapes if isinstance(shapes[0], list) else [shapes]
+    shapes = [s for p in parts for s in p]
     assert all(r % ROW_TILE == 0 for _, r in shapes)
-    sds = jax.ShapeDtypeStruct
-    fn = jax.jit(lambda x, mats, inv: bucket_aggregate(x, mats, inv,
-                                                       scope="rem_"))
-    hlo = fn.lower(
-        sds((n_src, f), dt, sharding=one_chip),
-        [sds(s, jnp.int32, sharding=one_chip) for s in shapes],
-        sds((n_src,), jnp.int32, sharding=one_chip)).compile().as_text()
     ops = [o for o in _scheduled(hlo)
            if "rem_reduce" in o[3] or "rem_gather" in o[3]]
     assert ops
@@ -155,11 +182,51 @@ def test_reduce_reads_the_transport_dtype_on_the_chip(one_chip, case):
         assert len(halves) >= 2 * len(shapes), sums
         assert all(got == "f32" and n in {r * slab // 2 for _, r in chunks}
                    for got, _, n in halves), sums
-        # and the table is packed in one pass a call, not once a chunk
+        # and each part's table is packed in one pass a call, not once
+        # a chunk
+        tables = {(hi - lo + 1) * slab // 2
+                  for lo, hi in part_bounds(n_src, len(parts))}
         packs = [o for o in ops if "rem_gather" in o[3]
-                 and any(got == "u16" and n == (n_src + 1) * slab // 2
+                 and any(got == "u16" and n in tables
                          for got, _, n in _arrays(o[1]))]
-        assert len(packs) == 1, packs
+        assert len(packs) == len(parts), packs
+
+
+@pytest.mark.parametrize("case", ["yelp-e4m3-parts", "reddit-e4m3"])
+def test_every_gather_reads_a_table_in_s1(one_chip, case):
+    """No gather reads a table taller than GATHER_PART_BYTES: Yelp's
+    716,847 source rows are cut into two parts (Reddit's 233,000 stay
+    one), every gather under `gather` reads a word table of at most
+    that many rows, and the chip's compiler keeps each in memory space
+    S(1) (a part's table is packed once the part before it is summed;
+    interleaved, a part's chunk loops read it from HBM). The parts meet
+    in the unpermute: one take a part, and their sum is no pass of its
+    own (it rides in the fusion that writes the slab's result)."""
+    shapes, n_src, f, dt = CASES[case]
+    k = len(shapes) if isinstance(shapes[0], list) else 1
+    assert source_parts(n_src) == k
+    rows = GATHER_PART_BYTES // SLAB_BYTES
+    hlo = _compiled(one_chip, case)
+    ops = _scheduled(hlo)
+    shape = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ", line)
+        if m:
+            shape[m.group(1)] = m.group(2)
+    operands = _operands(hlo)
+    gathers = [o for o in ops if o[2] == "fusion" and o[1].startswith("u16[")
+               and re.search(r"(^|/)gather/.*gather$", o[3])]
+    assert len(gathers) >= len([s for p in (shapes if k > 1 else [shapes])
+                                for s in p])
+    for o in gathers:
+        table = shape[operands[o[0]][0]]
+        m = re.match(r"u16\[(\d+),128\]", table)
+        assert m and int(m.group(1)) <= rows, (o, table)
+        assert "S(1)" in table, (o, table)
+    takes = [o for o in ops if re.search(r"(^|/)unpermute/", o[3])
+             and o[2] == "fusion" and o[1].startswith(f"f32[{n_src},")]
+    assert len(takes) == k, takes
+    assert not [o for o in ops if re.search(r"(^|/)unpermute/add", o[3])]
 
 
 # The dense classes of the Reddit cells (PERF.md section 5, PR 37: the

@@ -845,14 +845,15 @@ def test_older_format_table_is_refused_and_retuned(tmp_path, old_format):
     assert healed["sample_dense_coverage"] is not None
 
 
-@pytest.mark.parametrize("old_format", [6, 7, 8])
+@pytest.mark.parametrize("old_format", [6, 7, 8, 9])
 @pytest.mark.parametrize("impl", ["bucket", "block"])
 def test_older_format_tables_are_refused_and_rebuilt(tmp_path, impl,
                                                      old_format):
     """A `*_tables.npz` stamped with table format 6 (bucket and
     remainder tables destination-major, [P, cap, w]), 7 (slot-major,
-    on the x1.5 ladder's widths) or 8 (the block kernel's A one table
-    in block-id order beside its index matrices) is refused by name,
+    on the x1.5 ladder's widths), 8 (the block kernel's A one table
+    in block-id order beside its index matrices) or 9 (no direction
+    cut by source rows, however tall) is refused by name,
     rebuilt slot-major at the fitted widths, A stored in reading order,
     and replaced on disk; the next trainer loads the rebuilt file and
     reports the same padding."""
@@ -860,7 +861,7 @@ def test_older_format_tables_are_refused_and_rebuilt(tmp_path, impl,
     path = str(tmp_path / "art")
     sg.save(path)
     sgl = ShardedGraph.load(path)
-    assert Trainer._TABLES_FORMAT == 9
+    assert Trainer._TABLES_FORMAT == 10
     # tiles small enough for this graph to fill some
     kw = dict(spmm_impl=impl, **(dict(block_tile=16, block_nnz=4)
                                  if impl == "block" else {}))
@@ -893,9 +894,9 @@ def test_older_format_tables_are_refused_and_rebuilt(tmp_path, impl,
                  TrainConfig(seed=0))
     assert t1.tables_source == (
         f"built in this run (refused {fname}: table format "
-        f"{old_format} != 9)")
+        f"{old_format} != 10)")
     healed = np.load(fname)
-    assert int(healed["__stamp__"][0]) == 9
+    assert int(healed["__stamp__"][0]) == 10
     assert {k: healed[k].shape for k in plain} == slot_major
     assert "blk_a_bits" not in healed.files
     assert all(k in healed.files for k in dense)
@@ -908,7 +909,7 @@ def test_older_format_tables_are_refused_and_rebuilt(tmp_path, impl,
     for d in ("fwd", "bwd"):
         pad = t2.tables_pad[d]
         assert pad["slots"] >= pad["edges"] > 0
-        assert pad["widths"] == sorted(pad["widths"])
+        assert all(w == sorted(w) for w in pad["widths"])
         assert pad["pad_ratio"] == round(pad["slots"] / pad["edges"], 4)
         # under the block kernel, what the dense half stores as well
         assert ("dense_pad" in pad) == (impl == "block")
