@@ -428,27 +428,11 @@ def main(argv=None) -> int:
     logf = open(os.path.join(out_dir, "smoke.log"), "w")
     sys.stdout = _Tee(sys.__stdout__, logf)
 
-    # cache traffic of this process, from JAX's own monitoring events
-    cache = {"requests": 0, "hits": 0, "misses": 0}
-    names = {"/jax/compilation_cache/compile_requests_use_cache":
-             "requests",
-             "/jax/compilation_cache/cache_hits": "hits",
-             "/jax/compilation_cache/cache_misses": "misses"}
+    # compile and cache traffic of this process: the program's own
+    # counters (obs/trace.py), from JAX's monitoring events
+    from pipegcn_tpu.obs.trace import recorder
 
-    def on_event(name, **_kw):
-        if name in names:
-            cache[names[name]] += 1
-
-    jax.monitoring.register_event_listener(on_event)
-    # what each backend compile cost: JAX writes a program to the cache
-    # only if it took jax_persistent_cache_min_compile_time_secs (1 s)
-    compiles = []
-
-    def on_duration(name, secs, **_kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            compiles.append(secs)
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    recorder().install()
 
     from importlib import metadata
 
@@ -500,18 +484,25 @@ def main(argv=None) -> int:
     ref = check_reference(legs[0]["winner"])
     print(f"--- reference agreement on a small input: {ref}")
 
-    small = [c for c in compiles if c < 1.0]
-    cache.update(backend_compiles=len(compiles),
-                 backend_compile_s=round(sum(compiles), 1),
-                 under_threshold=len(small),
-                 under_threshold_s=round(sum(small), 1))
+    tot = recorder().totals
+    small = [c for c in tot["compile_times"] if c < 1.0]
+    cache = {"requests": tot["requests"], "hits": tot["cache_hits"],
+             "misses": tot["written"],
+             "cache_load_s": round(tot["cache_load_s"], 1),
+             "lower_s": round(tot["lower_s"], 1),
+             "backend_compiles": tot["compiles"],
+             "backend_compile_s": round(tot["compile_s"], 1),
+             "under_threshold": len(small),
+             "under_threshold_s": round(sum(small), 1)}
     print(f"compile cache traffic this process: {cache['requests']} "
-          f"requests, {cache['hits']} hits, {cache['misses']} written. "
-          f"{len(compiles)} backend compiles took "
-          f"{cache['backend_compile_s']} s; {len(small)} of them were "
-          f"under JAX's 1 s write threshold and took "
+          f"requests, {cache['hits']} hits loaded in "
+          f"{cache['cache_load_s']} s, {cache['misses']} written. "
+          f"{cache['backend_compiles']} backend compiles no hit served "
+          f"took {cache['backend_compile_s']} s; {len(small)} of them "
+          f"were under JAX's 1 s write threshold and took "
           f"{cache['under_threshold_s']} s together, which is all a warm "
-          f"process still pays for them")
+          f"process still pays for them; tracing and lowering took "
+          f"{cache['lower_s']} s")
     with open(os.path.join(out_dir, "summary.json"), "w") as f:
         json.dump({"device": dev, "nodes": opts.nodes, "legs": legs,
                    "reference": ref, "cache": cache,

@@ -32,6 +32,7 @@ import warnings
 
 from ..graph.datasets import inductive_split, load_data
 from ..models.sage import ModelConfig
+from ..obs.trace import recorder, span
 from ..partition.halo import ShardedGraph
 from ..partition.partitioner import locality_clusters, partition_graph
 from ..utils.checkpoint import (checkpoint_exists, load_checkpoint,
@@ -120,7 +121,12 @@ def _pad_text(tables_pad) -> str:
 
 def prepare(args):
     """Load, partition (or reuse artifact), and return
-    (sharded_graph, eval_graphs or None)."""
+    (sharded_graph, eval_graphs or None), under a `setup/graph` span."""
+    with span("setup/graph"):
+        return _prepare(args)
+
+
+def _prepare(args):
     graph_name = args.graph_name or derive_graph_name(args)
     # the local-id ordering is part of the artifact's identity: a
     # cluster-reordered layout and a plain one are both valid but not
@@ -274,7 +280,11 @@ def _await_partition_artifact(part_path: str, n_partitions: int,
 
 
 def run(args) -> dict:
-    """Full training run; returns a result dict (accuracies, timings)."""
+    """Full training run; returns a result dict (accuracies, timings).
+    Its set-up is recorded as spans (obs/trace.py): setup/process (the
+    process's first run only), setup/runtime, setup/services,
+    setup/graph, setup/trainer, then fit's own."""
+    recorder().begin_run()
     # seed semantics: random unless --fix-seed (reference main.py:11-14)
     if not args.fix_seed:
         if args.parts_per_node < args.n_partitions:
@@ -324,74 +334,80 @@ def run(args) -> dict:
 
         stream_plan = StreamPlan.parse(args.stream_plan)
 
-    # deferred jax import so the parser works without initializing backends
-    import jax
+    with span("setup/runtime"):
+        # deferred jax import so the parser works without initializing
+        # backends
+        import jax
 
-    from ..backend import device_line, place_compile_cache
+        from ..backend import device_line, place_compile_cache
 
-    cache_dir = place_compile_cache()
-    _maybe_init_distributed(args)
-    # what this process actually runs on: a run where JAX came up on
-    # the CPU must be visible in the log, not only in the metrics header
-    print(device_line())
-    print(f"compile cache: {cache_dir}")
+        recorder().install()
+        cache_dir = place_compile_cache()
+        _maybe_init_distributed(args)
+        # what this process actually runs on: a run where JAX came up
+        # on the CPU must be visible in the log, not only in the
+        # metrics header
+        print(device_line())
+        print(f"compile cache: {cache_dir}")
 
     from ..parallel.trainer import TrainConfig, Trainer
     from ..resilience import CoordConfig, Coordinator
 
-    # cross-rank coordination: inactive (pure no-ops) in single-process
-    # runs, so fit() keeps one code path. Built BEFORE the partition
-    # build and started immediately: heartbeats must flow while this
-    # rank spends minutes partitioning / compiling, or its
-    # already-training-blocked peers would mistake the silence for
-    # death. The shared coordination dir (heartbeats + desync resync)
-    # defaults under the partition dir — the filesystem multi-host runs
-    # already share — keyed by the rendezvous endpoint so concurrent
-    # runs never cross-talk. The consensus channel itself needs the
-    # training mesh and is attached after the trainer build.
-    coord_dir = args.watchdog_dir or os.path.join(
-        args.partition_dir,
-        f"coord-{args.master_addr}-{args.port}")
-    # under elastic supervision (cli.elastic) the membership generation
-    # keys the heartbeat filenames, so a relaunched fleet never sees a
-    # previous incarnation's files (resilience/elastic.py)
-    try:
-        membership_gen = int(os.environ.get("PIPEGCN_MEMBERSHIP_GEN", -1))
-    except ValueError:
-        membership_gen = -1
-    if membership_gen >= 0:
-        print(f"elastic membership generation {membership_gen}")
-    coord = Coordinator(
-        cfg=CoordConfig(
-            dir=coord_dir,
-            watchdog_timeout=args.watchdog_timeout,
-            desync_every=args.desync_check_every,
-            desync_resync=args.desync_resync,
-            generation=membership_gen,
-        ),
-        log=print)
-    coord.start()
+    with span("setup/services"):
+        # cross-rank coordination: inactive (pure no-ops) in single-process
+        # runs, so fit() keeps one code path. Built BEFORE the partition
+        # build and started immediately: heartbeats must flow while this
+        # rank spends minutes partitioning / compiling, or its
+        # already-training-blocked peers would mistake the silence for
+        # death. The shared coordination dir (heartbeats + desync resync)
+        # defaults under the partition dir — the filesystem multi-host runs
+        # already share — keyed by the rendezvous endpoint so concurrent
+        # runs never cross-talk. The consensus channel itself needs the
+        # training mesh and is attached after the trainer build.
+        coord_dir = args.watchdog_dir or os.path.join(
+            args.partition_dir,
+            f"coord-{args.master_addr}-{args.port}")
+        # under elastic supervision (cli.elastic) the membership generation
+        # keys the heartbeat filenames, so a relaunched fleet never sees a
+        # previous incarnation's files (resilience/elastic.py)
+        try:
+            membership_gen = int(os.environ.get("PIPEGCN_MEMBERSHIP_GEN", -1))
+        except ValueError:
+            membership_gen = -1
+        if membership_gen >= 0:
+            print(f"elastic membership generation {membership_gen}")
+        coord = Coordinator(
+            cfg=CoordConfig(
+                dir=coord_dir,
+                watchdog_timeout=args.watchdog_timeout,
+                desync_every=args.desync_check_every,
+                desync_resync=args.desync_resync,
+                generation=membership_gen,
+            ),
+            log=print)
+        coord.start()
 
-    # ---- flight recorder (obs/flight.py): on by default, breadcrumbs
-    # from here on dump to blackbox-r<k>.json in the coordination dir
-    # on fault / unhandled exception / preemption / watchdog trip, and
-    # on demand via SIGQUIT (kill -QUIT <pid>) ----
-    from ..obs import flight as flightrec
+        # ---- flight recorder (obs/flight.py): on by default, breadcrumbs
+        # from here on dump to blackbox-r<k>.json in the coordination dir
+        # on fault / unhandled exception / preemption / watchdog trip, and
+        # on demand via SIGQUIT (kill -QUIT <pid>) ----
+        from ..obs import flight as flightrec
 
-    flightrec.configure(rank=jax.process_index(), dump_dir=coord_dir)
-    flightrec.install_signal_dump()
-    flightrec.crumb("run-start", dataset=args.dataset,
-                    n_partitions=args.n_partitions,
-                    node_rank=args.node_rank)
+        flightrec.configure(rank=jax.process_index(), dump_dir=coord_dir)
+        flightrec.install_signal_dump()
+        flightrec.crumb("run-start", dataset=args.dataset,
+                        n_partitions=args.n_partitions,
+                        node_rank=args.node_rank)
 
-    t_setup = time.perf_counter()
-    if streaming:
-        # streaming needs the live host graph + parts the artifact path
-        # discards, so it always builds in memory (with slack headroom)
-        sg, eval_graphs, host_g, host_parts = _prepare_streaming(args)
-    else:
-        sg, eval_graphs = prepare(args)
-    prepare_s = time.perf_counter() - t_setup
+    with span("setup/graph") as graph_span:
+        if streaming:
+            # streaming needs the live host graph + parts the artifact
+            # path discards, so it always builds in memory (with slack
+            # headroom)
+            sg, eval_graphs, host_g, host_parts = _prepare_streaming(args)
+        else:
+            sg, eval_graphs = _prepare(args)
+    prepare_s = graph_span.dur_s
     # partition-size report (reference prints each rank's node count at
     # setup, train.py:267-268)
     sizes = ", ".join(str(int(c)) for c in sg.inner_count)
@@ -445,7 +461,8 @@ def run(args) -> dict:
         integrity_check_every=args.integrity_check_every,
         train_traces=not args.no_train_traces,
     )
-    trainer = Trainer(sg, cfg, tcfg)
+    with span("setup/trainer"):
+        trainer = Trainer(sg, cfg, tcfg)
     # set-up is paid by every run and is larger than a short run's
     # training: say where it went (host wall clock, not a device metric)
     setup_s = {"graph_partition": round(prepare_s, 2),
@@ -530,59 +547,65 @@ def run(args) -> dict:
             print(f"WARNING: --resume found no checkpoint in "
                   f"{args.checkpoint_dir!r}; training from scratch")
 
-    metrics = None
-    if args.metrics_out:
-        from ..obs import MetricsLogger, device_info, mesh_info
+    with span("setup/services"):
+        metrics = None
+        sink_prev = None
+        if args.metrics_out:
+            from ..obs import MetricsLogger, device_info, mesh_info
 
-        metrics = MetricsLogger(args.metrics_out)
-        # args-level header (richer than the trainer's fallback): the
-        # exact CLI invocation that produced the numbers
-        metrics.run_header(
-            config=vars(args),
-            device=device_info(),
-            mesh={"n_parts": args.n_partitions,
-                  **mesh_info(trainer.mesh)},
-            setup_s=setup_s, tables_pad=trainer.tables_pad,
-        )
-        if replay_stats is not None:
-            # the resume replay ran before the sink existed; its audit
-            # records land here (soak invariant #9 + the topo-rollback
-            # postmortem rule read them)
-            metrics.journal(
-                op="replay", seq=wm_seq,
-                topo_generation=int(trainer.topo_generation),
-                n_records=replay_stats["replayed"], source="resume",
-                rederived=replay_stats["rederived"],
-                watermark_generation=wm_gen)
-            if replay_stats["truncated"]:
+            metrics = MetricsLogger(args.metrics_out)
+            # args-level header (richer than the trainer's fallback): the
+            # exact CLI invocation that produced the numbers
+            metrics.run_header(
+                config=vars(args),
+                device=device_info(),
+                mesh={"n_parts": args.n_partitions,
+                      **mesh_info(trainer.mesh)},
+                setup_s=setup_s, tables_pad=trainer.tables_pad,
+            )
+            if replay_stats is not None:
+                # the resume replay ran before the sink existed; its audit
+                # records land here (soak invariant #9 + the topo-rollback
+                # postmortem rule read them)
                 metrics.journal(
-                    op="truncate", seq=wm_seq,
+                    op="replay", seq=wm_seq,
                     topo_generation=int(trainer.topo_generation),
-                    n_records=replay_stats["truncated"],
-                    source="resume")
+                    n_records=replay_stats["replayed"], source="resume",
+                    rederived=replay_stats["rederived"],
+                    watermark_generation=wm_gen)
+                if replay_stats["truncated"]:
+                    metrics.journal(
+                        op="truncate", seq=wm_seq,
+                        topo_generation=int(trainer.topo_generation),
+                        n_records=replay_stats["truncated"],
+                        source="resume")
+            if not args.no_train_traces:
+                # the set-up spans recorded so far, and every later one
+                sink_prev = recorder().attach(
+                    metrics, f"r{jax.process_index()}")
 
-    # ---- fault tolerance (docs/RESILIENCE.md) ----
-    from ..resilience import (DivergenceSentinel, FaultPlan,
-                              PreemptionHandler, SentinelConfig)
+        # ---- fault tolerance (docs/RESILIENCE.md) ----
+        from ..resilience import (DivergenceSentinel, FaultPlan,
+                                  PreemptionHandler, SentinelConfig)
 
-    sentinel = None
-    if getattr(args, "sentinel", True):
-        sentinel = DivergenceSentinel(SentinelConfig(
-            loss_factor=args.sentinel_loss_factor,
-            grad_norm_max=args.sentinel_grad_max,
-            max_retries=args.sentinel_max_retries,
-            lr_backoff=args.sentinel_lr_backoff,
-            snapshot_every=args.sentinel_snapshot_every,
-            flush_on_trip=args.sentinel_flush,
-        ))
-    fault_plan = FaultPlan.parse(args.fault_plan,
-                                 rank=jax.process_index()) \
-        if args.fault_plan else None
-    preemption = PreemptionHandler()
-    # the coordinator has been heartbeating since before the partition
-    # build; now that the mesh and metrics sink exist, complete it
-    coord.attach_mesh(trainer.mesh)
-    coord.metrics = metrics
+        sentinel = None
+        if getattr(args, "sentinel", True):
+            sentinel = DivergenceSentinel(SentinelConfig(
+                loss_factor=args.sentinel_loss_factor,
+                grad_norm_max=args.sentinel_grad_max,
+                max_retries=args.sentinel_max_retries,
+                lr_backoff=args.sentinel_lr_backoff,
+                snapshot_every=args.sentinel_snapshot_every,
+                flush_on_trip=args.sentinel_flush,
+            ))
+        fault_plan = FaultPlan.parse(args.fault_plan,
+                                     rank=jax.process_index()) \
+            if args.fault_plan else None
+        preemption = PreemptionHandler()
+        # the coordinator has been heartbeating since before the partition
+        # build; now that the mesh and metrics sink exist, complete it
+        coord.attach_mesh(trainer.mesh)
+        coord.metrics = metrics
 
     if getattr(args, "anatomy", False):
         # compiled-step anatomy: FLOPs/bytes per phase from the
@@ -656,6 +679,7 @@ def run(args) -> dict:
         # every record is already flushed; close releases the handle
         # even when training crashes mid-run
         if metrics is not None:
+            recorder().restore(sink_prev)
             metrics.close()
 
     result = {

@@ -8,7 +8,9 @@ Three pillars (docs/OBSERVABILITY.md):
   metrics.py  MetricsLogger, the JSONL event sink, plus host probes
               (device_info / mesh_info / memory_snapshot)
   trace.py    XLA trace annotations (named_phase for traced code,
-              trace_span for host spans) and PhaseTimer — the
+              trace_span for host spans), the recorded set-up spans
+              with JAX's compile counters (span, recorder), and
+              PhaseTimer — the
               exception-safe, nesting-aware generalization of the
               reference-parity CommTimer (utils/timer.py is now a shim
               over it)
@@ -28,9 +30,6 @@ Three pillars (docs/OBSERVABILITY.md):
               + the rolling LiveAggregator behind cli/monitor.py
   health.py   SLO alert rules, the Prometheus /metrics + /health
               exporters, and the MonitorServer HTTP endpoint
-  trend.py    bench trend tracking over BENCH_r*.json /
-              MULTICHIP_*.json with best-known-headline regression
-              flags (scripts/bench_trend.py is the CLI)
   flight.py   black-box flight recorder: bounded breadcrumb ring,
               atomic blackbox-r<k>.json crash dumps, faulthandler
               all-thread stack capture, sub-watchdog stall detector

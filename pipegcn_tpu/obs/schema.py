@@ -17,7 +17,16 @@ from __future__ import annotations
 
 from typing import Dict, Mapping
 
-SCHEMA_VERSION = 19  # v19: each direction of `tables_pad` says how its
+SCHEMA_VERSION = 20  # v20: set-up spans (trace id "setup", obs/trace.py:
+#                      setup/process, setup/runtime, setup/graph,
+#                      setup/trainer and its phases, setup/services,
+#                      fit/measure_comm, fit/load, fit/tail) carry
+#                      `parent` and the compile counters
+#                      (SPAN_COUNTER_FIELDS); a training `compute` span
+#                      (and fit/tail) carries the counters too and what
+#                      the host did since the previous harvest
+#                      (SPAN_BLOCK_FIELDS)
+#                 v19: each direction of `tables_pad` says how its
 #                      source rows are cut for the gathers: `parts`
 #                      (tables, bucket_spmm.source_parts) and
 #                      `part_rows` (rows of the tallest part); `widths`
@@ -440,6 +449,36 @@ SPAN_FIELDS: Dict[str, str] = {
     "status": "string",            # ok | shed | error
 }
 
+# v20: what JAX compiled while a span was the innermost open one
+# (obs/trace.SpanRecorder), on every set-up span and every training
+# `compute` span (validate_record holds a span carrying any of them to
+# all): seconds tracing and lowering, programs the persistent cache
+# served and the seconds of those loads, backend compiles no cache hit
+# served and their seconds, and the names JAX gave the programs
+SPAN_COUNTER_FIELDS: Dict[str, str] = {
+    "lower_s": "number",
+    "cache_hits": "integer",
+    "cache_load_s": "number",
+    "compiles": "integer",
+    "compile_s": "number",
+    "programs": "array",
+}
+# v20: a set-up span's parent (the innermost span open when it opened;
+# null at the top). fit/load and fit/measure_comm name the block's
+# `compute` span
+SPAN_SETUP_FIELDS: Dict[str, str] = {
+    "parent": "string?",
+}
+# v20: a training `compute` span's host side since the previous harvest
+# (obs/trace.BlockSpans; `fit/tail` after the last one): garbage-
+# collector seconds and collections, and the seconds of each of fit's
+# sections ({"fit/boundary": s, ...})
+SPAN_BLOCK_FIELDS: Dict[str, str] = {
+    "gc_s": "number",
+    "gc_collections": "integer",
+    "sections": "object",
+}
+
 # one record per black-box flight-recorder dump (obs/flight.py): the
 # breadcrumb ring a dying (or stalled, or signalled) process left
 # behind, written atomically to blackbox-r<k>.json — and mirrored into
@@ -623,6 +662,13 @@ def validate_record(rec: Mapping) -> None:
             if any(k in pad for k in TABLES_PAD_DENSE_FIELDS):
                 _check_fields(f"run tables_pad.{d}", pad,
                               TABLES_PAD_DENSE_FIELDS)
+    if ev == "span":
+        if any(k in rec for k in SPAN_COUNTER_FIELDS):
+            _check_fields("span", rec, SPAN_COUNTER_FIELDS)
+        if rec["trace_id"] == "setup":
+            _check_fields("setup span", rec, SPAN_SETUP_FIELDS)
+        if any(k in rec for k in SPAN_BLOCK_FIELDS):
+            _check_fields("compute span", rec, SPAN_BLOCK_FIELDS)
     if ev == "tuning":
         for c in rec["costs"]:
             if not isinstance(c, dict):

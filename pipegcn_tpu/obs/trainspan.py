@@ -139,12 +139,14 @@ class TrainSpanPlane:
         self.writer.emit(tid, op, t0, t1, status, **extra)
 
     def block(self, epoch: int, chunk: int, dur_s: float,
-              t_end: Optional[float] = None) -> None:
+              t_end: Optional[float] = None, **extra) -> None:
         """Spans for one dispatched block of `chunk` epochs starting at
         epoch `epoch`, whose dispatch→harvest wall window measured
         `dur_s` seconds and ended at plane-clock `t_end` (defaults to
         now — call right after harvest). Also lands the block's
-        ``tracesync`` barrier anchor."""
+        ``tracesync`` barrier anchor. `extra` rides the compute span
+        (`span_id` and what the host did since the previous harvest:
+        obs/trace.BlockSpans)."""
         if t_end is None:
             t_end = self._clock()
         tid = trace_id(epoch)
@@ -156,7 +158,7 @@ class TrainSpanPlane:
         # window could not have absorbed even at perfect overlap
         wait = max(comm_total - dur_s, 0.0)
         self._emit(tid, "compute", t_end - dur_s, t_end, epoch=epoch,
-                   epochs=chunk, comm_wait_s=round(wait, 6))
+                   epochs=chunk, comm_wait_s=round(wait, 6), **extra)
         self.blocks += 1
         if self._ml is not None:
             # wall-clock barrier anchor (same clock->unix offset rule

@@ -69,6 +69,8 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence,
 
 import numpy as np
 
+from ..obs.trace import recorder
+
 # 6: candidates are ranked by est_call_s, the estimate of a call at the
 # shard's size from two nested samples (shard_estimate), and the row
 # buckets' widths are fitted to the degree histogram, their chunks
@@ -675,6 +677,10 @@ def tune(sg, width: int, *, block_tile: int = 256,
     costs: List[Dict[str, Any]] = []
     for cand in cands:
         entry = dict(cand)
+        # the candidate's span: what JAX lowered, compiled or loaded for
+        # it are its counters, the seconds of its timed calls `timed_s`
+        cspan = recorder().open("candidate", candidate=cand["name"],
+                                timed_s=0.0)
         try:
             reps_best, reps_spread = [], []
             for (smp, _), tabs in zip(samples, tables):
@@ -684,6 +690,7 @@ def tune(sg, width: int, *, block_tile: int = 256,
                     bucket_merge=bucket_merge, reps=reps, fbuf=fbuf,
                     table_width=width, byte_budget=byte_budget(smp),
                     tables=tabs)
+                cspan.attrs["timed_s"] += sum(ts)
                 reps_best.append(min(ts))
                 # a typical rep over the best one. Not the slowest: one
                 # rep in a hundred is a host stall of many times the
@@ -720,6 +727,9 @@ def tune(sg, width: int, *, block_tile: int = 256,
             if log:
                 log(f"# tuner: {cand['name']:16s} FAILED: "
                     f"{entry['error']}")
+        finally:
+            cspan.attrs["timed_s"] = round(cspan.attrs["timed_s"], 6)
+            recorder().close(cspan)
         costs.append(entry)
 
     winner = pick_winner(costs)

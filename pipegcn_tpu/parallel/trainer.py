@@ -47,7 +47,8 @@ from ..obs import flight as flightrec
 from ..obs.format import epoch_line, reference_eval_line, reference_train_line
 from ..obs.metrics import device_info, memory_snapshot, mesh_info
 from ..obs.profiler import ANCHOR_SPAN
-from ..obs.trace import PhaseTimer, SpanCursor, named_phase, trace_span
+from ..obs.trace import (BlockSpans, PhaseTimer, SpanCursor, named_phase,
+                         recorder, span, trace_span)
 from ..ops import tuner
 from ..ops.spmm import spmm_mean
 from ..partition.halo import ShardedGraph
@@ -183,9 +184,11 @@ class Trainer:
             self._repl = NamedSharding(self.mesh, PartitionSpec())
 
         # host wall clock of each set-up phase (tuner / tables / upload
-        # / pp_precompute), accumulated by _timed; device work inside a
-        # phase is waited for, so the split is honest
+        # / pp_precompute), accumulated by _timed from the spans of
+        # those names; device work inside a phase is waited for, so
+        # the split is honest
         self.setup_s: Dict[str, float] = {}
+        recorder().install()
         self._setup_spmm()
         # with kernel tables active, the step (and the sharded
         # evaluator) aggregate through them and the raw edge list is
@@ -209,37 +212,38 @@ class Trainer:
             with self._timed("pp_precompute"):
                 self.data["feat"] = jax.block_until_ready(
                     self._precompute_pp())
-        if cfg.compute_dtype != jnp.float32:
-            # store input features in the compute dtype so the per-epoch
-            # HBM read (and layer-0 halo exchange) is half-width; the pp
-            # precompute above still ran in f32
-            self.data["feat"] = self.data["feat"].astype(cfg.compute_dtype)
-        if self._edges_trimmed and need_edges:
-            # edges were uploaded only for the precompute above; drop
-            # them now
-            dummy = jnp.zeros((self.P, 8), jnp.int32)
-            self.data["edge_src"] = jax.device_put(dummy, self._shard)
-            self.data["edge_dst"] = jax.device_put(dummy, self._shard)
+        with span("init_state"):
+            if cfg.compute_dtype != jnp.float32:
+                # store input features in the compute dtype so the per-epoch
+                # HBM read (and layer-0 halo exchange) is half-width; the pp
+                # precompute above still ran in f32
+                self.data["feat"] = self.data["feat"].astype(cfg.compute_dtype)
+            if self._edges_trimmed and need_edges:
+                # edges were uploaded only for the precompute above; drop
+                # them now
+                dummy = jnp.zeros((self.P, 8), jnp.int32)
+                self.data["edge_src"] = jax.device_put(dummy, self._shard)
+                self.data["edge_dst"] = jax.device_put(dummy, self._shard)
 
-        rng = jax.random.PRNGKey(tcfg.seed)
-        params = init_params(rng, self.cfg)
-        if self.emulated:
-            # replicated-by-construction: stacked copies stand in for
-            # shard_map's replicated spec (the psum'd update keeps every
-            # part's copy identical)
-            stack = lambda t: jax.tree_util.tree_map(
-                lambda v: jnp.stack([v] * self.P), t)
-            params, opt, norm = (stack(params), stack(adam_init(params)),
-                                 stack(init_norm_state(self.cfg)))
-        else:
-            opt = adam_init(params)
-            norm = init_norm_state(self.cfg)
-        self.state = {
-            "params": jax.device_put(params, self._repl),
-            "opt": jax.device_put(opt, self._repl),
-            "norm": jax.device_put(norm, self._repl),
-            "comm": jax.device_put(self._init_comm(), self._shard),
-        }
+            rng = jax.random.PRNGKey(tcfg.seed)
+            params = init_params(rng, self.cfg)
+            if self.emulated:
+                # replicated-by-construction: stacked copies stand in for
+                # shard_map's replicated spec (the psum'd update keeps every
+                # part's copy identical)
+                stack = lambda t: jax.tree_util.tree_map(
+                    lambda v: jnp.stack([v] * self.P), t)
+                params, opt, norm = (stack(params), stack(adam_init(params)),
+                                     stack(init_norm_state(self.cfg)))
+            else:
+                opt = adam_init(params)
+                norm = init_norm_state(self.cfg)
+            self.state = {
+                "params": jax.device_put(params, self._repl),
+                "opt": jax.device_put(opt, self._repl),
+                "norm": jax.device_put(norm, self._repl),
+                "comm": jax.device_put(self._init_comm(), self._shard),
+            }
         # ---- numerics guardrails (resilience/numerics.py) ----
         from ..resilience.numerics import LossScaleConfig, LossScaler
 
@@ -255,7 +259,8 @@ class Trainer:
         self.fallbacks: list = []
         self._kernel_proven = False
         self._inject_kernel_crash = False
-        self._step = self._build_step()
+        with span("build_step"):
+            self._step = self._build_step()
         self._eval_cache: Dict[int, Any] = {}
         self._sharded_eval_cache: Dict[int, Any] = {}
         # compiled sharded-eval programs keyed on (shape, dtype, impl) —
@@ -279,12 +284,14 @@ class Trainer:
 
     @contextlib.contextmanager
     def _timed(self, phase: str):
-        t0 = time.perf_counter()
+        """A set-up span of this name, whose seconds `setup_s` adds up
+        by name."""
+        sp = recorder().open(phase)
         try:
             yield
         finally:
-            self.setup_s[phase] = self.setup_s.get(phase, 0.0) \
-                + time.perf_counter() - t0
+            recorder().close(sp)
+            self.setup_s[phase] = self.setup_s.get(phase, 0.0) + sp.dur_s
 
     # ---------------- spmm kernel selection ---------------------------
 
@@ -2214,6 +2221,15 @@ class Trainer:
             stall_s = 0.0
         if stall_s > 0 and frec.enabled:
             stall_det = flightrec.StallDetector(frec, stall_s).start()
+        # the host side of each block, from one harvest to the next,
+        # rides its compute span (obs/trace.BlockSpans); the spans fit
+        # records (fit/load, fit/measure_comm, fit/tail) go to the same
+        # sink
+        blocks = sink_prev = None
+        if tspan is not None:
+            sink_prev = recorder().attach(metrics, f"r{tspan.rank}",
+                                          flush=False)
+            blocks = BlockSpans(hspan)
         try:
             while epoch < n_epochs:
                 # host spans of the loop's sections, for a profiler
@@ -2773,7 +2789,12 @@ class Trainer:
                 frec.enter("dispatch", epoch=epoch, chunk=chunk)
                 # annotate=True: the host span shows up in --profile-dir
                 # traces next to the named device phases
-                with timer.phase("step", annotate=True):
+                # fit/load: written where JAX lowered, loaded or compiled
+                # a program for this dispatch (a scan length's first
+                # block, a step rebuilt after a graph delta)
+                with timer.phase("step", annotate=True), \
+                        span("fit/load", only_if_compiled=True,
+                             annotate=False, epoch=epoch, epochs=chunk):
                     with trace_span("fit/dispatch"):
                         if chunk == 1:
                             loss = self.train_epoch(epoch)
@@ -2790,7 +2811,8 @@ class Trainer:
                     # the block's spans: the real dispatch->harvest wall
                     # window, plus (once measure_comm landed) the comm
                     # tail ending at the harvest barrier
-                    tspan.block(epoch, chunk, timer.durations()["step"])
+                    tspan.block(epoch, chunk, timer.durations()["step"],
+                                **blocks.harvest())
                 dur = timer.durations()["step"] / chunk
                 if profiling:
                     prof_lengths.add(chunk)
@@ -3146,7 +3168,8 @@ class Trainer:
                     # (the reference reports per-epoch exposed comm/reduce
                     # waits, train.py:366-371; SPMD overlaps those inside
                     # the step, so we report the collectives' own cost)
-                    comm_cost = self.measure_comm()
+                    with span("fit/measure_comm"):
+                        comm_cost = self.measure_comm()
                     comm_measured = True
                     if tspan is not None:
                         # arm the comm tail: standalone per-epoch costs
@@ -3427,6 +3450,9 @@ class Trainer:
             # above runs BEFORE this, still degraded — exactly like a
             # real host whose disk is full when it dies
             hspan.to()
+            if blocks is not None:
+                blocks.close()
+                recorder().restore(sink_prev)
             for kind in list(io_armed):
                 FAULTY_IO.disarm(kind)
             io_armed.clear()

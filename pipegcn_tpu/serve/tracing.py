@@ -17,11 +17,12 @@ the per-submit cost is one comparison.
 
 from __future__ import annotations
 
-import itertools
 import random
 import threading
 import time
 from typing import Callable, Optional
+
+from ..obs.trace import next_span_id
 
 
 class TraceSampler:
@@ -67,21 +68,24 @@ class SpanWriter:
         self._clock = clock
         self._now = now
         self.source = str(source)
-        self._ids = itertools.count()
         self._lock = threading.Lock()
         self.n_spans = 0
 
     def emit(self, trace_id: Optional[str], op: str, t0: float,
-             t1: float, status: str = "ok", **extra) -> None:
-        """One span: [t0, t1] in the loop clock's frame. No-op when
-        the ticket was unsampled (trace_id None) or there is no sink."""
+             t1: float, status: str = "ok", span_id: Optional[str] = None,
+             **extra) -> Optional[str]:
+        """One span: [t0, t1] in the loop clock's frame, under
+        `span_id` (a fresh id if None). Returns the span's id; no-op
+        returning None when the ticket was unsampled (trace_id None) or
+        there is no sink."""
         if trace_id is None or self._ml is None:
-            return
+            return None
         off = self._now() - self._clock()
+        sid = span_id or next_span_id()
         with self._lock:
-            sid = f"s{next(self._ids)}"
             self.n_spans += 1
         if self.source:
             extra.setdefault("source", self.source)
         self._ml.span(trace_id, sid, op, t0 + off,
                       max(t1 - t0, 0.0) * 1e3, status, **extra)
+        return sid

@@ -1,6 +1,5 @@
 """Live telemetry plane tests (obs/live.py + obs/health.py +
-cli/monitor.py + obs/trend.py, docs/OBSERVABILITY.md "Live
-monitoring"):
+cli/monitor.py, docs/OBSERVABILITY.md "Live monitoring"):
 
   - stream discovery over run directories / stems / per-generation
     elastic files, and the deduped generation-ordered merge the report
@@ -14,9 +13,7 @@ monitoring"):
   - span lifecycle conservation through the MicroBatcher (exactly one
     terminal span per sampled submit) and the timeline's Perfetto flow
     stitching;
-  - /metrics scrape parity against the JSONL-derived values;
-  - bench trend regression flags on a synthetic worsening series and a
-    smoke pass over the repo's real BENCH artifacts.
+  - /metrics scrape parity against the JSONL-derived values.
 
 Everything here is host-side and jax-free except nothing — the marker
 is `live` (scripts/chaos.sh monitor lane)."""
@@ -44,7 +41,6 @@ from pipegcn_tpu.obs.live import (
     read_stream,
 )
 from pipegcn_tpu.obs.metrics import MetricsLogger, read_metrics
-from pipegcn_tpu.obs.trend import format_trend, load_series, trend
 
 pytestmark = pytest.mark.live
 
@@ -505,75 +501,6 @@ def test_monitor_cli_once(tmp_path, capsys):
     # the alert sink landed next to the run
     recs = read_metrics(d / "alerts.jsonl")
     assert [r["state"] for r in recs] == ["fire"]
-
-
-# ---------------- trend ----------------------------------------------------
-
-
-def _round(n, ok=True, **headline):
-    h = None
-    if headline:
-        h = {"metric": "epoch_time", "unit": "s/epoch", **headline}
-    return {"round": n, "path": f"BENCH_r{n:02d}.json", "ok": ok,
-            "headline": h}
-
-
-def test_trend_flags_regression_on_worsening_series():
-    series = {"bench": [
-        _round(1, value=1.0),
-        _round(2, value=0.9),
-        _round(3, value=1.2),  # > 5% worse than best-known 0.9
-    ], "multichip": [], "sweep": None}
-    t = trend(series, tol=0.05)
-    lever = t["levers"]["value"]
-    assert lever["best"] == 0.9 and lever["best_round"] == 2
-    assert lever["regressed"] is True
-    assert t["regressed"] is True and "value" in t["flags"]
-    assert "REGRESSED" in format_trend(t)
-
-    # within tolerance: clean
-    series["bench"][-1] = _round(3, value=0.92)
-    t = trend(series, tol=0.05)
-    assert t["levers"]["value"]["regressed"] is False
-    assert t["regressed"] is False
-
-    # a config change resets best-known instead of flagging the new
-    # shape as a regression
-    series["bench"].append(
-        {"round": 4, "path": "BENCH_r04.json", "ok": True,
-         "headline": {"metric": "bigger_graph_epoch_time",
-                      "unit": "s/epoch", "value": 9.0}})
-    t = trend(series, tol=0.05)
-    assert t["levers"]["value"]["regressed"] is False
-    assert t["levers"]["value"]["n_comparable"] == 1
-
-    # a failed latest round after successes flags the verdict
-    series["bench"].append(
-        {"round": 5, "path": "BENCH_r05.json", "ok": False,
-         "headline": None})
-    t = trend(series, tol=0.05)
-    assert t["regressed"] is True
-    assert "latest-round-failed" in t["flags"]
-
-
-def test_bench_trend_copes_with_no_records(tmp_path, capsys):
-    """A checkout with no BENCH_*.json / MULTICHIP_*.json (the records
-    of the earlier harness were removed) is an empty series, not an
-    error: the table renders, the verdict is clean, the CLI exits 0
-    even under --strict."""
-    import importlib.util
-
-    series = load_series(str(tmp_path))
-    assert series == {"bench": [], "multichip": [], "sweep": None}
-    t = trend(series)
-    assert t["n_rounds"] == 0 and not t["levers"] and not t["regressed"]
-    assert "verdict: clean" in format_trend(t)
-    spec = importlib.util.spec_from_file_location(
-        "bench_trend", os.path.join(REPO, "scripts", "bench_trend.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.main(["--root", str(tmp_path), "--strict"]) == 0
-    assert "0 round(s)" in capsys.readouterr().out
 
 
 def test_report_cli_accepts_run_directory(tmp_path, capsys):

@@ -352,6 +352,45 @@ def test_schema_v19_drift_guard():
         assert obs_schema.SCHEMA_VERSION > 19
 
 
+# FROZEN copy of the v20 additions (set-up spans and the compile
+# counters, obs/trace.py). Same contract as the earlier guards.
+_V20_SPAN_COUNTER_FIELDS = {
+    "lower_s": "number", "cache_hits": "integer", "cache_load_s": "number",
+    "compiles": "integer", "compile_s": "number", "programs": "array",
+}
+_V20_SPAN_BLOCK_FIELDS = {"gc_s": "number", "gc_collections": "integer",
+                          "sections": "object"}
+
+
+def test_schema_v20_drift_guard():
+    if obs_schema.SCHEMA_VERSION == 20:
+        for frozen, live, what in (
+                (_V19_TABLES_PAD_FIELDS, obs_schema.TABLES_PAD_FIELDS,
+                 "run tables_pad"),
+                (_V20_SPAN_COUNTER_FIELDS, obs_schema.SPAN_COUNTER_FIELDS,
+                 "span"),
+                (_V20_SPAN_BLOCK_FIELDS, obs_schema.SPAN_BLOCK_FIELDS,
+                 "compute span"),
+                ({"parent": "string?"}, obs_schema.SPAN_SETUP_FIELDS,
+                 "setup span")):
+            for name, tag in frozen.items():
+                assert live.get(name) == tag, (
+                    f"schema field {what}.{name} removed or retyped "
+                    f"without bumping SCHEMA_VERSION")
+        base = {"event": "span", "trace_id": "setup", "span_id": "s1",
+                "op": "setup/graph", "t_start": 1.0, "dur_ms": 2.0,
+                "status": "ok", "parent": None,
+                **{k: 0 for k in _V20_SPAN_COUNTER_FIELDS}, "programs": []}
+        validate_record(base)
+        for broken in ({k: v for k, v in base.items() if k != "parent"},
+                       {k: v for k, v in base.items() if k != "compiles"},
+                       dict(base, trace_id="train-e0", gc_s=0.1)):
+            with pytest.raises(ValueError):
+                validate_record(broken)
+    else:
+        assert obs_schema.SCHEMA_VERSION > 20
+
+
 def test_validate_run_record_tables_pad():
     """A run record's `tables_pad` is held to TABLES_PAD_FIELDS a
     direction; where a direction says what the block kernel's dense

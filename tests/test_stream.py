@@ -300,6 +300,42 @@ def test_fit_applies_stream_plan_and_fault_grammar(tmp_path):
     assert plan.remaining() == 0
 
 
+def test_fit_rebuild_after_repad_writes_a_load_span(tmp_path):
+    """A delta past the slack re-pads and rebuilds the step inside fit():
+    the next dispatch compiles it again, and its `fit/load` span (a
+    child of that block's compute span) names the rebuilt step."""
+    from pipegcn_tpu.obs.metrics import MetricsLogger, read_metrics
+
+    g, parts, sg, cfg, tcfg, t, patcher = _stack(slack=0.0, n_epochs=4)
+    m = 12
+    rng = np.random.default_rng(0)
+    big = DeltaBatch(seq=0, add_edges=np.zeros((0, 2), np.int64),
+                     del_edges=np.zeros((0, 2), np.int64),
+                     node_feat=rng.normal(size=(m, 10)).astype(np.float32),
+                     node_label=np.zeros(m, dtype=np.int64),
+                     node_nbrs=tuple(np.array([0], dtype=np.int64)
+                                     for _ in range(m)))
+    dpath = str(tmp_path / "deltas.jsonl")
+    save_deltas(dpath, [big])
+    mpath = str(tmp_path / "metrics.jsonl")
+    step_before = t._step
+    with MetricsLogger(mpath) as ml:
+        t.fit(None, log_fn=lambda *_: None, metrics=ml,
+              stream_plan=StreamPlan.parse(f"{dpath}@2"))
+    assert t._step is not step_before
+    recs = read_metrics(mpath)
+    assert [r["repadded"] for r in recs if r["event"] == "stream"] == [True]
+    loads = {r["epoch"]: r for r in recs
+             if r["event"] == "span" and r["op"] == "fit/load"}
+    assert sorted(loads) == [0, 2]      # the first step, the rebuilt one
+    assert loads[2]["compiles"] >= 1
+    assert "jit(step)" in loads[0]["programs"]
+    assert loads[2]["programs"] == ["jit(step)"]
+    compute = {r["span_id"]: r for r in recs
+               if r["event"] == "span" and r["op"] == "compute"}
+    assert compute[loads[2]["parent"]]["epoch"] == 2
+
+
 # ---------------- serving topology refresh ---------------------------
 
 
