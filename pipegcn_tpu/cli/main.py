@@ -562,6 +562,7 @@ def run(args) -> dict:
                 mesh={"n_parts": args.n_partitions,
                       **mesh_info(trainer.mesh)},
                 setup_s=setup_s, tables_pad=trainer.tables_pad,
+                **trainer.dropout_mask_stats(),
             )
             if replay_stats is not None:
                 # the resume replay ran before the sink existed; its audit

@@ -28,6 +28,7 @@ weights and biases (the dense tail's torch default has the same bounds).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
@@ -402,7 +403,19 @@ def _gat_layer(fbuf, lp, edge_src, edge_dst, n_dst, n_heads, slope,
     return out.astype(out_dtype) + lp["b"].astype(out_dtype)
 
 
-def _dropout(rng, h, rate, bits: int = 32):
+def _dropout(rng, h, rate, bits: int = 32, pin: bool = True,
+             masks: Optional[List[int]] = None):
+    """Inverted dropout of `h` at `rate`, the mask drawn from `rng`.
+
+    With `pin` the mask is drawn ONCE and stored (a byte an element)
+    behind an optimization barrier, and every consumer reads it: the
+    forward's matmul or the aggregation's transport cast, and the
+    backward's dW and dX. Without the barrier XLA fuses the threefry
+    rounds into each consumer's fusion and re-derives the same bits
+    there (docs/PERF_NOTES.md "A threefry mask is re-derived in every
+    consumer fusion"). A mask with one consumer is cheaper drawn inside
+    it: `pin=False`. Each pinned mask appends its bytes to `masks`,
+    while the forward traces."""
     if rate <= 0.0:
         return h
     # named scope: the RNG + mask traffic show up as their own phase in
@@ -417,10 +430,43 @@ def _dropout(rng, h, rate, bits: int = 32):
             thresh = min(max(thresh, 1), 255)
             keep = jax.random.bits(rng, h.shape, jnp.uint8) >= jnp.uint8(
                 thresh)
-            keep_q = 1.0 - thresh / 256.0
-            return jnp.where(keep, h / keep_q, 0.0)
-        keep = jax.random.bernoulli(rng, 1.0 - rate, h.shape)
-        return jnp.where(keep, h / (1.0 - rate), 0.0)
+            keep_p = 1.0 - thresh / 256.0
+        else:
+            keep = jax.random.bernoulli(rng, 1.0 - rate, h.shape)
+            keep_p = 1.0 - rate
+        if pin:
+            keep = jax.lax.optimization_barrier(keep)
+            if masks is not None:
+                masks.append(keep.size * keep.dtype.itemsize)
+        return jnp.where(keep, h / keep_p, 0.0)
+
+
+@functools.lru_cache(maxsize=32)
+def dropout_masks(cfg: ModelConfig, feat: jax.ShapeDtypeStruct, n_dst: int,
+                  halo_rows: int = 0) -> Tuple[int, int]:
+    """(masks, bytes) of the dropout masks one training step draws once
+    and keeps for its backward, as `_dropout` counts them while the
+    forward traces at one device's shapes: `feat` its input, `n_dst`
+    its rows, `halo_rows` the rows the exchange appends. Nothing
+    compiles or runs (jax.eval_shape); the exchange and the aggregation
+    stand in by the shapes they return. Cached: a trace costs the host
+    about 10 ms, and every `fit` call that writes a run header asks."""
+    masks: List[int] = []
+
+    def trace(params, feat):
+        return forward(
+            params, cfg, feat, None, None,
+            jnp.ones((n_dst,), jnp.float32), n_dst, training=True,
+            rng=jax.random.PRNGKey(0),
+            comm_update=lambda i, h: jnp.concatenate(
+                [h, jnp.zeros((halo_rows, h.shape[1]), h.dtype)]),
+            spmm_fn=lambda h: h[:n_dst].astype(jnp.float32),
+            gat_fn=lambda z, el, er: z[:n_dst].astype(jnp.float32),
+            masks=masks)
+
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    jax.eval_shape(trace, params, feat)
+    return len(masks), sum(masks)
 
 
 def forward(
@@ -443,6 +489,7 @@ def forward(
     gat_fn: Optional[Callable[..., jax.Array]] = None,
     halo_eval: bool = False,
     probe: Optional[Callable[[str, jax.Array], None]] = None,
+    masks: Optional[List[int]] = None,
 ) -> Tuple[jax.Array, List[dict]]:
     """Run the GraphSAGE stack; returns (logits [n_dst, n_class],
     updated norm_state).
@@ -472,6 +519,9 @@ def forward(
     tensor so the caller can fold cheap in-graph finiteness counts into
     the step metrics. Phases emitted here: input / halo_concat / spmm /
     dense / norm / logits; loss and grads are the caller's to probe.
+
+    `masks` (optional) gets the bytes of each dropout mask the training
+    forward draws once and keeps for its backward (`_dropout`).
     """
     if probe is None:
         probe = lambda _name, _x: None  # noqa: E731 — trivial no-op
@@ -538,7 +588,13 @@ def forward(
                     h = comm_update(i, h)
                     probe("halo_concat", h)
                 if training and cfg.dropout > 0:
-                    h = _dropout(sub, h, cfg.dropout, cfg.dropout_bits)
+                    # under use_pp layer 0 drops the precomputed
+                    # features, which take no gradient: XLA writes the
+                    # dropped features once, from the draw's own fusion,
+                    # and a stored mask would only add a write
+                    h = _dropout(sub, h, cfg.dropout, cfg.dropout_bits,
+                                 pin=not (cfg.use_pp and i == 0),
+                                 masks=masks)
                 lp = params["layers"][i]
                 if cfg.use_pp and i == 0:
                     h = dense(h, lp["w"], lp["b"], out_dt)
@@ -591,7 +647,8 @@ def forward(
                     h = dense2(h, ah, lp, out_dt)
         else:
             if training and cfg.dropout > 0:
-                h = _dropout(sub, h, cfg.dropout, cfg.dropout_bits)
+                h = _dropout(sub, h, cfg.dropout, cfg.dropout_bits,
+                             masks=masks)
             lp = params["layers"][i]
             h = dense(h, lp["w"], lp["b"], out_dt)
 

@@ -17,7 +17,10 @@ from __future__ import annotations
 
 from typing import Dict, Mapping
 
-SCHEMA_VERSION = 20  # v20: set-up spans (trace id "setup", obs/trace.py:
+SCHEMA_VERSION = 21  # v21: the run record says what the training step
+#                      keeps of its dropout masks (RUN_DROPOUT_FIELDS,
+#                      both or neither: models/sage.py dropout_masks)
+#                 v20: set-up spans (trace id "setup", obs/trace.py:
 #                      setup/process, setup/runtime, setup/graph,
 #                      setup/trainer and its phases, setup/services,
 #                      fit/measure_comm, fit/load, fit/tail) carry
@@ -77,6 +80,15 @@ RUN_FIELDS: Dict[str, str] = {
     "config": "object",          # model/train/CLI config snapshot
     "device": "object",          # platform / device_kind / counts
     "mesh": "object",            # n_parts, axis names/shape
+}
+
+# what one training step keeps of its dropout masks, where a run record
+# says it (validate_record holds it to both): the masks drawn once and
+# stored for the backward (models/sage.py _dropout) and their bytes on
+# one device
+RUN_DROPOUT_FIELDS: Dict[str, str] = {
+    "dropout_masks": "integer",
+    "dropout_mask_bytes": "integer",
 }
 
 # one direction (`fwd`, `bwd`) of a run record's `tables_pad`, where it
@@ -657,6 +669,8 @@ def validate_record(rec: Mapping) -> None:
         return
     _check_fields(ev, rec, fields)
     if ev == "run":
+        if any(k in rec for k in RUN_DROPOUT_FIELDS):
+            _check_fields("run", rec, RUN_DROPOUT_FIELDS)
         for d, pad in (rec.get("tables_pad") or {}).items():
             _check_fields(f"run tables_pad.{d}", pad, TABLES_PAD_FIELDS)
             if any(k in pad for k in TABLES_PAD_DENSE_FIELDS):

@@ -42,7 +42,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..graph.csr import Graph
-from ..models.sage import ModelConfig, forward, init_norm_state, init_params
+from ..models.sage import (ModelConfig, dropout_masks, forward,
+                           init_norm_state, init_params)
 from ..obs import flight as flightrec
 from ..obs.format import epoch_line, reference_eval_line, reference_train_line
 from ..obs.metrics import device_info, memory_snapshot, mesh_info
@@ -1920,7 +1921,7 @@ class Trainer:
                 config={"model": dataclasses.asdict(self.cfg),
                         "train": dataclasses.asdict(self.tcfg)},
                 device=device_info(), mesh=mesh_info(self.mesh),
-                tables_pad=self.tables_pad)
+                tables_pad=self.tables_pad, **self.dropout_mask_stats())
         # ---- tuner decision (set at _setup_spmm for spmm_impl='auto'):
         # surface WHY this kernel dispatches, once per run ----
         if getattr(self, "tuning", None) is not None and \
@@ -3621,6 +3622,18 @@ class Trainer:
             return {}
         return {k: float(v) for k, v in ca.items()
                 if isinstance(v, (int, float))}
+
+    def dropout_mask_stats(self) -> Dict[str, int]:
+        """The run record's `dropout_masks` and `dropout_mask_bytes`:
+        the dropout masks one training step draws once and keeps for
+        its backward, and their bytes on one device, as the model's
+        forward counts them while it traces at this trainer's shapes
+        (models/sage.py dropout_masks; nothing compiles)."""
+        feat = self.data["feat"]
+        n, nbytes = dropout_masks(
+            self.cfg, jax.ShapeDtypeStruct(feat.shape[1:], feat.dtype),
+            self.sg.n_max, self.sg.halo_size)
+        return {"dropout_masks": n, "dropout_mask_bytes": nbytes}
 
     def est_halo_bytes_per_epoch(self, compressed: bool = True) -> int:
         """Estimated halo wire bytes per epoch: per exchanged graph

@@ -269,3 +269,101 @@ def test_spmm_bf16_in_deg_cotangent_matches_f32(small_graph):
     assert float(jnp.abs(gd32).max()) > 0
     np.testing.assert_allclose(np.asarray(gd16), np.asarray(gd32),
                                rtol=0.1, atol=0.02)
+
+
+def _dropout_unpinned(rng, h, rate, bits):
+    """Dropout as it was written before its mask was pinned: the draw
+    and the select in one expression, for XLA to fuse where it likes."""
+    if bits == 8:
+        thresh = min(max(int(round(rate * 256.0)), 1), 255)
+        keep = jax.random.bits(rng, h.shape, jnp.uint8) >= jnp.uint8(thresh)
+        return jnp.where(keep, h / (1.0 - thresh / 256.0), 0.0)
+    keep = jax.random.bernoulli(rng, 1.0 - rate, h.shape)
+    return jnp.where(keep, h / (1.0 - rate), 0.0)
+
+
+@pytest.mark.parametrize("bits", [32, 8])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("rate", [0.1, 0.5, 0.0])
+def test_pinned_dropout_is_bit_identical(bits, dtype, rate):
+    """The mask drawn once and kept behind a barrier selects the same
+    elements and scales them by the same keep probability as the draw
+    fused into its consumers: the outputs and the gradients of a
+    matmul that reads them, into the input and the weight, are the
+    same bits."""
+    from pipegcn_tpu.models.sage import _dropout
+
+    dt = jnp.dtype(dtype)
+    r = np.random.default_rng(5)
+    h = jnp.asarray(r.normal(size=(96, 40)), dt)
+    w = jnp.asarray(r.normal(size=(40, 24)), dt)
+    key = jax.random.PRNGKey(11)
+
+    def loss(fn):
+        def f(h, w):
+            y = fn(key, h, rate, bits) if rate > 0 else h
+            return jnp.sum(jnp.tanh(y @ w).astype(jnp.float32))
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1)))
+
+    got = loss(_dropout)(h, w)
+    want = loss(_dropout_unpinned)(h, w)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(
+            np.atleast_1d(np.asarray(a)).view(np.uint8),
+            np.atleast_1d(np.asarray(b)).view(np.uint8))
+    dropped = jax.jit(lambda h: _dropout(key, h, rate, bits))(h)
+    np.testing.assert_array_equal(
+        np.asarray(dropped).view(np.uint8),
+        np.asarray(jax.jit(
+            lambda h: _dropout_unpinned(key, h, rate, bits)
+            if rate > 0 else h)(h)).view(np.uint8))
+
+
+@pytest.mark.parametrize("shape", ["yelp", "reddit", "no-pp", "dropout0"])
+def test_dropout_masks_counted(shape):
+    """`dropout_masks` counts what `_dropout` pins while the forward
+    traces: one mask a dropout layer that takes a gradient, a byte an
+    element. Yelp's shape (use_pp, a dense tail of two): 3, layer 0's
+    mask drawn inside the fusion that drops the features, layer 1's
+    over inner and halo rows, the tail's over inner rows; Reddit's (use_pp, no tail): 3, the
+    graph layers' masks over their inner and halo rows; without use_pp
+    layer 0 pins too; none at dropout 0, and none in evaluation."""
+    from pipegcn_tpu.models.sage import dropout_masks
+
+    n, halo = 200, 24
+    cfg = {
+        "yelp": ModelConfig(layer_sizes=(30, 64, 64, 64, 10), n_linear=2,
+                            use_pp=True, dropout=0.1, dtype="bfloat16"),
+        "reddit": ModelConfig(layer_sizes=(60, 32, 32, 32, 8),
+                              use_pp=True, dropout=0.5,
+                              dtype="bfloat16"),
+        "no-pp": ModelConfig(layer_sizes=(30, 32, 32, 8), dropout=0.5),
+        "dropout0": ModelConfig(layer_sizes=(30, 64, 64, 64, 10),
+                                n_linear=2, use_pp=True, dropout=0.0),
+    }[shape]
+    pp = 2 if cfg.use_pp else 1
+    feat = jax.ShapeDtypeStruct((n, pp * cfg.layer_sizes[0]),
+                                cfg.compute_dtype)
+    want = {
+        "yelp": (3, (n + halo) * 64 + 2 * n * 64),
+        "reddit": (3, 3 * (n + halo) * 32),
+        "no-pp": (3, (n + halo) * (30 + 32 + 32)),
+        "dropout0": (0, 0),
+    }[shape]
+    assert dropout_masks(cfg, feat, n, halo) == want
+    # every `fit` that writes a run header asks again: no second trace
+    hits = dropout_masks.cache_info().hits
+    assert dropout_masks(cfg, feat, n, halo) == want
+    assert dropout_masks.cache_info().hits == hits + 1
+
+    # evaluation draws nothing, whatever the rate
+    g = karate_club(n_feat=8)
+    src, dst, deg = _graph_arrays(g)
+    ecfg = ModelConfig(layer_sizes=(8, 16, 4), dropout=0.5)
+    masks = []
+    forward(init_params(jax.random.PRNGKey(0), ecfg), ecfg,
+            jnp.array(g.ndata["feat"]), src, dst, deg, g.num_nodes,
+            training=False, masks=masks)
+    assert masks == []

@@ -391,6 +391,45 @@ def test_schema_v20_drift_guard():
         assert obs_schema.SCHEMA_VERSION > 20
 
 
+# FROZEN copy of the v21 additions (what the training step keeps of its
+# dropout masks, models/sage.py dropout_masks). Same contract as the
+# earlier guards.
+_V21_RUN_DROPOUT_FIELDS = {"dropout_masks": "integer",
+                           "dropout_mask_bytes": "integer"}
+
+
+def test_schema_v21_drift_guard():
+    if obs_schema.SCHEMA_VERSION == 21:
+        for frozen, live, what in (
+                (_V19_TABLES_PAD_FIELDS, obs_schema.TABLES_PAD_FIELDS,
+                 "run tables_pad"),
+                (_V20_SPAN_COUNTER_FIELDS, obs_schema.SPAN_COUNTER_FIELDS,
+                 "span"),
+                (_V21_RUN_DROPOUT_FIELDS, obs_schema.RUN_DROPOUT_FIELDS,
+                 "run")):
+            for name, tag in frozen.items():
+                assert live.get(name) == tag, (
+                    f"schema field {what}.{name} removed or retyped "
+                    f"without bumping SCHEMA_VERSION")
+    else:
+        assert obs_schema.SCHEMA_VERSION > 21
+
+
+def test_validate_run_record_dropout_masks():
+    """A run record that says what the step keeps of its dropout masks
+    says both numbers, as integers (v21); one that says neither passes."""
+    run = {"event": "run", "schema_version": obs_schema.SCHEMA_VERSION,
+           "time_unix": 0.0, "config": {}, "device": {}, "mesh": {}}
+    masks = {"dropout_masks": 3, "dropout_mask_bytes": 1_101_078_528}
+    validate_record(run)
+    validate_record({**run, **masks})
+    validate_record({**run, "dropout_masks": 0, "dropout_mask_bytes": 0})
+    with pytest.raises(ValueError, match="dropout_mask_bytes"):
+        validate_record({**run, "dropout_masks": 3})
+    with pytest.raises(ValueError, match="expected integer"):
+        validate_record({**run, **masks, "dropout_mask_bytes": 1.1e9})
+
+
 def test_validate_run_record_tables_pad():
     """A run record's `tables_pad` is held to TABLES_PAD_FIELDS a
     direction; where a direction says what the block kernel's dense
@@ -680,6 +719,19 @@ def test_cli_metrics_end_to_end(cli_metrics_run):
     assert summ[0]["n_epochs"] == 12
     assert summ[0]["best_val"] == pytest.approx(res["best_val"])
     assert summ[0]["comm_cost"]["comm"] > 0  # measure_comm_cost path
+
+
+def test_cli_run_record_counts_dropout_masks(cli_metrics_run):
+    """The CLI's run header says what the training step keeps of its
+    dropout masks: two graph layers at dropout 0.2, each mask over a
+    shard's inner and halo rows at the layer's input width (16 + 32),
+    a byte an element."""
+    _, mpath, args, _ = cli_metrics_run
+    header = read_metrics(mpath)[0]
+    assert args.dropout == 0.2 and args.n_layers == 2
+    assert header["dropout_masks"] == 2
+    assert header["dropout_mask_bytes"] > 0
+    assert header["dropout_mask_bytes"] % (16 + 32) == 0
 
 
 def test_cli_reference_logs_unchanged(cli_metrics_run):

@@ -1,6 +1,7 @@
-"""The aggregation kernels compiled for a v5e that is described, not
-attached (the TPU's compiler is installed here; nothing runs): what the
-chip's compiler makes of the bucket reduce at the benchmark's widths.
+"""The aggregation kernels and the training step's dropout compiled for
+a v5e that is described, not attached (the TPU's compiler is installed
+here; nothing runs): what the chip's compiler makes of the bucket
+reduce at the benchmark's widths, and where it draws the dropout masks.
 
 The topology is described inside a fixture and only there, and these
 tests live in this one file: a worker loads the TPU's library when it is
@@ -347,3 +348,98 @@ def test_stored_a_is_read_where_it_lies(one_chip, group):
     own = {lead[0] * lead[1] * group * tile * f for lead, _ in scanned}
     assert not [o for o in ops if o[2] != "parameter"
                 for dt, _, n in _arrays(o[1]) if dt == "f32" and n in own]
+
+
+def _fused_opcodes(hlo: str):
+    """{computation: the opcodes of its instructions and of every
+    computation it calls, nested} over the whole module."""
+    body, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            cur = head.group(1)
+            body[cur] = []
+        elif cur is not None:
+            body[cur].append(line)
+    memo = {}
+
+    def ops(name):
+        if name not in memo:
+            memo[name] = set()
+            for line in body.get(name, ()):
+                m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (?:\(.*?\)|\S+) "
+                             r"([\w\-]+)\(", line)
+                if m:
+                    memo[name].add(m.group(1))
+                for sub in re.findall(r"(?:calls|to_apply)=%([\w.\-]+)",
+                                      line):
+                    memo[name] |= ops(sub)
+        return memo[name]
+
+    return {name: ops(name) for name in body}
+
+
+def test_dropout_masks_drawn_once(one_chip):
+    """Yelp's training forward and backward (use_pp, 600 -> 512 -> 512
+    -> 512 -> 100 with a dense tail of two, dropout 0.1, bf16, 716,848
+    rows; an fp8 cast behind a barrier stands in for the aggregation,
+    whose transport cast reads the dropped input first): no scheduled
+    fusion outside `dropout` carries threefry's rounds (a shift-right-
+    logical with an xor in its fused computation), and each dropout
+    layer's mask is written by one fusion, once a step. Left to fuse,
+    the compiler re-derives a mask in every matmul that reads it,
+    forward, dW and dX, and in the cast (docs/PERF_NOTES.md)."""
+    from pipegcn_tpu.models.sage import ModelConfig, forward, init_params
+    from pipegcn_tpu.train.losses import bce_logits_sum
+
+    n = 716_848
+    cfg = ModelConfig(layer_sizes=(300, 512, 512, 512, 100), n_linear=2,
+                      use_pp=True, dropout=0.1, dtype="bfloat16",
+                      train_size=n)
+    sds = jax.ShapeDtypeStruct
+
+    def spmm(h):
+        q = jnp.clip(h.astype(jnp.float32), -448, 448).astype(
+            jnp.float8_e4m3fn)
+        return jax.lax.optimization_barrier(q).astype(jnp.float32)
+
+    def step(params, feat, label, mask, deg, rng):
+        def loss(p):
+            logits, _ = forward(p, cfg, feat, None, None, deg, n,
+                                training=True, rng=rng,
+                                comm_update=lambda i, h: h, spmm_fn=spmm)
+            return bce_logits_sum(logits, label, mask)
+        return jax.value_and_grad(loss)(params)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    hlo = jax.jit(step).lower(
+        params, sds((n, 600), jnp.bfloat16, sharding=one_chip),
+        sds((n, 100), jnp.float32, sharding=one_chip),
+        sds((n,), jnp.bool_, sharding=one_chip),
+        sds((n,), jnp.float32, sharding=one_chip),
+        jax.random.PRNGKey(0)).compile().as_text()
+    opcodes = _fused_opcodes(hlo)
+    calls = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = .* calls=%([\w.\-]+)",
+                     line)
+        if m:
+            calls[m.group(1)] = m.group(2)
+    rounds = [o for o in _scheduled(hlo) if o[2] == "fusion"
+              and {"shift-right-logical", "xor"}
+              <= opcodes.get(calls.get(o[0]), set())]
+    assert rounds
+    assert not [o for o in rounds if "/dropout/" not in o[3]], rounds
+    # the fusions that write a mask-sized array (layer 0's dropped
+    # features, the other layers' stored masks): one a layer at most,
+    # and each layer's mask written once
+    sized = {n * 600, n * 512}
+    draws = [o for o in rounds
+             if any(k in sized for _, _, k in _arrays(o[1]))]
+    layers = [re.search(r"layer(\d+)\)?/dropout/", o[3]).group(1)
+              for o in draws]
+    assert len(layers) == len(set(layers)), draws
+    written = [k for o in draws for _, _, k in _arrays(o[1]) if k in sized]
+    assert len(written) == cfg.n_layers, draws
