@@ -107,12 +107,15 @@ def _pad_text(tables_pad) -> str:
     """Trainer.tables_pad for the set-up line: per direction the row
     buckets' widths and gather slots an edge (bucket_spmm.pad_stats;
     under the block kernel, the remainder's, and what the dense half
-    stores: block_spmm.dense_pad_stats), and how many tables its
-    source rows are cut into for the gathers (bucket_spmm.source_parts)."""
+    stores: block_spmm.dense_pad_stats), how many tables its source
+    rows are cut into for the gathers (bucket_spmm.source_parts), and
+    the float32 bytes one call writes for its sums
+    (bucket_spmm.result_bytes)."""
     return "".join(
         f" | {d}: widths {t['widths']}, pad_ratio {t['pad_ratio']} "
         f"({t['slots']} slots / {t['edges']} edges), parts {t['parts']} "
-        f"of up to {t['part_rows']} rows"
+        f"of up to {t['part_rows']} rows, result_bytes "
+        f"{t['result_bytes']}"
         + (f", dense_pad {t['dense_pad']} ({t['dense_slots']} slots / "
            f"{t['dense_blocks']} blocks, a_bytes {t['a_bytes']})"
            if "dense_pad" in t else "")

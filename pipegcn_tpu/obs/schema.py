@@ -17,7 +17,12 @@ from __future__ import annotations
 
 from typing import Dict, Mapping
 
-SCHEMA_VERSION = 21  # v21: the run record says what the training step
+SCHEMA_VERSION = 22  # v22: each direction of `tables_pad` says what
+#                      one aggregation writes for its float32 sums:
+#                      `result_bytes` (the buffer of every part's
+#                      bucket sums and one take a part,
+#                      bucket_spmm.result_bytes)
+#                 v21: the run record says what the training step
 #                      keeps of its dropout masks (RUN_DROPOUT_FIELDS,
 #                      both or neither: models/sage.py dropout_masks)
 #                 v20: set-up spans (trace id "setup", obs/trace.py:
@@ -102,6 +107,7 @@ TABLES_PAD_FIELDS: Dict[str, str] = {
     "pad_ratio": "number",       # slots / edges
     "parts": "integer",          # tables the source rows are cut into
     "part_rows": "integer",      # source rows of the tallest part
+    "result_bytes": "integer",   # f32 bytes a call writes for its sums
 }
 TABLES_PAD_DENSE_FIELDS: Dict[str, str] = {
     "dense_blocks": "integer",   # [tile, tile] slots that hold an edge

@@ -24,8 +24,9 @@ formulation removes every scatter from both the forward AND the backward:
      in the element type whose requests the chip serves at half the
      price (SLAB_BYTES note), split back into bytes inside the
      reduction.
-  3. Results concatenate in bucket order; one final gather by a
-     precomputed inverse permutation restores destination order.
+  3. The sums go, in bucket order, into one f32 buffer, written in
+     place; one final gather by a precomputed inverse permutation
+     restores destination order.
 
 Where the source rows would make fbuf_pad taller than the chip keeps
 in its fast memory space S(1) (GATHER_PART_BYTES; Yelp's 717k rows,
@@ -456,24 +457,27 @@ def _pack_words(x):
 
 
 def _widen_sum(msgs, dt):
-    """Gathered words uint16 [w, rows, f/2] -> the f32 [rows, f] sum
-    over w of the `dt` elements they carry (_pack_words' layout): the
-    same values `astype(float32)` gives for every one of the 256 byte
-    patterns, NaN included, since each byte goes back through the
-    dtype's own convert. Byte split, convert and sum compile to one
-    fusion that reads the words and writes two [rows, f/2] halves."""
+    """Gathered words uint16 [w, rows, f/2] -> the f32 sums over w of
+    the `dt` elements they carry (_pack_words' layout), as the two
+    [rows, f/2] halves of [rows, f]: columns [0, f/2) from the low
+    bytes, [f/2, f) from the high ones. The same values
+    `astype(float32)` gives for every one of the 256 byte patterns, NaN
+    included, since each byte goes back through the dtype's own
+    convert. Byte split, convert and sum compile to one fusion that
+    reads the words and writes the two halves."""
     def plane(b):
         return jax.lax.bitcast_convert_type(
             b.astype(jnp.uint8), dt).astype(jnp.float32).sum(axis=0)
 
-    return jnp.concatenate([plane(msgs & 0xFF), plane(msgs >> 8)], axis=-1)
+    return plane(msgs & 0xFF), plane(msgs >> 8)
 
 
 def _gather_sum(table, mat, scope="", dt=None):
     """One slot-major table's (or chunk's) messages gathered and summed:
-    mat [w, rows] -> f32 [rows, F]. `table` is fbuf_pad itself, or (dt
-    given) its uint16 word view from _pack_words, whose rows carry
-    F elements of `dt` in F/2 words.
+    mat [w, rows] -> the f32 [rows, F] sums, as a tuple of column
+    blocks in order (one, or the word path's two halves). `table` is
+    fbuf_pad itself, or (dt given) its uint16 word view from
+    _pack_words, whose rows carry F elements of `dt` in F/2 words.
 
     The barrier keeps the widening to f32 INSIDE the reduction. The
     chip's gather yields the flat [w * rows, F] stream and a free view
@@ -490,7 +494,7 @@ def _gather_sum(table, mat, scope="", dt=None):
     with jax.named_scope(scope + "reduce"):
         if dt is not None:
             return _widen_sum(msgs, dt)
-        return msgs.astype(jnp.float32).sum(axis=0)
+        return (msgs.astype(jnp.float32).sum(axis=0),)
 
 
 def chunk_rows(w: int, n_b: int, f: int, chunk_elems: int):
@@ -515,6 +519,30 @@ def chunk_rows(w: int, n_b: int, f: int, chunk_elems: int):
     return r, -(-n_b // r)
 
 
+def part_heights(idx_mats) -> List[int]:
+    """Rows each part of a direction holds in bucket_aggregate's buffer
+    of sums: its buckets' rows (a table's last axis) and its zero row.
+    `idx_mats` is a list of tables a part (one device's [w, rows], or
+    stacked [P, w, rows])."""
+    return [sum(int(m.shape[-1]) for m in mats) + 1 for mats in idx_mats]
+
+
+def result_bytes(tables, stem: str, width: int) -> int:
+    """The float32 bytes one call of bucket_aggregate over one
+    direction's tables ('<stem>_<b>', a part's under its own stem; one
+    device's or stacked) writes for its sums, `width` features wide
+    (past one slab of the one-byte transports, SLAB_BYTES elements,
+    rounded up to whole slabs): the one buffer of every part's bucket
+    sums and zero row (part_heights), and one take a part, [n_out,
+    width]. Read off the tables' shapes as the kernel sizes those
+    buffers."""
+    heights = part_heights([[tables[k] for k in _bucket_keys(tables, s)]
+                            for s in _part_stems(tables, stem)])
+    n_out = int(tables[stem + "_inv"].shape[-1])
+    f = width if width <= SLAB_BYTES else -(-width // SLAB_BYTES) * SLAB_BYTES
+    return 4 * f * (sum(heights) + len(heights) * n_out)
+
+
 def bucket_aggregate(
     fbuf: jax.Array,
     idx_mats: Sequence[jax.Array],
@@ -536,32 +564,47 @@ def bucket_aggregate(
     first, with the part's row count as its zero sentinel. Each part
     is packed from its own rows and gathered from its own table (one
     that fits memory space S(1), GATHER_PART_BYTES), and the parts'
-    sums meet in the unpermute: sum over p of take(res_p, inv_p), all
-    in f32. One part is the uncut program, op for op; which runs
-    follows from the tables alone.
+    sums meet in the unpermute: sum over p of take(sums_p, inv_p), in
+    f32 and in part order. One part is the same program with one take;
+    which runs follows from the tables alone.
+
+    Every destination row's float32 sum is written ONCE, in place, into
+    one buffer of every part's sums (result_bytes counts it): part p's
+    buckets' rows one after the other and a zero row (part_heights),
+    F features wide. The buffer is allocated, not filled (lax.empty):
+    every bucket row is written by its reduce, and the zero rows are
+    written as such. Each part's take then reads it in rows of all F
+    features and writes [n_out, F], the result's own layout; their sum
+    is left to the consumer's fusion (the degree division), so no
+    bucket result, no stacked slab output and no relayout of the result
+    exists.
 
     `chunk_edges` (the --spmm-chunk edge budget) overrides the default
     element budget: each gather materializes at most ~chunk_edges
     messages. A bucket over the budget runs as a lax.scan over slices
     of its table along the rows, ROW_TILE-aligned and BALANCED
     (chunk_rows: the fewest chunks the budget allows, of equal size),
-    each writing its rows of the bucket's [rows, F] result; the last
-    slice is moved back to end at the table's end, so no padded copy
-    of the int32 table and no stacked copy of the result exist in the
-    step, and under ROW_TILE rows a chunk are gathered twice.
+    each writing its rows of the bucket's sums; the last slice is moved
+    back to end at the table's end, so no padded copy of the int32
+    table exists, and under ROW_TILE rows a chunk are gathered twice
+    (and written twice, the same values).
 
     Rows wider than SLAB_BYTES are processed per feature slab (see
-    SLAB_BYTES note above); `slab` overrides the element width (0
-    disables slabbing).
+    SLAB_BYTES note above) by a lax.scan over the slabs: each slab's
+    tables are packed from its columns of fbuf, and its sums go to its
+    columns of the buffer. `slab` overrides the element width (0
+    disables slabbing); a width that is no whole number of slabs is
+    padded with zero columns to one (`relayout`).
 
     One-byte elements and an even (slab) width ride the gathers
     as 16-bit words (_rides_as_words): fbuf_pad is packed into uint16
     [R + 1, f/2] once a call and a slab (_pack_words), the takes read
     that, and the reduction splits the words back into the two byte
-    planes as it widens them (_widen_sum). Rows, order, chunking,
-    tables and the f32 sums are the element path's, to the bit; which
-    path runs follows from fbuf's dtype and width alone. Every bucket,
-    chunked or not, goes through _gather_sum.
+    planes as it widens them (_widen_sum), each plane's half written
+    where it belongs. Rows, order, chunking, tables and the f32 sums
+    are the element path's, to the bit; which path runs follows from
+    fbuf's dtype and width alone. Every bucket, chunked or not, goes
+    through _gather_sum.
 
     Every gather runs with mode='clip' (clamped, no bounds-check
     select): the table indices are in-bounds BY CONSTRUCTION (pad
@@ -573,52 +616,89 @@ def bucket_aggregate(
     but never on CPU (docs/RESILIENCE.md "Numerics").
 
     The work is named for the profiler (obs/profiler.py SCOPE_NAMES):
-    `gather` (the row takes), `reduce` (the sum over a bucket's width,
-    widened to f32 as it reads), `unpermute` (concatenation and the
-    inv_perm take),
-    `relayout` (the feature-slab transposes in and out). `scope`
-    prefixes them: the block kernel's remainder passes "rem_"."""
-    f = fbuf.shape[-1]
+    `gather` (the row takes and the packing of their tables), `reduce`
+    (the sum over a bucket's width, widened to f32 as it reads, and its
+    write into the buffer of sums), `unpermute` (the inv_perm takes),
+    `relayout` (the zero columns of a width that is no whole number of
+    slabs). `scope` prefixes them: the block kernel's remainder passes
+    "rem_"."""
+    r, f = fbuf.shape
     if slab is None:
         slab = SLAB_BYTES // fbuf.dtype.itemsize
-    if slab and f > slab:
-        return _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems,
-                                  chunk_edges, slab, scope)
+    if not slab or f <= slab:
+        slab = f
+    n_s = -(-f // slab)
     if chunk_edges:
-        chunk_elems = chunk_edges * f
+        chunk_elems = chunk_edges * slab
     if not isinstance(inv_perm, (list, tuple)):
         idx_mats, inv_perm = [idx_mats], [inv_perm]
-    bounds = part_bounds(fbuf.shape[0], len(inv_perm))
-    results = []
-    for (lo, hi), mats in zip(bounds, idx_mats):
-        rows = fbuf
-        if results:
-            # a part's table is packed once the part before it is
-            # summed, so one part's table is live at a time and each
-            # keeps its place in S(1) (scheduled freely, the chip's
-            # compiler interleaves the parts' chunk loops and leaves a
-            # third of them reading from HBM)
-            with jax.named_scope(scope + "gather"):
-                rows, results[-1] = jax.lax.optimization_barrier(
-                    (fbuf, results[-1]))
-        results.append(_part_sums(rows if len(bounds) == 1
-                                  else rows[lo:hi], mats, chunk_elems,
-                                  scope))
+    k = len(inv_perm)
+    bounds = part_bounds(r, k)
+    heights = part_heights(idx_mats)
+    bases = np.cumsum([0] + heights[:-1]).tolist()
+    # under shard_map the buffer varies over the mesh axes its inputs
+    # vary over (a scan's carry keeps its type from the first
+    # iteration on)
+    vma = jax.typeof(fbuf).vma.union(
+        *(jax.typeof(m).vma for mats in idx_mats for m in mats))
+
+    def varying(x):
+        return jax.lax.pcast(x, tuple(vma), to="varying") if vma else x
+
+    with jax.named_scope(scope + "reduce"):
+        sums = varying(jax.lax.empty((sum(heights), n_s * slab),
+                                     jnp.float32))
+        zero = varying(jnp.zeros((1, n_s * slab), jnp.float32))
+        for b, h in zip(bases, heights):
+            sums = jax.lax.dynamic_update_slice(sums, zero, (b + h - 1, 0))
+    if n_s * slab > f:
+        with jax.named_scope(scope + "relayout"):
+            fbuf = jnp.pad(fbuf, ((0, 0), (0, n_s * slab - f)))
+
+    def one_slab(sums, s):
+        col = s * slab
+        for p, ((lo, hi), mats) in enumerate(zip(bounds, idx_mats)):
+            rows = fbuf
+            if p:
+                # a part's table is packed once the part before it is
+                # summed, so one part's table is live at a time and
+                # each keeps its place in S(1) (scheduled freely, the
+                # chip's compiler interleaves the parts' chunk loops
+                # and leaves a third of them reading from HBM)
+                with jax.named_scope(scope + "gather"):
+                    rows, sums = jax.lax.optimization_barrier((fbuf, sums))
+            if k > 1 or n_s > 1:
+                rows = jax.lax.dynamic_slice(rows, (lo, col),
+                                             (hi - lo, slab))
+            sums = _part_sums(rows, mats, sums, bases[p], col, chunk_elems,
+                              scope)
+        return sums, None
+
+    if n_s == 1:
+        sums, _ = one_slab(sums, 0)
+    else:
+        sums, _ = jax.lax.scan(one_slab, sums, jnp.arange(n_s))
     # a part contributes to every destination row that has an edge in
-    # it; a row with none reads the part's zero sentinel row. One take
-    # a part; their sum is no pass of its own, the chip's compiler
-    # fuses it into the write of the result (tests/test_tpu_compile.py)
+    # it; a row with none reads the part's zero row. One take a part,
+    # rows of all F features; their sum is no pass of its own, the
+    # chip's compiler fuses it into the consumer that reads the result
+    # (tests/test_tpu_compile.py)
     with jax.named_scope(scope + "unpermute"):
-        outs = [jnp.take(res, inv, axis=0, mode="clip")
-                for res, inv in zip(results, inv_perm)]
-        return functools.reduce(jnp.add, outs)
+        out = functools.reduce(jnp.add, [
+            jnp.take(sums, inv + b, axis=0, mode="clip")
+            for inv, b in zip(inv_perm, bases)])
+    if n_s * slab > f:
+        with jax.named_scope(scope + "relayout"):
+            out = out[:, :f]
+    return out
 
 
-def _part_sums(fbuf, idx_mats, chunk_elems, scope):
-    """One part's buckets (bucket_aggregate): fbuf [R, f] is the part's
-    source rows, the tables index it with R as the zero sentinel.
-    Returns the f32 concatenation of the buckets' sums and one zero
-    row, the operand of the part's inverse permutation."""
+def _part_sums(fbuf, idx_mats, sums, base, col, chunk_elems, scope):
+    """One part's buckets over one slab (bucket_aggregate): fbuf [R, f]
+    is the part's source rows in the slab's columns, the tables index
+    it with R as the zero sentinel. Each bucket's sums are written in
+    place into `sums`, from row `base` on in bucket order, at columns
+    [col, col + f); returns the buffer."""
     f = fbuf.shape[-1]
     with jax.named_scope(scope + "gather"):
         fbuf_pad = jnp.concatenate(
@@ -631,64 +711,38 @@ def _part_sums(fbuf, idx_mats, chunk_elems, scope):
         if _rides_as_words(fbuf.dtype, f):
             table, dt = _pack_words(fbuf_pad), fbuf.dtype
 
-    outs = []
+    def write(sums, mat, row):
+        # the word path's two byte planes each go straight to their
+        # half of the slab's columns: no pass joins them
+        planes = _gather_sum(table, mat, scope, dt)
+        with jax.named_scope(scope + "reduce"):
+            c = col
+            for plane in planes:
+                sums = jax.lax.dynamic_update_slice(sums, plane, (row, c))
+                c = c + plane.shape[-1]
+            return sums
+
+    row = base
     for mat in idx_mats:
         w, n_b = mat.shape
         if n_b == 0:
-            outs.append(jnp.zeros((0, f), jnp.float32))
             continue
         rows_per_chunk, n_chunks = chunk_rows(w, n_b, f, chunk_elems)
         if n_chunks == 1:
-            outs.append(_gather_sum(table, mat, scope, dt))
-            continue
+            sums = write(sums, mat, row)
+        else:
+            def body(sums, i):
+                # the last chunk starts early enough to be whole: it
+                # writes under ROW_TILE * n_chunks rows of the chunk
+                # before it again, the same values
+                start = jnp.minimum(i * rows_per_chunk, n_b - rows_per_chunk)
+                m = jax.lax.dynamic_slice_in_dim(mat, start, rows_per_chunk,
+                                                 axis=1)
+                return write(sums, m, row + start), None
 
-        def body(out, i):
-            # the last chunk starts early enough to be whole: it writes
-            # under ROW_TILE * n_chunks rows of the chunk before it
-            # again, the same values
-            start = jnp.minimum(i * rows_per_chunk, n_b - rows_per_chunk)
-            m = jax.lax.dynamic_slice_in_dim(mat, start, rows_per_chunk,
-                                             axis=1)
-            part = _gather_sum(table, m, scope, dt)
-            with jax.named_scope(scope + "reduce"):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    out, part, start, axis=0), None
-
-        with jax.named_scope(scope + "reduce"):
-            out0 = jnp.zeros((n_b, f), jnp.float32)
-            # a scan's carry keeps its type: under shard_map the result
-            # varies over the mesh axes its inputs vary over, from the
-            # first iteration on
-            vma = jax.typeof(fbuf_pad).vma | jax.typeof(mat).vma
-            if vma:
-                out0 = jax.lax.pcast(out0, tuple(vma), to="varying")
-        outs.append(jax.lax.scan(body, out0, jnp.arange(n_chunks))[0])
-    with jax.named_scope(scope + "unpermute"):
-        return jnp.concatenate(outs + [jnp.zeros((1, f), jnp.float32)],
-                               axis=0)
-
-
-def _slabbed_aggregate(fbuf, idx_mats, inv_perm, chunk_elems, chunk_edges,
-                       slab, scope=""):
-    """Run bucket_aggregate per feature slab of `slab` elements, scanning
-    over a [S, R, slab] re-layout so each slab is a compact operand."""
-    r, f = fbuf.shape
-    n_s = -(-f // slab)
-    pad_f = n_s * slab - f
-    with jax.named_scope(scope + "relayout"):
-        if pad_f:
-            fbuf = jnp.pad(fbuf, ((0, 0), (0, pad_f)))
-        slabs = fbuf.reshape(r, n_s, slab).swapaxes(0, 1)  # [S, R, slab]
-
-    def one(_, sl):
-        out = bucket_aggregate(sl, idx_mats, inv_perm, chunk_elems,
-                               chunk_edges, slab=0, scope=scope)
-        return None, out
-
-    _, outs = jax.lax.scan(one, None, slabs)  # [S, n_out, slab]
-    with jax.named_scope(scope + "relayout"):
-        out = outs.swapaxes(0, 1).reshape(-1, n_s * slab)
-        return out[:, :f] if pad_f else out
+            sums, _ = jax.lax.scan(body, sums, jnp.arange(n_chunks))
+        row += n_b
+    return sums
 
 
 class TablePart(NamedTuple):
@@ -1143,11 +1197,15 @@ def pad_stats(tables: Dict[str, np.ndarray], stem: str, n_src_rows: int,
 
 
 def bucket_pad_stats(tables: Dict[str, np.ndarray], n_max: int,
-                     n_src_rows: int, stem: str = "bkt") -> dict:
+                     n_src_rows: int, stem: str = "bkt",
+                     width: int = SLAB_BYTES) -> dict:
     """pad_stats of both directions of build_sharded_bucket_tables'
-    tables (or, stem 'blkrem', of the block kernel's remainder)."""
-    return {"fwd": pad_stats(tables, stem + "_fwd", n_src_rows),
-            "bwd": pad_stats(tables, stem + "_bwd", n_max)}
+    tables (or, stem 'blkrem', of the block kernel's remainder), each
+    with the `result_bytes` one call writes at `width` features (the
+    widest in-step aggregation's; one device's call)."""
+    return {d: {**pad_stats(tables, f"{stem}_{d}", rows),
+                "result_bytes": result_bytes(tables, f"{stem}_{d}", width)}
+            for d, rows in (("fwd", n_src_rows), ("bwd", n_max))}
 
 
 def validate_bucket_tables(tables: Dict[str, np.ndarray], n_max: int,

@@ -472,7 +472,8 @@ class Trainer:
             n_edges=[int(np.count_nonzero(d < self.sg.n_max))
                      for d in self.sg.edge_dst])
         self.tables_pad = bucket_pad_stats(
-            self._bucket_tables, self.sg.n_max, n_src_rows)
+            self._bucket_tables, self.sg.n_max, n_src_rows,
+            width=self._step_width())
 
     def _use_block(self) -> None:
         from ..ops.block_spmm import (build_sharded_block_tables,
@@ -500,7 +501,8 @@ class Trainer:
         validate_bucket_tables(self._block_tables, self.sg.n_max,
                                n_src_rows, stem="blkrem")
         self.tables_pad = bucket_pad_stats(
-            self._block_tables, self.sg.n_max, n_src_rows, stem="blkrem")
+            self._block_tables, self.sg.n_max, n_src_rows, stem="blkrem",
+            width=self._step_width())
         with self._timed("tables"):
             rem = table_edges(self._block_tables, "blkrem_fwd", n_src_rows)
             dense = validate_dense_tables(
@@ -533,7 +535,7 @@ class Trainer:
         # those aggregations
         in_step = [self._layer_width(i)
                    for i in self._graph_layer_range()]
-        step_width = max(in_step, default=width)
+        step_width = self._step_width()
         sig = tuner.signature_for(
             width=width, step_width=step_width,
             block_tile=cfg.block_tile,
@@ -710,6 +712,12 @@ class Trainer:
         (reference feature_buffer.py:60-61, model.py:45-46)."""
         start = 1 if self.cfg.use_pp else 0
         return range(start, self.cfg.n_graph_layers)
+
+    def _step_width(self) -> int:
+        """The widest operand an in-step aggregation sees: under use_pp
+        the first layer's is precomputed once, outside the step."""
+        return max((self._layer_width(i) for i in self._graph_layer_range()),
+                   default=max(self.cfg.layer_sizes[:self.cfg.n_graph_layers]))
 
     def _layer_width(self, i: int) -> int:
         # input width of graph layer i as seen by the exchange; under

@@ -61,7 +61,8 @@ def main():
             for (lo, hi), m in zip(bounds, mats):
                 table = _pack_words(jnp.concatenate(
                     [x[lo:hi], jnp.zeros((1, 256), dt)]))
-                acc = acc + _gather_sum(table, m, "", dt)
+                acc = acc + jnp.concatenate(_gather_sum(table, m, "", dt),
+                                            axis=-1)
             return acc
 
         x = jnp.asarray(rng.standard_normal((n_rows, 256)),

@@ -9,6 +9,7 @@ import pytest
 from pipegcn_tpu.graph import synthetic_graph
 from pipegcn_tpu.models import ModelConfig
 from pipegcn_tpu.ops.bucket_spmm import (
+    DEFAULT_CHUNK_ELEMS,
     BucketPlan,
     bucket_aggregate,
     build_tables_for_edges,
@@ -314,9 +315,10 @@ def test_scan_names_the_kernels_work():
 
 
 def test_slabbed_aggregate_names_its_relayout(edges):
-    """Rows wider than a slab go through the feature-slab transposes:
-    their copies are `relayout`, and a prefix (the block kernel's
-    remainder) renames all four scopes."""
+    """Rows wider than a slab and no whole number of slabs wide are
+    padded with zero columns to whole slabs and cut back after the
+    takes: those copies are `relayout`, and a prefix (the block
+    kernel's remainder) renames all four scopes."""
     import re
 
     src, dst, n_out, n_src = edges
@@ -641,7 +643,8 @@ def test_every_byte_widens_as_astype(fmt):
     table = jnp.stack([every, every[::-1]], axis=1)     # [256, 2]
     words = _pack_words(table)
     assert words.dtype == jnp.uint16 and words.shape == (256, 1)
-    got = np.asarray(_widen_sum(words[None], dt))       # one slot: no sum
+    # one slot: no sum; the two halves of the row, low bytes first
+    got = np.concatenate(_widen_sum(words[None], dt), axis=-1)
     np.testing.assert_array_equal(got[:, 0], want)
     np.testing.assert_array_equal(got[:, 1], want[::-1])
     # unsigned and byte for byte: the sentinel's zero row stays zero
@@ -1193,15 +1196,29 @@ PART_TRANSPORTS = {"float32": (jnp.float32, None),
                    "fp8-words": (jnp.float8_e4m3fn, "float8")}
 
 
+# (features, slab elements, chunk budget in elements) of the parity
+# cases: three whole slabs, a width that is no whole number of slabs
+# (padded to three), one slab; chunked buckets beside unchunked ones,
+# or none chunked
+PART_LAYOUTS = {"slabs": (24, 8, 8 * 32 * 3),
+                "ragged-slabs": (20, 8, 8 * 32 * 3),
+                "one-slab": (8, 8, 8 * 32 * 3),
+                "unchunked": (24, 8, DEFAULT_CHUNK_ELEMS)}
+
+
+@pytest.mark.parametrize("layout", sorted(PART_LAYOUTS))
 @pytest.mark.parametrize("transport", sorted(PART_TRANSPORTS))
 @pytest.mark.parametrize("k", [1, 2, 3])
-def test_parts_sum_what_one_table_sums(k, transport):
+def test_parts_sum_what_one_table_sums(k, transport, layout):
     """A direction cut by source rows into k parts (each its own table,
     zero row and fitted widths), forward and transpose, through feature
-    slabs and a chunked bucket: on integer-valued features every sum is
-    the uncut table's and the dense one's, exactly (the same f32 sums in
-    another order); the closure's VJP is the uncut one's within f32
-    tolerance. fp8 rides the gathers as 16-bit words."""
+    slabs (whole ones, a width padded to whole ones, or one) and
+    chunked buckets beside unchunked ones (or none chunked), with
+    destination rows that have edges but none in some part: on
+    integer-valued features every sum is the segment sum's and the
+    dense one's, exactly (the same f32 sums in another order); the
+    closure's VJP is the uncut one's within f32 tolerance. fp8 rides
+    the gathers as 16-bit words."""
     from pipegcn_tpu.ops.bucket_spmm import _rides_as_words, chunk_rows
 
     src, dst, n_out, n_src = _bucket_edges(19, seed=k, n_rows=260,
@@ -1210,19 +1227,33 @@ def test_parts_sum_what_one_table_sums(k, transport):
     cut = BucketPlan(src, dst, n_out, n_src, parts=(k, k))
     assert (cut.fwd.k, cut.bwd.k) == (k, k)
     dt, rem_dtype = PART_TRANSPORTS[transport]
-    f, slab, chunk = 24, 8, 8 * 32 * 3
+    f, slab, chunk = PART_LAYOUTS[layout]
     assert _rides_as_words(dt, slab) == (transport == "fp8-words")
-    assert any(chunk_rows(m.shape[0], m.shape[1], slab, chunk)[1] > 1
-               for p in cut.fwd.parts for m in p.mats if m.shape[1])
+    chunked = [chunk_rows(m.shape[0], m.shape[1], slab, chunk)[1] > 1
+               for p in cut.fwd.parts for m in p.mats if m.shape[1]]
+    assert any(chunked) == (layout != "unchunked")
+    if k > 1:
+        assert not all(chunked)
+        # rows with an edge, but none in some part: they read that
+        # part's zero row
+        assert any(((d == 0) & (np.bincount(dst, minlength=n_out) > 0))
+                   .any() for d in cut.fwd.degs)
     rng = np.random.default_rng(k)
     x = rng.integers(-3, 4, (n_src, f)).astype(np.float32)
     g = rng.integers(-3, 4, (n_out, f)).astype(np.float32)
     a = _dense(src, dst, n_out, n_src)
-    for plan_dir, feats, want in ((cut.fwd, x, a @ x), (cut.bwd, g, a.T @ g)):
-        got = bucket_aggregate(jnp.asarray(feats, dt),
-                               *_as_operands(plan_dir), chunk_elems=chunk,
-                               slab=slab)
-        assert np.array_equal(np.asarray(got), want)
+    agg = jax.jit(lambda v, m, i: bucket_aggregate(
+        v, m, i, chunk_elems=chunk, slab=slab))
+    for plan_dir, feats, s, d, rows in ((cut.fwd, x, src, dst, n_out),
+                                        (cut.bwd, g, dst, src, n_src)):
+        got = agg(jnp.asarray(feats, dt), *_as_operands(plan_dir))
+        want = jax.ops.segment_sum(jnp.asarray(feats)[s], d, rows)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        assert np.array_equal(np.asarray(got),
+                              (a if plan_dir is cut.fwd else a.T) @ feats)
+    # the closures' VJP, once a part count and transport
+    if layout != "slabs":
+        return
     deg = jnp.asarray(np.maximum(np.bincount(dst, minlength=n_out), 1),
                       jnp.float32)
     outs = []
@@ -1234,6 +1265,58 @@ def test_parts_sum_what_one_table_sums(k, transport):
         outs.append((np.asarray(y), np.asarray(vjp(jnp.asarray(g))[0])))
     for u, v in zip(*outs):
         np.testing.assert_allclose(v, u, rtol=1e-6, atol=1e-6)
+
+
+# Yelp's fwd tables (two parts of 358,424 source rows, its F of 512 two
+# slabs of the fp8 transport) and Reddit's remainder (one part, one
+# slab), as the compiled-program tests describe them: (table shapes a
+# part, destination rows, F)
+_RESULT_CASES = {
+    "yelp": ([[(3, 137_472), (6, 115_168), (19, 2656)],
+              [(3, 136_704), (7, 89_408), (19, 6784)]], 716_847, 512),
+    "reddit": ([[(90, 55_552), (104, 59_008), (538, 416)]], 232_965, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RESULT_CASES))
+def test_result_bytes_is_what_the_kernel_writes(case):
+    """`result_bytes`, read off a direction's tables as the builder keys
+    them, is the float32 bytes bucket_aggregate's traced program
+    allocates for its sums (one buffer of every part's bucket sums and
+    zero row) and writes in its takes (one a part, [n_out, F]): Yelp's
+    two parts of two slabs, Reddit's one of one. Nothing runs."""
+    from pipegcn_tpu.ops.bucket_spmm import (direction_tables,
+                                             part_heights, result_bytes)
+
+    parts, n_out, f = _RESULT_CASES[case]
+    sds = jax.ShapeDtypeStruct
+    tables = {}
+    for p, shapes in enumerate(parts):
+        stem = "bkt_fwd" if p == 0 else f"bkt_fwd_p{p}"
+        tables[f"{stem}_inv"] = sds((n_out,), jnp.int32)
+        tables.update({f"{stem}_{b:02d}": sds(sh, jnp.int32)
+                       for b, sh in enumerate(shapes)})
+    mats, invs = direction_tables(tables, "bkt_fwd")
+    jaxpr = jax.make_jaxpr(lambda x, m, i: bucket_aggregate(x, m, i))(
+        sds((n_out, f), jnp.float8_e4m3fn), mats, invs)
+
+    def f32_outs(jx, prims):
+        for eqn in jx.eqns:
+            if eqn.primitive.name in prims:
+                yield from (v.aval for v in eqn.outvars
+                            if v.aval.dtype == jnp.float32)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from f32_outs(sub, prims)
+
+    sums = list(f32_outs(jaxpr.jaxpr, {"empty"}))
+    takes = [a for a in f32_outs(jaxpr.jaxpr, {"gather"})
+             if a.shape[0] == n_out]
+    heights = part_heights(mats if len(parts) > 1 else [mats])
+    assert [a.shape for a in sums] == [(sum(heights), f)]
+    assert [a.shape for a in takes] == [(n_out, f)] * len(parts)
+    made = sum(4 * int(np.prod(a.shape)) for a in sums + takes)
+    assert result_bytes(tables, "bkt_fwd", f) == made
+    assert heights == [sum(r for _, r in shapes) + 1 for shapes in parts]
 
 
 @pytest.mark.parametrize("n_rows,k", [(232_966, 1), (393_215, 1),

@@ -415,6 +415,34 @@ def test_schema_v21_drift_guard():
         assert obs_schema.SCHEMA_VERSION > 21
 
 
+# FROZEN copy of the v22 additions (what one aggregation writes for its
+# float32 sums, ops/bucket_spmm.py result_bytes). Same contract as the
+# earlier guards.
+_V22_TABLES_PAD_FIELDS = {**_V19_TABLES_PAD_FIELDS,
+                          "result_bytes": "integer"}
+
+
+def test_schema_v22_drift_guard():
+    if obs_schema.SCHEMA_VERSION == 22:
+        for frozen, live, what in (
+                (_V22_TABLES_PAD_FIELDS, obs_schema.TABLES_PAD_FIELDS,
+                 "run tables_pad"),
+                (_V18_TABLES_PAD_DENSE_FIELDS,
+                 obs_schema.TABLES_PAD_DENSE_FIELDS, "run tables_pad"),
+                (_V20_SPAN_COUNTER_FIELDS, obs_schema.SPAN_COUNTER_FIELDS,
+                 "span"),
+                (_V20_SPAN_BLOCK_FIELDS, obs_schema.SPAN_BLOCK_FIELDS,
+                 "compute span"),
+                (_V21_RUN_DROPOUT_FIELDS, obs_schema.RUN_DROPOUT_FIELDS,
+                 "run")):
+            for name, tag in frozen.items():
+                assert live.get(name) == tag, (
+                    f"schema field {what}.{name} removed or retyped "
+                    f"without bumping SCHEMA_VERSION")
+    else:
+        assert obs_schema.SCHEMA_VERSION > 22
+
+
 def test_validate_run_record_dropout_masks():
     """A run record that says what the step keeps of its dropout masks
     says both numbers, as integers (v21); one that says neither passes."""
@@ -435,11 +463,13 @@ def test_validate_run_record_tables_pad():
     direction; where a direction says what the block kernel's dense
     half stores it says all of it (v18); every direction says how its
     source rows are cut for the gathers, its widths a list a part
-    (v19); null under `xla`."""
+    (v19), and the float32 bytes one call writes for its sums (v22);
+    null under `xla`."""
     run = {"event": "run", "schema_version": obs_schema.SCHEMA_VERSION,
            "time_unix": 0.0, "config": {}, "device": {}, "mesh": {}}
     rows = {"widths": [[7, 32]], "slots": 1036, "edges": 1000,
-            "pad_ratio": 1.036, "parts": 1, "part_rows": 232_966}
+            "pad_ratio": 1.036, "parts": 1, "part_rows": 232_966,
+            "result_bytes": 356_222_976}
     dense = {"dense_blocks": 38744, "dense_slots": 46135,
              "dense_pad": 1.1908, "a_bytes": 377_937_920}
     validate_record(run)
@@ -464,6 +494,12 @@ def test_validate_run_record_tables_pad():
     with pytest.raises(ValueError, match="tables_pad.fwd.*part_rows"):
         validate_record({**run, "tables_pad": {"fwd": {
             k: v for k, v in cut.items() if k != "part_rows"}}})
+    with pytest.raises(ValueError, match="tables_pad.bwd.*result_bytes"):
+        validate_record({**run, "tables_pad": {"fwd": cut, "bwd": {
+            k: v for k, v in cut.items() if k != "result_bytes"}}})
+    with pytest.raises(ValueError, match="expected integer"):
+        validate_record({**run, "tables_pad": {"fwd": {
+            **cut, "result_bytes": 3.9e9}}})
 
 
 def test_validate_record():

@@ -102,8 +102,10 @@ CASES = {
 
 
 def _compiled(one_chip, case, scope=""):
-    """The compiled text of bucket_aggregate over CASES[case]'s tables:
-    one list of them, or a list a part and a permutation a part."""
+    """The compiled text of bucket_aggregate over CASES[case]'s tables
+    (one list of them, or a list a part and a permutation a part), as
+    its callers use it: the result divided by the destination rows'
+    degrees (make_bucket_spmm_fn's and the block kernel's `scale`)."""
     shapes, n_src, f, dt = CASES[case]
     sds = jax.ShapeDtypeStruct
 
@@ -116,10 +118,11 @@ def _compiled(one_chip, case, scope=""):
             [inv] * len(shapes)
     else:
         mats, invs = [idx(s) for s in shapes], inv
-    fn = jax.jit(lambda x, mats, inv: bucket_aggregate(x, mats, inv,
-                                                       scope=scope))
-    return fn.lower(sds((n_src, f), dt, sharding=one_chip), mats,
-                    invs).compile().as_text()
+    fn = jax.jit(lambda x, mats, inv, deg: bucket_aggregate(
+        x, mats, inv, scope=scope) / deg[:, None])
+    return fn.lower(sds((n_src, f), dt, sharding=one_chip), mats, invs,
+                    sds((n_src,), jnp.float32, sharding=one_chip)
+                    ).compile().as_text()
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -202,7 +205,7 @@ def test_every_gather_reads_a_table_in_s1(one_chip, case):
     S(1) (a part's table is packed once the part before it is summed;
     interleaved, a part's chunk loops read it from HBM). The parts meet
     in the unpermute: one take a part, and their sum is no pass of its
-    own (it rides in the fusion that writes the slab's result)."""
+    own (it rides in the fusion that consumes the result)."""
     shapes, n_src, f, dt = CASES[case]
     k = len(shapes) if isinstance(shapes[0], list) else 1
     assert source_parts(n_src) == k
@@ -227,7 +230,85 @@ def test_every_gather_reads_a_table_in_s1(one_chip, case):
     takes = [o for o in ops if re.search(r"(^|/)unpermute/", o[3])
              and o[2] == "fusion" and o[1].startswith(f"f32[{n_src},")]
     assert len(takes) == k, takes
-    assert not [o for o in ops if re.search(r"(^|/)unpermute/add", o[3])]
+    assert not [o for o in ops if re.search(r"(^|/)unpermute/add", o[3])
+                and o[1].startswith("f32")]
+
+
+@pytest.mark.parametrize("case", ["yelp-e4m3-parts", "reddit-e4m3"])
+def test_each_row_sum_is_written_once(one_chip, case):
+    """Yelp's two parts of two slabs and Reddit's one of one: under the
+    aggregation's scopes every destination row's float32 sum is written
+    once, in place, into one buffer of every part's sums, which is
+    allocated and never filled (only each part's zero row is written
+    as such), and each part's take writes [n_out, F], the result's own
+    layout: no zero fill of a bucket's sums, no stacked [S, n_out,
+    slab] slab output, no copy, transpose, pad or concatenation of the
+    result, and no other f32 array of n_out rows is written (one take a
+    part, at most one a slab). What the counter `result_bytes` says is
+    what the program allocates for its sums and writes in its takes."""
+    from pipegcn_tpu.ops.bucket_spmm import part_heights, result_bytes
+
+    shapes, n_out, f, dt = CASES[case]
+    parts = shapes if isinstance(shapes[0], list) else [shapes]
+    k, slab = len(parts), SLAB_BYTES // jnp.dtype(dt).itemsize
+    n_s = -(-f // slab)
+    hlo = _compiled(one_chip, case)
+    plumbing = ("parameter", "tuple", "get-tuple-element", "bitcast",
+                "while", "copy-start", "copy-done")
+    ops = [o for o in _scheduled(hlo) if o[2] not in plumbing
+           and re.search(r"(^|/)(gather|reduce|unpermute|relayout)(/|$)",
+                         o[3])]
+    assert ops
+    f32 = [(o, n) for o in ops for dt_, _, n in _arrays(o[1])
+           if dt_ == "f32"]
+    # zero fills: only the parts' zero rows, one row of F each (or
+    # fused into their write)
+    fills = [(o, n) for o, n in f32
+             if o[2] == "broadcast" or o[0].startswith("broadcast")]
+    assert all(n == f for _, n in fills), fills
+    # the buffer of sums: allocated once, [sum of the parts' heights,
+    # F] (scheduled alone, or inside the fusion that writes the first
+    # zero row)
+    mats = [[jax.ShapeDtypeStruct(shape, jnp.int32) for shape in p]
+            for p in parts]
+    heights = part_heights(mats)
+    buffer = f"f32[{sum(heights)},{f}]"
+    allocs = [line for line in hlo.splitlines()
+              if 'custom_call_target="AllocateBuffer"' in line]
+    assert len(allocs) == 1 and f"= {buffer}" in allocs[0], allocs
+    alloc = [o for o in ops if o[2] == "custom-call"]
+    assert all(o[1].startswith(buffer) for o in alloc), alloc
+    # nothing of the result is stacked by slab, moved or laid out again
+    big = n_out * slab
+    assert not [o for o, n in f32 if n >= big and (
+        o[2] in ("copy", "transpose", "pad", "concatenate")
+        or re.search(r"copy|transpose|pad|concatenate", o[0]))]
+    assert not [o for o in ops if re.match(rf"f32\[\d+,{n_out},", o[1])]
+    # the writes of n_out rows: one take a part, [n_out, F], no more
+    # than one a slab; the in-place writes into the buffer are its size
+    rows = [o for o, n in f32 if n >= big and o[2] != "dynamic-update-slice"
+            and o not in alloc]
+    assert len(rows) == k <= n_s, rows
+    assert all(o[1].startswith(f"f32[{n_out},{f}]") and o[2] == "fusion"
+               and re.search(r"(^|/)unpermute/", o[3]) for o in rows), rows
+    written = [o for o, n in f32 if o[2] == "dynamic-update-slice"
+               or "dynamic-update-slice" in o[0]]
+    assert written and all(o[1].startswith(buffer) for o in written), \
+        written
+    # and every other f32 write is a chunk's sums, written by its reduce
+    others = [o for o, n in f32 if o not in alloc + rows + written
+              and (o, n) not in fills]
+    assert others and all(o[2] == "fusion" and "reduce" in o[0]
+                          and n < big for o, n in f32 if o in others), others
+    # the counter reads what the kernel allocates and takes, off the
+    # direction's tables as the builder stacks them (stack_direction)
+    tables = {}
+    for p, part in enumerate(mats):
+        stem = "bkt_fwd" if p == 0 else f"bkt_fwd_p{p}"
+        tables[f"{stem}_inv"] = jax.ShapeDtypeStruct((n_out,), jnp.int32)
+        tables.update({f"{stem}_{b:02d}": m for b, m in enumerate(part)})
+    made = 4 * (sum(heights) * f + sum(n for o, n in f32 if o in rows))
+    assert result_bytes(tables, "bkt_fwd", f) == made
 
 
 # The dense classes of the Reddit cells (PERF.md section 5, PR 37: the
